@@ -93,6 +93,7 @@ from bluefog_tpu.utils import (
     timeline_context,
 )
 from bluefog_tpu.utils.checkpoint import CheckpointManager, run_with_restart
+from bluefog_tpu.utils.compile_cache import configure_compile_cache
 from bluefog_tpu import metrics
 from bluefog_tpu.metrics import metrics_active, metrics_start, metrics_stop
 from bluefog_tpu import blackbox

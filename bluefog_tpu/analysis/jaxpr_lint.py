@@ -104,7 +104,7 @@ def _iter_axis_names(params: Dict[str, Any]) -> Iterable[str]:
 
 def _sub_jaxprs(value: Any):
     """Yield every (Closed)Jaxpr reachable from one eqn param value."""
-    from jax.core import ClosedJaxpr, Jaxpr
+    from jax.extend.core import ClosedJaxpr, Jaxpr
 
     if isinstance(value, ClosedJaxpr):
         yield value.jaxpr

@@ -39,7 +39,7 @@ from typing import List, Optional
 
 from bluefog_tpu.analysis.report import Diagnostic
 
-__all__ = ["check_republish_sites", "check_file"]
+__all__ = ["find_republish_sites", "check_file"]
 
 _VOCAB_RE = re.compile(r"(?:^|_)(resync|anchor|cursor)(?:_|$|s$)")
 _INTAKE_NAMES = ("leaves", "Snapshot")
@@ -121,7 +121,7 @@ def _scan_function(fn: ast.AST, name: str, filename: str
         pass_name="relay-lint", subject=name)]
 
 
-def check_republish_sites(source: str, *, filename: str = "<source>",
+def find_republish_sites(source: str, *, filename: str = "<source>",
                           relay_module: Optional[bool] = None
                           ) -> List[Diagnostic]:
     """Lint one Python source blob for guard-free re-publish hops."""
@@ -153,4 +153,4 @@ def check_file(path: str) -> List[Diagnostic]:
         return [Diagnostic(
             "warning", "BF-RLY003", f"could not read {path}: {e}",
             pass_name="relay-lint", subject=os.path.basename(path))]
-    return check_republish_sites(src, filename=path)
+    return find_republish_sites(src, filename=path)

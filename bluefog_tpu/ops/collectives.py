@@ -123,19 +123,6 @@ def fuse_apply(fn, x, *, threshold_bytes: int = 8 << 20):
     return jax.tree_util.tree_unflatten(treedef, out)
 
 
-def axis_size(axis_name) -> int:
-    """Size of a named mesh axis, as a trace-time Python int.
-
-    ``jax.lax.axis_size`` only exists in newer jax releases; on older ones
-    the pre-API idiom ``psum(1, axis)`` folds to the same constant at
-    trace time.
-    """
-    size = getattr(lax, "axis_size", None)
-    if size is not None:
-        return size(axis_name)
-    return lax.psum(1, axis_name)
-
-
 # one group token per neighbor_allreduce_dynamic call site: the switch's
 # branches are mutually exclusive at runtime, so their (identical) id
 # leases must not be audited against each other — but two DIFFERENT
@@ -648,7 +635,7 @@ def neighbor_allreduce_aperiodic(x, mixing_matrix, axis_name: str,
     See :func:`bluefog_tpu.topology.dynamic.one_peer_exp2_mixing_matrix` for
     a jittable step->W builder.
     """
-    n = axis_size(axis_name)
+    n = lax.axis_size(axis_name)
     i = lax.axis_index(axis_name)
     W = jnp.asarray(mixing_matrix, jnp.float32)
     if W.shape != (n, n):
@@ -789,7 +776,7 @@ def allreduce(x, axis_name: str, *, average: bool = True):
     def one(leaf):
         s = lax.psum(leaf, axis_name)
         if average:
-            n = axis_size(axis_name)
+            n = lax.axis_size(axis_name)
             s = (s.astype(_acc_dtype(leaf)) / n).astype(leaf.dtype)
         return s
 

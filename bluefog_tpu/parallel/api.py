@@ -22,14 +22,13 @@ from __future__ import annotations
 
 import contextlib
 import functools
-import inspect
 import threading
-from typing import Any, Dict, List, Optional, Sequence
+from typing import Dict, Optional
 
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax import lax
+from jax import lax, shard_map
 from jax.sharding import PartitionSpec as P
 
 from bluefog_tpu import ops as _ops
@@ -39,59 +38,7 @@ from bluefog_tpu.topology.graphs import Topology
 from bluefog_tpu.topology.schedule import GossipSchedule, build_schedule
 from bluefog_tpu.utils import lockcheck as _lc
 
-try:  # JAX >= 0.4.35
-    from jax import shard_map as _shard_map_mod  # type: ignore
-
-    _shard_map_impl = (_shard_map_mod.shard_map
-                       if hasattr(_shard_map_mod, "shard_map")
-                       else _shard_map_mod)
-except ImportError:  # pragma: no cover
-    from jax.experimental.shard_map import shard_map as _shard_map_impl  # type: ignore
-
-_SHARD_MAP_PARAMS = frozenset(
-    inspect.signature(_shard_map_impl).parameters)
-
-
-@functools.wraps(_shard_map_impl)
-def shard_map(*args, **kwargs):
-    """``jax.shard_map`` with version-portable kwargs.
-
-    The replication-check flag was renamed ``check_rep`` -> ``check_vma``
-    across jax releases; every call site here (and the test suite) uses
-    the new name, so translate to whatever the installed jax accepts —
-    the same boolean under either name — and drop flags it lacks
-    entirely.
-    """
-    for new, old in (("check_vma", "check_rep"), ("check_rep", "check_vma")):
-        if new in kwargs and new not in _SHARD_MAP_PARAMS:
-            val = kwargs.pop(new)
-            if old in _SHARD_MAP_PARAMS:
-                kwargs[old] = val
-    return _shard_map_impl(*args, **kwargs)
-
-
-def abstract_mesh(axis_sizes: Sequence[int], axis_names: Sequence[str]):
-    """``jax.sharding.AbstractMesh`` with version-portable construction
-    (same compat pattern as :func:`shard_map` above).
-
-    Newer jax takes ``AbstractMesh(axis_sizes, axis_names)``; older
-    releases take a single ``shape_tuple`` of ``(name, size)`` pairs.
-    Device-free lowering (program-size censuses, pod-scale compile
-    checks) should come through here so a jax upgrade changes one line.
-    """
-    from jax.sharding import AbstractMesh
-
-    sizes = tuple(int(s) for s in axis_sizes)
-    names = tuple(axis_names)
-    if len(sizes) != len(names):
-        raise ValueError(f"{len(sizes)} axis sizes vs {len(names)} names")
-    try:
-        return AbstractMesh(sizes, names)
-    except TypeError:
-        return AbstractMesh(tuple(zip(names, sizes)))
-
 __all__ = [
-    "abstract_mesh",
     "allreduce",
     "allgather",
     "broadcast",

@@ -2,8 +2,10 @@
 
 The reference ships a C++ core compiled by setup.py's custom build_ext
 (SURVEY.md §2.2 "Build").  Here the shared library is built lazily with g++
-on first use (no pybind11 in the image; plain ``extern "C"`` + ctypes), cached
-next to the sources, and rebuilt when any source is newer than the binary.
+on first use (no pybind11 in the image; plain ``extern "C"`` + ctypes) and
+cached next to the sources under a name that carries the sources' hash — a
+binary built from other sources has another name and can never be loaded,
+whatever its mtime (trees copied with their build products included).
 Everything degrades gracefully: if no C++ toolchain is available,
 ``load()`` returns ``None`` and pure-Python fallbacks take over
 (`bluefog_tpu.utils.timeline`, :class:`PyEngine` below).
@@ -12,6 +14,8 @@ Everything degrades gracefully: if no C++ toolchain is available,
 from __future__ import annotations
 
 import ctypes
+import functools
+import glob
 import os
 import queue as _queue
 import subprocess
@@ -24,22 +28,25 @@ from bluefog_tpu.utils import log
 _CSRC = os.path.join(os.path.dirname(os.path.dirname(__file__)), "csrc")
 _SOURCES = ("logging.cc", "timeline.cc", "engine.cc", "windows.cc",
             "tfrecord.cc")
-_LIB_PATH = os.path.join(_CSRC, "libbf_runtime.so")
+
+
+@functools.lru_cache(maxsize=1)
+def _lib_path() -> str:
+    """``libbf_runtime.<digest of the sources>.so`` next to the sources."""
+    import hashlib
+
+    h = hashlib.sha256()
+    for name in _SOURCES + ("bf_runtime.h",):
+        with open(os.path.join(_CSRC, name), "rb") as f:
+            h.update(name.encode() + b"\0" + f.read() + b"\0")
+    return os.path.join(_CSRC, f"libbf_runtime.{h.hexdigest()[:16]}.so")
+
 
 _lib = None
 _lib_attempted = False
 _build_lock = _lc.lock("runtime.native._build_lock")
 
 _CALLBACK_T = ctypes.CFUNCTYPE(ctypes.c_int, ctypes.c_void_p)
-
-
-def _needs_build() -> bool:
-    if not os.path.exists(_LIB_PATH):
-        return True
-    lib_mtime = os.path.getmtime(_LIB_PATH)
-    srcs = [os.path.join(_CSRC, s) for s in _SOURCES]
-    srcs.append(os.path.join(_CSRC, "bf_runtime.h"))
-    return any(os.path.getmtime(s) > lib_mtime for s in srcs)
 
 
 def build(force: bool = False) -> Optional[str]:
@@ -59,9 +66,10 @@ def build(force: bool = False) -> Optional[str]:
         except Exception:
             lock_file = None
         try:
-            if not force and not _needs_build():
-                return _LIB_PATH
-            tmp = f"{_LIB_PATH}.tmp.{os.getpid()}"
+            lib_path = _lib_path()
+            if not force and os.path.exists(lib_path):
+                return lib_path
+            tmp = f"{lib_path}.tmp.{os.getpid()}"
             cmd = [
                 "g++", "-std=c++17", "-O2", "-fPIC", "-shared", "-pthread",
                 "-Wall", "-o", tmp,
@@ -82,8 +90,12 @@ def build(force: bool = False) -> Optional[str]:
                 if proc.returncode != 0:
                     log.warn("native runtime build failed:\n%s", proc.stderr)
                     return None
-                os.replace(tmp, _LIB_PATH)
-                return _LIB_PATH
+                os.replace(tmp, lib_path)
+                for stale in glob.glob(
+                        os.path.join(_CSRC, "libbf_runtime.*so")):
+                    if stale != lib_path:
+                        os.remove(stale)
+                return lib_path
             finally:
                 if os.path.exists(tmp):
                     try:
@@ -204,17 +216,6 @@ def load() -> Optional[ctypes.CDLL]:
             except OSError as e:
                 log.warn("native runtime load failed: %s", e)
                 _lib = None
-            except AttributeError as e:
-                # A prebuilt .so with mtime newer than the sources (rsync -a,
-                # docker layer) can predate newly added symbols; rebuild once
-                # from source before giving up.
-                log.warn("stale native runtime (%s); rebuilding", e)
-                path = build(force=True)
-                try:
-                    _lib = _bind(ctypes.CDLL(path)) if path else None
-                except (OSError, AttributeError) as e2:
-                    log.warn("native runtime reload failed: %s", e2)
-                    _lib = None
         _lib_attempted = True
         return _lib
 

@@ -211,10 +211,10 @@ class GossipMesh:
                                 use_ici_order=use_ici_order)
 
     def abstract(self):
-        from bluefog_tpu.parallel.api import abstract_mesh
+        from jax.sharding import AbstractMesh
 
         sizes = self.axis_sizes
-        return abstract_mesh(tuple(sizes.values()), tuple(sizes.keys()))
+        return AbstractMesh(tuple(sizes.values()), tuple(sizes.keys()))
 
     def views(self, specs) -> List[ShardView]:
         return [ShardView(specs=specs, axes=self.inner, coord=c)

@@ -32,7 +32,7 @@ __all__ = ["ReaderTreeConfig", "ReaderTreeReport", "run_reader_tree"]
 class ReaderTreeConfig:
     """Shape and physics of one reader-tree run.  ``hop_dt_s`` is the
     mean per-hop push latency (jittered ±50% per edge, seeded);
-    ``kill`` schedules ``(t, tier, index)`` relay deaths; children
+    ``kill`` schedules ``(t, tier, index)`` relay-node deaths; children
     re-parent to the dead relay's parent after ``reparent_dt_s``."""
 
     readers: int = 2048

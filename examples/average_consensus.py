@@ -50,6 +50,7 @@ def main():
     ap.add_argument("--dim", type=int, default=1000)
     ap.add_argument("--topology", choices=sorted(TOPOLOGIES), default="exp2")
     args = ap.parse_args()
+    bf.configure_compile_cache()
 
     n = args.size or len(jax.devices())
     bf.init(topology=TOPOLOGIES[args.topology](n), size=n)

@@ -212,13 +212,11 @@ class TestDegreeCapped:
         """Program-size census at n=64 (pod-scale proxy): the capped
         program must contain an order-of-magnitude fewer collective
         permutes than the full decomposition's 63.  Lowering census runs
-        on an ABSTRACT 64-device mesh (no need for 64 real devices;
-        constructed through the version-portable compat helper — the
-        installed jax's AbstractMesh takes a (name, size) shape tuple)."""
-        from bluefog_tpu.parallel.api import abstract_mesh
+        on an ABSTRACT 64-device mesh (no need for 64 real devices)."""
+        from jax.sharding import AbstractMesh
 
         n = 64
-        mesh64 = abstract_mesh((n,), ("bf",))
+        mesh64 = AbstractMesh((n,), ("bf",))
 
         def lower(cap):
             fn = jax.jit(shard_map(

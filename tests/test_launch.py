@@ -72,6 +72,41 @@ def test_timeline_flag_writes_trace(tmp_path):
     assert any(e["name"] == "launcher_span" for e in events)
 
 
+def test_supervising_parent_never_touches_a_device(tmp_path):
+    """A chip belongs to one process: the --supervise parent must leave it
+    to the child, so it may import jax but never initialise a backend."""
+    script = tmp_path / "child.py"
+    script.write_text("print('CHILD RAN')\n")
+    from tests._util import clean_env
+
+    proc = subprocess.run(
+        [sys.executable, "-c",
+         "import sys\n"
+         "from bluefog_tpu.runtime.launch import main\n"
+         "try:\n"
+         f"    main(['--supervise', '0', {str(script)!r}])\n"
+         "except SystemExit as e:\n"
+         "    assert e.code == 0, e.code\n"
+         "from jax._src import xla_bridge\n"
+         "assert not xla_bridge.backends_are_initialized()\n"
+         "print('PARENT CLEAN')\n"],
+        capture_output=True, text=True, env=clean_env(), cwd=REPO,
+        timeout=180)
+    assert proc.returncode == 0, proc.stderr[-2000:]
+    assert "CHILD RAN" in proc.stdout and "PARENT CLEAN" in proc.stdout
+
+
+def test_no_cluster_to_detect_runs_single_process(tmp_path):
+    """`bfrun-tpu train.py` with no cluster arguments on a machine with
+    nothing to auto-detect warns once and runs the script."""
+    script = tmp_path / "probe.py"
+    script.write_text("print('RAN')\n")
+    r = _run_cli([str(script)], env_extra={"JAX_PLATFORMS": "cpu"})
+    assert r.returncode == 0, r.stderr
+    assert "RAN" in r.stdout
+    assert "jax.distributed.initialize skipped" in r.stderr
+
+
 def test_interactive_repl_smoke():
     """ibfrun-tpu (the ibfrun analog) brings the framework up and serves a
     REPL: pipe a command stream in, assert the banner, evaluated output,

@@ -25,7 +25,30 @@ def lib():
 
 
 def test_build_produces_library(lib):
-    assert os.path.exists(native._LIB_PATH)
+    assert os.path.exists(native._lib_path())
+
+
+def test_library_name_follows_the_sources(tmp_path, monkeypatch):
+    """The binary's name carries its sources' digest: a library built from
+    other sources has another name, so a stale one can never be loaded,
+    whatever its mtime."""
+    import shutil
+
+    current = native._lib_path()
+    csrc = tmp_path / "csrc"
+    shutil.copytree(native._CSRC, csrc,
+                    ignore=shutil.ignore_patterns("libbf_runtime.*"))
+    monkeypatch.setattr(native, "_CSRC", str(csrc))
+    native._lib_path.cache_clear()
+    try:
+        same = native._lib_path()
+        assert os.path.basename(same) == os.path.basename(current)
+        with open(csrc / "logging.cc", "a") as f:
+            f.write("\n// edited\n")
+        native._lib_path.cache_clear()
+        assert os.path.basename(native._lib_path()) != os.path.basename(same)
+    finally:
+        native._lib_path.cache_clear()
 
 
 def test_log_level_roundtrip(lib):

@@ -1,13 +1,12 @@
 """AOT compilation of the Pallas RDMA kernels for a REAL TPU topology.
 
 The RDMA transport (ops/pallas_gossip.py) is interpret-validated for
-semantics, but this environment has no multi-chip slice to execute it on
-(PROFILE.md).  What CAN be proven without hardware: Mosaic lowers and the
-XLA TPU backend **compiles** the kernels for a real 8-chip v5e slice via
-the PJRT topology API — barrier semaphores, remote DMAs, collective ids
-and all.  A kernel that schedules for the target hardware is one step from
-measured; a kernel that only interprets is not.  Skips cleanly when libtpu
-or the topology API is unavailable (same policy as test_overlap_aot).
+semantics here and executed on four v5e chips by ``chip_smoke.py``.  What
+the CPU sandbox can prove for topologies it has no chips for: Mosaic lowers
+and the XLA TPU backend **compiles** the kernels for a real v5e slice via
+the PJRT topology API — barrier semaphores, remote DMAs, collective ids,
+VMEM limits and all.  Skips cleanly when libtpu or the topology API is
+unavailable (same policy as test_overlap_aot).
 
 Marked ``slow`` (same reason as test_overlap_aot): the shared
 session-scoped AOT topology fixture costs ~8 minutes of setup in this
@@ -21,6 +20,8 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+from conftest import aot_topology as _aot_topo  # single skip policy + cache
 
 from bluefog_tpu.ops import pallas_gossip as pg
 from bluefog_tpu.parallel.api import shard_map
@@ -66,6 +67,50 @@ def test_deliver_kernel_compiles_for_v5e(accumulate, tpu_aot_topology):
                              sharding=NamedSharding(mesh, P("bf")))
     txt = fn.lower(x, b).compile().as_text()
     assert "tpu_custom_call" in txt, "deliver kernel was not lowered"
+
+
+@pytest.mark.parametrize("graph", [ExponentialTwoGraph, "full"],
+                         ids=["exp2_2slots", "full_3slots"])
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["f32_wire", "bf16_wire"])
+def test_largest_planner_chunk_compiles_for_v5e_2x2(dtype, graph):
+    """The four-chip host, snake-ordered as ``bf.init`` orders it: a leaf
+    of 2.5 caps chunks into three kernels, two of them at the largest size
+    the planner can emit — each must fit VMEM under the limit it states
+    (before the reduction was tiled, a two-slot f32 kernel was refused from
+    3.3 MiB and a bf16 one from 2.7 MiB).  The deliver kernel is compiled
+    at the window cutoff, the same size."""
+    from bluefog_tpu.ops import collectives as C
+    from bluefog_tpu.topology import FullyConnectedGraph
+    from bluefog_tpu.topology.mapping import ici_ring_order
+
+    devs = ici_ring_order(_aot_topo("v5e:2x2").devices)
+    assert [d.id for d in devs] == [0, 2, 3, 1]
+    n = len(devs)
+    mesh = Mesh(np.array(devs), ("bf",))
+    sched = build_schedule(
+        FullyConnectedGraph(n) if graph == "full" else graph(n))
+    cap = pg.DEFAULT_AUTO_MAX_BYTES
+    itemsize = np.dtype(dtype).itemsize
+    sharding = NamedSharding(mesh, P("bf"))
+
+    elems = (2 * cap + cap // 2) // itemsize
+    fn = jax.jit(shard_map(
+        lambda v: C.neighbor_allreduce(v, sched, "bf", backend="pallas"),
+        mesh=mesh, in_specs=(P("bf"),), out_specs=P("bf"), check_vma=False))
+    x = jax.ShapeDtypeStruct((n, elems), dtype, sharding=sharding)
+    assert fn.lower(x).compile().as_text().count("tpu_custom_call") >= 3
+
+    k = sched.num_slots
+    deliver = jax.jit(shard_map(
+        lambda v, b: pg.deliver_pallas(
+            v[0], b[0], sched, "bf", accumulate=True)[None],
+        mesh=mesh, in_specs=(P("bf"), P("bf")), out_specs=P("bf"),
+        check_vma=False))
+    v = jax.ShapeDtypeStruct((n, cap // itemsize), dtype, sharding=sharding)
+    b = jax.ShapeDtypeStruct((n, k, cap // itemsize), dtype,
+                             sharding=sharding)
+    assert "tpu_custom_call" in deliver.lower(v, b).compile().as_text()
 
 
 # ---------------------------------------------------------------------------
@@ -117,9 +162,6 @@ def mosaic_modules(stablehlo_txt: str):
         ctx.allow_unregistered_dialects = True
         mods.append((cfg, str(ir.Module.parse(raw, ctx))))
     return mods
-
-
-from conftest import aot_topology as _aot_topo  # single skip policy + cache
 
 
 @pytest.mark.parametrize("topo_name", ["v5e:2x4", "v5e:4x4"])

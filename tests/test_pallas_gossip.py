@@ -170,3 +170,41 @@ def test_circulant_shift_extraction():
     assert pallas_gossip.circulant_shifts(build_schedule(RingGraph(N))) == (1, N - 1)
     assert pallas_gossip.circulant_shifts(build_schedule(ExponentialTwoGraph(N))) == (1, 2, 4)
     assert pallas_gossip.circulant_shifts(build_schedule(MeshGrid2DGraph(6))) is None
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["f32_wire", "bf16_wire"])
+def test_tiled_reduction_covers_full_tiles_and_remainder(dtype, monkeypatch):
+    """Payloads beyond one row tile reduce in a loop over full tiles plus a
+    static remainder: rank-distinct values across full tiles + a ragged
+    tail must still equal W @ x (gossip) and land whole (deliver).  The
+    tile is shrunk because the emulation stalls on payloads past ~32 KiB:
+    f32 pads to 40 rows (2 tiles of 16 + 8), bf16 to 48 (1 tile of 32 + 16)."""
+    monkeypatch.setattr(pallas_gossip, "_TILE_ROWS",
+                        16 if dtype == jnp.float32 else 32)
+    topo = ExponentialTwoGraph(N)
+    sched = build_schedule(topo)
+    elems = 40 * 128 - 100
+    x = (jnp.arange(N, dtype=jnp.float32)[:, None]
+         + jnp.linspace(0.0, 1.0, elems)[None, :]).astype(dtype)
+
+    def gossip(xs):
+        return pallas_gossip.neighbor_allreduce_pallas(
+            xs[0], sched, "bf", interpret=True)[None]
+
+    out = _run(gossip, x)
+    ref = topo.weights @ np.asarray(x, np.float64)
+    tol = 1e-6 if dtype == jnp.float32 else 2e-2
+    np.testing.assert_allclose(np.asarray(out, np.float64), ref,
+                               rtol=tol, atol=tol)
+
+    def deliver(xs):
+        bufs = jnp.zeros((sched.num_slots,) + xs[0].shape, xs.dtype)
+        return pallas_gossip.deliver_pallas(
+            xs[0], bufs, sched, "bf", accumulate=True, interpret=True)[None]
+
+    landed = np.asarray(_run(deliver, x), np.float64)
+    src = np.asarray(sched.recv_src)
+    for k in range(sched.num_slots):
+        np.testing.assert_array_equal(
+            landed[:, k], np.asarray(x, np.float64)[src[:, k]])

@@ -789,18 +789,18 @@ class TestTreePlan:
 
 class TestRelayLint:
     def test_guard_free_republish_flagged(self):
-        from bluefog_tpu.analysis.relay_lint import check_republish_sites
+        from bluefog_tpu.analysis.relay_lint import find_republish_sites
 
         bad = (
             "import bluefog_tpu.relay\n"
             "def forward(tbl, snap):\n"
             "    tbl.publish('g', snap.round, snap.leaves)\n")
-        diags = check_republish_sites(bad, filename="bad.py")
+        diags = find_republish_sites(bad, filename="bad.py")
         assert any(d.code == "BF-RLY001" and d.severity == "error"
                    for d in diags)
 
     def test_cursor_guard_passes(self):
-        from bluefog_tpu.analysis.relay_lint import check_republish_sites
+        from bluefog_tpu.analysis.relay_lint import find_republish_sites
 
         ok = (
             "import bluefog_tpu.relay\n"
@@ -809,10 +809,10 @@ class TestRelayLint:
             "    if snap.round <= cursor:\n"
             "        return\n"
             "    tbl.publish('g', snap.round, snap.leaves)\n")
-        assert check_republish_sites(ok, filename="ok.py") == []
+        assert find_republish_sites(ok, filename="ok.py") == []
 
     def test_desync_handler_passes(self):
-        from bluefog_tpu.analysis.relay_lint import check_republish_sites
+        from bluefog_tpu.analysis.relay_lint import find_republish_sites
 
         ok = (
             "from bluefog_tpu.relay import RelayNode\n"
@@ -822,25 +822,25 @@ class TestRelayLint:
             "        tbl.publish('g', snap.round, snap.leaves)\n"
             "    except DeltaDesync:\n"
             "        pass\n")
-        assert check_republish_sites(ok, filename="ok2.py") == []
+        assert find_republish_sites(ok, filename="ok2.py") == []
 
     def test_plain_publisher_out_of_scope(self):
-        from bluefog_tpu.analysis.relay_lint import check_republish_sites
+        from bluefog_tpu.analysis.relay_lint import find_republish_sites
 
         ok = (
             "import bluefog_tpu.relay\n"
             "import numpy as np\n"
             "def publish_model(tbl, rnd, x):\n"
             "    tbl.publish('g', rnd, {'x': x})\n")
-        assert check_republish_sites(ok, filename="pub.py") == []
+        assert find_republish_sites(ok, filename="pub.py") == []
 
     def test_non_relay_module_out_of_scope(self):
-        from bluefog_tpu.analysis.relay_lint import check_republish_sites
+        from bluefog_tpu.analysis.relay_lint import find_republish_sites
 
         src = (
             "def forward(tbl, snap):\n"
             "    tbl.publish('g', snap.round, snap.leaves)\n")
-        assert check_republish_sites(src, filename="other.py") == []
+        assert find_republish_sites(src, filename="other.py") == []
 
     def test_relay_node_itself_is_clean(self):
         from bluefog_tpu.analysis.relay_lint import check_file

@@ -13,7 +13,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 from jax.sharding import Mesh, PartitionSpec as P
-from bluefog_tpu.parallel.api import shard_map  # version-portable check_vma/check_rep
+from bluefog_tpu.parallel.api import shard_map
 
 from bluefog_tpu.models.transformer import GPTConfig, TransformerLM
 from bluefog_tpu.ops.ring_attention import (

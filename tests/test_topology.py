@@ -287,6 +287,19 @@ class TestICIRingOrder:
         assert self._torus_dist(ordered[-1].coords, ordered[0].coords,
                                 dims) == 1
 
+    def test_v5e_2x2_host_order(self):
+        """The four-chip v5e host as JAX reports it (id i at coords
+        (i % 2, i // 2, 0)): the snake is device ids [0, 2, 3, 1] — a
+        PERMUTED mesh, which is what peers addressed by mesh position must
+        survive (checked on hardware by chip_smoke.py)."""
+        from bluefog_tpu.topology.mapping import ici_ring_order
+
+        devs = [self.FakeDev(i, (i % 2, i // 2, 0)) for i in range(4)]
+        ordered = ici_ring_order(devs)
+        assert [d.id for d in ordered] == [0, 2, 3, 1]
+        for a, b in zip(ordered, ordered[1:] + ordered[:1]):
+            assert self._torus_dist(a.coords, b.coords, (2, 2, 1)) == 1
+
     def test_no_coords_falls_back_to_id(self):
         from bluefog_tpu.topology.mapping import ici_ring_order
 
