@@ -102,24 +102,29 @@ def fuse_apply(fn, x, *, threshold_bytes: int = 8 << 20):
     for i, leaf in enumerate(leaves):
         if i not in big:
             groups.setdefault(str(jnp.asarray(leaf).dtype), []).append(i)
-    bufs = {
-        dt: jnp.concatenate([jnp.asarray(leaves[i]).ravel() for i in idxs])
-        for dt, idxs in groups.items()
-    }
+    with jax.named_scope("bf.gossip.fuse"):
+        bufs = {
+            dt: jnp.concatenate([jnp.asarray(leaves[i]).ravel() for i in idxs])
+            for dt, idxs in groups.items()
+        }
     # One fn call over {fused buffers} ∪ {large leaves}: fn is leaf-wise, so
     # large leaves ride the same collective unfused, with no extra copy.
+    # No scope around it: a Pallas kernel is named in the device trace by
+    # the innermost name-stack entry above its call, and the kernels of fn
+    # are found by that name (``shard_map.N``).
     out_all = fn({"fused": bufs,
                   "big": {str(i): leaves[i] for i in sorted(big)}})
     out_bufs, out_big = out_all["fused"], out_all["big"]
     out = [None] * len(leaves)
     for i in big:
         out[i] = out_big[str(i)]
-    for dt, idxs in groups.items():
-        buf, off = out_bufs[dt], 0
-        for i in idxs:
-            sz = int(np.prod(jnp.shape(leaves[i]), dtype=np.int64))
-            out[i] = buf[off:off + sz].reshape(jnp.shape(leaves[i]))
-            off += sz
+    with jax.named_scope("bf.gossip.split"):
+        for dt, idxs in groups.items():
+            buf, off = out_bufs[dt], 0
+            for i in idxs:
+                sz = int(np.prod(jnp.shape(leaves[i]), dtype=np.int64))
+                out[i] = buf[off:off + sz].reshape(jnp.shape(leaves[i]))
+                off += sz
     return jax.tree_util.tree_unflatten(treedef, out)
 
 
@@ -349,15 +354,17 @@ def neighbor_allreduce(
                     collective_id=cid))
                 cid += 1
                 continue
-            flat = leaf.reshape(-1)
+            with jax.named_scope("bf.gossip.pack"):
+                pieces = jnp.array_split(leaf.reshape(-1), n_chunks)
             chunk_outs = []
-            for piece in jnp.array_split(flat, n_chunks):
+            for piece in pieces:
                 chunk_outs.append(pallas_gossip.neighbor_allreduce_pallas(
                     piece, sched, axis_name,
                     self_weight=self_weight, recv_weights=recv_weights,
                     collective_id=cid))
                 cid += 1
-            outs.append(jnp.concatenate(chunk_outs).reshape(leaf.shape))
+            with jax.named_scope("bf.gossip.unpack"):
+                outs.append(jnp.concatenate(chunk_outs).reshape(leaf.shape))
         out = jax.tree_util.tree_unflatten(treedef, outs)
         # per-round wire accounting (identity when metrics are off): each
         # kernel invocation performs one transfer per schedule slot of its
@@ -393,7 +400,8 @@ def neighbor_allreduce(
                 out = out + recv_w[k] * recvd.astype(acc_dt)
         return out.astype(leaf.dtype)
 
-    out = jax.tree_util.tree_map(one, x)
+    with jax.named_scope("bf.gossip.exchange"):
+        out = jax.tree_util.tree_map(one, x)
     # one ppermute per slot per leaf; every slot ships the full tree
     out = _mt.record_collective(
         out, op="neighbor_allreduce",

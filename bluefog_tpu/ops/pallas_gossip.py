@@ -490,17 +490,21 @@ def neighbor_allreduce_pallas(
 
     orig_dtype = x.dtype
     wire = _wire_dtype(orig_dtype)
-    flat = x.astype(wire).reshape(-1)
-    block, true_len = _pad_to_tiles(flat)
+    with jax.named_scope("bf.gossip.pack"):
+        flat = x.astype(wire).reshape(-1)
+        block, true_len = _pad_to_tiles(flat)
 
-    sw = (jnp.asarray(sched.self_weights, jnp.float32)[i]
-          if self_weight is None else jnp.asarray(self_weight, jnp.float32))
-    rw = (jnp.asarray(sched.recv_weights, jnp.float32)[i]
-          if recv_weights is None else jnp.asarray(recv_weights, jnp.float32))
-    sw = sw.reshape(1, 1)
-    rw = rw.reshape(1, -1)
+        sw = (jnp.asarray(sched.self_weights, jnp.float32)[i]
+              if self_weight is None else jnp.asarray(self_weight, jnp.float32))
+        rw = (jnp.asarray(sched.recv_weights, jnp.float32)[i]
+              if recv_weights is None else jnp.asarray(recv_weights, jnp.float32))
+        sw = sw.reshape(1, 1)
+        rw = rw.reshape(1, -1)
 
     kernel = _make_exchange_kernel(shifts, n, axis_name, "gossip", sched.num_slots)
+    # No scope and no name= here (nor around any caller): the kernel's name
+    # in the device trace is the innermost name-stack entry above this call,
+    # and the benchmark finds the gossip kernels by it (``shard_map.N``).
     out = pl.pallas_call(
         kernel,
         out_shape=jax.ShapeDtypeStruct(block.shape, wire),
@@ -521,7 +525,8 @@ def neighbor_allreduce_pallas(
         ),
         interpret=pltpu.InterpretParams() if interpret else False,
     )(block, sw, rw)
-    return out.reshape(-1)[:true_len].reshape(x.shape).astype(orig_dtype)
+    with jax.named_scope("bf.gossip.unpack"):
+        return out.reshape(-1)[:true_len].reshape(x.shape).astype(orig_dtype)
 
 
 def deliver_pallas(
@@ -557,19 +562,21 @@ def deliver_pallas(
 
     orig_dtype = payload.dtype
     wire = _wire_dtype(orig_dtype)
-    flat = payload.astype(wire).reshape(-1)
-    block, true_len = _pad_to_tiles(flat)
     k_slots = len(shifts)
-    bufs_f = bufs.astype(wire).reshape(k_slots, -1)
-    bufs_block = jnp.pad(
-        bufs_f, ((0, 0), (0, block.size - bufs_f.shape[1]))
-    ).reshape((k_slots,) + block.shape)
+    with jax.named_scope("bf.gossip.pack"):
+        flat = payload.astype(wire).reshape(-1)
+        block, true_len = _pad_to_tiles(flat)
+        bufs_f = bufs.astype(wire).reshape(k_slots, -1)
+        bufs_block = jnp.pad(
+            bufs_f, ((0, 0), (0, block.size - bufs_f.shape[1]))
+        ).reshape((k_slots,) + block.shape)
 
-    mask = jnp.asarray(sched.recv_src >= 0, jnp.int32)[i].reshape(1, -1)
+        mask = jnp.asarray(sched.recv_src >= 0, jnp.int32)[i].reshape(1, -1)
 
     kernel = _make_exchange_kernel(
         shifts, n, axis_name, "acc" if accumulate else "put", sched.num_slots
     )
+    # no scope, no name=: as in neighbor_allreduce_pallas
     out_bufs = pl.pallas_call(
         kernel,
         out_shape=jax.ShapeDtypeStruct(bufs_block.shape, wire),
@@ -589,5 +596,6 @@ def deliver_pallas(
         ),
         interpret=pltpu.InterpretParams() if interpret else False,
     )(block, bufs_block, mask)
-    return (out_bufs.reshape(k_slots, -1)[:, : bufs_f.shape[1]]
-            .reshape(bufs.shape).astype(orig_dtype))
+    with jax.named_scope("bf.gossip.unpack"):
+        return (out_bufs.reshape(k_slots, -1)[:, : bufs_f.shape[1]]
+                .reshape(bufs.shape).astype(orig_dtype))

@@ -316,22 +316,30 @@ def decentralized_optimizer(
             # centralized baseline: average gradients, plain step (fused)
             grads = C.fuse_apply(
                 lambda t: C.allreduce(t, axis_name, average=True), grads)
-        updates, base_state = base.update(grads, state.base_state, params)
+        # Phase scopes (bf.<layer>.<phase>, docs/metrics.md "Reading a device
+        # trace"): trace-time metadata only, leaf-level and disjoint.  None may
+        # enclose _combine: a Pallas kernel takes its trace name from the
+        # innermost name-stack entry above it (ops/collectives.py).
+        with jax.named_scope("bf.optim.base_update"):
+            updates, base_state = base.update(grads, state.base_state, params)
 
         k = num_steps_per_communication
 
+        def apply(p):
+            with jax.named_scope("bf.optim.apply"):
+                return optax.apply_updates(p, updates)
+
         def comm_step(p):
             if ct == CommunicationType.allreduce or ct == CommunicationType.empty:
-                new_p = optax.apply_updates(p, updates)
+                new_p = apply(p)
             elif atc:
-                new_p = _combine(optax.apply_updates(p, updates), state.comm_count)
+                new_p = _combine(apply(p), state.comm_count)
             else:  # AWC: gossip(p) has no dependency on updates -> overlaps
                 mixed = _combine(p, state.comm_count)
-                new_p = optax.apply_updates(mixed, updates)
+                new_p = apply(mixed)
             return new_p
 
-        def local_step(p):
-            return optax.apply_updates(p, updates)
+        local_step = apply
 
         if runtime_cadence:
             # the gate is a TRACED operand: (count+1) % comm_every == 0
@@ -355,10 +363,11 @@ def decentralized_optimizer(
         new_count = state.count + 1
 
         # express as optax updates so callers use apply_updates as usual
-        new_updates = jax.tree_util.tree_map(
-            lambda np_, p: (np_.astype(jnp.float32) - p.astype(jnp.float32)).astype(p.dtype),
-            new_params, params,
-        )
+        with jax.named_scope("bf.optim.as_updates"):
+            new_updates = jax.tree_util.tree_map(
+                lambda np_, p: (np_.astype(jnp.float32) - p.astype(jnp.float32)).astype(p.dtype),
+                new_params, params,
+            )
         if _mreg.current() is not None:
             # per-execution step / communication-round counters (comm_inc
             # is the traced local-SGD gate, so skipped rounds don't count);

@@ -271,3 +271,44 @@ def test_chunked_gossip_aot_structure(tpu_aot_topology, monkeypatch):
         1024 <= i < 2048 for i in ids), f"bad collective ids: {ids}"
     # and the whole chunked program still compiles for the real target
     assert "tpu_custom_call" in lowered.compile().as_text()
+
+
+def test_chunked_gossip_keeps_its_trace_names_under_the_phase_scopes(
+        tpu_aot_topology, monkeypatch):
+    """Compiled for the chip, the chunked gossip under ``fuse_apply`` keeps
+    what the benchmark finds it by: every kernel is a side-effecting custom
+    call named ``shard_map.N`` (``gossip_kernel_ms_per_step`` matches
+    ``^shard_map\\.\\d+$`` in the device trace; a scope open above the call,
+    or a ``name=`` on it, would rename it), and the ops around the kernels
+    carry ``bf.gossip.pack`` / ``unpack`` / ``fuse`` / ``split`` in their
+    ``op_name`` (``chipbench/reducers/scope_ms.py`` reads those)."""
+    monkeypatch.setenv("BLUEFOG_TPU_PALLAS_MAX_BYTES", str(64 << 10))
+    from bluefog_tpu.ops import collectives as C
+
+    topo = tpu_aot_topology
+    n = len(topo.devices)
+    mesh = Mesh(np.array(topo.devices), ("bf",))
+    sched = build_schedule(ExponentialTwoGraph(n))
+    sharding = NamedSharding(mesh, P("bf"))
+    tree = {"big": (40_000,), "a": (300, 7), "b": (129,)}  # big: 3 chunks
+
+    def gossip(t):
+        t = jax.tree_util.tree_map(lambda v: v[0], t)
+        out = C.fuse_apply(
+            lambda u: C.neighbor_allreduce(u, sched, "bf", backend="pallas"),
+            t, threshold_bytes=100_000)
+        return jax.tree_util.tree_map(lambda v: v[None], out)
+
+    fn = jax.jit(shard_map(gossip, mesh=mesh, in_specs=(P("bf"),),
+                           out_specs=P("bf"), check_vma=False))
+    x = {k: jax.ShapeDtypeStruct((n,) + shape, jnp.float32, sharding=sharding)
+         for k, shape in tree.items()}
+    text = fn.lower(x).compile().as_text()
+    kernels = _re.findall(
+        r'%([\w.\-]+) = [^\n]*custom_call_target="tpu_custom_call"'
+        r"[^\n]*custom_call_has_side_effect=true", text)
+    assert len(kernels) == 4, kernels        # 3 chunks + the fused buffer
+    assert all(_re.fullmatch(r"shard_map\.\d+", k) for k in kernels), kernels
+    for scope in ("bf.gossip.pack", "bf.gossip.unpack", "bf.gossip.fuse",
+                  "bf.gossip.split"):
+        assert scope in text, scope

@@ -1,0 +1,138 @@
+"""The phase scopes inside the compiled step (``bf.<layer>.<phase>``).
+
+The benchmark reads device time by phase through the HLO's ``op_name``
+(``chipbench/reducers/scope_ms.py``), so the scopes are part of what it
+measures with: each must reach the compiled text, no op may sit under two of
+them (the phases would not add up), and none may be open above a Pallas
+gossip kernel, which would rename the kernel in the device trace.
+"""
+
+import functools
+import re
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import optax
+import pytest
+from jax.sharding import Mesh, PartitionSpec as P
+
+from bluefog_tpu.ops import collectives as C
+from bluefog_tpu.optim import CommunicationType, decentralized_optimizer
+from bluefog_tpu.parallel.api import shard_map
+from bluefog_tpu.topology import ExponentialTwoGraph
+from bluefog_tpu.topology.schedule import build_schedule
+
+N = 4
+OPTIM = {"bf.optim.base_update", "bf.optim.apply", "bf.optim.as_updates"}
+FUSE = {"bf.gossip.fuse", "bf.gossip.split"}
+PACK = {"bf.gossip.pack", "bf.gossip.unpack"}
+EXCHANGE = {"bf.gossip.exchange"}
+SCOPE = re.compile(r"bf\.(?:optim|gossip)\.\w+")
+FUSE_THRESHOLD = 3000     # bytes: w1 (4096) ships unfused, the rest fuse
+
+
+@pytest.fixture(autouse=True)
+def lowered_fuse_threshold(monkeypatch):
+    monkeypatch.setattr(C, "fuse_apply", functools.partial(
+        C.fuse_apply, threshold_bytes=FUSE_THRESHOLD))
+
+
+def mlp_step(comm, atc, backend="xla"):
+    """A train step of a tiny MLP over four ranks, and its stacked input."""
+    opt = decentralized_optimizer(
+        optax.sgd(0.1, momentum=0.9), build_schedule(ExponentialTwoGraph(N)),
+        "bf", communication_type=comm, atc=atc, backend=backend)
+
+    def loss(p, x):
+        return jnp.mean((jnp.tanh(x @ p["w1"] + p["b1"]) @ p["w2"]
+                         + p["b2"]) ** 2)
+
+    def step(p_blk, x_blk):
+        p = jax.tree_util.tree_map(lambda t: t[0], p_blk)
+        updates, _ = opt.update(jax.grad(loss)(p, x_blk[0]), opt.init(p), p)
+        return jax.tree_util.tree_map(lambda t: t[None],
+                                      optax.apply_updates(p, updates))
+
+    params = {"w1": jnp.ones((N, 16, 64)), "b1": jnp.ones((N, 64)),
+              "w2": jnp.ones((N, 64, 8)), "b2": jnp.ones((N, 8))}
+    mesh = Mesh(np.array(jax.devices()[:N]), ("bf",))
+    fn = shard_map(step, mesh=mesh, in_specs=(P("bf"), P("bf")),
+                   out_specs=P("bf"), check_vma=False)
+    return fn, (params, jnp.ones((N, 4, 16)))
+
+
+@pytest.mark.parametrize("atc", [False, True], ids=["awc", "atc"])
+@pytest.mark.parametrize("comm,expected", [
+    (CommunicationType.neighbor_allreduce, OPTIM | FUSE | EXCHANGE),
+    (CommunicationType.allreduce, OPTIM | FUSE),
+    (CommunicationType.empty, OPTIM),
+], ids=["neighbor_xla", "allreduce", "empty"])
+def test_scopes_reach_the_compiled_step_and_never_nest(comm, expected, atc):
+    fn, args = mlp_step(comm, atc)
+    text = jax.jit(fn).lower(*args).compile().as_text()
+    found = set()
+    for op_name in re.findall(r'op_name="([^"]*)"', text):
+        # XLA joins the names of ops it merges with ";": each is one op's
+        for one_op in op_name.split(";"):
+            scopes = SCOPE.findall(one_op)
+            assert len(scopes) <= 1, one_op
+            found.update(scopes)
+    assert found == expected
+
+
+def walk(jaxpr, above=""):
+    """Every equation with the name stacks of the equations enclosing it."""
+    for eqn in jaxpr.eqns:
+        stack = f"{above}/{eqn.source_info.name_stack}"
+        yield eqn, stack
+        for value in eqn.params.values():
+            for sub in value if isinstance(value, (list, tuple)) else [value]:
+                sub = getattr(sub, "jaxpr", sub)
+                if hasattr(sub, "eqns"):
+                    yield from walk(sub, stack)
+
+
+def pallas_step_equations(monkeypatch, atc, max_bytes):
+    monkeypatch.setenv("BLUEFOG_TPU_PALLAS_INTERPRET", "1")
+    monkeypatch.setenv("BLUEFOG_TPU_PALLAS_MAX_BYTES", str(max_bytes))
+    fn, args = mlp_step(CommunicationType.neighbor_allreduce, atc,
+                        backend="pallas")
+    return list(walk(jax.make_jaxpr(fn)(*args).jaxpr))
+
+
+@pytest.mark.parametrize("atc", [False, True], ids=["awc", "atc"])
+def test_no_scope_and_no_name_above_a_gossip_kernel(monkeypatch, atc):
+    """A Pallas kernel takes its name in the device trace from the innermost
+    name-stack entry above its call (``shard_map.N`` today), or from its
+    ``name=``.  The benchmark's ``gossip_kernel_ms_per_step`` finds the
+    gossip kernels by ``^shard_map\\.\\d+$``: a scope opened at any depth
+    above the call, or a name on it, silently zeroes that metric."""
+    kernels = [(eqn, stack) for eqn, stack in pallas_step_equations(
+        monkeypatch, atc, max_bytes=1024)
+        if eqn.primitive.name == "pallas_call"]
+    assert len(kernels) >= 3
+    for eqn, stack in kernels:
+        assert "bf." not in stack, stack
+        assert eqn.params["name"] is None
+
+
+@pytest.mark.parametrize("max_bytes,kernels", [(1 << 20, 2), (1024, 7)],
+                         ids=["unchunked", "chunked"])
+def test_pack_and_unpack_scopes_on_the_pallas_path(monkeypatch, max_bytes,
+                                                   kernels):
+    """w1 (4096 bytes) and the fused buffer of the rest (2336 bytes): one
+    kernel each under a cap that holds them, 4 + 3 chunks under 1 KiB."""
+    equations = pallas_step_equations(monkeypatch, False, max_bytes)
+    under = {scope: {eqn.primitive.name for eqn, stack in equations
+                     if scope in stack}
+             for scope in OPTIM | FUSE | PACK}
+    assert all(under.values()), under
+    assert not any("bf.gossip.exchange" in stack for _, stack in equations)
+    assert "pad" in under["bf.gossip.pack"]
+    assert "slice" in under["bf.gossip.unpack"]
+    # the chunking itself (array_split / concatenate) is pack / unpack too
+    assert ("split" in under["bf.gossip.pack"]) == (kernels > 2)
+    assert ("concatenate" in under["bf.gossip.unpack"]) == (kernels > 2)
+    assert sum(eqn.primitive.name == "pallas_call"
+               for eqn, _ in equations) == kernels
