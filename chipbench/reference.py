@@ -10,10 +10,27 @@ the benchmark built from the traffic file.  What is mixed follows the order
 the configuration states: ``atc=False`` gives ``W @ p + update``, ``atc=True``
 gives ``W @ (p + update)``.
 
-The loss itself (the model's forward pass, flash attention included) is shared
-with the system: this reference guards the optimizer and the gossip, the part
-of the step that is this system's own.  The models' numerics against dense
-f32 attention are the tier-1 tests' and ``chip_smoke.py``'s business.
+Which comparison guards what:
+
+- the parameters and the model state after ``STEPS`` steps, leaf by leaf
+  (:func:`compare`): the optimizer as the system wraps it, the order of update
+  and mixing, the mixing matrix, the wire's precision, the gossip kernels.  A
+  step that leaves out a rank's update or a neighbour's share moves every leaf;
+- the ``STEPS`` losses on every rank (``loss_rtol``): that the system's step
+  saw the whole batch it was fed, from the state the window left;
+- the model's own numerics only where the family brings
+  ``reference_loss(params, model_state, batch)``, its forward pass and loss in
+  plain ``jax.numpy`` and f32 (:func:`model_loss_error`,
+  ``model_loss_rtol``).  Without it the loss (the model's forward pass, flash
+  attention included) is shared with the system, and the models' numerics
+  against dense f32 attention are the tier-1 tests' and ``chip_smoke.py``'s
+  business.
+
+The reference starts from the very state the window left.  Its copy waits on
+the host while the system takes its steps (:func:`to_host`) and comes back to
+the chip once the system's state has gone there in its turn
+(:func:`from_host`), so that the chip holds one training state a rank at a
+time.
 """
 
 import functools
@@ -36,18 +53,37 @@ def mixing_matrix(topology, comm: str) -> np.ndarray:
     raise SystemExit(f"chipbench: no plain reference for comm={comm!r}")
 
 
-def per_rank(tree, devices, copy=False):
+def per_rank(tree, devices):
     """Rank-stacked, mesh-sharded tree -> one tree per rank: each leaf the
-    rank's own ``[1, ...]`` block on the rank's own device.  Without ``copy``
-    the blocks share ``tree``'s buffers and die with them when a step donates
-    it; with ``copy`` they are the reference's own."""
+    rank's own ``[1, ...]`` block on the rank's own device.  The blocks share
+    ``tree``'s buffers and die with them when a step donates it."""
     def block(leaf, device):
         shard, = (s for s in leaf.addressable_shards if s.device == device)
-        if copy:
-            return jax.device_put(shard.data, device, may_alias=False)
         return shard.data
     return [jax.tree_util.tree_map(lambda leaf, d=d: block(leaf, d), tree)
             for d in devices]
+
+
+def to_host(tree, devices):
+    """:func:`per_rank`, copied to the host: one tree of ``numpy`` blocks per
+    rank, read shard by shard (a ``device_get`` of the rank-stacked tree
+    would gather every rank's block through one device).  The copies are
+    exact and hold nothing of ``tree``."""
+    def own(block, host):
+        # the CPU backend hands out its own buffer, which would pin it
+        # against donation and change under the system's steps
+        if host.ctypes.data == block.unsafe_buffer_pointer():
+            return host.copy()
+        return host
+    blocks = per_rank(tree, devices)
+    return jax.tree_util.tree_map(own, blocks, jax.device_get(blocks))
+
+
+def from_host(host_trees, devices):
+    """What :func:`to_host` took, back on each rank's own device as arrays of
+    their own: :func:`run` takes them over."""
+    return [jax.device_put(tree, device)
+            for tree, device in zip(host_trees, devices)]
 
 
 @jax.jit
@@ -70,13 +106,16 @@ def _mix(w, leaves, updates, devices):
     return out
 
 
-def run(family, base_opt, atc, w, states, batches, devices):
+def run(family, base_opt, atc, w, states, batches, devices,
+        after_first_step=None):
     """``STEPS`` reference steps from ``states`` (per rank: params, model
     state, the base optimizer's state, as ``[1, ...]`` blocks on
     ``devices[rank]``) over ``batches[k][rank]``.  Returns the per-rank
     ``(params, model_state)`` and the ``[STEPS, ranks]`` losses.  ``states``
     is taken over: the list is emptied, the model and optimizer states are
-    donated, the parameters are dropped leaf by leaf.
+    donated, the parameters are dropped leaf by leaf.  ``after_first_step``
+    is called once every rank has mixed its first step: the reference's
+    program is loaded and its state whole, the place to read memory.
 
     The mixing goes leaf by leaf over all ranks, waits for each leaf, and
     drops its old value and update once every rank has mixed it: on the chip
@@ -119,7 +158,26 @@ def run(family, base_opt, atc, w, states, batches, devices):
                 old[r][i] = upd[r][i] = None
         states = [(treedef.unflatten(new[r]),) + states[r][1:]
                   for r in ranks]
+        if k == 0 and after_first_step is not None:
+            after_first_step()
     return [s[:2] for s in states], np.asarray(jax.device_get(losses))
+
+
+def model_loss_error(family, params, model_state, batch):
+    """The family's plain model against the system's, on one rank's
+    ``[1, ...]`` blocks: ``family.reference_loss`` with every matmul at
+    ``highest`` precision (on a TPU an f32 matmul is otherwise computed in
+    bf16 passes) beside ``family.loss`` on the same inputs.  Returns the
+    relative difference and the two losses."""
+    def on_blocks(loss):
+        return jax.jit(lambda *blocks: loss(*jax.tree_util.tree_map(
+            lambda t: t[0], blocks)))
+    with jax.default_matmul_precision("highest"):
+        want = float(on_blocks(family.reference_loss)(
+            params, model_state, batch))
+    got = float(on_blocks(lambda *a: family.loss(*a)[0])(
+        params, model_state, batch))
+    return abs(got - want) / abs(want), want, got
 
 
 @jax.jit

@@ -128,36 +128,83 @@ def loss_ok(rec, ring):
     return failed, bool(end < start), float(start), float(end)
 
 
-def agreement(cell, state, k):
+def agreement(cell, state, k, report=None):
     """Three steps through the system against the plain reference, from the
-    state the window left (see ``reference.py``).  The reference starts from
-    its own copy of that state and runs after the system's steps, so that
-    the two never hold their activations at once."""
+    state the window left (see ``reference.py``).  The chip holds one
+    training state a rank at a time, so that a state may fill the chip as a
+    job's would: the reference's copy of the state waits on the host while
+    the system takes its steps, and the system's result waits there while
+    the reference takes its own (beside its state, its updates and its
+    program's scratch, a second set of parameters did not fit: PR 27's
+    probe).  The two never hold their activations at once either.
+
+    ``report``, where given, takes what a run prints beside the verdict: the
+    fullest chip's memory where the next configuration is sized from it, and
+    the model's own comparison where the family brings one."""
     import numpy as np
 
     from chipbench import reference
     from chipbench.cell import base_optimizer
 
     devices = cell.devices
+    report = {} if report is None else report
+    memory = report.setdefault("memory", {})
     ring = [cell.ring[(k + j) % len(cell.ring)]
             for j in range(reference.STEPS)]
     params, model_state, opt_state = state
-    ref_states = list(zip(*(
-        reference.per_rank(t, devices, copy=True)
-        for t in (params, model_state, opt_state.base_state))))
+    held = reference.to_host(
+        (params, model_state, opt_state.base_state), devices)
     del params, model_state, opt_state
+    memory["before_steps"] = memory_reading(devices)
     got_losses = []
     for batch in ring:
         state, loss = cell.step(state, batch)
         got_losses.append(np.asarray(loss))
-    got = list(zip(*(reference.per_rank(t, devices) for t in state[:2])))
-    del state     # the system's optimizer state makes room for the reference
+    got = reference.to_host(state[:2], devices)
+    del state     # the system's state makes room for the reference's
+    memory["after_del_state"] = memory_reading(devices)
+    ref_states = reference.from_host(held, devices)
+    del held
+
+    def after_first_step():
+        memory["after_reference_step"] = memory_reading(devices)
+
     want, want_losses = reference.run(
         cell.family, base_optimizer(cell.config), cell.config["atc"],
         reference.mixing_matrix(cell.ctx.topology, cell.traffic["comm"]),
-        ref_states, [reference.per_rank(b, devices) for b in ring], devices)
-    return reference.compare(got, want, np.stack(got_losses), want_losses,
-                             cell.config["tolerance"])
+        ref_states, [reference.per_rank(b, devices) for b in ring], devices,
+        after_first_step=after_first_step)
+    got = reference.from_host(got, devices)
+    ok, leaves, loss_err = reference.compare(
+        got, want, np.stack(got_losses), want_losses,
+        cell.config["tolerance"])
+    del want
+    if hasattr(cell.family, "reference_loss"):
+        # rank 0's first batch of the check, at the parameters the system's
+        # steps ended on; the reference's state is gone by now
+        batch0, = reference.per_rank(ring[0], devices[:1])
+        err, want_loss, got_loss = reference.model_loss_error(
+            cell.family, *got[0], batch0)
+        report["model_loss"] = {"rel_err": err, "reference": want_loss,
+                                "system": got_loss}
+        ok = ok and err <= cell.config["tolerance"]["model_loss_rtol"]
+    return ok, leaves, loss_err
+
+
+MEMORY_KEYS = ("bytes_in_use", "bytes_reserved", "largest_free_block_bytes",
+               "bytes_limit")
+
+
+def memory_reading(devices):
+    """``MEMORY_KEYS`` of the fullest chip (live buffers plus what loaded
+    programs reserve) as the runtime reports them now; ``None`` on a backend
+    that reports nothing (the CPU)."""
+    stats = [d.memory_stats() for d in devices]
+    if None in stats:
+        return None
+    fullest = max(stats, key=lambda s: int(s["bytes_in_use"])
+                  + int(s["bytes_reserved"]))
+    return {key: int(fullest[key]) for key in MEMORY_KEYS}
 
 
 def device_record(devices, chips, pinned_cpu):
@@ -327,10 +374,11 @@ def main(argv=None):
 
     # ---- correctness, after the window ------------------------------------
     t = time.perf_counter()
-    agrees, leaves, loss_err = agreement(cell, state, k)
+    report = {}
+    agrees, leaves, loss_err = agreement(cell, state, k, report)
     say("agreement", ok=agrees, seconds=time.perf_counter() - t,
         compile_s_total=clock.seconds, loss_rel_err=loss_err,
-        worst_leaves=leaves[:4])
+        worst_leaves=leaves[:4], **report)
     correct = bool(agrees and failed == 0 and fell
                    and compiles_in_window == 0)
 

@@ -92,7 +92,7 @@ def settled_cell():
     return cell, state, k
 
 
-def agreement_with(monkeypatch, settled_cell, w=None):
+def agreement_with(monkeypatch, settled_cell, w=None, report=None):
     import jax
 
     from chipbench import reference, run
@@ -102,7 +102,7 @@ def agreement_with(monkeypatch, settled_cell, w=None):
     state = jax.tree_util.tree_map(lambda x: x + 0, state)
     if w is not None:
         monkeypatch.setattr(reference, "mixing_matrix", lambda *_: w)
-    return run.agreement(cell, state, k)
+    return run.agreement(cell, state, k, report)
 
 
 def test_agreement_holds_for_the_topologys_w(monkeypatch, settled_cell):
@@ -126,3 +126,178 @@ def test_a_leaf_takes_the_first_exception_that_names_it():
     assert allowance("[0]['block_1']['qkv']['bias']", 0.5, tolerance) == 2e-3
     assert allowance("[0]['block_1']['qkv']['kernel']", 0.5, tolerance) == (
         pytest.approx(5e-4 + 1e-6))
+
+
+def buffers(arrays):
+    """Device buffer -> its bytes, for every shard of ``arrays``: a buffer
+    that several arrays share counts once."""
+    return {s.data.unsafe_buffer_pointer(): s.data.nbytes
+            for a in arrays for s in a.addressable_shards}
+
+
+def live_bytes():
+    import gc
+
+    import jax
+
+    gc.collect()
+    return sum(buffers(jax.live_arrays()).values())
+
+
+@pytest.fixture(scope="module", params=["tiny.solo", "tiny.ring4"])
+def watched_agreement(request):
+    """One agreement check of the cell, watched from outside: the state it
+    was given, the bytes alive on the devices at each of the system's steps,
+    and what ``reference.run`` was handed."""
+    import jax
+
+    from chipbench import cell as cells
+    from chipbench import reference, run
+
+    cell = cells.build_cell(cells.Manifest.load(MANIFEST), request.param,
+                            seed=7)
+    state, cell.state = cell.state, None
+    state, k, _ = run.drive(cell.step, state, cell.ring, 0, steps=6)
+    params, model_state, opt_state = state
+    # copies of the host's own: a view of a CPU buffer would pin it
+    seen = {"given": jax.tree_util.tree_map(
+        np.array, jax.device_get((params, model_state, opt_state.base_state))),
+        "bytes_at_step": []}
+    del params, model_state, opt_state
+    system_step, reference_run = cell.step, reference.run
+
+    def step(state, batch):
+        seen["bytes_at_step"].append(live_bytes())
+        return system_step(state, batch)
+
+    def run_reference(family, base_opt, atc, w, states, *rest, **kwargs):
+        blocks = jax.tree_util.tree_leaves(states)
+        others = [a for a in jax.live_arrays()
+                  if not any(a is b for b in blocks)]
+        seen["handed"] = jax.tree_util.tree_map(
+            np.array, jax.device_get(states))
+        seen["shared"] = set(buffers(blocks)) & set(buffers(others))
+        seen["on_own_device"] = [
+            [b.devices() == {d} for b in jax.tree_util.tree_leaves(s)]
+            for s, d in zip(states, cell.devices)]
+        donated = [leaf for s in states
+                   for leaf in jax.tree_util.tree_leaves(s[1:])]
+        out = reference_run(family, base_opt, atc, w, states, *rest, **kwargs)
+        seen["donated"] = [leaf.is_deleted() for leaf in donated]
+        return out
+
+    with pytest.MonkeyPatch.context() as patch:
+        patch.setattr(cell, "step", step)
+        patch.setattr(reference, "run", run_reference)
+        seen["bytes_before"] = live_bytes()
+        seen["result"] = run.agreement(cell, state, k)
+    return cell, seen
+
+
+@pytest.mark.parametrize("check", [
+    "starts_from_the_same_bits", "one_state_on_the_chip",
+    "blocks_of_its_own"])
+def test_the_references_state_waits_on_the_host(watched_agreement, check):
+    cell, seen = watched_agreement
+    ok, leaves, loss_err = seen["result"]
+    assert ok, (leaves[:3], loss_err)
+    if check == "starts_from_the_same_bits":
+        # what reference.run starts from is the state agreement was given
+        import jax
+
+        given = jax.tree_util.tree_leaves(seen["given"])
+        assert given
+        for r, handed in enumerate(seen["handed"]):
+            handed = jax.tree_util.tree_leaves(handed)
+            assert len(handed) == len(given)
+            for a, b in zip(given, handed):
+                assert b.shape == (1,) + a.shape[1:] and b.dtype == a.dtype
+                assert a[r:r + 1].tobytes() == b.tobytes()
+    elif check == "one_state_on_the_chip":
+        # during the system's steps no second copy of the state is alive on
+        # the devices; the steps' own [ranks] f32 losses are the slack
+        assert len(seen["bytes_at_step"]) == 3
+        slack = 2 * 4 * len(cell.devices)
+        assert max(seen["bytes_at_step"]) <= seen["bytes_before"] + slack
+    else:
+        # arrays of the reference's own, each on its rank's device: no
+        # buffer shared with another live array, so that donating them
+        # (reference.run does) deletes nothing that is read later
+        assert seen["shared"] == set()
+        assert all(all(rank) for rank in seen["on_own_device"])
+        assert seen["donated"] and all(seen["donated"])
+
+
+def plain_decoder_loss(params, model_state, batch, heads=4, positions=True):
+    """``models/transformer.py``'s decoder and the family's loss in plain
+    ``jax.numpy`` and f32: what a family brings as ``reference_loss``.
+    ``positions=False`` leaves out the position embedding, a dropped term."""
+    import jax
+    import jax.numpy as jnp
+
+    def norm(x, p):
+        mean = x.mean(-1, keepdims=True)
+        var = ((x - mean) ** 2).mean(-1, keepdims=True)
+        return (x - mean) / jnp.sqrt(var + 1e-6) * p["scale"] + p["bias"]
+
+    def dense(x, p):
+        return x @ p["kernel"] + p.get("bias", 0.0)
+
+    tokens, targets = batch[:, :-1], batch[:, 1:]
+    b, t = tokens.shape
+    x = params["tok"]["embedding"][tokens]
+    if positions:
+        x = x + params["pos"]["embedding"][:t]
+    causal = jnp.tril(jnp.ones((t, t), bool))
+    for i in range(sum(name.startswith("block_") for name in params)):
+        p = params[f"block_{i}"]
+        q, k, v = (h.reshape(b, t, heads, -1) for h in jnp.split(
+            dense(norm(x, p["ln1"]), p["qkv"]), 3, axis=-1))
+        scores = jnp.einsum("bqhd,bkhd->bhqk", q, k) / q.shape[-1] ** 0.5
+        weights = jax.nn.softmax(jnp.where(causal, scores, -jnp.inf), -1)
+        x = x + dense(jnp.einsum("bhqk,bkhd->bqhd", weights, v).reshape(
+            b, t, -1), p["proj"])
+        x = x + dense(jax.nn.gelu(dense(norm(x, p["ln2"]), p["up"])),
+                      p["down"])
+    logits = dense(norm(x, params["ln_f"]), params["lm_head"])
+    picked = jnp.take_along_axis(jax.nn.log_softmax(logits),
+                                 targets[..., None], -1)
+    return -picked.mean()
+
+
+class FamilyWithReference:
+    """The cell's family, and a ``reference_loss`` beside it."""
+
+    def __init__(self, family, reference_loss):
+        self.family, self.reference_loss = family, reference_loss
+
+    def __getattr__(self, name):
+        return getattr(self.family, name)
+
+
+@pytest.mark.parametrize("positions,agrees", [(True, True), (False, False)])
+def test_a_familys_reference_loss_is_held_to_model_loss_rtol(
+        monkeypatch, settled_cell, positions, agrees):
+    import functools
+
+    from chipbench import run
+
+    cell, state, k = settled_cell
+    monkeypatch.setattr(cell, "family", FamilyWithReference(
+        cell.family, functools.partial(plain_decoder_loss,
+                                       positions=positions)))
+    monkeypatch.setattr(cell, "config", {**cell.config, "tolerance": {
+        **cell.config["tolerance"], "model_loss_rtol": 1e-4}})
+    report = {}
+    ok, leaves, _ = agreement_with(monkeypatch, settled_cell, report=report)
+    assert leaves[0][0] <= 1.0        # optimizer and gossip agree either way
+    assert ok is agrees, report
+    assert (report["model_loss"]["rel_err"] <= 1e-4) is agrees
+    assert report["model_loss"]["system"] > 0
+
+
+def test_without_reference_loss_nothing_of_the_model_is_evaluated(
+        monkeypatch, settled_cell):
+    report = {}
+    ok, _, _ = agreement_with(monkeypatch, settled_cell, report=report)
+    assert ok and set(report) == {"memory"}
