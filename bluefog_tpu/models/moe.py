@@ -6,6 +6,18 @@ with the :class:`~bluefog_tpu.models.transformer.TransformerLM` skeleton:
 every block's MLP is replaced by a Switch-MoE FFN whose experts are sharded
 over the ``'ep'`` mesh axis, with tokens batch-sharded over the same axis.
 
+**Which expert layer.**  The Switch / GShard routers here build dense
+one-hot ``(T, E, C)`` dispatch tensors with a per-expert capacity: the shape
+the ``all_to_all`` exchange of :func:`expert_parallel_ffn` needs (every
+shard sends every other a fixed-size buffer), fine for a few wide experts,
+and they drop what overflows.  For many small experts (hundreds, top-8) use
+the dropless layer instead: ``GPTConfig(ffn="routed+shared",
+experts=ExpertSizes(...))`` →
+:class:`bluefog_tpu.models.transformer.RoutedSharedFFN` over
+:func:`bluefog_tpu.ops.moe.routed_experts` (sort by expert, grouped matmuls
+over the experts the chip holds, no capacity, no drop; the ``(T, E, C)``
+tensors cannot hold 64 experts at 8k tokens).
+
 Loss convention for training inside ``shard_map``: normalize by the GLOBAL
 token count (see ops/moe.py docstring) so raw ``jax.grad`` is exact.
 """
@@ -57,7 +69,10 @@ def moe_param_rules(ep_axis: str = "ep", tp_axis: Optional[str] = None):
 
 @dataclasses.dataclass(frozen=True)
 class MoEConfig:
-    """Switch-MoE hyperparameters on top of a :class:`GPTConfig`."""
+    """Switch-MoE hyperparameters on top of a :class:`GPTConfig`: one-hot
+    routing with a static capacity (tokens past it are dropped), for the
+    expert-parallel ``all_to_all`` path.  Many small experts take the
+    dropless layer (module docstring)."""
 
     gpt: GPTConfig
     num_experts: int = 8
@@ -94,7 +109,10 @@ class MoEConfig:
 class MoEMLP(nn.Module):
     """Switch-MoE FFN; expert weights sharded over ``cfg.ep_axis`` when
     ``cfg.ep_size > 1`` (params hold only the local experts), dense reference
-    path when ``ep_size == 1``."""
+    path when ``ep_size == 1``.  Still the layer for experts spread over
+    chips with the exchange inside the step; the dropless
+    :class:`~bluefog_tpu.models.transformer.RoutedSharedFFN` computes one
+    chip's share without an exchange (module docstring)."""
 
     cfg: MoEConfig
 

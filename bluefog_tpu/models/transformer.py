@@ -13,19 +13,72 @@ same module runs
 
 TPU-first: bf16 activations/matmuls with f32 layernorm + softmax-accumulate,
 fused QKV, static shapes, dims sized for 128-lane MXU tiles.
+
+**One block, assembled from the configuration** (ROADMAP D7).
+:class:`GPTConfig` names the kind of each sub-layer; the defaults are the
+GPT-2 decoder this file always built, parameter for parameter:
+
+===============  ===========================================================
+``attention``    ``fused_qkv`` (one biased projection to q, k, v of one
+                 width) or ``latent`` (:class:`LatentAttention`: low-rank
+                 queries and keys/values, a rotary part of the key shared
+                 by the heads, values narrower than keys)
+``ffn``          ``gelu`` (biased, ``mlp_ratio`` wide), ``swiglu``
+                 (:class:`GatedMLP`, ``ffn_width`` wide) or
+                 ``routed+shared`` (:class:`RoutedSharedFFN`; the first
+                 ``experts.first_dense`` blocks take ``swiglu``)
+``norm``         ``layernorm`` or ``rmsnorm`` (scale only, ``norm_eps``)
+``position``     ``learned`` (a table added to the embedding) or ``rotary``
+                 (no table; the attention turns its rotary part)
+``mtp_depth``    0, or 1 for one multi-token-prediction module sharing the
+                 embedding and the head (:func:`next_token_loss`)
+===============  ===========================================================
 """
 
 from __future__ import annotations
 
 import dataclasses
-from typing import Callable, Optional
+import functools
+from typing import Callable, Optional, Tuple
 
 import flax.linen as nn
+import jax
 import jax.numpy as jnp
 
+from bluefog_tpu.ops.moe import routed_experts, sigmoid_topk_router
 from bluefog_tpu.ops.ring_attention import local_attention
 
 AttnFn = Callable[..., jnp.ndarray]  # (q, k, v) -> (B, T, H, D)
+
+
+@dataclasses.dataclass(frozen=True)
+class LatentSizes:
+    """Multi-head latent attention (DeepSeek-V2, arXiv:2405.04434 §2.1):
+    the ranks of the query and key/value bottlenecks and a head's widths.
+    A query and a key are ``qk_nope_head_dim + qk_rope_head_dim`` wide, a
+    value ``v_head_dim``."""
+
+    q_lora_rank: int = 1536
+    kv_lora_rank: int = 512
+    qk_nope_head_dim: int = 128
+    qk_rope_head_dim: int = 64
+    v_head_dim: int = 128
+    rope_theta: float = 10000.0
+
+
+@dataclasses.dataclass(frozen=True)
+class ExpertSizes:
+    """A routed-and-shared expert layer (DeepSeek-V3, arXiv:2412.19437
+    §2.1.2): the router scores all ``num_experts``, a token takes ``top_k``,
+    and this chip computes the experts ``held = (first, count)``."""
+
+    num_experts: int = 256
+    top_k: int = 8
+    width: int = 768               # of one expert, routed or shared
+    num_shared: int = 1
+    scale: float = 2.5             # routed_scaling_factor
+    held: Tuple[int, int] = (0, 256)
+    first_dense: int = 1           # leading blocks with the dense swiglu
 
 
 @dataclasses.dataclass(frozen=True)
@@ -40,6 +93,37 @@ class GPTConfig:
     remat: bool = False              # rematerialize each block's activations
     # (jax.checkpoint): backward recomputes the block instead of storing its
     # intermediates — O(sqrt-ish) HBM for long sequences at ~1/3 extra FLOPs
+    # the block's kinds (module docstring); the defaults are GPT-2's
+    attention: str = "fused_qkv"
+    ffn: str = "gelu"
+    norm: str = "layernorm"
+    position: str = "learned"
+    ffn_width: Optional[int] = None  # swiglu; None: mlp_ratio * hidden_size
+    norm_eps: float = 1e-6           # rmsnorm's
+    latent: Optional[LatentSizes] = None
+    experts: Optional[ExpertSizes] = None
+    mtp_depth: int = 0
+
+    def __post_init__(self):
+        for field, kinds in (("attention", ("fused_qkv", "latent")),
+                             ("ffn", ("gelu", "swiglu", "routed+shared")),
+                             ("norm", ("layernorm", "rmsnorm")),
+                             ("position", ("learned", "rotary"))):
+            if getattr(self, field) not in kinds:
+                raise ValueError(f"unknown {field} {getattr(self, field)!r};"
+                                 f" expected one of {kinds}")
+        if (self.attention == "latent") != (self.latent is not None):
+            raise ValueError("attention='latent' and the `latent` sizes come "
+                             "together")
+        if (self.position == "rotary") != (self.attention == "latent"):
+            raise ValueError("position='rotary' is the latent attention's "
+                             "(its keys carry the rotary part); fused_qkv "
+                             "heads take position='learned'")
+        if (self.ffn == "routed+shared") != (self.experts is not None):
+            raise ValueError("ffn='routed+shared' and the `experts` sizes "
+                             "come together")
+        if self.mtp_depth not in (0, 1):
+            raise ValueError(f"mtp_depth {self.mtp_depth}: one module or none")
 
     @staticmethod
     def small() -> "GPTConfig":
@@ -56,37 +140,175 @@ class GPTConfig:
                          num_heads=4, max_position=512, dtype=jnp.float32)
 
 
-class Block(nn.Module):
-    """Pre-LN attention + MLP residual block.
+def _norm(cfg: GPTConfig, name: str) -> nn.Module:
+    """The configuration's norm, computing and returning f32."""
+    if cfg.norm == "rmsnorm":
+        return nn.RMSNorm(epsilon=cfg.norm_eps, dtype=jnp.float32, name=name)
+    return nn.LayerNorm(dtype=jnp.float32, name=name)
 
-    ``mlp`` is a pluggable sublayer factory ``() -> nn.Module`` (the module
-    maps ``(B, T, D) -> (B, T, D)``); ``None`` gives the dense GELU MLP.
-    The MoE variant (models/moe.py) injects a Switch-MoE FFN here instead of
-    duplicating the attention trunk.
+
+def rotary(x, positions, theta: float):
+    """Rotate the pairs ``(0, 1), (2, 3), ...`` of the last axis (interleaved
+    as stored) by ``position * theta ** (-2i / width)``.  ``x (B, T, H, R)``,
+    ``positions (B or 1, T)``; computed in f32."""
+    r = x.shape[-1]
+    inv_freq = theta ** (-jnp.arange(0, r, 2, dtype=jnp.float32) / r)
+    angle = positions[..., None].astype(jnp.float32) * inv_freq
+    cos, sin = jnp.cos(angle)[:, :, None, :], jnp.sin(angle)[:, :, None, :]
+    pairs = x.astype(jnp.float32).reshape(x.shape[:-1] + (r // 2, 2))
+    even, odd = pairs[..., 0], pairs[..., 1]
+    turned = jnp.stack([even * cos - odd * sin, even * sin + odd * cos], -1)
+    return turned.reshape(x.shape).astype(x.dtype)
+
+
+class LatentAttention(nn.Module):
+    """Multi-head latent attention, as trained (keys and values are
+    materialised; no matrix absorption): ``(B, T, D) -> (B, T, D)``.
+
+    ``cq = RMSNorm(W_dq y)``, ``q = W_uq cq`` per head ``[q_nope; q_rope]``;
+    ``[ckv; k_rope] = W_dkv y``, ``[k_nope; v] = W_ukv RMSNorm(ckv)`` per
+    head; rotary on ``q_rope`` and on the one ``k_rope`` a token, which every
+    head shares.  ``attn_fn`` sees ``q, k (B, T, H, nope + rope)`` and
+    ``v (B, T, H, v_head_dim)`` and scales by the query's width.  No bias.
+    """
+
+    cfg: GPTConfig
+
+    @nn.compact
+    def __call__(self, y, attn_fn: AttnFn, positions):
+        cfg, la, h = self.cfg, self.cfg.latent, self.cfg.num_heads
+        nope, rope = la.qk_nope_head_dim, la.qk_rope_head_dim
+        dense = functools.partial(nn.Dense, use_bias=False, dtype=cfg.dtype)
+
+        def rms(name):
+            return nn.RMSNorm(epsilon=cfg.norm_eps, dtype=jnp.float32,
+                              name=name)
+
+        with jax.named_scope("bf.mla.project"):
+            cq = rms("q_norm")(dense(la.q_lora_rank, name="q_down")(y))
+            q = dense(h * (nope + rope), name="q_up")(cq.astype(cfg.dtype))
+            q = q.reshape(q.shape[:-1] + (h, nope + rope))
+            ckv = dense(la.kv_lora_rank + rope, name="kv_down")(y)
+            k_rope = ckv[..., None, la.kv_lora_rank:]        # (B, T, 1, rope)
+            ckv = rms("kv_norm")(ckv[..., :la.kv_lora_rank])
+            kv = dense(h * (nope + la.v_head_dim), name="kv_up")(
+                ckv.astype(cfg.dtype))
+            kv = kv.reshape(kv.shape[:-1] + (h, nope + la.v_head_dim))
+            q = jnp.concatenate(
+                [q[..., :nope], rotary(q[..., nope:], positions,
+                                       la.rope_theta)], axis=-1)
+            k_rope = rotary(k_rope, positions, la.rope_theta)
+            k = jnp.concatenate(
+                [kv[..., :nope],
+                 jnp.broadcast_to(k_rope, kv.shape[:-1] + (rope,))], axis=-1)
+        a = attn_fn(q, k, kv[..., nope:])
+        with jax.named_scope("bf.mla.project"):
+            return dense(cfg.hidden_size, name="o")(
+                a.reshape(a.shape[:-2] + (h * la.v_head_dim,)))
+
+
+class GatedMLP(nn.Module):
+    """``down(silu(gate(y)) * up(y))``, no bias."""
+
+    width: int
+    dtype: jnp.dtype
+
+    @nn.compact
+    def __call__(self, y):
+        dense = functools.partial(nn.Dense, use_bias=False, dtype=self.dtype)
+        gated = nn.silu(dense(self.width, name="gate")(y))
+        return dense(y.shape[-1], name="down")(
+            gated * dense(self.width, name="up")(y))
+
+
+class RoutedSharedFFN(nn.Module):
+    """A shared expert every token takes plus this chip's share of the
+    routed experts (:func:`bluefog_tpu.ops.moe.routed_experts`: dropless,
+    grouped matmuls over the held experts).  The parameters hold the held
+    experts only; the router scores all of them.  The selection bias is a
+    buffer (collection ``buffers``, no gradient).  The routing record is
+    sown into the collection ``moe_metrics``."""
+
+    cfg: GPTConfig
+
+    @nn.compact
+    def __call__(self, y):
+        cfg, ex = self.cfg, self.cfg.experts
+        d, count = cfg.hidden_size, ex.held[1]
+        per_expert = nn.initializers.lecun_normal(
+            in_axis=1, out_axis=2, batch_axis=(0,))
+        router = self.param("router", nn.initializers.lecun_normal(),
+                            (d, ex.num_experts), jnp.float32)
+        bias = self.variable("buffers", "selection_bias", jnp.zeros,
+                             (ex.num_experts,), jnp.float32)
+        w_gate = self.param("w_gate", per_expert, (count, d, ex.width),
+                            jnp.float32)
+        w_up = self.param("w_up", per_expert, (count, d, ex.width),
+                          jnp.float32)
+        w_down = self.param("w_down", per_expert, (count, ex.width, d),
+                            jnp.float32)
+        flat = y.reshape(-1, d)
+        idx, weights = sigmoid_topk_router(
+            flat, router, bias.value, top_k=ex.top_k, scale=ex.scale)
+        routed, record = routed_experts(
+            flat, idx, weights, w_gate, w_up, w_down,
+            num_experts=ex.num_experts, held=ex.held)
+        for name, value in record.items():
+            self.sow("moe_metrics", name, value)
+        shared = GatedMLP(ex.num_shared * ex.width, cfg.dtype,
+                          name="shared")(y)
+        return shared + routed.reshape(y.shape)
+
+
+class Block(nn.Module):
+    """Pre-norm attention + feed-forward residual block, assembled from the
+    configuration's kinds (module docstring).
+
+    ``mlp`` and ``attn`` are pluggable sublayer factories ``() -> nn.Module``
+    that replace the configured kind: ``mlp()`` maps ``(B, T, D) -> (B, T,
+    D)`` (the MoE variant of models/moe.py injects a Switch-MoE FFN here
+    instead of duplicating the attention trunk), ``attn()`` maps
+    ``(y, attn_fn, positions) -> (B, T, D)``.  ``ffn`` overrides
+    ``cfg.ffn`` for this block (the leading dense blocks of an expert model).
     """
 
     cfg: GPTConfig
     mlp: Optional[Callable[[], nn.Module]] = None
+    attn: Optional[Callable[[], nn.Module]] = None
+    ffn: Optional[str] = None
 
     @nn.compact
-    def __call__(self, x, attn_fn: AttnFn):
+    def __call__(self, x, attn_fn: AttnFn, positions=None):
         cfg = self.cfg
-        head_dim = cfg.hidden_size // cfg.num_heads
-        y = nn.LayerNorm(dtype=jnp.float32, name="ln1")(x).astype(cfg.dtype)
-        qkv = nn.Dense(3 * cfg.hidden_size, dtype=cfg.dtype, name="qkv")(y)
-        q, k, v = jnp.split(qkv, 3, axis=-1)
+        y = _norm(cfg, "ln1")(x).astype(cfg.dtype)
+        if self.attn is not None:
+            a = self.attn()(y, attn_fn, positions)
+        elif cfg.attention == "latent":
+            a = LatentAttention(cfg, name="attn")(y, attn_fn, positions)
+        else:
+            head_dim = cfg.hidden_size // cfg.num_heads
+            qkv = nn.Dense(3 * cfg.hidden_size, dtype=cfg.dtype,
+                           name="qkv")(y)
+            q, k, v = jnp.split(qkv, 3, axis=-1)
 
-        def heads(t):
-            return t.reshape(t.shape[:-1] + (cfg.num_heads, head_dim))
+            def heads(t):
+                return t.reshape(t.shape[:-1] + (cfg.num_heads, head_dim))
 
-        a = attn_fn(heads(q), heads(k), heads(v))
-        a = a.reshape(a.shape[:-2] + (cfg.hidden_size,))
-        x = x + nn.Dense(cfg.hidden_size, dtype=cfg.dtype, name="proj")(a)
+            a = attn_fn(heads(q), heads(k), heads(v))
+            a = a.reshape(a.shape[:-2] + (cfg.hidden_size,))
+            a = nn.Dense(cfg.hidden_size, dtype=cfg.dtype, name="proj")(a)
+        x = x + a
 
-        y = nn.LayerNorm(dtype=jnp.float32, name="ln2")(x).astype(cfg.dtype)
+        y = _norm(cfg, "ln2")(x).astype(cfg.dtype)
+        ffn = self.ffn or cfg.ffn
         if self.mlp is not None:
             return x + self.mlp()(y)
-        y = nn.Dense(cfg.mlp_ratio * cfg.hidden_size, dtype=cfg.dtype, name="up")(y)
+        if ffn == "routed+shared":
+            return x + RoutedSharedFFN(cfg, name="moe")(y)
+        width = cfg.ffn_width or cfg.mlp_ratio * cfg.hidden_size
+        if ffn == "swiglu":
+            return x + GatedMLP(width, cfg.dtype, name="mlp")(y)
+        y = nn.Dense(width, dtype=cfg.dtype, name="up")(y)
         y = nn.gelu(y)
         return x + nn.Dense(cfg.hidden_size, dtype=cfg.dtype, name="down")(y)
 
@@ -95,14 +317,22 @@ class TransformerLM(nn.Module):
     """Tokens → logits.  ``attn_fn(q, k, v) -> out`` defaults to full causal
     attention; inject a sequence-parallel attention inside ``shard_map`` and
     pass this rank's global ``position_offset``.  ``mlp`` (a sublayer factory,
-    see :class:`Block`) swaps every block's MLP — e.g. for Switch-MoE."""
+    see :class:`Block`) swaps every block's MLP — e.g. for Switch-MoE.
+
+    With ``cfg.mtp_depth == 1`` and ``next_tokens`` (the tokens one place
+    on, ``t_{i+1}``) it returns ``(logits, mtp_logits)``: the
+    multi-token-prediction module of DeepSeek-V3 (arXiv:2412.19437 §2.2),
+    ``h'_i = M [norm(Emb(t_{i+1})); norm(h_i)]`` with ``h_i`` the trunk's
+    output before its final norm, one more block, and the trunk's own
+    embedding and head (one leaf each, used twice); ``mtp_logits`` predicts
+    ``t_{i+2}``."""
 
     cfg: GPTConfig
     mlp: Optional[Callable[[], nn.Module]] = None
 
     @nn.compact
     def __call__(self, tokens, *, attn_fn: Optional[AttnFn] = None,
-                 position_offset=0, positions=None):
+                 position_offset=0, positions=None, next_tokens=None):
         cfg = self.cfg
         if attn_fn is None:
             # the model layer is the perf path: opt into the fused TPU flash
@@ -114,13 +344,57 @@ class TransformerLM(nn.Module):
         # else: explicit per-token global positions — required by layouts
         # whose local block is not contiguous (e.g. the zigzag causal ring,
         # where a rank holds a front chunk and its mirrored back chunk)
-        x = nn.Embed(cfg.vocab_size, cfg.hidden_size, dtype=cfg.dtype,
-                     name="tok")(tokens)
-        x = x + nn.Embed(cfg.max_position, cfg.hidden_size, dtype=cfg.dtype,
-                         name="pos")(positions)
+        embed = nn.Embed(cfg.vocab_size, cfg.hidden_size, dtype=cfg.dtype,
+                         name="tok")
+        x = embed(tokens)
+        if cfg.position == "learned":
+            x = x + nn.Embed(cfg.max_position, cfg.hidden_size,
+                             dtype=cfg.dtype, name="pos")(positions)
         block_cls = nn.remat(Block, static_argnums=(2,)) if cfg.remat else Block
+        dense_blocks = cfg.experts.first_dense if cfg.experts else 0
         for i in range(cfg.num_layers):
-            x = block_cls(cfg, mlp=self.mlp, name=f"block_{i}")(x, attn_fn)
-        x = nn.LayerNorm(dtype=jnp.float32, name="ln_f")(x)
-        return nn.Dense(cfg.vocab_size, dtype=jnp.float32, use_bias=False,
-                        name="lm_head")(x)
+            x = block_cls(cfg, mlp=self.mlp,
+                          ffn="swiglu" if i < dense_blocks else None,
+                          name=f"block_{i}")(x, attn_fn, positions)
+        head = nn.Dense(cfg.vocab_size, dtype=jnp.float32, use_bias=False,
+                        name="lm_head")
+        logits = head(_norm(cfg, "ln_f")(x))
+        if next_tokens is None:
+            return logits
+        if not cfg.mtp_depth:
+            raise ValueError("next_tokens needs cfg.mtp_depth == 1")
+        merged = jnp.concatenate(
+            [_norm(cfg, "mtp_enorm")(embed(next_tokens)),
+             _norm(cfg, "mtp_hnorm")(x)], axis=-1).astype(cfg.dtype)
+        z = nn.Dense(cfg.hidden_size, use_bias=False, dtype=cfg.dtype,
+                     name="mtp_proj")(merged)
+        z = block_cls(cfg, mlp=self.mlp, name="mtp_block")(
+            z, attn_fn, positions)
+        return logits, head(_norm(cfg, "mtp_norm")(z))
+
+
+def next_token_loss(model: TransformerLM, params, model_state, tokens, *,
+                    mtp_weight: float = 0.0, attn_fn: Optional[AttnFn] = None):
+    """The training loss of ``tokens (B, T + 1 + mtp_depth)``: the mean over
+    the ``B * T`` positions of the cross entropy of the main head against
+    ``t_{i+1}`` plus, with a multi-token-prediction module, ``mtp_weight``
+    times that of the module's head against ``t_{i+2}``; logits in f32.
+    ``model_state`` holds the non-parameter collections (``buffers``)."""
+    import optax
+
+    depth = model.cfg.mtp_depth
+    t = tokens.shape[1] - 1 - depth
+    variables = {"params": params, **model_state}
+
+    def cross_entropy(logits, targets):
+        return optax.softmax_cross_entropy_with_integer_labels(
+            logits.astype(jnp.float32), targets).mean()
+
+    if not depth:
+        logits = model.apply(variables, tokens[:, :t], attn_fn=attn_fn)
+        return cross_entropy(logits, tokens[:, 1:])
+    logits, mtp_logits = model.apply(
+        variables, tokens[:, :t], attn_fn=attn_fn,
+        next_tokens=tokens[:, 1:t + 1])
+    return (cross_entropy(logits, tokens[:, 1:t + 1])
+            + mtp_weight * cross_entropy(mtp_logits, tokens[:, 2:]))

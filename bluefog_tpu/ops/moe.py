@@ -12,6 +12,13 @@ Pieces:
   capacity: returns dense dispatch/combine tensors.
 - :func:`expert_parallel_ffn` — dispatch → all_to_all → local expert FFNs →
   reverse all_to_all → combine, inside ``shard_map``.
+- :func:`sigmoid_topk_router` and :func:`routed_experts` — the dropless
+  layer for many small experts: sigmoid scores with a selection bias, top-k
+  of all the experts, and this chip's share of the result over the experts
+  it is told it holds (sort by expert, grouped matmuls over the held groups,
+  weighted gather back).  No capacity, no ``(T, E, C)`` tensor, no drop.
+  The one-hot routers above stay for the ``all_to_all`` path, which needs
+  the static per-expert capacity they provide.
 
 Gradient convention: normalize the per-rank loss by the GLOBAL token count
 (``local_sum / total_tokens``) so the per-rank loss seeds sum to the true
@@ -27,11 +34,14 @@ tests/test_moe.py::test_expert_parallel_grads_match_reference and the
 
 from __future__ import annotations
 
-from typing import NamedTuple
+import functools
+from typing import NamedTuple, Tuple
 
 import jax
 import jax.numpy as jnp
 from jax import lax
+
+from bluefog_tpu.metrics import comm as metrics_comm
 
 __all__ = [
     "RouterOutput",
@@ -40,6 +50,8 @@ __all__ = [
     "get_router",
     "expert_parallel_ffn",
     "moe_ffn_reference",
+    "sigmoid_topk_router",
+    "routed_experts",
 ]
 
 
@@ -242,3 +254,207 @@ def moe_ffn_reference(x, router_kernel, wi, wo, *, num_experts: int,
     outputs = _local_ffn(inputs, wi, wo)
     y = jnp.einsum("tec,ecd->td", combine.astype(x.dtype), outputs)
     return y, aux, metrics
+
+
+# ---------------------------------------------------------------------------
+# Dropless routing over the experts a chip holds
+# ---------------------------------------------------------------------------
+
+
+def sigmoid_topk_router(x, router_kernel, bias, *, top_k: int,
+                        scale: float = 1.0):
+    """DeepSeek-V3's ``noaux_tc`` routing with one group (arXiv:2412.19437
+    section 2.1.2): ``s = sigmoid(x @ W_g)`` in f32, the chosen set is the
+    ``top_k`` largest of ``s + bias``, the weights are ``scale * s_i / sum of
+    the chosen s`` — from ``s`` **without** the bias, which only steers the
+    selection and takes no gradient.
+
+    ``x (T, D)``, ``router_kernel (D, E)``, ``bias (E,)`` →
+    ``idx (T, top_k)`` int32 expert ids, ``weights (T, top_k)`` f32.  The
+    matmul runs at ``highest`` precision: on a TPU an f32 product is
+    otherwise rounded to bf16, and the chosen set flips on that rounding.
+    """
+    with jax.named_scope("bf.moe.route"):
+        s = jax.nn.sigmoid(jnp.dot(
+            x.astype(jnp.float32), router_kernel.astype(jnp.float32),
+            precision=lax.Precision.HIGHEST))
+        _, idx = lax.top_k(s + lax.stop_gradient(bias.astype(jnp.float32)),
+                           top_k)
+        chosen = jnp.take_along_axis(s, idx, axis=-1)
+        weights = scale * chosen / jnp.sum(chosen, axis=-1, keepdims=True)
+    return idx, weights
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
+def _expand_rows(x, order, inv, k):
+    """``x (T, D)`` → the ``(T*k, D)`` sorted rows ``x[order // k]``.  The
+    transpose is a gather too (every token has exactly ``k`` rows, found
+    through ``inv``), where autodiff of the gather would scatter-add."""
+    del inv
+    return x[order // k]
+
+
+def _expand_rows_fwd(x, order, inv, k):
+    return x[order // k], inv
+
+
+def _expand_rows_bwd(k, inv, g):
+    per_token = g[inv].reshape(g.shape[0] // k, k, g.shape[1])
+    dx = per_token.astype(jnp.float32).sum(axis=1).astype(g.dtype)
+    return dx, None, None
+
+
+_expand_rows.defvjp(_expand_rows_fwd, _expand_rows_bwd)
+
+
+@jax.custom_vjp
+def _collect_rows(rows, inv, order):
+    """The sorted rows back in assignment order: ``rows[inv]``; ``order`` is
+    ``inv``'s inverse, so the transpose is the gather ``g[order]``."""
+    del order
+    return rows[inv]
+
+
+def _collect_rows_fwd(rows, inv, order):
+    return rows[inv], order
+
+
+def _collect_rows_bwd(order, g):
+    return g[order], None, None
+
+
+_collect_rows.defvjp(_collect_rows_fwd, _collect_rows_bwd)
+
+
+def _gmm_tiling(m: int, k: int, n: int) -> Tuple[int, int, int]:
+    """Tiles ``(rows, contraction, output)`` of one grouped product, which
+    its two transposes in the backward pass reuse: 256
+    rows (the largest power of two up to it dividing ``m``: the kernel needs
+    exact row tiles) by, for each width, its largest divisor that is a
+    multiple of 128 and at most 1024 (2048 -> 1024, 768 -> 768).  Measured
+    on a v5e at 16 groups of about 256 rows of 2048 against experts 768
+    wide (one layer's nine products, forward and backward, 4,096 held rows
+    of 65,536): 6.35 ms, against 8.45 ms at (512, 256, 256) and 6.87 ms at
+    512 rows by the same widths; and the time grows half as fast with the
+    rows the router sends (PERF.md section 6, PR 28)."""
+    tm = 256
+    while m % tm:
+        tm //= 2
+
+    def widest(x):
+        tile = 1024
+        while tile > 128 and x % tile:
+            tile -= 128
+        return tile
+
+    return tm, widest(k), widest(n)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
+def _grouped_matmul(rows, w, sizes, interpret):
+    """``rows (R, K)`` sorted by group, ``w (G, K, N)``, ``sizes (G + 1,)``
+    int32 (the last group is the rows no held expert takes) →
+    ``(R, N)``: row ``r`` of group ``g < G`` times ``w[g]``, zero for the
+    last group.  The Pallas kernel (``megablox.gmm``) visits the row tiles
+    of the first ``G`` groups only."""
+    from jax.experimental.pallas.ops.tpu.megablox.gmm import gmm
+
+    return gmm(
+        rows, w, sizes, rows.dtype,
+        _gmm_tiling(rows.shape[0], w.shape[1], w.shape[2]),
+        interpret=interpret)
+
+
+def _grouped_matmul_fwd(rows, w, sizes, interpret):
+    return _grouped_matmul(rows, w, sizes, interpret), (rows, w, sizes)
+
+
+def _grouped_matmul_bwd(interpret, res, g):
+    from jax.experimental.pallas.ops.tpu.megablox.gmm import gmm, tgmm
+
+    rows, w, sizes = res
+    # the forward's tiles serve its two transposes (measured so)
+    tiling = _gmm_tiling(rows.shape[0], w.shape[1], w.shape[2])
+    d_rows = gmm(g, w, sizes, rows.dtype, tiling, transpose_rhs=True,
+                 interpret=interpret)
+    d_w = tgmm(rows.swapaxes(0, 1), g, sizes, w.dtype, tiling,
+               num_actual_groups=w.shape[0], interpret=interpret)
+    return d_rows, d_w, None
+
+
+_grouped_matmul.defvjp(_grouped_matmul_fwd, _grouped_matmul_bwd)
+
+
+def routed_experts(x, idx, weights, w_gate, w_up, w_down, *,
+                   num_experts: int, held: Tuple[int, int],
+                   backend: str = "auto"):
+    """This chip's share of a routed expert layer, without dropping a token.
+
+    ``x (T, D)`` tokens; ``idx``/``weights (T, k)`` from the router, over
+    all ``num_experts``; ``w_gate``/``w_up (count, D, F)`` and
+    ``w_down (count, F, D)`` the gated-SiLU experts ``held = (first,
+    count)`` names: global experts ``first .. first + count - 1``.  Returns
+    ``(y, record)``: ``y[t] = sum over the chosen i that are held of
+    weights[t, i] * E_i(x[t])`` in ``x.dtype`` — what the absent experts
+    would add is left out, for the caller's exchange (or nothing, on one
+    chip) to supply — and the routing record, not differentiated:
+    ``rows_per_expert (count,)`` and ``held_share`` (held assignments over
+    all ``T * k``).  With metrics on, the record feeds the counters
+    ``bf_moe_assignments_total`` and ``bf_moe_assignments_held_total``.
+
+    The ``T * k`` assignments are sorted by expert (held experts first, the
+    rest as one trailing group), the rows gathered in that order, three
+    grouped matmuls run over the held groups, and each token gathers its
+    ``k`` rows back and sums them by weight.  **The row buffer is all
+    ``T * k`` rows**: any routing fits, none is dropped (a token may send
+    ``min(k, count)`` rows here, and every token may).  Its cost is the
+    gathers' traffic over rows no held expert takes (``T * k * D`` elements
+    a gather; at 8,192 tokens, top-8 and 2,048 wide, 268 MB in bf16); the
+    grouped matmul skips those rows' tiles.
+
+    ``backend``: ``'gmm'`` the Pallas kernel (``megablox.gmm``), ``'ragged'``
+    ``lax.ragged_dot`` (portable, what CI runs), ``'auto'`` the kernel on a
+    TPU; ``'gmm_interpret'`` runs the kernel in the Pallas interpreter.
+    """
+    first, count = held
+    if not (0 <= first and first + count <= num_experts and count >= 1):
+        raise ValueError(f"held={held} is not a range of the "
+                         f"{num_experts} experts")
+    if w_gate.shape[0] != count:
+        raise ValueError(f"held {count} experts but the weights bring "
+                         f"{w_gate.shape[0]}")
+    if backend == "auto":
+        backend = "gmm" if jax.default_backend() == "tpu" else "ragged"
+    if backend not in ("gmm", "gmm_interpret", "ragged"):
+        raise ValueError(f"unknown backend {backend!r}")
+    t, k = idx.shape
+    n_rows = t * k
+    with jax.named_scope("bf.moe.dispatch"):
+        # held experts become groups 0 .. count-1, every other expert the
+        # trailing group `count`
+        group = jnp.minimum((idx.reshape(n_rows) - first) % num_experts,
+                            count).astype(jnp.int32)
+        order = jnp.argsort(group).astype(jnp.int32)
+        inv = jnp.zeros(n_rows, jnp.int32).at[order].set(
+            jnp.arange(n_rows, dtype=jnp.int32), unique_indices=True)
+        sizes = jnp.zeros(count + 1, jnp.int32).at[group].add(1)
+        rows = _expand_rows(x, order, inv, k)
+    with jax.named_scope("bf.moe.experts"):
+        if backend == "ragged":
+            def product(a, w):
+                return lax.ragged_dot(a, w, sizes[:count])
+        else:
+            def product(a, w):
+                return _grouped_matmul(a, w, sizes,
+                                       backend == "gmm_interpret")
+        wg, wu, wd = (w.astype(x.dtype) for w in (w_gate, w_up, w_down))
+        out = product(jax.nn.silu(product(rows, wg)) * product(rows, wu), wd)
+    with jax.named_scope("bf.moe.combine"):
+        picked = _collect_rows(out, inv, order).reshape(t, k, x.shape[1])
+        y = (picked.astype(jnp.float32) * weights[..., None]).sum(axis=1)
+    held_rows = jnp.sum(sizes[:count])
+    record = {"rows_per_expert": sizes[:count],
+              "held_share": held_rows.astype(jnp.float32) / n_rows}
+    y = metrics_comm.count(y, [("bf_moe_assignments_total", float(n_rows)),
+                               ("bf_moe_assignments_held_total", held_rows)])
+    return y.astype(x.dtype), record

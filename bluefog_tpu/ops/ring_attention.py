@@ -42,13 +42,16 @@ __all__ = [
 _NEG_INF = -1e30  # large finite negative: avoids -inf NaN traps in exp
 
 
-def _flash_eligible(q, k, causal, q_offset, k_offset) -> bool:
+def _flash_eligible(q, k, causal, q_offset, k_offset, v=None) -> bool:
     """Static eligibility check for the fused TPU attention kernel.
 
     The Pallas kernel (``jax.experimental.pallas.ops.tpu.splash_attention``)
     needs: a TPU backend, sequence length a multiple of its 128-row block,
     equal q/k lengths, and — because its causal mask is the standard aligned
     one — *static* offsets with ``q_offset == k_offset`` when causal.
+    The contract is ``q, k (..., D_qk)``, ``v (..., D_v)``: the kernel takes
+    values of their own width (latent attention: 192-wide q and k, 128-wide
+    v), both at least 32.
     """
     if jax.default_backend() != "tpu":
         return False
@@ -57,7 +60,8 @@ def _flash_eligible(q, k, causal, q_offset, k_offset) -> bool:
     if causal and q_offset != k_offset:
         return False
     t_q, t_k = q.shape[1], k.shape[1]
-    return t_q == t_k and t_q >= 128 and t_q % 128 == 0 and q.shape[-1] >= 32
+    widths = (q.shape[-1],) if v is None else (q.shape[-1], v.shape[-1])
+    return t_q == t_k and t_q >= 128 and t_q % 128 == 0 and min(widths) >= 32
 
 
 def _splash_block_sizes(t: int):
@@ -136,8 +140,10 @@ def _splash_kernel(t: int, heads: int, causal: bool, interpret: bool):
 
 def _splash_attention(q, k, v, *, causal: bool, scale: float,
                       interpret: bool = False):
-    """:func:`local_attention` on the splash kernel: ``(B, T, H, D)`` in and
-    out, operands in their own dtype, scores and softmax in f32."""
+    """:func:`local_attention` on the splash kernel: ``q, k (B, T, H, D_qk)``
+    and ``v (B, T, H, D_v)`` in, ``(B, T, H, D_v)`` out, operands in their
+    own dtype, scores and softmax in f32.  Mosaic takes ``D_qk = 192`` beside
+    ``D_v = 128`` as it is (compiled ahead of time for a v5e): no padding."""
     kernel = _splash_kernel(q.shape[1], q.shape[2], causal, interpret)
     # the kernel takes no scale: fold it into q, once, in f32
     q = (q.astype(jnp.float32) * scale).astype(q.dtype)
@@ -152,7 +158,10 @@ def local_attention(q, k, v, *, causal: bool = False, scale: Optional[float] = N
                     q_offset=0, k_offset=0, backend: str = "dense"):
     """Plain softmax attention on local blocks (also the Ulysses inner step).
 
-    Shapes: ``q (B, Tq, H, D)``, ``k/v (B, Tk, H, D)`` → ``(B, Tq, H, D)``.
+    Shapes: ``q (B, Tq, H, D_qk)``, ``k (B, Tk, H, D_qk)``,
+    ``v (B, Tk, H, D_v)`` → ``(B, Tq, H, D_v)``: the values may be narrower
+    (or wider) than the queries and keys, on both backends; the default
+    ``scale`` is ``D_qk ** -0.5``.
     ``q_offset``/``k_offset`` are the *global* positions of the first query /
     key row, used for causal masking of shifted blocks (may be traced).
 
@@ -168,14 +177,20 @@ def local_attention(q, k, v, *, causal: bool = False, scale: Optional[float] = N
     """
     scale = scale if scale is not None else 1.0 / math.sqrt(q.shape[-1])
 
-    eligible = _flash_eligible(q, k, causal, q_offset, k_offset)
+    if q.shape[-1] != k.shape[-1] or k.shape[:-1] != v.shape[:-1]:
+        raise ValueError(
+            "local_attention takes q, k (..., D_qk) and v (..., D_v) with k "
+            f"and v of one length and head count; got q {q.shape}, "
+            f"k {k.shape}, v {v.shape}")
+    eligible = _flash_eligible(q, k, causal, q_offset, k_offset, v)
     if backend == "flash" and not eligible:
         raise ValueError(
             "backend='flash' requires a TPU backend, Tq == Tk with T a "
-            "multiple of 128, head_dim >= 32, and static equal offsets when "
+            "multiple of 128, q, k (..., D_qk) and v (..., D_v) with both "
+            "widths >= 32, and static equal offsets when "
             f"causal; got backend={jax.default_backend()!r}, "
-            f"Tq={q.shape[1]}, Tk={k.shape[1]}, D={q.shape[-1]}, "
-            f"causal={causal}, offsets=({q_offset}, {k_offset}) — the Pallas "
+            f"Tq={q.shape[1]}, Tk={k.shape[1]}, D_qk={q.shape[-1]}, "
+            f"D_v={v.shape[-1]}, causal={causal}, offsets=({q_offset}, {k_offset}) — the Pallas "
             "kernel has no offset mask, so forcing it here would be "
             "silently wrong")
     if backend == "flash" or (backend == "auto" and eligible):

@@ -18,7 +18,9 @@ comes out by the repo's own means:
 3. ``gpt``      — ``examples/synthetic_benchmark.py --model gpt-small --comm
    neighbor --seq-len 2048``: two decentralized steps.
 4. ``flash``    — forced ``local_attention(backend="flash")`` forward and
-   backward against the dense path at bf16 tolerance.
+   backward against the dense path at bf16 tolerance, at GPT-small's heads
+   (T=2048) and at latent attention's (192-wide queries and keys, 128-wide
+   values, 32 heads, T=1024) against dense attention computed in f32.
 
 Any failed phase fails the run (no handler lets one pass), a whole-run
 watchdog turns a hang into a failure with stacks, and finding no TPU is a
@@ -49,6 +51,7 @@ GPT_ARGS = ["--model", "gpt-small", "--comm", "neighbor", "--seq-len", "2048",
             "--batch-size", "4", "--iters", "1", "--inner", "2",
             "--warmup", "0"]
 FLASH_SHAPE = (2, 2048, 12, 64)  # (B, T, H, D): GPT-small's heads at T=2048
+MLA_SHAPE = (2, 1024, 32, 192, 128)  # (B, T, H, D_qk, D_v): latent attention
 CHUNKED_LEAF_BYTES = (9 << 20) + 12  # three kernel invocations, ragged tail
 
 _REPO = os.path.dirname(os.path.abspath(__file__))
@@ -226,10 +229,7 @@ def run_flash():
 
     from bluefog_tpu.ops.ring_attention import local_attention
 
-    q, k, v = (jax.random.normal(jax.random.PRNGKey(i), FLASH_SHAPE,
-                                 jnp.bfloat16) for i in range(3))
-
-    def fwd_bwd(backend):
+    def fwd_bwd(backend, q, k, v):
         def loss(q, k, v):
             out = local_attention(q, k, v, causal=True, backend=backend)
             return jnp.sum(out.astype(jnp.float32) ** 2), out
@@ -237,15 +237,27 @@ def run_flash():
             loss, argnums=(0, 1, 2), has_aux=True)(q, k, v)
         return (out,) + grads
 
-    dense = jax.jit(lambda: fwd_bwd("dense"))()
-    flash = jax.jit(lambda: fwd_bwd("flash"))()
-    for name, a, b in zip(("out", "dq", "dk", "dv"), dense, flash):
-        a, b = np.asarray(a, np.float32), np.asarray(b, np.float32)
-        assert np.isfinite(b).all(), f"flash {name} not finite"
-        err = np.abs(a - b).max() / max(np.abs(a).max(), 1e-6)
-        assert err < 5e-2, f"flash {name} off dense by {err:.3g} of max"
-        print(f"chip_smoke: flash {name} vs dense: {err:.2e} of max",
-              flush=True)
+    def check(what, shapes, dense_dtype):
+        q, k, v = (jax.random.normal(jax.random.PRNGKey(i), shape,
+                                     jnp.bfloat16)
+                   for i, shape in enumerate(shapes))
+        with jax.default_matmul_precision("highest"):
+            dense = jax.jit(lambda: fwd_bwd(
+                "dense", *(t.astype(dense_dtype) for t in (q, k, v))))()
+        flash = jax.jit(lambda: fwd_bwd("flash", q, k, v))()
+        for name, a, b in zip(("out", "dq", "dk", "dv"), dense, flash):
+            a, b = np.asarray(a, np.float32), np.asarray(b, np.float32)
+            assert a.shape == b.shape, (what, name, a.shape, b.shape)
+            assert np.isfinite(b).all(), f"{what} {name} not finite"
+            err = np.abs(a - b).max() / max(np.abs(a).max(), 1e-6)
+            assert err < 5e-2, f"{what} {name} off dense by {err:.3g} of max"
+            print(f"chip_smoke: {what} {name} vs dense: {err:.2e} of max",
+                  flush=True)
+
+    check("flash", (FLASH_SHAPE,) * 3, jnp.bfloat16)
+    b, t, h, d_qk, d_v = MLA_SHAPE
+    check("flash 192/128", ((b, t, h, d_qk), (b, t, h, d_qk), (b, t, h, d_v)),
+          jnp.float32)
 
 
 def main():

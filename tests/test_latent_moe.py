@@ -1,0 +1,754 @@
+"""The configurable decoder block: latent attention, the dropless expert
+layer over the experts a chip holds, the multi-token-prediction module, held
+to the plain reference of ``chipbench/latent_moe_reference.py`` (loss and
+gradients), at tiny widths in f32 on the CPU.  Also: the published widths of
+``chipbench/configs/joyai-llm-flash.json``, the counting functions, and the
+family through the benchmark's harness on virtual CPU devices."""
+
+import dataclasses
+import functools
+import json
+import os
+import re
+
+import flax.linen as nn
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from bluefog_tpu.models.transformer import (
+    Block, ExpertSizes, GPTConfig, LatentAttention, LatentSizes,
+    RoutedSharedFFN, TransformerLM, next_token_loss, rotary)
+from bluefog_tpu.ops import moe as moe_ops
+from bluefog_tpu.ops.moe import routed_experts, sigmoid_topk_router
+from bluefog_tpu.ops.ring_attention import _splash_attention, local_attention
+
+from chipbench import latent_moe_flops
+from chipbench import latent_moe_reference as ref
+
+REPO = os.path.join(os.path.dirname(os.path.abspath(__file__)), "..")
+E, K = 16, 4                       # router outputs, experts a token
+LATENT = LatentSizes(q_lora_rank=48, kv_lora_rank=32, qk_nope_head_dim=16,
+                     qk_rope_head_dim=8, v_head_dim=16, rope_theta=1e4)
+
+
+def tiny(held=(4, 8), **over):
+    return GPTConfig(**{**dict(
+        vocab_size=128, hidden_size=64, num_layers=2, num_heads=4,
+        dtype=jnp.float32, attention="latent", ffn="routed+shared",
+        norm="rmsnorm", position="rotary", ffn_width=96, latent=LATENT,
+        experts=ExpertSizes(num_experts=E, top_k=K, width=32, held=held,
+                            first_dense=1), mtp_depth=1), **over})
+
+
+def sizes_of(cfg, mtp_weight=0.1):
+    return {"heads": cfg.num_heads, "qk_nope": cfg.latent.qk_nope_head_dim,
+            "qk_rope": cfg.latent.qk_rope_head_dim,
+            "rope_theta": cfg.latent.rope_theta, "eps": cfg.norm_eps,
+            "top_k": cfg.experts.top_k, "scale": cfg.experts.scale,
+            "held_first": cfg.experts.held[0], "mtp_weight": mtp_weight}
+
+
+def rand(shape, key, scale=1.0):
+    return scale * jax.random.normal(jax.random.PRNGKey(key), shape,
+                                     jnp.float32)
+
+
+def close(got, want, tol=2e-5):
+    """Leaf by leaf, as a share of the reference leaf's largest entry."""
+    got = jax.tree_util.tree_leaves_with_path(got)
+    want = jax.tree_util.tree_leaves(want)
+    assert len(got) == len(want)
+    for (path, a), b in zip(got, want):
+        assert a.shape == b.shape
+        err = float(jnp.abs(a - b).max()) / max(float(jnp.abs(b).max()), 1e-6)
+        assert err < tol, f"{jax.tree_util.keystr(path)} off by {err:.3g}"
+
+
+@pytest.fixture(autouse=True)
+def _highest_precision():
+    with jax.default_matmul_precision("highest"):
+        yield
+
+
+# ---- rotary and the latent attention --------------------------------------
+
+
+@pytest.mark.parametrize("width", [8, 64])
+def test_rotary_equals_the_reference_and_keeps_the_norm(width):
+    x = rand((2, 12, 3, width), 0)
+    positions = jnp.arange(5, 17)[None, :]
+    got = rotary(x, positions, 32e6)
+    want = jnp.moveaxis(ref.rotary(jnp.moveaxis(x, 1, 2), positions[0],
+                                   32e6), 2, 1)
+    np.testing.assert_allclose(got, want, atol=1e-5)
+    np.testing.assert_allclose(jnp.linalg.norm(got, axis=-1),
+                               jnp.linalg.norm(x, axis=-1), rtol=1e-5)
+    # position 0 turns nothing
+    np.testing.assert_allclose(rotary(x, jnp.zeros((1, 12)), 32e6), x)
+
+
+def _heads_seen(offset):
+    cfg = tiny()
+    seen = {}
+
+    def attn_fn(q, k, v):
+        seen.update(q=q, k=k, v=v)
+        return local_attention(q, k, v, causal=True)
+
+    y = rand((2, 12, 64), 1)
+    module = LatentAttention(cfg)
+    positions = offset + jnp.arange(12)[None, :]
+    params = module.init(jax.random.PRNGKey(0), y, attn_fn, positions)
+    module.apply(params, y, attn_fn, positions)
+    return seen
+
+
+def test_rotary_turns_the_64_wide_part_only():
+    nope = LATENT.qk_nope_head_dim
+    here, moved = _heads_seen(0), _heads_seen(7)
+    for name in "qk":
+        np.testing.assert_array_equal(here[name][..., :nope],
+                                      moved[name][..., :nope])
+        assert float(jnp.abs(here[name][..., nope:]
+                             - moved[name][..., nope:]).max()) > 1e-2
+    np.testing.assert_array_equal(here["v"], moved["v"])
+    assert here["q"].shape == (2, 12, 4, 24) and here["v"].shape[-1] == 16
+
+
+def test_the_rotary_key_is_one_per_token_shared_by_the_heads():
+    k = _heads_seen(3)["k"]
+    nope = LATENT.qk_nope_head_dim
+    for head in range(1, 4):
+        np.testing.assert_array_equal(k[:, :, 0, nope:], k[:, :, head, nope:])
+    assert float(jnp.abs(k[:, :, 0, :nope] - k[:, :, 1, :nope]).max()) > 1e-3
+
+
+@pytest.mark.parametrize("causal", [False, True], ids=["full", "causal"])
+def test_dense_attention_takes_values_of_their_own_width(causal):
+    q, k, v = rand((2, 24, 3, 24), 0), rand((2, 24, 3, 24), 1), rand(
+        (2, 24, 3, 16), 2)
+    got = local_attention(q, k, v, causal=causal)
+    assert got.shape == (2, 24, 3, 16)
+    if causal:
+        np.testing.assert_allclose(got, ref.causal_attention(q, k, v),
+                                   atol=1e-5)
+    scores = jnp.einsum("bqhd,bkhd->bhqk", q, k) / np.sqrt(24.0)
+    if causal:
+        scores = jnp.where(jnp.tril(jnp.ones((24, 24), bool)), scores,
+                           -jnp.inf)
+    want = jnp.einsum("bhqk,bkhd->bqhd", jax.nn.softmax(scores, -1), v)
+    np.testing.assert_allclose(got, want, atol=1e-5)
+
+
+def test_attention_refuses_keys_and_queries_of_two_widths():
+    with pytest.raises(ValueError, match="D_qk"):
+        local_attention(jnp.zeros((1, 8, 2, 24)), jnp.zeros((1, 8, 2, 16)),
+                        jnp.zeros((1, 8, 2, 16)))
+
+
+# the published head: 192-wide queries and keys, 128-wide values
+_MLA_SHAPES = ((1, 256, 2, 192), (1, 256, 2, 128))
+
+
+@pytest.mark.parametrize("causal", [False, True], ids=["full", "causal"])
+def test_splash_interpret_192_128_forward_matches_dense(causal):
+    q, k = rand(_MLA_SHAPES[0], 0), rand(_MLA_SHAPES[0], 1)
+    v = rand(_MLA_SHAPES[1], 2)
+    scale = 192 ** -0.5
+    got = jax.jit(lambda q, k, v: _splash_attention(
+        q, k, v, causal=causal, scale=scale, interpret=True))(q, k, v)
+    want = local_attention(q, k, v, causal=causal, backend="dense")
+    assert got.shape == _MLA_SHAPES[1]
+    np.testing.assert_allclose(got, want, atol=1e-4, rtol=1e-4)
+
+
+def test_splash_interpret_192_128_grads_match_dense():
+    q, k = rand(_MLA_SHAPES[0], 0), rand(_MLA_SHAPES[0], 1)
+    v = rand(_MLA_SHAPES[1], 2)
+
+    def grads(attn):
+        return jax.jit(jax.grad(
+            lambda q, k, v: jnp.sum(attn(q, k, v) ** 2), argnums=(0, 1, 2)))(
+                q, k, v)
+
+    splash = grads(lambda q, k, v: _splash_attention(
+        q, k, v, causal=True, scale=192 ** -0.5, interpret=True))
+    dense = grads(lambda q, k, v: local_attention(q, k, v, causal=True))
+    for name, a, b in zip("qkv", splash, dense):
+        assert a.shape == b.shape
+        err = float(jnp.abs(a - b).max() / jnp.abs(b).max())
+        assert err < 1e-4, f"d{name} off dense by {err:.3g} of max"
+
+
+# ---- the router ------------------------------------------------------------
+
+
+@pytest.mark.parametrize("seed", [0, 1, 2])
+def test_selection_uses_s_plus_b_and_weights_use_s(seed):
+    x, kernel = rand((32, 64), seed), rand((64, E), seed + 10, 0.2)
+    bias = rand((E,), seed + 20, 0.5)
+    idx, weights = sigmoid_topk_router(x, kernel, bias, top_k=K, scale=2.5)
+    s = jax.nn.sigmoid(x @ kernel)
+    want_idx = jnp.argsort(-(s + bias), axis=-1)[:, :K]
+    assert (jnp.sort(idx, -1) == jnp.sort(want_idx, -1)).all()
+    chosen = jnp.take_along_axis(s, idx, -1)            # s, not s + b
+    np.testing.assert_allclose(
+        weights, 2.5 * chosen / chosen.sum(-1, keepdims=True), rtol=1e-6)
+    np.testing.assert_allclose(weights.sum(-1), 2.5, rtol=1e-6)
+    # without the bias another set is chosen somewhere: b steers it
+    plain, _ = sigmoid_topk_router(x, kernel, jnp.zeros(E), top_k=K)
+    assert (jnp.sort(plain, -1) != jnp.sort(idx, -1)).any()
+
+
+def test_the_selection_bias_takes_no_gradient():
+    x, kernel = rand((8, 64), 0), rand((64, E), 1, 0.2)
+
+    def total(bias):
+        return sigmoid_topk_router(x, kernel, bias, top_k=K)[1].sum()
+
+    assert float(jnp.abs(jax.grad(total)(rand((E,), 2))).max()) == 0.0
+
+
+# ---- routed_experts --------------------------------------------------------
+
+
+def _expert_weights(count, d=64, f=32, key=3):
+    return (rand((count, d, f), key, 0.1), rand((count, d, f), key + 1, 0.1),
+            rand((count, f, d), key + 2, 0.1))
+
+
+def _dense_share(x, kernel, bias, wg, wu, wd, first):
+    """Every held expert sees every token, weighted 0 where not chosen."""
+    s = jax.nn.sigmoid(x @ kernel)
+    steer = s + bias
+    chosen = steer >= jax.lax.top_k(steer, K)[0][:, -1:]
+    g = 2.5 * s * chosen / (s * chosen).sum(-1, keepdims=True)
+    return sum(g[:, first + i, None]
+               * ((jax.nn.silu(x @ wg[i]) * (x @ wu[i])) @ wd[i])
+               for i in range(wg.shape[0]))
+
+
+def _routed(x, kernel, bias, wg, wu, wd, held, backend):
+    idx, weights = sigmoid_topk_router(x, kernel, bias, top_k=K, scale=2.5)
+    return routed_experts(x, idx, weights, wg, wu, wd, num_experts=E,
+                          held=held, backend=backend)
+
+
+@pytest.mark.parametrize("backend", ["ragged", "gmm_interpret"])
+@pytest.mark.parametrize("held", [(0, 4), (4, 8), (12, 4), (0, 16)],
+                         ids=lambda h: f"held{h[0]}+{h[1]}")
+def test_routed_experts_match_the_dense_share_loss_and_gradients(held,
+                                                                 backend):
+    x, kernel = rand((64, 64), 0), rand((64, E), 1, 0.2)
+    bias = rand((E,), 2, 0.1)
+    w = _expert_weights(held[1])
+    args = (x, kernel, *w)
+
+    def got(x, kernel, wg, wu, wd):
+        return _routed(x, kernel, bias, wg, wu, wd, held, backend)[0]
+
+    def want(x, kernel, wg, wu, wd):
+        return _dense_share(x, kernel, bias, wg, wu, wd, held[0])
+
+    np.testing.assert_allclose(got(*args), want(*args), atol=2e-5)
+    grads = [jax.grad(lambda *a: jnp.sum(f(*a) ** 2), argnums=range(5))(*args)
+             for f in (got, want)]
+    close(grads[0], grads[1])
+
+
+@pytest.mark.parametrize("backend", ["ragged", "gmm_interpret"])
+def test_no_token_is_dropped_when_every_one_routes_to_held_experts(backend):
+    """All 16 experts held, and a bias that sends every token to the same
+    four: 64 rows an expert where a balanced router would send 16."""
+    x, kernel = rand((64, 64), 0), rand((64, E), 1, 0.2)
+    bias = jnp.zeros(E).at[jnp.array([1, 2, 3, 5])].set(10.0)
+    w = _expert_weights(E)
+    y, record = _routed(x, kernel, bias, *w, (0, E), backend)
+    np.testing.assert_allclose(
+        y, _dense_share(x, kernel, bias, *w, 0), atol=2e-5)
+    assert float(record["held_share"]) == 1.0
+    rows = np.asarray(record["rows_per_expert"])
+    assert rows.sum() == 64 * K and set(np.nonzero(rows)[0]) == {1, 2, 3, 5}
+    assert (rows[[1, 2, 3, 5]] == 64).all()
+
+
+@pytest.mark.parametrize("backend", ["ragged", "gmm_interpret"])
+def test_routed_output_is_zero_when_no_token_routes_to_a_held_expert(backend):
+    x, kernel = rand((64, 64), 0), rand((64, E), 1, 0.2)
+    bias = jnp.zeros(E).at[8:12].set(10.0)        # every token takes 8..11
+    w = _expert_weights(4)
+    y, record = _routed(x, kernel, bias, *w, (0, 4), backend)
+    assert float(jnp.abs(y).max()) == 0.0
+    assert float(record["held_share"]) == 0.0
+    assert int(record["rows_per_expert"].sum()) == 0
+
+
+def test_routed_experts_refuses_a_share_that_is_no_range_of_the_experts():
+    x, w = rand((8, 64), 0), _expert_weights(4)
+    idx, weights = jnp.zeros((8, K), jnp.int32), jnp.ones((8, K))
+    with pytest.raises(ValueError, match="held"):
+        routed_experts(x, idx, weights, *w, num_experts=E, held=(14, 4))
+    with pytest.raises(ValueError, match="weights bring"):
+        routed_experts(x, idx, weights, *w, num_experts=E, held=(0, 8))
+    with pytest.raises(ValueError, match="backend"):
+        routed_experts(x, idx, weights, *w, num_experts=E, held=(0, 4),
+                       backend="onehot")
+
+
+@pytest.mark.parametrize("m,k,n,want", [
+    (65536, 2048, 768, (256, 1024, 768)), (65536, 768, 2048, (256, 768, 1024)),
+    (65536, 2048, 1024, (256, 1024, 1024)), (256, 64, 32, (256, 128, 128)),
+    (96, 128, 128, (32, 128, 128))])
+def test_grouped_matmul_tiles_divide_the_rows_and_each_width(m, k, n, want):
+    assert moe_ops._gmm_tiling(m, k, n) == want
+
+
+def test_routing_record_feeds_the_metrics_when_they_are_on():
+    from bluefog_tpu.metrics import registry
+
+    x, kernel = rand((64, 64), 0), rand((64, E), 1, 0.2)
+    w = _expert_weights(8)
+    registry.metrics_stop()
+    registry._STOPPED = False
+    reg = registry.metrics_start()
+    try:
+        _, record = jax.jit(lambda x: _routed(
+            x, kernel, jnp.zeros(E), *w, (4, 8), "ragged"))(x)
+        jax.effects_barrier()
+        snap = reg.snapshot()
+        assert snap["bf_moe_assignments_total"] == 64 * K
+        assert snap["bf_moe_assignments_held_total"] == int(
+            record["rows_per_expert"].sum())
+    finally:
+        registry.metrics_stop()
+        registry._STOPPED = False
+
+
+# ---- the shares add up -----------------------------------------------------
+
+
+@pytest.mark.parametrize("count", [1, 4, 8], ids=lambda c: f"{E // c}shares")
+def test_the_shares_of_every_chip_add_up_to_the_uncut_layer(count):
+    """``E / count`` chips hold ``count`` experts each; their routed parts,
+    with the shared expert counted once, sum to what one chip holding all
+    the experts computes, and to the plain reference's uncut layer."""
+    whole_cfg = tiny(held=(0, E))
+    y = rand((2, 16, 64), 5)
+    whole = RoutedSharedFFN(whole_cfg)
+    variables = whole.init(jax.random.PRNGKey(0), y)
+    variables = {"params": variables["params"], "buffers": {
+        "selection_bias": rand((E,), 6, 0.1)}}
+    uncut = whole.apply(variables, y)
+    params = variables["params"]
+    shared_once = ref.gated_mlp(params["shared"], y)
+    total = shared_once
+    for first in range(0, E, count):
+        share = {**params, **{name: params[name][first:first + count]
+                              for name in ("w_gate", "w_up", "w_down")}}
+        out = RoutedSharedFFN(tiny(held=(first, count))).apply(
+            {"params": share, "buffers": variables["buffers"]}, y)
+        total = total + (out - shared_once)
+    np.testing.assert_allclose(total, uncut, atol=2e-5)
+    want = ref.expert_layer(params, variables["buffers"]["selection_bias"],
+                            y, sizes_of(whole_cfg))
+    np.testing.assert_allclose(uncut, want, atol=2e-5)
+
+
+# ---- block and whole model against the plain reference --------------------
+
+
+@pytest.mark.duration_budget(60)   # the first compile of the plain reference
+@pytest.mark.parametrize("ffn", ["swiglu", None], ids=["dense", "experts"])
+def test_block_matches_the_reference_loss_and_gradients(ffn):
+    cfg = tiny()
+    x = rand((1, 8, 64), 7)
+    positions = jnp.arange(8)[None, :]
+    attn = functools.partial(local_attention, causal=True)
+    module = Block(cfg, ffn=ffn)
+    variables = jax.jit(lambda key: module.init(key, x, attn, positions))(
+        jax.random.PRNGKey(1))
+    buffers = jax.tree_util.tree_map(lambda b: rand(b.shape, 8, 0.1),
+                                     variables.get("buffers", {}))
+
+    def got(params, x):
+        return module.apply({"params": params, "buffers": buffers}, x, attn,
+                            positions)
+
+    def want(params, x):
+        return ref.block(params, buffers, x, positions[0], sizes_of(cfg))
+
+    params = variables["params"]
+    np.testing.assert_allclose(got(params, x), want(params, x), atol=2e-5)
+    grads = [jax.jit(jax.grad(lambda p, x: jnp.sum(f(p, x) ** 2),
+                              argnums=(0, 1)))(params, x)
+             for f in (got, want)]
+    close(grads[0], grads[1])
+
+
+@functools.lru_cache(maxsize=None)
+def _model_and_state(cfg, seed=0):
+    model = TransformerLM(cfg)
+    tokens = jnp.zeros((1, 8), jnp.int32)
+    variables = jax.jit(functools.partial(model.init, next_tokens=tokens))(
+        jax.random.PRNGKey(seed), tokens)
+    buffers = jax.tree_util.tree_map(lambda b: rand(b.shape, 9, 0.1),
+                                     variables["buffers"])
+    return model, variables["params"], {"buffers": buffers}
+
+
+@pytest.mark.duration_budget(60)   # two whole-model gradient programs
+@pytest.mark.parametrize("remat", [False, True], ids=["plain", "remat"])
+def test_model_matches_the_reference_loss_and_gradients(remat):
+    # one dense block, one expert block (remat: the module's alone), the module
+    cfg = tiny(remat=remat, num_layers=1 if remat else 2)
+    model, params, state = _model_and_state(cfg)
+    tokens = jax.random.randint(jax.random.PRNGKey(4), (2, 10), 0, 128)
+    got = jax.jit(jax.value_and_grad(lambda p: next_token_loss(
+        model, p, state, tokens, mtp_weight=0.1)))(params)
+    want = jax.jit(jax.value_and_grad(lambda p: ref.loss(
+        sizes_of(cfg), p, state, tokens)))(params)
+    assert abs(float(got[0]) - float(want[0])) < 1e-5 * float(want[0])
+    close(got[1], want[1], tol=1e-4)
+    # every leaf learns: none is left out of the program
+    for path, g in jax.tree_util.tree_leaves_with_path(got[1]):
+        assert float(jnp.abs(g).max()) > 0, jax.tree_util.keystr(path)
+
+
+def test_the_reference_reads_the_tokens_the_loss_says():
+    """Main head against ``t_{i+1}``, the module against ``t_{i+2}``: with
+    the second prediction switched off the last token does not matter, with
+    it on it does."""
+    cfg = tiny()
+    model, params, state = _model_and_state(cfg)
+    tokens = jax.random.randint(jax.random.PRNGKey(4), (2, 18), 0, 128)
+    other = tokens.at[:, -1].set((tokens[:, -1] + 1) % 128)
+
+    @jax.jit
+    def loss(tok, weight):
+        return next_token_loss(model, params, state, tok, mtp_weight=weight)
+
+    assert float(loss(tokens, 0.0)) == float(loss(other, 0.0))
+    assert float(loss(tokens, 0.1)) != float(loss(other, 0.1))
+
+
+@pytest.mark.parametrize("leaf", [("tok", "embedding"), ("lm_head", "kernel")],
+                         ids=["embedding", "head"])
+def test_mtp_shares_the_embedding_and_the_head(leaf):
+    """One leaf each, no copy for the module; its gradient is the sum of the
+    trunk's use and the module's, and both are there."""
+    cfg = tiny()
+    model, params, state = _model_and_state(cfg)
+    paths = [jax.tree_util.keystr(p)
+             for p, _ in jax.tree_util.tree_leaves_with_path(params)]
+    assert sum("embedding" in p for p in paths) == 1
+    assert sum("lm_head" in p for p in paths) == 1
+    tokens = jax.random.randint(jax.random.PRNGKey(4), (2, 18), 0, 128)
+
+    @jax.jit
+    def grad(weight):
+        g = jax.grad(lambda p: next_token_loss(
+            model, p, state, tokens, mtp_weight=weight))(params)
+        return g[leaf[0]][leaf[1]]
+
+    trunk, both = grad(0.0), grad(1.0)
+    module = both - trunk
+    assert float(jnp.abs(trunk).max()) > 1e-4
+    assert float(jnp.abs(module).max()) > 1e-4
+    np.testing.assert_allclose(grad(0.3), trunk + 0.3 * module, atol=1e-6)
+
+
+def test_without_a_module_the_model_returns_logits_alone():
+    cfg = tiny(mtp_depth=0)
+    model = TransformerLM(cfg)
+    tokens = jnp.zeros((1, 8), jnp.int32)
+    variables = model.init(jax.random.PRNGKey(0), tokens)
+    assert not any("mtp" in k for k in variables["params"])
+    assert "pos" not in variables["params"]          # rotary: no table
+    assert model.apply(variables, tokens).shape == (1, 8, 128)
+    with pytest.raises(ValueError, match="mtp_depth"):
+        model.apply(variables, tokens, next_tokens=tokens)
+    loss = next_token_loss(model, variables["params"],
+                           {"buffers": variables["buffers"]},
+                           jnp.zeros((1, 9), jnp.int32))
+    assert np.isfinite(float(loss))
+
+
+@pytest.mark.parametrize("over,match", [
+    (dict(attention="sliding"), "attention"), (dict(ffn="relu"), "ffn"),
+    (dict(norm="batch"), "norm"), (dict(position="alibi"), "position"),
+    (dict(latent=None), "come together"),
+    (dict(experts=None), "come together"),
+    (dict(position="learned"), "rotary"), (dict(mtp_depth=2), "mtp_depth")])
+def test_the_configuration_refuses_kinds_it_cannot_build(over, match):
+    with pytest.raises(ValueError, match=match):
+        tiny(**over)
+
+
+@functools.lru_cache(maxsize=1)
+def _name_stacks_of_a_train_step():
+    cfg = tiny(remat=True)
+    model, params, state = _model_and_state(cfg)
+    tokens = jnp.zeros((2, 18), jnp.int32)
+    text = jax.jit(jax.grad(lambda p: next_token_loss(
+        model, p, state, tokens, mtp_weight=0.1))).lower(params).as_text(
+            debug_info=True)
+    return frozenset(re.findall(r'loc\("([^"]*)"', text))
+
+
+@pytest.mark.parametrize("scope", ["bf.moe.route", "bf.moe.dispatch",
+                                   "bf.moe.experts", "bf.moe.combine",
+                                   "bf.mla.project"])
+def test_the_step_carries_the_scopes_the_benchmark_reads(scope):
+    """Forward and backward: a custom-vjp rule keeps its forward scope."""
+    names = _name_stacks_of_a_train_step()
+    assert any(scope in n and "transpose" not in n for n in names)
+    assert any(scope in n and "transpose" in n for n in names)
+    # leaf-level: no bf scope opens inside another
+    assert not any(len(set(re.findall(r"bf\.\w+\.\w+", n))) > 1
+                   for n in names)
+
+
+# ---- the GPT-2 decoder is what it was --------------------------------------
+
+
+def _decoder_as_it_was():
+    """``Block`` and ``TransformerLM`` as they stood before the block took
+    its kinds from the configuration (commit 58bdb1a), word for word."""
+    from typing import Callable, Optional
+
+    class Block(nn.Module):
+        cfg: GPTConfig
+        mlp: Optional[Callable[[], nn.Module]] = None
+
+        @nn.compact
+        def __call__(self, x, attn_fn):
+            cfg = self.cfg
+            head_dim = cfg.hidden_size // cfg.num_heads
+            y = nn.LayerNorm(dtype=jnp.float32, name="ln1")(x).astype(cfg.dtype)
+            qkv = nn.Dense(3 * cfg.hidden_size, dtype=cfg.dtype, name="qkv")(y)
+            q, k, v = jnp.split(qkv, 3, axis=-1)
+
+            def heads(t):
+                return t.reshape(t.shape[:-1] + (cfg.num_heads, head_dim))
+
+            a = attn_fn(heads(q), heads(k), heads(v))
+            a = a.reshape(a.shape[:-2] + (cfg.hidden_size,))
+            x = x + nn.Dense(cfg.hidden_size, dtype=cfg.dtype, name="proj")(a)
+
+            y = nn.LayerNorm(dtype=jnp.float32, name="ln2")(x).astype(cfg.dtype)
+            if self.mlp is not None:
+                return x + self.mlp()(y)
+            y = nn.Dense(cfg.mlp_ratio * cfg.hidden_size, dtype=cfg.dtype, name="up")(y)
+            y = nn.gelu(y)
+            return x + nn.Dense(cfg.hidden_size, dtype=cfg.dtype, name="down")(y)
+
+    class TransformerLM(nn.Module):
+        cfg: GPTConfig
+        mlp: Optional[Callable[[], nn.Module]] = None
+
+        @nn.compact
+        def __call__(self, tokens, *, attn_fn=None, position_offset=0,
+                     positions=None):
+            cfg = self.cfg
+            if attn_fn is None:
+                attn_fn = lambda q, k, v: local_attention(q, k, v, causal=True,
+                                                          backend="auto")
+            if positions is None:
+                positions = position_offset + jnp.arange(tokens.shape[1])[None, :]
+            x = nn.Embed(cfg.vocab_size, cfg.hidden_size, dtype=cfg.dtype,
+                         name="tok")(tokens)
+            x = x + nn.Embed(cfg.max_position, cfg.hidden_size, dtype=cfg.dtype,
+                             name="pos")(positions)
+            block_cls = nn.remat(Block, static_argnums=(2,)) if cfg.remat else Block
+            for i in range(cfg.num_layers):
+                x = block_cls(cfg, mlp=self.mlp, name=f"block_{i}")(x, attn_fn)
+            x = nn.LayerNorm(dtype=jnp.float32, name="ln_f")(x)
+            return nn.Dense(cfg.vocab_size, dtype=jnp.float32, use_bias=False,
+                            name="lm_head")(x)
+
+    return TransformerLM
+
+
+@pytest.mark.parametrize("remat", [False, True], ids=["plain", "remat"])
+def test_gpt_decoder_parameter_tree_and_lowered_program_unchanged(remat):
+    cfg = dataclasses.replace(GPTConfig.tiny(), remat=remat)
+    tokens = jnp.zeros((2, 32), jnp.int32)
+
+    def lowered(model):
+        shapes = jax.eval_shape(
+            lambda: model.init(jax.random.PRNGKey(0), tokens))
+        step = jax.jit(jax.grad(lambda p, t: model.apply(p, t).sum()))
+        return (jax.tree_util.tree_map(lambda s: (s.shape, s.dtype), shapes),
+                step.lower(shapes, tokens).as_text())
+
+    now, was = lowered(TransformerLM(cfg)), lowered(_decoder_as_it_was()(cfg))
+    assert now[0] == was[0]
+    assert now[1] == was[1]
+
+
+# ---- the configuration file and the counting functions ---------------------
+
+CONFIG = os.path.join(REPO, "chipbench", "configs", "joyai-llm-flash.json")
+PUBLISHED = {
+    "hidden_size": 2048, "q_lora_rank": 1536, "kv_lora_rank": 512,
+    "qk_nope_head_dim": 128, "qk_rope_head_dim": 64, "v_head_dim": 128,
+    "num_attention_heads": 32, "moe_intermediate_size": 768,
+    "num_experts_per_tok": 8, "intermediate_size": 7168,
+    "n_shared_experts": 1, "rope_theta": 32000000,
+    "routed_scaling_factor": 2.5, "num_nextn_predict_layers": 1,
+    "first_k_dense_replace": 1, "rms_norm_eps": 1e-06,
+    "scoring_func": "sigmoid", "topk_method": "noaux_tc"}
+
+
+def _family_from(config):
+    from chipbench.cell import Manifest, load_json
+
+    manifest = Manifest.load(os.path.join(REPO, "BENCHMARK.json"))
+    traffic = load_json(manifest.find("traffic", "t4096.b2.remat.solo"))
+    return manifest.module("families", config["family"]).build(
+        config, traffic)
+
+
+def _family():
+    with open(CONFIG) as f:
+        config = json.load(f)
+    return config, _family_from(config)
+
+
+@pytest.mark.parametrize("key", sorted(PUBLISHED))
+def test_the_configuration_file_keeps_the_published_widths(key):
+    with open(CONFIG) as f:
+        config = json.load(f)
+    assert config[key] == PUBLISHED[key]
+    assert key not in config["reduced"]
+
+
+def test_the_configuration_file_states_its_cuts_and_its_deployment():
+    with open(CONFIG) as f:
+        config = json.load(f)
+    with open(os.path.join(REPO, "BENCHMARK.json")) as f:
+        entry, = (c for c in json.load(f)["configs"]
+                  if c["name"] == "joyai-llm-flash")
+    assert entry["reduced"] == config["reduced"] == [
+        "num_hidden_layers", "n_routed_experts", "vocab_size"]
+    assert entry["source"] == config["source"]
+    assert (config["num_hidden_layers"], config["n_routed_experts"],
+            config["vocab_size"]) == (5, 16, 16160)
+    deployment = config["deployment"]
+    assert deployment["router_outputs"] == 256          # the router is whole
+    assert deployment["published"] == {
+        "num_hidden_layers": 40, "n_routed_experts": 256,
+        "vocab_size": 129280}
+    assert deployment["chips_sharing_a_layer"] * config[
+        "n_routed_experts"] == 256
+    assert deployment["vocabulary_shards"] * config["vocab_size"] == 129280
+
+
+def test_the_parameter_count_is_the_files():
+    config, family = _family()
+    cfg = family.model.cfg
+    assert cfg.latent == LatentSizes(1536, 512, 128, 64, 128, 32e6)
+    assert cfg.experts == ExpertSizes(256, 8, 768, 1, 2.5, (0, 16), 1)
+    params, state = jax.eval_shape(family.init, jax.random.PRNGKey(0))
+    count = sum(int(np.prod(leaf.shape))
+                for leaf in jax.tree_util.tree_leaves(params))
+    print(f"joyai-llm-flash as run: {count:,} parameters")
+    assert count == config["parameters"] == 680_439_808
+    attention = sum(int(np.prod(leaf.shape)) for leaf in
+                    jax.tree_util.tree_leaves(params["block_1"]["attn"]))
+    assert attention == 26_347_520
+    assert all(leaf.dtype == jnp.float32
+               for leaf in jax.tree_util.tree_leaves(params))
+    assert [b.shape for b in jax.tree_util.tree_leaves(state)] == [(256,)] * 5
+    assert family.make_batch(jax.random.PRNGKey(0)).shape == (2, 4098)
+
+
+def test_the_family_refuses_a_configuration_it_does_not_compute():
+    config, _ = _family()
+    with pytest.raises(SystemExit, match="scoring_func"):
+        _family_from({**config, "scoring_func": "softmax"})
+
+
+def test_forward_flops_per_token_against_a_hand_count():
+    _, family = _family()
+    mla = (2048 * 1536 + 1536 * 32 * 192 + 2048 * 576 + 512 * 32 * 256
+           + 32 * 128 * 2048)
+    attention = 32 * 4096 * (192 + 128) // 2
+    expert_block = 2048 * 256 + 3 * 2048 * 768 + 0.5 * 3 * 2048 * 768
+    macs = (6 * (mla + attention) + 3 * 2048 * 7168 + 5 * expert_block
+            + 2 * 2048 * 16160 + 2 * 2048 * 2048)
+    assert mla == 26_345_472
+    assert family.flops_per_item() == 3 * 2 * macs
+    assert round(family.flops_per_item() / 3 / 1e6, 1) == 881.1
+
+
+def test_mla_attention_cost_counts_each_matmul_at_its_own_width():
+    flops, nbytes = latent_moe_flops.mla_attention_cost(
+        2, 32, 4096, 192, 128, layers=6, forward_calls=2)
+    wide, narrow = (2 * 2 * 32 * 4096 ** 2 * d / 2 for d in (192, 128))
+    assert flops == 6 * (2 * (wide + narrow) + 3 * wide + 2 * narrow)
+    # equal widths: the accepted count of causal_attention_cost
+    from chipbench.flops import causal_attention_cost
+
+    assert latent_moe_flops.mla_attention_cost(
+        2, 12, 2048, 64, 64, layers=3, forward_calls=2)[0] == (
+            causal_attention_cost(2, 12, 2048, 64, layers=3,
+                                  forward_calls=2))[0]
+    assert nbytes == 6 * (2 * (2 * 2 * 32 * 4096 * 192 * 2
+                               + 2 * 2 * 32 * 4096 * 128 * 2
+                               + 2 * 32 * 4096 * 4)
+                          + 4 * 2 * 32 * 4096 * 192 * 2
+                          + 4 * 2 * 32 * 4096 * 128 * 2 + 2 * 32 * 4096 * 4)
+
+
+def test_kernel_costs_price_the_expected_routed_rows():
+    _, family = _family()
+    costs = family.kernel_costs()
+    flops, _ = costs["grouped_matmul"]
+    # 8,192 tokens * 8 / 256 * 16 held = 4,096 rows a layer, 5 expert blocks,
+    # 3 products forward (twice under remat) and 6 backward
+    assert flops == 5 * (3 * 2 + 6) * 2 * 4096 * 2048 * 768
+    assert set(costs) == {"mla_attention", "grouped_matmul"}
+
+
+# ---- the family through the benchmark's harness ----------------------------
+
+
+@pytest.mark.duration_budget(90)   # compiles five programs: init, step, the
+# reference's step and the two model-loss evaluations (ISSUE 28 asks for it
+# in tier-1)
+def test_the_family_runs_through_the_harness_and_agrees(tmp_path):
+    """``cell.build_cell`` and three steps of ``run.py::agreement`` on a
+    virtual CPU device, from a manifest written here and a tiny configuration
+    that exists only under ``tests/data``."""
+    from chipbench import cell as cells
+    from chipbench import run
+
+    data = os.path.join(os.path.dirname(os.path.abspath(__file__)), "data",
+                        "latent_moe")
+    (tmp_path / "traffic").mkdir()
+    (tmp_path / "traffic" / "t32.b2.remat.solo.json").write_text(json.dumps({
+        "ranks": 1, "batch": 2, "seq_len": 32, "remat": True,
+        "comm": "neighbor", "topology": "ExponentialTwoGraph",
+        "backend": "auto"}))
+    manifest_path = tmp_path / "BENCHMARK.json"
+    manifest_path.write_text(json.dumps({
+        "paths": [str(tmp_path), "chipbench"],
+        "configs": [{"name": "tiny-latent-moe",
+                     "file": os.path.join(data, "tiny-latent-moe.json")}],
+        "workloads": [{"name": "tinymoe.solo", "config": "tiny-latent-moe",
+                       "traffic": "t32.b2.remat.solo", "chips": 1}]}))
+    manifest = cells.Manifest.load(str(manifest_path))
+    cell = cells.build_cell(manifest, "tinymoe.solo", seed=2147483659)
+    assert hasattr(cell.family, "reference_loss")
+    state, cell.state = cell.state, None
+    for k in range(2):                                   # as the warm-up
+        state, loss = cell.step(state, cell.ring[k])
+    report = {}
+    ok, leaves, loss_err = run.agreement(cell, state, 2, report)
+    assert ok, (leaves[:3], loss_err, report)
+    assert loss_err < 1e-4
+    assert report["model_loss"]["rel_err"] < 1e-4
+    assert report["model_loss"]["reference"] > 1.0       # ln(250) = 5.5

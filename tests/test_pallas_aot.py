@@ -312,3 +312,58 @@ def test_chunked_gossip_keeps_its_trace_names_under_the_phase_scopes(
     for scope in ("bf.gossip.pack", "bf.gossip.unpack", "bf.gossip.fuse",
                   "bf.gossip.split"):
         assert scope in text, scope
+
+
+def _one_chip(topo):
+    from jax.sharding import SingleDeviceSharding
+
+    return SingleDeviceSharding(topo.devices[0])
+
+
+def test_latent_attention_kernels_compile_for_v5e(tpu_aot_topology):
+    """The splash kernels at latent attention's published head: 192-wide
+    queries and keys beside 128-wide values, 32 heads, T=4096, forward and
+    fused backward.  Mosaic takes the 192 as it is (no padding to 256)."""
+    from bluefog_tpu.ops.ring_attention import _splash_attention
+
+    one = _one_chip(tpu_aot_topology)
+    qk = jax.ShapeDtypeStruct((2, 4096, 32, 192), jnp.bfloat16, sharding=one)
+    v = jax.ShapeDtypeStruct((2, 4096, 32, 128), jnp.bfloat16, sharding=one)
+
+    def grads(q, k, v):
+        return jax.grad(lambda q, k, v: _splash_attention(
+            q, k, v, causal=True, scale=192 ** -0.5).astype(
+                jnp.float32).sum(), argnums=(0, 1, 2))(q, k, v)
+
+    txt = jax.jit(grads).lower(qk, qk, v).compile().as_text()
+    assert txt.count("tpu_custom_call") >= 2
+    assert "flash_attention_splash_mha_fwd" in txt
+    assert "flash_mha_bwd_splash_mha_dkv" in txt
+
+
+def test_grouped_matmul_kernels_compile_for_v5e(tpu_aot_topology):
+    """``routed_experts`` on the Pallas grouped matmul at the published
+    widths: 65,536 sorted rows of 2,048, 16 held experts of width 768,
+    forward and both transposes, named ``gmm`` / ``tgmm`` for the trace."""
+    from bluefog_tpu.ops.moe import routed_experts
+
+    one = _one_chip(tpu_aot_topology)
+
+    def shape(dims, dtype):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=one)
+
+    def grads(x, idx, weights, wg, wu, wd):
+        def total(x, wg, wu, wd):
+            return routed_experts(
+                x, idx, weights, wg, wu, wd, num_experts=256, held=(0, 16),
+                backend="gmm")[0].astype(jnp.float32).sum()
+        return jax.grad(total, argnums=(0, 1, 2, 3))(x, wg, wu, wd)
+
+    txt = jax.jit(grads).lower(
+        shape((8192, 2048), jnp.bfloat16), shape((8192, 8), jnp.int32),
+        shape((8192, 8), jnp.float32), shape((16, 2048, 768), jnp.float32),
+        shape((16, 2048, 768), jnp.float32),
+        shape((16, 768, 2048), jnp.float32)).compile().as_text()
+    # the forward product of `down` feeds no gradient and is removed
+    assert len(_re.findall(r"%gmm(\.\d+)? = ", txt)) == 5
+    assert len(_re.findall(r"%tgmm(\.\d+)? = ", txt)) == 3
