@@ -15,8 +15,9 @@ the dropless layer instead: ``GPTConfig(ffn="routed+shared",
 experts=ExpertSizes(...))`` →
 :class:`bluefog_tpu.models.transformer.RoutedSharedFFN` over
 :func:`bluefog_tpu.ops.moe.routed_experts` (sort by expert, grouped matmuls
-over the experts the chip holds, no capacity, no drop; the ``(T, E, C)``
-tensors cannot hold 64 experts at 8k tokens).
+over the experts the chip holds in passes of a row buffer sized from the
+held share, no capacity, no drop; the ``(T, E, C)`` tensors cannot hold 64
+experts at 8k tokens).
 
 Loss convention for training inside ``shard_map``: normalize by the GLOBAL
 token count (see ops/moe.py docstring) so raw ``jax.grad`` is exact.

@@ -15,8 +15,10 @@ Pieces:
 - :func:`sigmoid_topk_router` and :func:`routed_experts` — the dropless
   layer for many small experts: sigmoid scores with a selection bias, top-k
   of all the experts, and this chip's share of the result over the experts
-  it is told it holds (sort by expert, grouped matmuls over the held groups,
-  weighted gather back).  No capacity, no ``(T, E, C)`` tensor, no drop.
+  it is told it holds: sort by expert, then passes of a row buffer sized
+  from the held share (gather the rows, grouped matmuls over the held
+  groups, weighted scatter-add back) until the held rows are done.  No
+  capacity, no ``(T, E, C)`` tensor, no drop.
   The one-hot routers above stay for the ``all_to_all`` path, which needs
   the static per-expert capacity they provide.
 
@@ -285,45 +287,18 @@ def sigmoid_topk_router(x, router_kernel, bias, *, top_k: int,
     return idx, weights
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
-def _expand_rows(x, order, inv, k):
-    """``x (T, D)`` → the ``(T*k, D)`` sorted rows ``x[order // k]``.  The
-    transpose is a gather too (every token has exactly ``k`` rows, found
-    through ``inv``), where autodiff of the gather would scatter-add."""
-    del inv
-    return x[order // k]
+def _ceil_div(a, b):
+    return -(-a // b)
 
 
-def _expand_rows_fwd(x, order, inv, k):
-    return x[order // k], inv
-
-
-def _expand_rows_bwd(k, inv, g):
-    per_token = g[inv].reshape(g.shape[0] // k, k, g.shape[1])
-    dx = per_token.astype(jnp.float32).sum(axis=1).astype(g.dtype)
-    return dx, None, None
-
-
-_expand_rows.defvjp(_expand_rows_fwd, _expand_rows_bwd)
-
-
-@jax.custom_vjp
-def _collect_rows(rows, inv, order):
-    """The sorted rows back in assignment order: ``rows[inv]``; ``order`` is
-    ``inv``'s inverse, so the transpose is the gather ``g[order]``."""
-    del order
-    return rows[inv]
-
-
-def _collect_rows_fwd(rows, inv, order):
-    return rows[inv], order
-
-
-def _collect_rows_bwd(order, g):
-    return g[order], None, None
-
-
-_collect_rows.defvjp(_collect_rows_fwd, _collect_rows_bwd)
+def _row_buffer(n_rows: int, count: int, num_experts: int) -> int:
+    """Height ``C`` of :func:`routed_experts`' row buffer, from the shapes
+    alone: twice the rows uniform routing sends to ``count`` held experts of
+    ``num_experts`` (``n_rows = T * k`` assignments), in whole 256-row tiles
+    of the grouped matmul, and never more than every row.  A chip that
+    holds every expert gets ``n_rows``: one pass by construction."""
+    share = _ceil_div(2 * n_rows * count, num_experts)
+    return min(n_rows, _ceil_div(share, 256) * 256)
 
 
 def _gmm_tiling(m: int, k: int, n: int) -> Tuple[int, int, int]:
@@ -350,39 +325,143 @@ def _gmm_tiling(m: int, k: int, n: int) -> Tuple[int, int, int]:
     return tm, widest(k), widest(n)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
-def _grouped_matmul(rows, w, sizes, interpret):
-    """``rows (R, K)`` sorted by group, ``w (G, K, N)``, ``sizes (G + 1,)``
-    int32 (the last group is the rows no held expert takes) →
-    ``(R, N)``: row ``r`` of group ``g < G`` times ``w[g]``, zero for the
-    last group.  The Pallas kernel (``megablox.gmm``) visits the row tiles
-    of the first ``G`` groups only."""
-    from jax.experimental.pallas.ops.tpu.megablox.gmm import gmm
+def _grouped_products(sizes, backend):
+    """``(product, transposes)`` over rows sorted by group; ``sizes
+    (G + 1,)`` int32, the last group the rows no held expert takes.
+    ``product(rows (R, K), w (G, K, N)) -> (R, N)``: row ``r`` of group
+    ``g < G`` times ``w[g]``, zero for the last group (the Pallas kernel,
+    ``megablox.gmm``, visits the row tiles of the first ``G`` groups only).
+    ``transposes(rows, w, g) -> (d_rows, d_w)`` for a cotangent ``g (R,
+    N)``."""
+    if backend == "ragged":
+        def product(rows, w):
+            return lax.ragged_dot(rows, w, sizes[:-1])
 
-    return gmm(
-        rows, w, sizes, rows.dtype,
-        _gmm_tiling(rows.shape[0], w.shape[1], w.shape[2]),
-        interpret=interpret)
+        def transposes(rows, w, g):
+            return jax.vjp(product, rows, w)[1](g)
+    else:
+        from jax.experimental.pallas.ops.tpu.megablox.gmm import gmm, tgmm
+
+        interpret = backend == "gmm_interpret"
+
+        def product(rows, w):
+            return gmm(rows, w, sizes, rows.dtype,
+                       _gmm_tiling(rows.shape[0], *w.shape[1:]),
+                       interpret=interpret)
+
+        def transposes(rows, w, g):
+            # the forward's tiles serve its two transposes (measured so)
+            tiling = _gmm_tiling(rows.shape[0], *w.shape[1:])
+            return (gmm(g, w, sizes, rows.dtype, tiling, transpose_rhs=True,
+                        interpret=interpret),
+                    tgmm(rows.swapaxes(0, 1), g, sizes, w.dtype, tiling,
+                         num_actual_groups=w.shape[0], interpret=interpret))
+    return product, transposes
 
 
-def _grouped_matmul_fwd(rows, w, sizes, interpret):
-    return _grouped_matmul(rows, w, sizes, interpret), (rows, w, sizes)
+def _gate(gate, up):
+    return jax.nn.silu(gate) * up
 
 
-def _grouped_matmul_bwd(interpret, res, g):
-    from jax.experimental.pallas.ops.tpu.megablox.gmm import gmm, tgmm
+def _window(j, weights, order, ends, k, c):
+    """Pass ``j`` of the sorted assignments: ``order[j*c : (j+1)*c]``.
+    Returns the assignment ids, their tokens, the window's group sizes
+    ``(count + 1,)`` (the held groups' cumulative ``ends`` clipped to the
+    window; what is left of ``c`` is the trailing group the kernels skip)
+    and each row's f32 weight, 0 past the last held row."""
+    start = j * c
+    ids = lax.dynamic_slice(order, (start,), (c,))
+    inside = jnp.clip(ends - start, 0, c)
+    sizes = jnp.diff(jnp.concatenate(
+        [jnp.zeros(1, jnp.int32), inside, jnp.full(1, c, jnp.int32)]))
+    live = start + jnp.arange(c, dtype=jnp.int32) < ends[-1]
+    w = jnp.where(live, weights.reshape(-1)[ids].astype(jnp.float32), 0.0)
+    return ids, ids // k, sizes, w
 
-    rows, w, sizes = res
-    # the forward's tiles serve its two transposes (measured so)
-    tiling = _gmm_tiling(rows.shape[0], w.shape[1], w.shape[2])
-    d_rows = gmm(g, w, sizes, rows.dtype, tiling, transpose_rhs=True,
-                 interpret=interpret)
-    d_w = tgmm(rows.swapaxes(0, 1), g, sizes, w.dtype, tiling,
-               num_actual_groups=w.shape[0], interpret=interpret)
-    return d_rows, d_w, None
+
+def _cast_experts(x, *ws):
+    with jax.named_scope("bf.moe.experts"):
+        return tuple(w.astype(x.dtype) for w in ws)
 
 
-_grouped_matmul.defvjp(_grouped_matmul_fwd, _grouped_matmul_bwd)
+@functools.partial(jax.custom_vjp, nondiff_argnums=(7, 8, 9))
+def _held_experts(x, weights, order, ends, w_gate, w_up, w_down, k, c,
+                  backend):
+    """``y (T, D)``: every held assignment's ``weight * E(x[token])``,
+    summed by token in f32, in passes of ``c`` rows over the held head of
+    ``order`` (``ends``: the held groups' cumulative sizes).  One
+    differentiation rule of its own: a loop with a traced trip count has no
+    reverse mode, and the rule keeps nothing of a pass but the inputs."""
+    wg, wu, wd = _cast_experts(x, w_gate, w_up, w_down)
+
+    def one_pass(j, y):
+        with jax.named_scope("bf.moe.dispatch"):
+            _, tokens, sizes, w = _window(j, weights, order, ends, k, c)
+            rows = x[tokens]
+        with jax.named_scope("bf.moe.experts"):
+            product, _ = _grouped_products(sizes, backend)
+            out = product(_gate(product(rows, wg), product(rows, wu)), wd)
+        with jax.named_scope("bf.moe.combine"):
+            return y.at[tokens].add(out.astype(jnp.float32) * w[:, None])
+
+    y = lax.fori_loop(0, _ceil_div(ends[-1], c), one_pass,
+                      jnp.zeros(x.shape, jnp.float32))
+    return y.astype(x.dtype)
+
+
+def _held_experts_fwd(x, weights, order, ends, w_gate, w_up, w_down, k, c,
+                      backend):
+    return (_held_experts(x, weights, order, ends, w_gate, w_up, w_down, k,
+                          c, backend),
+            (x, weights, order, ends, w_gate, w_up, w_down))
+
+
+def _held_experts_bwd(k, c, backend, res, g):
+    """The same passes: a pass's rows and products again, then their
+    transposes; ``d_x``, ``d_weights`` and the experts' gradients add up
+    over the passes in f32."""
+    x, weights, order, ends, *experts = res
+    wg, wu, wd = _cast_experts(x, *experts)
+
+    def one_pass(j, carry):
+        d_x, d_weights, d_ws = carry
+        with jax.named_scope("bf.moe.dispatch"):
+            ids, tokens, sizes, w = _window(j, weights, order, ends, k, c)
+            rows = x[tokens]
+        with jax.named_scope("bf.moe.experts"):
+            product, transposes = _grouped_products(sizes, backend)
+            hidden, gate_transpose = jax.vjp(
+                _gate, product(rows, wg), product(rows, wu))
+            out = product(hidden, wd)
+        with jax.named_scope("bf.moe.combine"):
+            g_rows = g[tokens].astype(jnp.float32)
+            # rows past the last held one: `out` is zero there
+            d_weights = d_weights.at[ids].add(
+                (out.astype(jnp.float32) * g_rows).sum(axis=1))
+            d_out = (g_rows * w[:, None]).astype(x.dtype)
+        with jax.named_scope("bf.moe.experts"):
+            d_hidden, d_wd = transposes(hidden, wd, d_out)
+            d_gate, d_up = gate_transpose(d_hidden)
+            d_rows_gate, d_wg = transposes(rows, wg, d_gate)
+            d_rows_up, d_wu = transposes(rows, wu, d_up)
+            d_ws = tuple(acc + d.astype(jnp.float32)
+                         for acc, d in zip(d_ws, (d_wg, d_wu, d_wd)))
+        with jax.named_scope("bf.moe.dispatch"):
+            d_x = d_x.at[tokens].add(d_rows_gate.astype(jnp.float32)
+                                     + d_rows_up.astype(jnp.float32))
+        return d_x, d_weights, d_ws
+
+    d_x, d_weights, d_ws = lax.fori_loop(
+        0, _ceil_div(ends[-1], c), one_pass,
+        (jnp.zeros(x.shape, jnp.float32),
+         jnp.zeros(weights.size, jnp.float32),
+         tuple(jnp.zeros(w.shape, jnp.float32) for w in experts)))
+    return (d_x.astype(x.dtype),
+            d_weights.reshape(weights.shape).astype(weights.dtype), None,
+            None, *(d.astype(w.dtype) for d, w in zip(d_ws, experts)))
+
+
+_held_experts.defvjp(_held_experts_fwd, _held_experts_bwd)
 
 
 def routed_experts(x, idx, weights, w_gate, w_up, w_down, *,
@@ -398,19 +477,24 @@ def routed_experts(x, idx, weights, w_gate, w_up, w_down, *,
     weights[t, i] * E_i(x[t])`` in ``x.dtype`` — what the absent experts
     would add is left out, for the caller's exchange (or nothing, on one
     chip) to supply — and the routing record, not differentiated:
-    ``rows_per_expert (count,)`` and ``held_share`` (held assignments over
-    all ``T * k``).  With metrics on, the record feeds the counters
-    ``bf_moe_assignments_total`` and ``bf_moe_assignments_held_total``.
+    ``rows_per_expert (count,)``, ``held_share`` (held assignments over
+    all ``T * k``) and ``row_passes`` (int32, below).  With metrics on, the
+    record feeds the counters ``bf_moe_assignments_total``,
+    ``bf_moe_assignments_held_total`` and ``bf_moe_row_passes_total``.
 
     The ``T * k`` assignments are sorted by expert (held experts first, the
-    rest as one trailing group), the rows gathered in that order, three
-    grouped matmuls run over the held groups, and each token gathers its
-    ``k`` rows back and sums them by weight.  **The row buffer is all
-    ``T * k`` rows**: any routing fits, none is dropped (a token may send
-    ``min(k, count)`` rows here, and every token may).  Its cost is the
-    gathers' traffic over rows no held expert takes (``T * k * D`` elements
-    a gather; at 8,192 tokens, top-8 and 2,048 wide, 268 MB in bf16); the
-    grouped matmul skips those rows' tiles.
+    rest as one trailing group).  **The row buffer is ``C`` rows**
+    (:func:`_row_buffer`: twice what uniform routing sends to the held
+    experts, from the shapes alone; all ``T * k`` where every expert is
+    held).  A pass gathers the next ``C`` sorted rows' tokens, runs the
+    three grouped matmuls and the gate at that height, and scatter-adds
+    ``weight * out`` into ``y`` in f32; ``row_passes = ceil(held rows /
+    C)`` passes run, from the router's own counts: one while the router
+    sends this chip at most twice its share, ``T * k / C`` if every
+    assignment of every token lands here, none if no row is held (``y`` is
+    then exactly zero).  Any routing fits, none is dropped, and the cost
+    follows the rows the router sent.  The gradient runs the same passes
+    (:func:`_held_experts`).
 
     ``backend``: ``'gmm'`` the Pallas kernel (``megablox.gmm``), ``'ragged'``
     ``lax.ragged_dot`` (portable, what CI runs), ``'auto'`` the kernel on a
@@ -429,32 +513,24 @@ def routed_experts(x, idx, weights, w_gate, w_up, w_down, *,
         raise ValueError(f"unknown backend {backend!r}")
     t, k = idx.shape
     n_rows = t * k
+    c = _row_buffer(n_rows, count, num_experts)
     with jax.named_scope("bf.moe.dispatch"):
         # held experts become groups 0 .. count-1, every other expert the
         # trailing group `count`
         group = jnp.minimum((idx.reshape(n_rows) - first) % num_experts,
                             count).astype(jnp.int32)
-        order = jnp.argsort(group).astype(jnp.int32)
-        inv = jnp.zeros(n_rows, jnp.int32).at[order].set(
-            jnp.arange(n_rows, dtype=jnp.int32), unique_indices=True)
-        sizes = jnp.zeros(count + 1, jnp.int32).at[group].add(1)
-        rows = _expand_rows(x, order, inv, k)
-    with jax.named_scope("bf.moe.experts"):
-        if backend == "ragged":
-            def product(a, w):
-                return lax.ragged_dot(a, w, sizes[:count])
-        else:
-            def product(a, w):
-                return _grouped_matmul(a, w, sizes,
-                                       backend == "gmm_interpret")
-        wg, wu, wd = (w.astype(x.dtype) for w in (w_gate, w_up, w_down))
-        out = product(jax.nn.silu(product(rows, wg)) * product(rows, wu), wd)
-    with jax.named_scope("bf.moe.combine"):
-        picked = _collect_rows(out, inv, order).reshape(t, k, x.shape[1])
-        y = (picked.astype(jnp.float32) * weights[..., None]).sum(axis=1)
-    held_rows = jnp.sum(sizes[:count])
-    record = {"rows_per_expert": sizes[:count],
-              "held_share": held_rows.astype(jnp.float32) / n_rows}
+        # whole windows: the last one must not clamp
+        order = jnp.pad(jnp.argsort(group).astype(jnp.int32),
+                        (0, -n_rows % c))
+        ends = (group[None, :] <= jnp.arange(count)[:, None]).sum(
+            axis=1, dtype=jnp.int32)                 # held groups, cumulative
+    y = _held_experts(x, weights, order, ends, w_gate, w_up, w_down, k, c,
+                      backend)
+    row_passes = _ceil_div(ends[-1], c)
+    record = {"rows_per_expert": jnp.diff(ends, prepend=0),
+              "held_share": ends[-1].astype(jnp.float32) / n_rows,
+              "row_passes": row_passes}
     y = metrics_comm.count(y, [("bf_moe_assignments_total", float(n_rows)),
-                               ("bf_moe_assignments_held_total", held_rows)])
-    return y.astype(x.dtype), record
+                               ("bf_moe_assignments_held_total", ends[-1]),
+                               ("bf_moe_row_passes_total", row_passes)])
+    return y, record

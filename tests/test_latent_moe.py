@@ -283,6 +283,146 @@ def test_routed_output_is_zero_when_no_token_routes_to_a_held_expert(backend):
     assert float(jnp.abs(y).max()) == 0.0
     assert float(record["held_share"]) == 0.0
     assert int(record["rows_per_expert"].sum()) == 0
+    assert int(record["row_passes"]) == 0       # no pass: y is the zeros
+    grads = jax.grad(lambda x, *w: jnp.sum(_routed(
+        x, kernel, bias, *w, (0, 4), backend)[0]), argnums=range(4))(x, *w)
+    assert all(float(jnp.abs(g).max()) == 0.0 for g in grads)
+
+
+# the row buffer: C rows, passes of it until the held rows are done
+
+
+@pytest.mark.parametrize("n_rows,count,num_experts,want", [
+    (65536, 16, 256, 8192),        # the published layer, 16 of 256 held
+    (65536, 256, 256, 65536),      # every expert held: all T * k, one pass
+    (65536, 128, 256, 65536), (65536, 64, 256, 32768),
+    (1024, 4, 16, 512), (800, 4, 16, 512), (4096, 1, 16, 512),
+    (256, 4, 16, 256), (256, 16, 16, 256),
+    (1000, 1, 16, 256),            # 125 rows: a whole tile of 256
+    (3000, 4, 16, 1536), (96, 4, 16, 96)])
+def test_the_row_buffer_is_twice_the_held_share_in_whole_tiles(
+        n_rows, count, num_experts, want):
+    c = moe_ops._row_buffer(n_rows, count, num_experts)
+    assert c == want
+    assert c == n_rows or (c % 256 == 0 and c * num_experts
+                           >= 2 * n_rows * count)
+
+
+def _all_to_the_held_four():
+    return jnp.zeros(E).at[:4].set(10.0)
+
+
+@pytest.mark.parametrize("backend", ["ragged", "gmm_interpret"])
+@pytest.mark.parametrize("tokens", [256, 200], ids=["whole", "padded"])
+def test_two_passes_match_the_dense_share_loss_and_gradients(tokens,
+                                                             backend):
+    """Held ``(0, 4)`` of 16: ``C`` = 512 of the ``T * K`` = 1,024 (800)
+    rows, and a selection bias that sends every token's four choices to the
+    held four: twice ``C`` held rows (the second window runs past the 800).
+    Nothing is dropped: the second pass takes what the first left."""
+    x, kernel = rand((tokens, 64), 0), rand((64, E), 1, 0.2)
+    bias, w = _all_to_the_held_four(), _expert_weights(4)
+    args = (x, kernel, *w)
+
+    def got(x, kernel, wg, wu, wd):
+        return _routed(x, kernel, bias, wg, wu, wd, (0, 4), backend)[0]
+
+    def want(x, kernel, wg, wu, wd):
+        return _dense_share(x, kernel, bias, wg, wu, wd, 0)
+
+    y, record = _routed(x, kernel, bias, *w, (0, 4), backend)
+    assert int(record["rows_per_expert"].sum()) == tokens * K
+    assert int(record["row_passes"]) == 2
+    np.testing.assert_allclose(y, want(*args), atol=2e-5)
+    grads = [jax.grad(lambda *a: jnp.sum(f(*a) ** 2), argnums=range(5))(*args)
+             for f in (got, want)]
+    close(grads[0], grads[1])
+
+
+def _dense_from_the_routing(x, idx, weights, wg, wu, wd, first):
+    """The held share from a routing given as it is (no router)."""
+    return sum(
+        (weights * (idx == first + i)).sum(-1, keepdims=True)
+        * ((jax.nn.silu(x @ wg[i]) * (x @ wu[i])) @ wd[i])
+        for i in range(wg.shape[0]))
+
+
+@pytest.mark.parametrize("backend", ["ragged", "gmm_interpret"])
+@pytest.mark.parametrize("held_rows,passes", [(512, 1), (513, 2), (1, 1)],
+                         ids=["C", "C+1", "one"])
+def test_the_pass_boundary_loss_and_gradients(held_rows, passes, backend):
+    """256 tokens, ``C`` = 512: the first tokens take the held experts
+    0..3, one more token takes expert 0 and three absent ones."""
+    full, extra = divmod(held_rows, K)
+    idx = jnp.tile(jnp.arange(4, 8), (256, 1))
+    idx = idx.at[:full].set(jnp.arange(4)).at[full, :extra].set(
+        jnp.arange(extra)).astype(jnp.int32)
+    x, w = rand((256, 64), 0), _expert_weights(4)
+    weights = 0.5 + jax.random.uniform(jax.random.PRNGKey(7), (256, K))
+    args = (x, weights, *w)
+
+    def got(x, weights, wg, wu, wd):
+        return routed_experts(x, idx, weights, wg, wu, wd, num_experts=E,
+                              held=(0, 4), backend=backend)
+
+    def want(x, weights, wg, wu, wd):
+        return _dense_from_the_routing(x, idx, weights, wg, wu, wd, 0)
+
+    y, record = got(*args)
+    assert int(record["rows_per_expert"].sum()) == held_rows
+    assert int(record["row_passes"]) == passes
+    np.testing.assert_allclose(y, want(*args), atol=2e-5)
+    grads = [jax.grad(lambda *a: jnp.sum(f(*a) ** 2), argnums=range(5))(*args)
+             for f in (lambda *a: got(*a)[0], want)]
+    close(grads[0], grads[1])
+
+
+@pytest.mark.parametrize("skewed,passes", [(False, 1), (True, 2)],
+                         ids=["balanced", "skewed"])
+def test_row_passes_follow_the_rows_the_router_sent(skewed, passes):
+    x, kernel = rand((256, 64), 0), rand((64, E), 1, 0.2)
+    bias = _all_to_the_held_four() if skewed else jnp.zeros(E)
+    _, record = jax.jit(lambda x: _routed(
+        x, kernel, bias, *_expert_weights(4), (0, 4), "ragged"))(x)
+    held = int(record["rows_per_expert"].sum())
+    assert record["row_passes"].dtype == jnp.int32
+    assert int(record["row_passes"]) == passes == -(-held // 512)
+    assert (held == 1024) == skewed
+
+
+def _equations(jaxpr):
+    for eqn in jaxpr.eqns:
+        yield eqn
+        for value in eqn.params.values():
+            for sub in value if isinstance(value, (list, tuple)) else [value]:
+                sub = getattr(sub, "jaxpr", sub)
+                if hasattr(sub, "eqns"):
+                    yield from _equations(sub)
+
+
+def test_no_array_has_all_the_rows_when_a_sixteenth_is_held():
+    """Loss and gradient at ``T * k`` = 4,096 with one expert of 16 held:
+    every intermediate as tall as the row buffer (512) or as the tokens
+    (512 of another width), none as tall as all the assignments by the
+    model's or the experts' width: the buffer PR 29 removed."""
+    t, k, d, f = 512, 8, 40, 24
+    x, w = rand((t, d), 0), _expert_weights(1, d=d, f=f)
+    idx = jnp.argsort(rand((t, E), 1), axis=-1)[:, :k].astype(jnp.int32)
+    weights = jnp.ones((t, k)) / k
+
+    def loss(x, weights, wg, wu, wd):
+        return jnp.sum(routed_experts(
+            x, idx, weights, wg, wu, wd, num_experts=E, held=(0, 1),
+            backend="ragged")[0] ** 2)
+
+    jaxpr = jax.make_jaxpr(jax.value_and_grad(loss, argnums=range(5)))(
+        x, weights, *w)
+    shapes = {tuple(v.aval.shape) for eqn in _equations(jaxpr.jaxpr)
+              for v in eqn.outvars if hasattr(v.aval, "shape")}
+    assert moe_ops._row_buffer(t * k, 1, E) == 512
+    assert {(512, d), (512, f)} <= shapes      # the walk sees the passes
+    tall = {s for s in shapes if s and s[0] == t * k and len(s) > 1}
+    assert not tall, tall
 
 
 def test_routed_experts_refuses_a_share_that_is_no_range_of_the_experts():
@@ -321,6 +461,7 @@ def test_routing_record_feeds_the_metrics_when_they_are_on():
         assert snap["bf_moe_assignments_total"] == 64 * K
         assert snap["bf_moe_assignments_held_total"] == int(
             record["rows_per_expert"].sum())
+        assert snap["bf_moe_row_passes_total"] == 1    # once an execution
     finally:
         registry.metrics_stop()
         registry._STOPPED = False

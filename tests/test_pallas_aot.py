@@ -343,27 +343,35 @@ def test_latent_attention_kernels_compile_for_v5e(tpu_aot_topology):
 
 def test_grouped_matmul_kernels_compile_for_v5e(tpu_aot_topology):
     """``routed_experts`` on the Pallas grouped matmul at the published
-    widths: 65,536 sorted rows of 2,048, 16 held experts of width 768,
-    forward and both transposes, named ``gmm`` / ``tgmm`` for the trace."""
-    from bluefog_tpu.ops.moe import routed_experts
+    widths: 8,192 tokens of 2,048 choosing 8 of 256 experts, 16 of them held
+    of width 768, so a row buffer of 8,192 of the 65,536 sorted rows; value
+    and gradient, kernels named ``gmm`` / ``tgmm`` for the trace."""
+    from bluefog_tpu.ops.moe import _row_buffer, routed_experts
 
     one = _one_chip(tpu_aot_topology)
+    assert _row_buffer(8192 * 8, 16, 256) == 8192
 
     def shape(dims, dtype):
         return jax.ShapeDtypeStruct(dims, dtype, sharding=one)
 
-    def grads(x, idx, weights, wg, wu, wd):
-        def total(x, wg, wu, wd):
-            return routed_experts(
+    def value_and_grads(x, idx, weights, wg, wu, wd):
+        def total(x, weights, wg, wu, wd):
+            return (routed_experts(
                 x, idx, weights, wg, wu, wd, num_experts=256, held=(0, 16),
-                backend="gmm")[0].astype(jnp.float32).sum()
-        return jax.grad(total, argnums=(0, 1, 2, 3))(x, wg, wu, wd)
+                backend="gmm")[0].astype(jnp.float32) ** 2).sum()
+        return jax.value_and_grad(total, argnums=(0, 1, 2, 3, 4))(
+            x, weights, wg, wu, wd)
 
-    txt = jax.jit(grads).lower(
+    txt = jax.jit(value_and_grads).lower(
         shape((8192, 2048), jnp.bfloat16), shape((8192, 8), jnp.int32),
         shape((8192, 8), jnp.float32), shape((16, 2048, 768), jnp.float32),
         shape((16, 2048, 768), jnp.float32),
         shape((16, 768, 2048), jnp.float32)).compile().as_text()
-    # the forward product of `down` feeds no gradient and is removed
-    assert len(_re.findall(r"%gmm(\.\d+)? = ", txt)) == 5
+    # three products in the forward's loop; in the backward's, the three
+    # once more, their three row transposes and three weight transposes
+    assert len(_re.findall(r"%gmm(\.\d+)? = ", txt)) == 3 + 6
     assert len(_re.findall(r"%tgmm(\.\d+)? = ", txt)) == 3
+    # the kernels run at the buffer's height, and nothing is 65,536 tall
+    # but the sort's own vectors
+    assert "bf16[8192,768]" in txt
+    assert not _re.findall(r"\[65536,\d{2,}\]", txt)

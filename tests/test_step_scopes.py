@@ -136,3 +136,34 @@ def test_pack_and_unpack_scopes_on_the_pallas_path(monkeypatch, max_bytes,
     assert ("concatenate" in under["bf.gossip.unpack"]) == (kernels > 2)
     assert sum(eqn.primitive.name == "pallas_call"
                for eqn, _ in equations) == kernels
+
+
+MOE_SCOPE = re.compile(r"bf\.moe\.\w+")
+
+
+@pytest.mark.parametrize("backend", ["ragged", "gmm_interpret"])
+def test_expert_layer_scopes_in_both_rules_and_never_nested(backend):
+    """``routed_experts`` differentiates by a rule of its own, whose backward
+    runs the passes again: it opens ``bf.moe.dispatch`` / ``.experts`` /
+    ``.combine`` itself, in the loop's body.
+    ``expert_dispatch_ms_per_step`` reads the first and the last: all three
+    reach both rules, and no op sits under two."""
+    from bluefog_tpu.ops.moe import routed_experts
+
+    x = jnp.ones((64, 32))
+    idx = jnp.tile(jnp.arange(4, dtype=jnp.int32), (64, 1))
+    w = (jnp.ones((4, 32, 16)), jnp.ones((4, 32, 16)), jnp.ones((4, 16, 32)))
+
+    def loss(x, weights, *w):
+        return jnp.sum(routed_experts(x, idx, weights, *w, num_experts=16,
+                                      held=(0, 4), backend=backend)[0] ** 2)
+
+    text = jax.jit(jax.value_and_grad(loss, argnums=range(5))).lower(
+        x, jnp.ones((64, 4)), *w).as_text(debug_info=True)
+    forward, backward = set(), set()
+    for name in re.findall(r'loc\("([^"]*)"', text):
+        scopes = set(MOE_SCOPE.findall(name))
+        assert len(scopes) <= 1, name
+        (backward if "transpose(" in name else forward).update(scopes)
+    want = {"bf.moe.dispatch", "bf.moe.experts", "bf.moe.combine"}
+    assert forward == backward == want
