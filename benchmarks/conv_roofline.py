@@ -33,15 +33,18 @@ import argparse
 import json
 import os
 import sys
+import tempfile
+import time
 
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), ".."))
 
 import jax
 import jax.numpy as jnp
-import numpy as np
 from jax import lax
 
-from benchmarks._trace_util import timed_trace
+from chipbench import xplane
+from chipbench.peaks import peaks_for
+from chipbench.run import read_trace
 
 # ResNet-50/224 conv inventory: (label, H, W, Cin, Cout, k, stride, count).
 # Counts are per forward pass (bottleneck expansions included; projection
@@ -74,8 +77,37 @@ RESNET50_CONVS = [
     ("s4 proj 2048/2", 14, 14, 1024, 2048, 1, 2, 1),
 ]
 
-NOMINAL_TFLOPS = 197.0  # v5e bf16
-HBM_GBPS = 819.0        # v5e
+
+def trace_step_ms(trace_dir, steps):
+    """Device-busy ms per step and chip from the trace under ``trace_dir``,
+    or ``None`` where it holds no device lane (a CPU run) or no trace."""
+    trace = read_trace(trace_dir)
+    if trace is None:
+        return None
+    return xplane.mean_over_lanes(trace, xplane.busy_ns) / 1e6 / steps
+
+
+def timed_trace(fn, args_, steps, trace_steps: int = 3):
+    """Time ``steps`` untraced calls, then trace ``trace_steps`` more.
+
+    The wall clock is measured WITHOUT the profiler running (host-side
+    tracing overhead would land in it), and a separate short traced window
+    supplies the device time.  Returns ``(wall_ms_per_step,
+    trace_ms_per_step | None)``.  Compile happens outside both clocks.
+    """
+    jax.tree_util.tree_leaves(fn(*args_))[0].block_until_ready()
+    t0 = time.perf_counter()
+    out = None
+    for _ in range(steps):
+        out = fn(*args_)
+    jax.tree_util.tree_leaves(out)[0].block_until_ready()
+    wall_ms = (time.perf_counter() - t0) / steps * 1e3
+    with tempfile.TemporaryDirectory(prefix="bftpu_trace_") as trace_dir:
+        with jax.profiler.trace(trace_dir):
+            for _ in range(trace_steps):
+                out = fn(*args_)
+            jax.tree_util.tree_leaves(out)[0].block_until_ready()
+        return wall_ms, trace_step_ms(trace_dir, trace_steps)
 
 
 def conv_fn(B, H, W, Cin, Cout, k, s):
@@ -128,6 +160,7 @@ def main():
                     help="only the N most FLOP-heavy shapes (0 = all)")
     args = ap.parse_args()
     B = args.batch
+    peak_flops, peak_bytes = peaks_for(jax.devices()[0].device_kind)
 
     shapes = [r for r in RESNET50_CONVS if r[7] > 0]
     if args.top:
@@ -166,8 +199,8 @@ def main():
         c_ms = (c_trace if both_traced else c_wall) / REPEATS
         g_ms = (g_trace if both_traced else g_wall) / REPEATS
 
-        t_peak_ms = flops / (NOMINAL_TFLOPS * 1e12) * 1e3
-        t_bw_ms = bytes_min / (HBM_GBPS * 1e9) * 1e3
+        t_peak_ms = flops / peak_flops * 1e3
+        t_bw_ms = bytes_min / peak_bytes * 1e3
         ratio = c_ms / g_ms if g_ms > 0 else float("inf")
         bound = ("matmul_equivalent" if ratio <= 1.15 else
                  "bandwidth" if c_ms <= 1.25 * t_bw_ms else
@@ -204,7 +237,7 @@ def main():
         "fwd_conv_tflops_twin_bound": round(
             fwd_flops / (twin_total_ms * 1e-3) / 1e12, 1),
         "attainable_mfu_vs_nominal": round(
-            fwd_flops / (twin_total_ms * 1e-3) / 1e12 / NOMINAL_TFLOPS, 4),
+            fwd_flops / (twin_total_ms * 1e-3) / peak_flops, 4),
         "note": ("twin = im2col GEMM with identical MAC count; its rate is "
                  "the empirically attainable per-shape ceiling.  Forward "
                  "convs only; backward convs are GEMM-twins of the same "

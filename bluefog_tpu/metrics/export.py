@@ -2,7 +2,7 @@
 
 Three consumers, three formats:
 
-- ``bench.py`` / the dash CLI want a **per-step time series** — one JSON
+- The dash CLI (``bfmetrics-tpu``) wants a **per-step time series** — one JSON
   line per :func:`step` call, each a full registry snapshot (cumulative
   counters; the reader differentiates).  Append-only so a crash loses at
   most the last line, and the file is tail-able while training runs.

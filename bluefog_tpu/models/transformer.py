@@ -130,10 +130,6 @@ class GPTConfig:
         return GPTConfig()
 
     @staticmethod
-    def large() -> "GPTConfig":
-        return GPTConfig(hidden_size=1536, num_layers=24, num_heads=16)
-
-    @staticmethod
     def tiny() -> "GPTConfig":
         """For tests/dryruns."""
         return GPTConfig(vocab_size=512, hidden_size=64, num_layers=2,
@@ -264,26 +260,22 @@ class Block(nn.Module):
     """Pre-norm attention + feed-forward residual block, assembled from the
     configuration's kinds (module docstring).
 
-    ``mlp`` and ``attn`` are pluggable sublayer factories ``() -> nn.Module``
-    that replace the configured kind: ``mlp()`` maps ``(B, T, D) -> (B, T,
+    ``mlp`` is a pluggable sublayer factory ``() -> nn.Module`` that replaces
+    the configured feed-forward kind: ``mlp()`` maps ``(B, T, D) -> (B, T,
     D)`` (the MoE variant of models/moe.py injects a Switch-MoE FFN here
-    instead of duplicating the attention trunk), ``attn()`` maps
-    ``(y, attn_fn, positions) -> (B, T, D)``.  ``ffn`` overrides
+    instead of duplicating the attention trunk).  ``ffn`` overrides
     ``cfg.ffn`` for this block (the leading dense blocks of an expert model).
     """
 
     cfg: GPTConfig
     mlp: Optional[Callable[[], nn.Module]] = None
-    attn: Optional[Callable[[], nn.Module]] = None
     ffn: Optional[str] = None
 
     @nn.compact
     def __call__(self, x, attn_fn: AttnFn, positions=None):
         cfg = self.cfg
         y = _norm(cfg, "ln1")(x).astype(cfg.dtype)
-        if self.attn is not None:
-            a = self.attn()(y, attn_fn, positions)
-        elif cfg.attention == "latent":
+        if cfg.attention == "latent":
             a = LatentAttention(cfg, name="attn")(y, attn_fn, positions)
         else:
             head_dim = cfg.hidden_size // cfg.num_heads

@@ -8,8 +8,8 @@ never hits.  Two cases, no third:
   JAX reads that variable itself; this code sets nothing.
 - it is not set — the cache is ``<checkout>/.jax_cache`` (git-ignored).
 
-Every entry point that compiles (``chip_smoke.py``, ``bench.py``, the
-``examples/`` trainers, ``bfrun-tpu``) calls :func:`configure_compile_cache`
+Every entry point that compiles (``chip_smoke.py``, the ``examples/``
+trainers, ``bfrun-tpu``) calls :func:`configure_compile_cache`
 before its first compile.
 """
 

@@ -27,7 +27,7 @@ def uniq(tag: str) -> str:
 
 
 def load_script(relpath: str):
-    """Import a repo script (bench.py, benchmarks/*.py) as a module — these
+    """Import a repo script (chip_smoke.py, benchmarks/*.py) as a module — these
     live outside the package, so the ordinary import system can't see
     them.  One canonical loader, not one copy per test file."""
     path = os.path.join(REPO, relpath)
