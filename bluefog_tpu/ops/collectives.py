@@ -23,9 +23,11 @@ dynamic per-call topology                    :func:`neighbor_allreduce_dynamic`
 ===========================================  ===================================
 
 The reference executes the weighted average on the host CPU after
-``MPI_Neighbor_allgatherv`` (SURVEY.md §3.2); here the ``ppermute`` payloads
-and the weighted sum are one fused XLA computation that overlaps with
-surrounding compute — the background-thread/negotiation machinery of
+``MPI_Neighbor_allgatherv`` (SURVEY.md §3.2); here each ``ppermute`` is an
+asynchronous ``collective-permute-start`` / ``-done`` pair that the DMA
+engines serve while the core computes, and XLA fuses the weighted sum into
+whatever consumes it (in a train step: the weight-gradient fusion of the
+same leaf).  The background-thread/negotiation machinery of
 ``bluefog/common/operations.cc`` has no equivalent because XLA's static
 schedule already guarantees every rank issues identical collectives in
 identical order.
@@ -67,23 +69,31 @@ __all__ = [
 ]
 
 
-def fuse_apply(fn, x, *, threshold_bytes: int = 8 << 20):
-    """Tensor fusion: run a tree-polymorphic collective on ONE flat buffer
-    per dtype instead of per-leaf.
+def fuse_apply(fn, x, *, threshold_bytes: int = 4 << 20):
+    """Tensor fusion: run a tree-polymorphic collective on a few flat
+    buffers of bounded size instead of per-leaf.
 
     The reference batches small tensors through a fusion buffer so each
     negotiation round issues one wire transfer (`bluefog/common/tensor_queue`
     fusion-buffer manager, SURVEY.md §2.1).  The XLA analog: a model like
     ResNet-50 has ~160 parameter leaves, and leaf-wise gossip emits ~160
     ``ppermute`` ops per schedule slot — each with its own latency.  Packing
-    the tree into a single 1-D buffer per dtype turns that into one large
-    bandwidth-bound transfer per slot, then splits back.
+    the small leaves into 1-D buffers turns that into about nine
+    bandwidth-bound transfers per slot, then splits back.
 
-    Leaves at or above ``threshold_bytes`` ship unfused: a large tensor is
-    already one bandwidth-bound transfer, so concatenating it buys no latency
-    and costs a full transient copy of the leaf (concat + split) in HBM —
-    the same reason the reference's fusion buffer has a size cutoff.  Set
-    ``threshold_bytes=None`` to fuse everything.
+    ``threshold_bytes`` is the one rule for what is "large", for a leaf and
+    for a buffer alike.  Leaves at or above it ship unfused: a large tensor
+    is already one bandwidth-bound transfer, so concatenating it buys no
+    latency and costs a full transient copy of the leaf (concat + split) in
+    HBM — the same reason the reference's fusion buffer has a size cutoff.
+    The smaller leaves of one dtype are packed in tree order, and a buffer
+    is closed as soon as it holds ``threshold_bytes``: no buffer reaches
+    twice the threshold, whatever the model.  A single buffer per dtype (the
+    form before PR 31) made the fused group the largest thing exchanged —
+    113.7 MB in the GPT-2 cell — and its concatenation, its landing buffers
+    and its output stayed alive across the backward pass, 0.46 GB a chip;
+    in pieces the size of the large leaves they are transients.  Set
+    ``threshold_bytes=None`` to fuse everything into one buffer per dtype.
 
     ``fn`` must be shape-polymorphic and leaf-wise (all collectives here
     are).  Leaves keep their dtypes: each dtype group is fused separately, so
@@ -92,35 +102,35 @@ def fuse_apply(fn, x, *, threshold_bytes: int = 8 << 20):
     leaves, treedef = jax.tree_util.tree_flatten(x)
     if len(leaves) <= 1:
         return fn(x)
-    big = set()
-    if threshold_bytes is not None:
-        for i, leaf in enumerate(leaves):
-            a = jnp.asarray(leaf)
-            if a.size * a.dtype.itemsize >= threshold_bytes:
-                big.add(i)
-    groups: dict = {}  # dtype str -> small-leaf indices
+    large = float("inf") if threshold_bytes is None else threshold_bytes
+    big, groups, open_group = [], [], {}  # open_group: dtype -> [bytes, idxs]
     for i, leaf in enumerate(leaves):
-        if i not in big:
-            groups.setdefault(str(jnp.asarray(leaf).dtype), []).append(i)
+        a = jnp.asarray(leaf)
+        nbytes = a.size * a.dtype.itemsize
+        if nbytes >= large:
+            big.append(i)
+            continue
+        group = open_group.setdefault(str(a.dtype), [0, []])
+        group[0] += nbytes
+        group[1].append(i)
+        if group[0] >= large:
+            groups.append(open_group.pop(str(a.dtype))[1])
+    groups.extend(idxs for _, idxs in open_group.values())
     with jax.named_scope("bf.gossip.fuse"):
-        bufs = {
-            dt: jnp.concatenate([jnp.asarray(leaves[i]).ravel() for i in idxs])
-            for dt, idxs in groups.items()
-        }
+        bufs = [jnp.concatenate([jnp.asarray(leaves[i]).ravel() for i in idxs])
+                for idxs in groups]
     # One fn call over {fused buffers} ∪ {large leaves}: fn is leaf-wise, so
     # large leaves ride the same collective unfused, with no extra copy.
     # No scope around it: a Pallas kernel is named in the device trace by
     # the innermost name-stack entry above its call, and the kernels of fn
     # are found by that name (``shard_map.N``).
-    out_all = fn({"fused": bufs,
-                  "big": {str(i): leaves[i] for i in sorted(big)}})
-    out_bufs, out_big = out_all["fused"], out_all["big"]
+    out_all = fn({"fused": bufs, "big": [leaves[i] for i in big]})
     out = [None] * len(leaves)
-    for i in big:
-        out[i] = out_big[str(i)]
+    for i, leaf in zip(big, out_all["big"]):
+        out[i] = leaf
     with jax.named_scope("bf.gossip.split"):
-        for dt, idxs in groups.items():
-            buf, off = out_bufs[dt], 0
+        for idxs, buf in zip(groups, out_all["fused"]):
+            off = 0
             for i in idxs:
                 sz = int(np.prod(jnp.shape(leaves[i]), dtype=np.int64))
                 out[i] = buf[off:off + sz].reshape(jnp.shape(leaves[i]))
@@ -207,16 +217,28 @@ def neighbor_allreduce(
         XLA, and forcing ``backend='pallas'`` with it raises (the fused
         kernel folds weights on the arrival path only).
 
-    Lowering: one ``lax.ppermute`` per schedule slot (a single ICI rotation
-    for circulant graphs) + fused multiply-adds; or the fused RDMA kernel
-    (:mod:`bluefog_tpu.ops.pallas_gossip`), which folds the weighted
-    reduction into the arrival path.  ``backend``: ``'xla'`` and
-    ``'pallas'`` force a path; ``'auto'`` selects per call under the stated
-    conditions of :func:`bluefog_tpu.ops.pallas_gossip.auto_gossip_backend`
-    (real TPU slice, multi-device, circulant schedule — else XLA).  On the
-    pallas path, leaves beyond the per-invocation VMEM cap are split into
-    cap-sized chunks (one kernel each), so fused optimizer buffers ride the
-    RDMA kernels by default.
+    Lowering (``backend``): ``'xla'`` is one ``lax.ppermute`` per schedule
+    slot and leaf (a single ICI rotation for circulant graphs) + fused
+    multiply-adds.  On a TPU each is a ``collective-permute-start`` /
+    ``-done`` pair: the DMA engines move the bytes while the TensorCore
+    runs whatever XLA schedules between the two, and the received buffers
+    land in HBM.  XLA:TPU keeps about five such transfers in flight: it
+    opens the first five at the top of the program and each further one
+    where an earlier one closes, next to the consumer of its result — so a
+    caller who wants the exchange hidden gives it heavy consumers (the
+    optimizers do: the mix is fused into each leaf's weight-gradient
+    fusion) and pieces of bounded size (:func:`fuse_apply`).  ``'pallas'``
+    is the fused RDMA kernel (:mod:`bluefog_tpu.ops.pallas_gossip`): it
+    folds the weighted reduction into the arrival path, in VMEM, but it IS
+    the core's program while its transfers fly, so none of it overlaps
+    compute; leaves beyond the per-invocation cap are split into cap-sized
+    chunks, one kernel each.  ``'auto'`` decides per call under the stated
+    conditions of :func:`bluefog_tpu.ops.pallas_gossip.auto_gossip_backend`:
+    the kernels for a payload one kernel carries on a real multi-device
+    TPU slice with a circulant schedule, XLA for everything else — any
+    optimizer tree among it (four v5e chips, GPT-2 small: 35.6 ms of a
+    186.7 ms step exposed under the kernels, 21.6 of 172.7 under XLA, and
+    0.22 GiB less memory; PERF.md, PR 31).
 
     ``collective_id_base`` / ``collective_id_limit``: the half-open id
     range ``[base, limit)`` this call's pallas kernels enumerate
@@ -290,11 +312,10 @@ def neighbor_allreduce(
         #
         # Leaves larger than the per-invocation cap (the kernel keeps
         # (num_slots+2) whole-payload copies resident in VMEM) are CHUNKED
-        # into cap-sized pieces rather than routed to XLA: this is what
-        # makes the RDMA kernels the real default under fuse_apply's
-        # one-flat-buffer-per-dtype optimizer trees, and it preserves the
-        # kernel's advantage — every received chunk accumulates in VMEM on
-        # arrival instead of materializing in HBM like a ppermute output.
+        # into cap-sized pieces: a forced backend='pallas' runs at any
+        # size (auto sends such payloads to XLA), and every received chunk
+        # accumulates in VMEM on arrival instead of materializing in HBM
+        # like a ppermute output.
         leaves, treedef = jax.tree_util.tree_flatten(x)
         limit = pallas_gossip.auto_max_bytes()
         n_invocations = sum(

@@ -31,7 +31,9 @@ Synchronization protocol (per kernel invocation, SPMD-symmetric):
    storing (deliver).
 
 Use on real multi-chip slices; single-chip and CPU meshes route to the XLA
-path automatically (``backend='auto'``).
+path automatically (``backend='auto'``), and so does every gossip payload
+beyond one kernel's cap: the kernels occupy the TensorCore while they wait,
+XLA's asynchronous collective-permutes do not (``auto_gossip_backend``).
 """
 
 from __future__ import annotations
@@ -62,15 +64,16 @@ _LANES = 128
 _SUBLANES = 8
 
 # Per-kernel-invocation payload cap in on-wire bytes (bf16 leaves ship as
-# bf16, the rest as f32).  The GOSSIP op layer CHUNKS any larger leaf into
-# <=cap pieces (one kernel per chunk, distinct collective ids) instead of
-# falling back to XLA — that keeps every received payload accumulating in
-# VMEM on arrival, never landing in HBM, which is the kernel's whole
-# advantage over ppermute-materialize-then-add (saves ~2*num_slots HBM
-# passes over the buffer per gossip; the per-chunk barrier handshake costs
-# microseconds against that).  The WINDOW deliver path cannot chunk (its
-# landing buffers are persistent window state), so for it this value remains
-# a routing cutoff: bigger payloads take XLA.
+# bf16, the rest as f32), and the routing cutoff of backend='auto'
+# (auto_gossip_backend, condition 4): a gossip tree of at most this many
+# bytes, or a window payload whose every leaf is, rides the kernels; larger
+# ones take XLA's asynchronous collective-permutes, which the core does not
+# wait for.  Under a FORCED backend='pallas' the gossip op layer chunks any
+# larger leaf into <=cap pieces (one kernel per chunk, distinct collective
+# ids): every received payload accumulates in VMEM on arrival and never
+# lands in HBM (~2*num_slots HBM passes fewer than ppermute-then-add), at
+# the price of a core that runs nothing else meanwhile.  The WINDOW deliver
+# path cannot chunk (its landing buffers are persistent window state).
 # Override with BLUEFOG_TPU_PALLAS_MAX_BYTES.
 DEFAULT_AUTO_MAX_BYTES = 4 << 20
 
@@ -165,18 +168,28 @@ def auto_gossip_backend(sched: GossipSchedule, x, *,
        chip;
     3. a circulant schedule (every slot one uniform ICI rotation — all
        standard topologies; irregular graphs take XLA);
-    4. ``chunkable=False`` only (the window deliver path): every leaf at
-       most the size cutoff (see :data:`DEFAULT_AUTO_MAX_BYTES`).  Gossip
-       callers (``chunkable=True``, the default) have no size condition —
-       the op layer splits oversized leaves into cutoff-sized chunks, so
-       the fused-optimizer buffers (``fuse_apply``'s one-flat-buffer-per-
-       dtype trees, far beyond the cutoff for any real model) ride the
-       RDMA kernels BY DEFAULT rather than quietly falling back to XLA;
+    4. a payload one kernel carries.  A Pallas kernel IS the TensorCore's
+       program while it runs: handshake, RDMA, ``wait_recv``, and no matmul
+       beside it — 168 us a 4 MiB kernel on a v5e, 30 ms a step for a
+       GPT-2-small tree in 180 of them, none of it hidden (PERF.md, PR 31).
+       ``lax.ppermute`` lowers to ``collective-permute-start`` / ``-done``
+       and the DMA engines move the bytes while the core computes.  So
+       gossip callers (``chunkable=True``, the default) get the kernels
+       only while the WHOLE tree's on-wire bytes fit one invocation's cap
+       (:data:`DEFAULT_AUTO_MAX_BYTES`): there the core waits one handshake
+       and at most one cap's transfer, and the weighted sum never leaves
+       VMEM.  Anything larger — any optimizer tree — takes XLA.  The window
+       deliver path (``chunkable=False``) has nothing beside it to hide
+       behind and cannot chunk its persistent landing buffers: for it the
+       cap is a per-leaf cutoff, every leaf at most the cap;
     5. not disabled via ``BLUEFOG_TPU_PALLAS_GOSSIP=0`` (the kill switch if
        a deployment's kernels misbehave);
     6. the kernel's VMEM plan (:func:`vmem_plan_bytes`) for the largest
-       chunk fits the budget — true for every schedule under 14 slots at
+       leaf fits the budget — true for every schedule under 14 slots at
        the default cap.
+
+    A forced ``backend='pallas'`` skips this rule: the op layer then splits
+    leaves beyond the cap into cap-sized chunks, one kernel each.
     """
     import os
 
@@ -192,10 +205,10 @@ def auto_gossip_backend(sched: GossipSchedule, x, *,
     limit = auto_max_bytes()
     if limit <= 0:
         return "xla"  # explicit "never use the kernels" override
-    biggest = max(leaf_wire_bytes(l) for l in leaves)
-    if not chunkable and biggest > limit:
+    wire = [leaf_wire_bytes(l) for l in leaves]
+    if (sum(wire) if chunkable else max(wire)) > limit:
         return "xla"
-    if vmem_plan_bytes(min(biggest, limit), sched.num_slots,
+    if vmem_plan_bytes(max(wire), sched.num_slots,
                        deliver=not chunkable) > _VMEM_BUDGET:
         return "xla"  # schedule too dense for the kernel's VMEM plan
     return "pallas"

@@ -7,8 +7,14 @@ and combines (SURVEY.md §3.3).  The TPU-native translation: the communication
 is part of the jitted SPMD train step, and **XLA's latency-hiding scheduler
 provides the overlap** the reference gets from its background thread — the
 gossip ``ppermute``s have no data dependency on the backward pass in AWC
-("adapt-with-combine") mode, so they run concurrently on the ICI DMA engines
-while the MXU computes gradients.
+("adapt-with-combine") mode, so the ICI DMA engines can move them while the
+MXU computes.  What the scheduler makes of that freedom on a v5e (PERF.md,
+PR 31): it fuses each leaf's mix into that leaf's weight-gradient fusion,
+moves those fusions behind the backward pass and runs the transfers beside
+them, five in flight at a time.  That hides the transfers of all but the
+largest leaves (what the exchange adds to a four-rank GPT-2-small step fell
+from 35.6 to 21.6 ms); the two 154 MB leaves' transfers, which wait for
+one giant fusion each, and the slower fusions are what is left.
 
 Modes (reference: adapt_then_combine / adapt_with_combine):
 
@@ -208,13 +214,21 @@ def decentralized_optimizer(
         pair of a two-level mesh (``ctx.hier_mesh`` — the multi-slice/DCN
         form, dispatching to ``hierarchical_neighbor_allreduce_2d``).
       communication_type: which combine to run (reference enum).
-      atc: adapt-then-combine when True, adapt-with-combine (overlappable,
-        reference default) when False.
+      atc: adapt-with-combine when False (the reference's default):
+        ``W p + update``, where the exchange of ``p`` depends on nothing the
+        step computes, so the asynchronous path (``backend``) runs it
+        beside the weight-gradient and optimizer fusions.  Adapt-then-
+        combine when True: ``W (p + update)``, a true dependency of every
+        transfer on its leaf's update — no backend can hide that exchange
+        behind the step's own compute, and it keeps its order.
       num_steps_per_communication: gossip every k-th step (local SGD).
       local_size / machine_topology: for the hierarchical mode.
-      backend: gossip transport — 'xla' (ppermute), 'pallas' (fused RDMA
-        kernels), or 'auto' (per
-        :func:`bluefog_tpu.ops.pallas_gossip.auto_gossip_backend`).
+      backend: gossip transport — 'xla' (asynchronous ppermutes, moved by
+        the DMA engines while the core computes), 'pallas' (fused RDMA
+        kernels, which occupy the core while they wait), or 'auto' (per
+        :func:`bluefog_tpu.ops.pallas_gossip.auto_gossip_backend`: XLA
+        for any parameter tree, the kernel for a payload of at most one
+        kernel's cap).
       max_rotations: program-size cap for the CALLABLE-topology (aperiodic)
         mode at pod scale — D runtime-shift rotation slots instead of the
         full n-1 decomposition; exceeding D active rotations NaN-poisons
@@ -282,8 +296,9 @@ def decentralized_optimizer(
         )
 
     def _combine(params, count):
-        # fuse_apply: one flat buffer per dtype → one ppermute/psum per slot
-        # instead of one per parameter leaf (reference fusion-buffer parity)
+        # fuse_apply: the small leaves in flat buffers of about 4 MiB → a few
+        # ppermutes/psums per slot instead of one per parameter leaf
+        # (reference fusion-buffer parity), none much larger than a large leaf
         if ct == CommunicationType.neighbor_allreduce:
             if matrix_fn is not None:
                 return C.fuse_apply(

@@ -10,13 +10,14 @@ users to sanity-check what a sharded step will put on the ICI wire.
 
 from __future__ import annotations
 
+import math
 import re
-from typing import Any, Dict, Mapping
+from typing import Any, Dict, Mapping, Optional
 
 import jax
 
 __all__ = ["collective_census", "compiled_flops", "collective_overlap_report",
-           "parse_overlap_windows"]
+           "parse_overlap_windows", "transfer_schedule"]
 
 _COLLECTIVE_OPS = (
     "collective-permute",
@@ -110,6 +111,60 @@ def parse_overlap_windows(hlo: str) -> Dict[str, Any]:
         "overlapped_fraction": (sum(1 for w in windows if w > 0) / pairs)
         if pairs else 0.0,
     }
+
+
+_ITEMSIZE = {"pred": 1, "s8": 1, "u8": 1, "bf16": 2, "f16": 2, "s16": 2,
+             "u16": 2, "f32": 4, "s32": 4, "u32": 4, "f64": 8, "s64": 8,
+             "u64": 8}
+
+
+def transfer_schedule(hlo: str,
+                      marks: Optional[Mapping[str, str]] = None) -> Dict[str, Any]:
+    """Where each asynchronous collective-permute opens and closes in the
+    compiled program's own schedule, with its payload's bytes.
+
+    Reads the ENTRY computation of a post-optimization module (its text is
+    the schedule; the fused computations printed before it are not) and
+    numbers the compute instructions (fusion, convolution, dot,
+    custom-call) as it goes.  Returns ``{"compute_ops": n, "transfers":
+    [(opened_at, closed_at, nbytes), ...] in closing order, "marks": {name:
+    [positions]}}``: ``opened_at`` / ``closed_at`` are the number of compute
+    instructions scheduled before the ``-start`` / ``-done``, and ``marks``
+    gives the positions of the instructions whose line matches each regular
+    expression of ``marks`` (the attention kernels, a layer's weight
+    gradient), so a test or a session without a chip can say what a
+    transfer runs beside.  PERF.md (PR 31) reads the four-rank step with it:
+    XLA:TPU keeps about five collective-permutes in flight, opens the first
+    five before the forward pass and every other one where an earlier one
+    closes, next to the weight-gradient fusion that consumes it.
+    """
+    entry = hlo[hlo.index("ENTRY "):] if "ENTRY " in hlo else hlo
+    compute_re = re.compile(r"\b(fusion|convolution|dot|custom-call)\(")
+    name_re = re.compile(r"^\s*(?:ROOT )?%?([\w.\-]+) = ")
+    shape_re = re.compile(r"= \(?(\w+)\[([\d,]*)\]")
+    done_re = re.compile(r"collective-permute-done\(%?([\w.\-]+)\)")
+    mark_res = {k: re.compile(v) for k, v in (marks or {}).items()}
+    n, opened, transfers = 0, {}, []
+    found: Dict[str, list] = {k: [] for k in mark_res}
+    for line in entry.splitlines():
+        m = name_re.match(line)
+        if not m:
+            continue
+        if compute_re.search(line):
+            n += 1
+        for k, r in mark_res.items():
+            if r.search(line):
+                found[k].append(n)
+        if "collective-permute-start(" in line:
+            dtype, dims = shape_re.search(line).groups()
+            size = math.prod(int(d) for d in dims.split(",") if d)
+            opened[m.group(1)] = (n, size * _ITEMSIZE.get(dtype, 4))
+            continue
+        d = done_re.search(line)
+        if d and d.group(1) in opened:
+            at, nbytes = opened.pop(d.group(1))
+            transfers.append((at, n, nbytes))
+    return {"compute_ops": n, "transfers": transfers, "marks": found}
 
 
 def compiled_flops(fn, *args, **lower_kwargs) -> float:

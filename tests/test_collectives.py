@@ -494,6 +494,153 @@ class TestFuseApply:
         assert called["x"] is x and out is x
 
 
+class TestFuseApplyGrouping:
+    """``fuse_apply`` packs the small leaves into buffers of about
+    ``threshold_bytes`` (PR 31): what the collective is handed, buffer by
+    buffer, and that the values do not depend on the packing."""
+
+    @staticmethod
+    def _tree():
+        # f32 leaves of 40..400 B and bf16 leaves of 20..200 B, interleaved
+        # in tree order, and two leaves at or over a 1 KiB threshold
+        tree = {}
+        for i in range(10):
+            tree[f"a{i:02d}"] = rank_values((10 * (i + 1),), jnp.float32)
+            tree[f"b{i:02d}"] = rank_values((10 * (i + 1),), jnp.bfloat16)
+        tree["big_at"] = rank_values((256,), jnp.float32)      # == 1 KiB
+        tree["big_over"] = rank_values((32, 20), jnp.float32)  # 2.5 KiB
+        return tree
+
+    @staticmethod
+    def _local(tree):
+        return jax.tree_util.tree_map(lambda t: t[0], tree)
+
+    @pytest.mark.parametrize("threshold", [64, 256, 1024, 1 << 20])
+    def test_no_fused_buffer_reaches_twice_the_threshold(self, threshold):
+        from bluefog_tpu.ops import collectives as C
+
+        seen = {}
+
+        def fn(t):
+            seen.update(t)
+            return t
+
+        local = self._local(self._tree())
+        out = C.fuse_apply(fn, local, threshold_bytes=threshold)
+        nbytes = lambda a: a.size * a.dtype.itemsize
+        small = [l for l in jax.tree_util.tree_leaves(local)
+                 if nbytes(l) < threshold]
+        assert all(b.ndim == 1 and nbytes(b) < 2 * threshold
+                   for b in seen["fused"]), [nbytes(b) for b in seen["fused"]]
+        # every buffer but the last of its dtype was closed AT the threshold
+        for dt in (jnp.float32, jnp.bfloat16):
+            of_dt = [b for b in seen["fused"] if b.dtype == dt]
+            assert all(nbytes(b) >= threshold for b in of_dt[:-1])
+        assert (sum(map(nbytes, seen["fused"])) == sum(map(nbytes, small)))
+        # identity collective: the tree comes back leaf for leaf
+        for a, b in zip(jax.tree_util.tree_leaves(out),
+                        jax.tree_util.tree_leaves(local)):
+            assert a.dtype == b.dtype and a.shape == b.shape
+            np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+    def test_leaves_at_or_over_the_threshold_ride_unfused(self):
+        from bluefog_tpu.ops import collectives as C
+
+        seen = {}
+
+        def fn(t):
+            seen.update(t)
+            return t
+
+        local = self._local(self._tree())
+        C.fuse_apply(fn, local, threshold_bytes=1024)
+        assert [b.shape for b in seen["big"]] == [(256,), (32, 20)]
+        assert any(b is local["big_at"] for b in seen["big"])
+        assert any(b is local["big_over"] for b in seen["big"])
+        assert all(b.size * b.dtype.itemsize < 1024 + 400
+                   for b in seen["fused"])
+
+    def test_threshold_none_is_one_buffer_a_dtype(self):
+        from bluefog_tpu.ops import collectives as C
+
+        seen = {}
+
+        def fn(t):
+            seen.update(t)
+            return t
+
+        C.fuse_apply(fn, self._local(self._tree()), threshold_bytes=None)
+        assert seen["big"] == []
+        assert sorted(str(b.dtype) for b in seen["fused"]) == [
+            "bfloat16", "float32"]
+
+    @pytest.mark.parametrize("threshold", [64, 256, 1024, None])
+    def test_values_equal_the_unfused_call_on_a_mixed_dtype_tree(
+            self, threshold):
+        from jax.sharding import PartitionSpec as P
+
+        from bluefog_tpu.ops import collectives as C
+        from bluefog_tpu.parallel.api import shard_map as smap
+        from bluefog_tpu.topology import ExponentialTwoGraph
+        from bluefog_tpu.topology.schedule import build_schedule
+
+        bf.init(topology=ExponentialTwoGraph(N))
+        ctx = bf.get_context()
+        sched = build_schedule(ExponentialTwoGraph(N))
+        tree = self._tree()
+
+        def run(fused):
+            def step(blk):
+                local = jax.tree_util.tree_map(lambda t: t[0], blk)
+                fn = lambda t: C.neighbor_allreduce(t, sched, "bf")
+                out = (C.fuse_apply(fn, local, threshold_bytes=threshold)
+                       if fused else fn(local))
+                return jax.tree_util.tree_map(lambda t: t[None], out)
+
+            return jax.jit(smap(
+                step, mesh=ctx.mesh, in_specs=(P(ctx.axis_name),),
+                out_specs=P(ctx.axis_name), check_vma=False))(tree)
+
+        a, b = run(True), run(False)
+        for leaf_a, leaf_b in zip(jax.tree_util.tree_leaves(a),
+                                  jax.tree_util.tree_leaves(b)):
+            np.testing.assert_array_equal(np.asarray(leaf_a),
+                                          np.asarray(leaf_b))
+            assert leaf_a.dtype == leaf_b.dtype
+
+    def test_a_schedule_with_no_slot_is_the_identity(self):
+        """One rank (the benchmark's one-chip cells): nothing is exchanged,
+        the tree comes back bit for bit and no collective is compiled.  (On
+        the TPU XLA also removes the packing itself, as it did the single
+        buffer a dtype before PR 31: ``tests/test_overlap_aot.py``.)"""
+        from jax.sharding import PartitionSpec as P
+
+        from bluefog_tpu.ops import collectives as C
+        from bluefog_tpu.parallel.api import shard_map as smap
+        from bluefog_tpu.topology import ExponentialTwoGraph
+        from bluefog_tpu.topology.schedule import build_schedule
+
+        ctx = bf.init(topology=ExponentialTwoGraph(1), size=1)
+        sched = build_schedule(ExponentialTwoGraph(1))
+        assert sched.num_slots == 0
+        tree = jax.tree_util.tree_map(lambda t: t[:1], self._tree())
+
+        def step(blk):
+            local = jax.tree_util.tree_map(lambda t: t[0], blk)
+            out = C.fuse_apply(
+                lambda t: C.neighbor_allreduce(t, sched, "bf"), local,
+                threshold_bytes=256)
+            return jax.tree_util.tree_map(lambda t: t[None], out)
+
+        fn = jax.jit(smap(step, mesh=ctx.mesh, in_specs=(P(ctx.axis_name),),
+                          out_specs=P(ctx.axis_name), check_vma=False))
+        out = fn(tree)
+        for a, b in zip(jax.tree_util.tree_leaves(out),
+                        jax.tree_util.tree_leaves(tree)):
+            np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+        assert "collective-permute" not in fn.lower(tree).compile().as_text()
+
+
 class TestCollectiveCensus:
     """HLO-level proof of the fusion win: one ppermute per schedule slot
     instead of one per leaf (utils.inspect counts post-optimization HLO)."""
@@ -566,3 +713,42 @@ class TestOverlapReport:
         rep = parse_overlap_windows(
             "%pp = f32[8] collective-permute(%x)\n%f = f32[8] fusion(%x)")
         assert rep["pairs"] == 0 and rep["mean_compute_in_flight"] == 0.0
+
+
+class TestTransferSchedule:
+    """transfer_schedule reads the ENTRY computation alone: positions in
+    compute instructions, payload bytes, and the marks a caller asks for."""
+
+    HLO = "\n".join([
+        "%fused_computation.1 {",
+        "  %dot.99 = f32[8,8] dot(%q, %r)",          # not ENTRY: not counted
+        "}",
+        "ENTRY %main {",
+        "  %collective-permute-start.1 = (f32[768,3072]{1,0}, f32[768,3072]{1,0}, u32[], u32[]) collective-permute-start(%p0)",
+        "  %fwd_kernel.3 = (f32[8]) custom-call(%a), custom_call_target=\"tpu_custom_call\"",
+        "  %fusion.1 = f32[8] fusion(%a), kind=kLoop",
+        "  %collective-permute-start.12 = (bf16[1024]{0}, bf16[1024]{0}) collective-permute-start(%p1)",
+        "  %copy-done.3 = f32[8] copy-done(%cp)",
+        "  %convolution.2 = f32[8] convolution(%d, %e)",
+        "  %cpd.12 = bf16[1024]{0} collective-permute-done(%collective-permute-start.12)",
+        "  %fusion.2 = f32[8] fusion(%f), kind=kOutput",
+        "  %cpd.1 = f32[768,3072]{1,0} collective-permute-done(%collective-permute-start.1)",
+        "}",
+    ])
+
+    def test_positions_bytes_and_marks(self):
+        from bluefog_tpu.utils.inspect import transfer_schedule
+
+        rep = transfer_schedule(self.HLO, {"kernel": r"%fwd_kernel\S* = ",
+                                           "absent": r"no such thing"})
+        assert rep["compute_ops"] == 4
+        # closing order; (opened_at, closed_at, bytes of ONE payload)
+        assert rep["transfers"] == [(2, 3, 2048), (0, 4, 768 * 3072 * 4)]
+        assert rep["marks"] == {"kernel": [1], "absent": []}
+
+    def test_a_module_without_async_pairs(self):
+        from bluefog_tpu.utils.inspect import transfer_schedule
+
+        rep = transfer_schedule(
+            "%pp = f32[8] collective-permute(%x)\n%f = f32[8] fusion(%x)")
+        assert rep["transfers"] == [] and rep["compute_ops"] == 1

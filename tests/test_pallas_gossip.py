@@ -147,8 +147,10 @@ def test_wire_dtype_selection_and_chunk_accounting():
     try:
         orig = _jax.default_backend
         _jax.default_backend = lambda: "tpu"
-        # gossip: chunking means no size-based fallback either way
-        assert pallas_gossip.auto_gossip_backend(sched, f32_big) == "pallas"
+        # gossip: the wire width decides here too (one kernel's payload
+        # rides the kernel, anything larger the asynchronous path)
+        assert pallas_gossip.auto_gossip_backend(sched, f32_big) == "xla"
+        assert pallas_gossip.auto_gossip_backend(sched, bf16_same) == "pallas"
         # window transport (non-chunkable): the wire width decides
         assert pallas_gossip.auto_gossip_backend(
             sched, f32_big, chunkable=False) == "xla"

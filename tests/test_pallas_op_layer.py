@@ -156,12 +156,14 @@ def test_gossip_chunked_leaf_matches_xla(monkeypatch):
     assert len(ids) == 6 and all(1024 <= i < 2048 for i in ids), calls
 
 
-def test_default_optimizer_path_selects_chunked_pallas(monkeypatch):
-    """THE round-4 verdict gate for the fuse_apply x auto-routing
-    contradiction: the DEFAULT optimizer path (backend='auto', fused
-    buffers) on a TPU mesh must actually exercise the RDMA kernels — the
-    fused flat buffer CHUNKS instead of silently falling back to XLA —
-    and produce the same training step as the XLA backend."""
+def test_default_optimizer_path_is_async_and_a_forced_kernel_path_chunks(
+        monkeypatch):
+    """The DEFAULT optimizer path (backend='auto', fused buffers) on a TPU
+    mesh hands a tree beyond one kernel's payload to XLA's asynchronous
+    collective-permutes (PR 31: the kernels occupy the core while they
+    wait, so nothing of a 30 ms exchange was hidden); a tree one kernel
+    carries still rides the kernel; and a FORCED backend='pallas' chunks
+    the fused buffer as before.  All three produce the same training step."""
     import optax
     import bluefog_tpu as bf
     from bluefog_tpu.optim import DistributedNeighborAllreduceOptimizer
@@ -170,7 +172,7 @@ def test_default_optimizer_path_selects_chunked_pallas(monkeypatch):
 
     # pretend the CPU mesh is a TPU slice (interpret mode executes the
     # kernels); shrink the cap so the fused buffer (5,000 floats = 20 KB)
-    # needs 3 chunks at 8 KiB
+    # is beyond one kernel's payload and needs 3 chunks at 8 KiB
     monkeypatch.setattr(pg, "on_tpu_platform", lambda: True)
     monkeypatch.setenv("BLUEFOG_TPU_PALLAS_MAX_BYTES", str(8 << 10))
 
@@ -191,9 +193,10 @@ def test_default_optimizer_path_selects_chunked_pallas(monkeypatch):
                                                      (t.ndim - 1)), t.shape),
         params)
 
-    def run_step():
+    def run_step(backend="auto"):
         opt = DistributedNeighborAllreduceOptimizer(
-            optax.sgd(0.1), topology=ExponentialTwoGraph(N), axis_name="bf")
+            optax.sgd(0.1), topology=ExponentialTwoGraph(N), axis_name="bf",
+            backend=backend)
 
         def body(p, g):
             st = opt.init(p)
@@ -204,16 +207,26 @@ def test_default_optimizer_path_selects_chunked_pallas(monkeypatch):
             body, mesh=_mesh(), in_specs=(P("bf"), P("bf")),
             out_specs=P("bf"), check_vma=False))(params, grads)
 
-    got = run_step()
-    assert calls, "default optimizer path never reached the pallas kernels"
-    # fused buffer = 5,000 floats -> ceil(20,000 B / 8,192 B) = 3 chunks
+    want = run_step()
+    assert not calls, "a tree beyond the cap must take the asynchronous path"
+
+    # forced kernels: fused buffer = 5,000 floats -> ceil(20,000 B / 8,192 B)
+    # = 3 chunks
+    forced = run_step("pallas")
     assert len(calls) == 3 and sum(calls) == 5000, calls
 
-    # numerics: the same step on the forced-XLA path
+    # a cap the whole tree fits under: auto keeps the kernel, one invocation
+    calls.clear()
+    monkeypatch.setenv("BLUEFOG_TPU_PALLAS_MAX_BYTES", str(32 << 10))
+    small = run_step()
+    assert calls == [5000], calls
+
+    # and the kill switch still forces XLA
     monkeypatch.setenv("BLUEFOG_TPU_PALLAS_GOSSIP", "0")
     calls.clear()
-    want = run_step()
+    run_step()
     assert not calls, "kill switch must force XLA"
-    for k in params:
-        np.testing.assert_allclose(np.asarray(got[k]), np.asarray(want[k]),
-                                   rtol=1e-5, atol=1e-6)
+    for got in (forced, small):
+        for k in params:
+            np.testing.assert_allclose(np.asarray(got[k]), np.asarray(want[k]),
+                                       rtol=1e-5, atol=1e-6)

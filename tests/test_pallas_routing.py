@@ -2,7 +2,8 @@
 
 `backend='auto'` must provably choose per the stated conditions
 (pallas_gossip.auto_gossip_backend): real TPU + multi-device + circulant +
-small-enough payloads -> pallas; anything else -> XLA.  The policy is pure
+a payload one kernel carries (the whole gossip tree; each leaf of a window
+payload) -> pallas; anything else -> XLA.  The policy is pure
 and cheap, so every branch is asserted directly; integration (the XLA side
 of auto on the CPU mesh + interpret-mode kernel parity) is covered by
 test_collectives.py / test_pallas_gossip.py.
@@ -40,15 +41,73 @@ def test_auto_picks_pallas_on_tpu_small_circulant(on_tpu):
     assert pg.auto_gossip_backend(build_schedule(RingGraph(8)), tree) == "pallas"
 
 
-def test_auto_gossip_has_no_size_cutoff(on_tpu):
-    """Gossip chunks oversized leaves at the op layer, so auto routes ANY
-    size to pallas — this is what makes the RDMA kernels the real default
-    under fuse_apply's flat optimizer buffers (round-4 verdict: the 4 MiB
-    cutoff + fusion silently cancelled the kernels out of the default
-    training path)."""
+CAP = pg.DEFAULT_AUTO_MAX_BYTES
+
+
+def _f32(nbytes):
+    return jax.ShapeDtypeStruct((nbytes // 4,), jnp.float32)
+
+
+@pytest.mark.parametrize("tree, want", [
+    (_f32(CAP), "pallas"),                            # one kernel's payload
+    (_f32(CAP + 4), "xla"),                           # one element beyond it
+    ({"a": _f32(CAP // 2), "b": _f32(CAP // 2)}, "pallas"),
+    # every leaf under the cap, the tree over it: the whole payload decides
+    ({"a": _f32(CAP // 2), "b": _f32(CAP // 2 + 4)}, "xla"),
+    # bf16 travels as bf16: twice the elements ride one kernel
+    (jax.ShapeDtypeStruct((CAP // 2,), jnp.bfloat16), "pallas"),
+    (jax.ShapeDtypeStruct((CAP // 2 + 1,), jnp.bfloat16), "xla"),
+    (BIG, "xla"),
+    ({"a": SMALL, "b": BIG}, "xla"),
+], ids=["at_cap", "over_cap", "tree_at_cap", "tree_over_cap", "bf16_at_cap",
+        "bf16_over_cap", "big_leaf", "small_and_big"])
+def test_auto_gossip_takes_the_async_path_beyond_one_kernels_payload(
+        on_tpu, tree, want):
+    """A Pallas gossip kernel occupies the TensorCore while its RDMAs fly
+    (168 us a 4 MiB kernel on a v5e, 180 of them a GPT-2-small step, none
+    hidden: PERF.md, PR 31), XLA's collective-permute-start/-done do not.
+    ``auto`` keeps the kernels for a payload one invocation carries and
+    gives everything larger to XLA, deciding from the tree's on-wire bytes
+    alone."""
     sched = build_schedule(RingGraph(8))
+    assert pg.auto_gossip_backend(sched, tree) == want
+
+
+def test_any_optimizer_tree_is_on_the_async_side(on_tpu):
+    """What ``decentralized_optimizer`` hands the exchange — a few fused
+    buffers of about 8 MiB and the large leaves — is far beyond one
+    kernel's payload at either shape the rule was set on (PERF.md, PR 31):
+    GPT-2 small's 27 large leaves and 13 buffers, ResNet-50's 3 and 9."""
+    sched = build_schedule(ExponentialTwoGraph(4))
+    gpt2 = {"big": [_f32(154_533_888)] * 2 + [_f32(25_165_824)]
+            + [_f32(9_437_184)] * 24, "fused": [_f32(9_470_000)] * 12}
+    resnet = {"big": [_f32(9_437_184)] * 3, "fused": [_f32(8_500_000)] * 9}
+    assert pg.auto_gossip_backend(sched, gpt2) == "xla"
+    assert pg.auto_gossip_backend(sched, resnet) == "xla"
+
+
+def test_the_cutoff_follows_the_cap_override(on_tpu, monkeypatch):
+    """One number, no new knob: ``BLUEFOG_TPU_PALLAS_MAX_BYTES`` is the
+    chunk cap of a forced kernel path and the cutoff of ``auto``."""
+    sched = build_schedule(RingGraph(8))
+    assert pg.auto_gossip_backend(sched, BIG) == "xla"
+    monkeypatch.setenv("BLUEFOG_TPU_PALLAS_MAX_BYTES", str(8 << 20))
     assert pg.auto_gossip_backend(sched, BIG) == "pallas"
-    assert pg.auto_gossip_backend(sched, {"a": SMALL, "b": BIG}) == "pallas"
+    monkeypatch.setenv("BLUEFOG_TPU_PALLAS_MAX_BYTES", "1024")
+    assert pg.auto_gossip_backend(sched, SMALL) == "xla"
+
+
+def test_a_forced_backend_is_not_routed(on_tpu):
+    """``resolve_backend`` asks the rule only for ``auto``: a forced
+    ``'pallas'`` stays the kernels at any size (the op layer chunks), a
+    forced ``'xla'`` stays XLA for a payload the kernels would take."""
+    sched = build_schedule(RingGraph(8))
+    assert pg.resolve_backend("pallas", sched, BIG) == "pallas"
+    assert pg.resolve_backend("xla", sched, SMALL) == "xla"
+    assert pg.resolve_backend("auto", sched, BIG) == "xla"
+    assert pg.resolve_backend("auto", sched, SMALL) == "pallas"
+    with pytest.raises(ValueError, match="unknown backend"):
+        pg.resolve_backend("rdma", sched, SMALL)
 
 
 def test_window_deliver_keeps_size_cutoff(on_tpu):
