@@ -32,6 +32,20 @@ GPT-2 decoder this file always built, parameter for parameter:
                  (no table; the attention turns its rotary part)
 ``mtp_depth``    0, or 1 for one multi-token-prediction module sharing the
                  embedding and the head (:func:`next_token_loss`)
+``layer_types``  ``None``, or **the token mixer of each block** (a
+                 decoder-hybrid-decoder, SambaY, arXiv:2507.06607; sizes in
+                 :class:`HybridSizes`): ``mamba`` (:class:`MambaMixer`, a
+                 selective state space), ``diff_attention`` and
+                 ``diff_attention_window`` (:class:`DiffAttention`: two
+                 softmax maps a head pair, over all keys or the last
+                 ``window``), ``gmu`` (:class:`GatedMemoryUnit`, which reads
+                 the memory the last ``mamba`` block left) and
+                 ``cross_diff_attention`` (queries of its own over the keys
+                 and values of the last ``diff_attention`` block).  Blocks
+                 hand these tensors on; with it come ``position="none"``,
+                 ``ffn="swiglu"`` and ``norm_eps`` for the LayerNorms
+``tie_head``     the head is the embedding's transpose: one leaf, whose
+                 gradient is the sum of both uses
 ===============  ===========================================================
 """
 
@@ -39,14 +53,17 @@ from __future__ import annotations
 
 import dataclasses
 import functools
+import math
 from typing import Callable, Optional, Tuple
 
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
 
+from bluefog_tpu.metrics import comm as metrics_comm
 from bluefog_tpu.ops.moe import routed_experts, sigmoid_topk_router
 from bluefog_tpu.ops.ring_attention import local_attention
+from bluefog_tpu.ops.selective_scan import selective_scan
 
 AttnFn = Callable[..., jnp.ndarray]  # (q, k, v) -> (B, T, H, D)
 
@@ -81,6 +98,28 @@ class ExpertSizes:
     first_dense: int = 1           # leading blocks with the dense swiglu
 
 
+MIXERS = ("mamba", "diff_attention", "diff_attention_window", "gmu",
+          "cross_diff_attention")
+
+
+@dataclasses.dataclass(frozen=True)
+class HybridSizes:
+    """What the mixers of ``layer_types`` need beyond the trunk's widths:
+    Mamba-1's inner width, state, convolution taps and ``delta`` rank
+    (arXiv:2312.00752), and differential attention's key/value heads and
+    window (arXiv:2410.05258; a head is ``hidden_size / num_heads`` wide, a
+    pair's value twice that).  ``first_layer`` is the published index of
+    block 0: ``lambda_init = 0.8 - 0.6 exp(-0.3 l)`` depends on the layer."""
+
+    d_inner: int = 5120
+    d_state: int = 16
+    d_conv: int = 4
+    dt_rank: int = 160
+    kv_heads: int = 20
+    window: int = 512
+    first_layer: int = 0
+
+
 @dataclasses.dataclass(frozen=True)
 class GPTConfig:
     vocab_size: int = 50304          # 50257 padded up to a 128 multiple
@@ -99,19 +138,32 @@ class GPTConfig:
     norm: str = "layernorm"
     position: str = "learned"
     ffn_width: Optional[int] = None  # swiglu; None: mlp_ratio * hidden_size
-    norm_eps: float = 1e-6           # rmsnorm's
+    norm_eps: float = 1e-6           # either norm's
     latent: Optional[LatentSizes] = None
     experts: Optional[ExpertSizes] = None
     mtp_depth: int = 0
+    layer_types: Optional[Tuple[str, ...]] = None   # a mixer a block
+    hybrid: Optional[HybridSizes] = None
+    tie_head: bool = False
 
     def __post_init__(self):
         for field, kinds in (("attention", ("fused_qkv", "latent")),
                              ("ffn", ("gelu", "swiglu", "routed+shared")),
                              ("norm", ("layernorm", "rmsnorm")),
-                             ("position", ("learned", "rotary"))):
+                             ("position", ("learned", "rotary", "none"))):
             if getattr(self, field) not in kinds:
                 raise ValueError(f"unknown {field} {getattr(self, field)!r};"
                                  f" expected one of {kinds}")
+        if (self.layer_types is None) != (self.hybrid is None):
+            raise ValueError("`layer_types` and the `hybrid` sizes come "
+                             "together")
+        if (self.position == "none") != (self.layer_types is not None):
+            raise ValueError("position='none' is for `layer_types` (the "
+                             "recurrence orders the tokens); fused_qkv and "
+                             "latent heads need positions")
+        if self.layer_types is not None:
+            self._check_layer_types()
+            return
         if (self.attention == "latent") != (self.latent is not None):
             raise ValueError("attention='latent' and the `latent` sizes come "
                              "together")
@@ -124,6 +176,39 @@ class GPTConfig:
                              "come together")
         if self.mtp_depth not in (0, 1):
             raise ValueError(f"mtp_depth {self.mtp_depth}: one module or none")
+
+    def _check_layer_types(self):
+        types, hy = tuple(self.layer_types), self.hybrid
+        if len(types) != self.num_layers:
+            raise ValueError(f"{len(types)} layer_types for "
+                             f"{self.num_layers} layers")
+        for i, kind in enumerate(types):
+            if kind not in MIXERS:
+                raise ValueError(f"unknown mixer {kind!r} in layer_types; "
+                                 f"expected one of {MIXERS}")
+            if kind == "gmu" and "mamba" not in types[:i]:
+                raise ValueError(f"block {i} is a gmu with no mamba block "
+                                 "before it to read the memory of")
+            if (kind == "cross_diff_attention"
+                    and "diff_attention" not in types[:i]):
+                raise ValueError(
+                    f"block {i} is a cross_diff_attention with no "
+                    "diff_attention block (full, not windowed) before it "
+                    "to read keys and values of")
+        if (self.attention, self.ffn, self.norm, self.mtp_depth, self.latent,
+                self.experts) != ("fused_qkv", "swiglu", "layernorm", 0,
+                                  None, None):
+            raise ValueError(
+                "`layer_types` blocks are built with ffn='swiglu', "
+                "norm='layernorm', no MTP module, no `latent` or `experts` "
+                "sizes and `attention` left at its default")
+        heads, groups = self.num_heads, hy.kv_heads
+        if heads % 2 or groups % 2 or heads % groups or (
+                self.hidden_size % heads):
+            raise ValueError(
+                f"differential attention pairs adjacent heads: {heads} "
+                f"query and {groups} key/value heads of "
+                f"{self.hidden_size}/{heads} do not pair up")
 
     @staticmethod
     def small() -> "GPTConfig":
@@ -140,7 +225,7 @@ def _norm(cfg: GPTConfig, name: str) -> nn.Module:
     """The configuration's norm, computing and returning f32."""
     if cfg.norm == "rmsnorm":
         return nn.RMSNorm(epsilon=cfg.norm_eps, dtype=jnp.float32, name=name)
-    return nn.LayerNorm(dtype=jnp.float32, name=name)
+    return nn.LayerNorm(epsilon=cfg.norm_eps, dtype=jnp.float32, name=name)
 
 
 def rotary(x, positions, theta: float):
@@ -203,6 +288,169 @@ class LatentAttention(nn.Module):
                 a.reshape(a.shape[:-2] + (h * la.v_head_dim,)))
 
 
+def lambda_init(layer: int) -> float:
+    """Differential attention's layer-dependent constant (arXiv:2410.05258
+    section 2), from the published layer index."""
+    return 0.8 - 0.6 * math.exp(-0.3 * layer)
+
+
+def causal_depthwise_conv(x, kernel, bias):
+    """``out_t = sum_j kernel[j] * x_{t - (K - 1) + j} + bias`` a channel,
+    with zeros before the sequence: ``x (B, T, C)``, ``kernel (K, C)``.  As
+    ``K`` shifted multiply-adds, which XLA fuses into one pass."""
+    taps, t = kernel.shape[0], x.shape[1]
+    padded = jnp.pad(x, ((0, 0), (taps - 1, 0), (0, 0)))
+    return sum(kernel[j] * padded[:, j:j + t] for j in range(taps)) + bias
+
+
+class MambaMixer(nn.Module):
+    """Mamba-1's mixer (arXiv:2312.00752 section 3.4): ``(B, T, D) ->``
+    the output ``(B, T, D)`` and the memory ``m (B, T, d_inner)``, the scan's
+    output before the gate, which a later gated memory unit reads.
+
+    ``[xi; z] = W_in y``; ``x = silu(conv(xi) + b_c)``;
+    ``[dr; B; C] = W_x x``; ``delta = softplus(W_dt dr + b_dt)``;
+    ``m = selective_scan(x, delta, -exp(A_log), B, C, D)``; output
+    ``W_out (m * silu(z))``.  ``delta``, ``A`` and the recurrence are f32.
+    The initialisers are Mamba's published ones, which make the recurrence
+    the dynamical system it was designed as: ``A_log = log(1 .. N)``,
+    ``D = 1``, ``b_dt`` the inverse softplus of a ``delta`` log-uniform in
+    [1e-3, 1e-1], ``W_dt`` uniform within ``dt_rank ** -0.5``."""
+
+    cfg: GPTConfig
+
+    @nn.compact
+    def __call__(self, y):
+        cfg, hy = self.cfg, self.cfg.hybrid
+        inner, n, rank = hy.d_inner, hy.d_state, hy.dt_rank
+        dense = functools.partial(nn.Dense, use_bias=False, dtype=cfg.dtype)
+
+        def dt_bias(key, shape, dtype):
+            dt = jnp.exp(jax.random.uniform(key, shape, dtype)
+                         * (math.log(0.1) - math.log(1e-3)) + math.log(1e-3))
+            return dt + jnp.log(-jnp.expm1(-dt))      # softplus's inverse
+
+        def within(bound):
+            return lambda key, shape, dtype=jnp.float32: jax.random.uniform(
+                key, shape, dtype, -bound, bound)
+
+        with jax.named_scope("bf.ssm.project"):
+            xi, z = jnp.split(dense(2 * inner, name="in_proj")(y), 2, axis=-1)
+        with jax.named_scope("bf.ssm.conv"):
+            taps = self.param("conv_kernel", within(hy.d_conv ** -0.5),
+                              (hy.d_conv, inner), jnp.float32)
+            bias = self.param("conv_bias", within(hy.d_conv ** -0.5),
+                              (inner,), jnp.float32)
+            x = nn.silu(causal_depthwise_conv(
+                xi.astype(jnp.float32), taps, bias)).astype(cfg.dtype)
+        with jax.named_scope("bf.ssm.project"):
+            dbc = dense(rank + 2 * n, name="x_proj")(x)
+            delta = nn.softplus(nn.Dense(
+                inner, dtype=jnp.float32, name="dt_proj",
+                kernel_init=within(rank ** -0.5), bias_init=dt_bias)(
+                    dbc[..., :rank]))
+        a_log = self.param(
+            "A_log", lambda key, shape, dtype: jnp.broadcast_to(
+                jnp.log(jnp.arange(1, shape[1] + 1, dtype=dtype)), shape),
+            (inner, n), jnp.float32)
+        skip = self.param("D", nn.initializers.ones, (inner,), jnp.float32)
+        with jax.named_scope("bf.ssm.scan"):
+            m = selective_scan(x, delta, -jnp.exp(a_log),
+                               dbc[..., rank:rank + n], dbc[..., rank + n:],
+                               skip)
+        with jax.named_scope("bf.ssm.project"):
+            return dense(cfg.hidden_size, name="out_proj")(m * nn.silu(z)), m
+
+
+class GatedMemoryUnit(nn.Module):
+    """``W_out (m * silu(W_in y))`` (SambaY, arXiv:2507.06607 section 2):
+    the memory ``m`` of an earlier Mamba block, gated element by element by
+    this layer's own input.  No bias."""
+
+    cfg: GPTConfig
+
+    @nn.compact
+    def __call__(self, y, memory):
+        cfg = self.cfg
+        dense = functools.partial(nn.Dense, use_bias=False, dtype=cfg.dtype)
+        with jax.named_scope("bf.gmu.gate"):
+            gated = memory * nn.silu(
+                dense(cfg.hybrid.d_inner, name="in_proj")(y))
+            return dense(cfg.hidden_size, name="out_proj")(gated)
+
+
+class DiffAttention(nn.Module):
+    """Differential attention (arXiv:2410.05258) over grouped key/value
+    heads: ``(B, T, D) ->`` the output and the ``(k, v)`` it attended over.
+
+    Query pair ``p`` is heads ``(2p, 2p + 1)``, key pair ``g = p // (P / G)``
+    likewise, the pair's value its two value heads side by side;
+    ``o_p = A_1 V_g - lam A_2 V_g`` with ``A_j`` the softmax map of the
+    pair's ``j``-th query and key head, ``lam = exp(lq1 . lk1) -
+    exp(lq2 . lk2) + lam_init``; ``o_p <- RMSNorm(o_p) (1 - lam_init)``,
+    one scale shared by the pairs; then the biased output projection.  With
+    ``keys_values`` (another layer's ``(k, v)``) only the query is this
+    layer's: cross attention.
+
+    For the kernel the ``2P`` maps are ``2P`` plain heads with ``D``-wide
+    queries and keys and ``2D``-wide values, in the order ``(g, j, r)`` (key
+    pair, map, query pair within the group), so that head ``h`` reads key
+    head ``h // (P / G)`` and value pair ``h // (2P / G)``: the grouped
+    heads of :func:`~bluefog_tpu.ops.ring_attention.local_attention`.
+    ``k (B, T, 2G, D)`` and ``v (B, T, G, 2D)`` are handed on as they are.
+    """
+
+    cfg: GPTConfig
+    layer: int                      # published index, for lambda_init
+    window: Optional[int] = None
+    cross: bool = False
+
+    @nn.compact
+    def __call__(self, y, attn_fn, keys_values=None):
+        cfg = self.cfg
+        heads, groups = cfg.num_heads, cfg.hybrid.kv_heads
+        dim, width = cfg.hidden_size // cfg.num_heads, cfg.hidden_size
+        share = heads // groups         # query pairs a key pair
+        lead = y.shape[:-1]
+        with jax.named_scope("bf.attn.project"):
+            if self.cross:
+                q = nn.Dense(width, dtype=cfg.dtype, name="q")(y)
+                k, v = keys_values
+            else:
+                qkv = nn.Dense(width + 2 * groups * dim, dtype=cfg.dtype,
+                               name="qkv")(y)
+                q = qkv[..., :width]
+                k = qkv[..., width:width + groups * dim].reshape(
+                    lead + (groups, dim))
+                v = qkv[..., width + groups * dim:].reshape(
+                    lead + (groups // 2, 2 * dim))
+            # (g, r, j) as projected -> (g, j, r) for the kernel
+            q = q.reshape(lead + (groups // 2, share, 2, dim))
+            q = jnp.swapaxes(q, -3, -2).reshape(lead + (heads, dim))
+        mask = {} if self.window is None else {"window": self.window}
+        a = attn_fn(q, k, v, **mask)
+        a = metrics_comm.count(a, [
+            ("bf_attn_full_calls_total", 1.0) if self.window is None
+            else ("bf_attn_window_calls_total", 1.0)])
+        lam = {name: self.param(name, nn.initializers.normal(0.1), (dim,),
+                                jnp.float32)
+               for name in ("lambda_q1", "lambda_k1", "lambda_q2",
+                            "lambda_k2")}
+        with jax.named_scope("bf.attn.diff"):
+            init = lambda_init(self.layer)
+            weight = (jnp.exp(jnp.sum(lam["lambda_q1"] * lam["lambda_k1"]))
+                      - jnp.exp(jnp.sum(lam["lambda_q2"] * lam["lambda_k2"]))
+                      + init)
+            a = a.astype(jnp.float32).reshape(
+                lead + (groups // 2, 2, share, 2 * dim))
+            o = a[..., 0, :, :] - weight * a[..., 1, :, :]
+            o = nn.RMSNorm(epsilon=cfg.norm_eps, dtype=jnp.float32,
+                           name="subln")(o) * (1.0 - init)
+            o = o.reshape(lead + (width,)).astype(cfg.dtype)
+        with jax.named_scope("bf.attn.project"):
+            return nn.Dense(width, dtype=cfg.dtype, name="out")(o), (k, v)
+
+
 class GatedMLP(nn.Module):
     """``down(silu(gate(y)) * up(y))``, no bias."""
 
@@ -256,6 +504,46 @@ class RoutedSharedFFN(nn.Module):
         return shared + routed.reshape(y.shape)
 
 
+def _mix(block, y, attn_fn, carried):
+    """The block's token mixer (``block.mixer``) over the normed input, and
+    what it leaves for later blocks.  A plain function: flax puts a method's
+    name into the name stack, and so into every ``op_name`` under it."""
+    cfg = block.cfg
+    memory, keys, values = carried
+    if block.mixer == "mamba":
+        a, memory = MambaMixer(cfg, name="mamba")(y)
+    elif block.mixer == "gmu":
+        a = GatedMemoryUnit(cfg, name="gmu")(y, memory)
+    elif block.mixer == "cross_diff_attention":
+        a, _ = DiffAttention(cfg, block.layer, cross=True, name="attn")(
+            y, attn_fn, (keys, values))
+    else:
+        windowed = block.mixer == "diff_attention_window"
+        a, kv = DiffAttention(
+            cfg, block.layer, name="attn",
+            window=cfg.hybrid.window if windowed else None)(y, attn_fn)
+        if not windowed:
+            keys, values = kv
+    return a, (memory, keys, values)
+
+
+def _feed_forward(block, x):
+    """``x + FFN(norm(x))`` of the block's feed-forward kind."""
+    cfg = block.cfg
+    y = _norm(cfg, "ln2")(x).astype(cfg.dtype)
+    ffn = block.ffn or cfg.ffn
+    if block.mlp is not None:
+        return x + block.mlp()(y)
+    if ffn == "routed+shared":
+        return x + RoutedSharedFFN(cfg, name="moe")(y)
+    width = cfg.ffn_width or cfg.mlp_ratio * cfg.hidden_size
+    if ffn == "swiglu":
+        return x + GatedMLP(width, cfg.dtype, name="mlp")(y)
+    y = nn.Dense(width, dtype=cfg.dtype, name="up")(y)
+    y = nn.gelu(y)
+    return x + nn.Dense(cfg.hidden_size, dtype=cfg.dtype, name="down")(y)
+
+
 class Block(nn.Module):
     """Pre-norm attention + feed-forward residual block, assembled from the
     configuration's kinds (module docstring).
@@ -265,16 +553,30 @@ class Block(nn.Module):
     D)`` (the MoE variant of models/moe.py injects a Switch-MoE FFN here
     instead of duplicating the attention trunk).  ``ffn`` overrides
     ``cfg.ffn`` for this block (the leading dense blocks of an expert model).
+
+    ``mixer`` (one of ``cfg.layer_types``) replaces the attention with that
+    token mixer.  Such a block takes and returns ``carried = (memory, keys,
+    values)`` beside ``x``: what the last Mamba block and the last full
+    differential-attention block left for the gated memory units and the
+    cross-attention layers after them.  Under ``nn.remat`` they are a
+    block's outputs and the next blocks' inputs, so they are saved and not
+    recomputed, and a reader's gradient flows back into the block that made
+    them.
     """
 
     cfg: GPTConfig
     mlp: Optional[Callable[[], nn.Module]] = None
     ffn: Optional[str] = None
+    mixer: Optional[str] = None
+    layer: int = 0                   # published index (differential lambda)
 
     @nn.compact
-    def __call__(self, x, attn_fn: AttnFn, positions=None):
+    def __call__(self, x, attn_fn: AttnFn, positions=None, carried=None):
         cfg = self.cfg
         y = _norm(cfg, "ln1")(x).astype(cfg.dtype)
+        if self.mixer is not None:
+            a, carried = _mix(self, y, attn_fn, carried)
+            return _feed_forward(self, x + a), carried
         if cfg.attention == "latent":
             a = LatentAttention(cfg, name="attn")(y, attn_fn, positions)
         else:
@@ -289,26 +591,15 @@ class Block(nn.Module):
             a = attn_fn(heads(q), heads(k), heads(v))
             a = a.reshape(a.shape[:-2] + (cfg.hidden_size,))
             a = nn.Dense(cfg.hidden_size, dtype=cfg.dtype, name="proj")(a)
-        x = x + a
-
-        y = _norm(cfg, "ln2")(x).astype(cfg.dtype)
-        ffn = self.ffn or cfg.ffn
-        if self.mlp is not None:
-            return x + self.mlp()(y)
-        if ffn == "routed+shared":
-            return x + RoutedSharedFFN(cfg, name="moe")(y)
-        width = cfg.ffn_width or cfg.mlp_ratio * cfg.hidden_size
-        if ffn == "swiglu":
-            return x + GatedMLP(width, cfg.dtype, name="mlp")(y)
-        y = nn.Dense(width, dtype=cfg.dtype, name="up")(y)
-        y = nn.gelu(y)
-        return x + nn.Dense(cfg.hidden_size, dtype=cfg.dtype, name="down")(y)
+        return _feed_forward(self, x + a)
 
 
 class TransformerLM(nn.Module):
     """Tokens → logits.  ``attn_fn(q, k, v) -> out`` defaults to full causal
     attention; inject a sequence-parallel attention inside ``shard_map`` and
-    pass this rank's global ``position_offset``.  ``mlp`` (a sublayer factory,
+    pass this rank's global ``position_offset``.  (A windowed block calls
+    ``attn_fn(q, k, v, window=w)``, and the differential blocks hand it
+    grouped key/value heads: an injected ``attn_fn`` has to take both.)  ``mlp`` (a sublayer factory,
     see :class:`Block`) swaps every block's MLP — e.g. for Switch-MoE.
 
     With ``cfg.mtp_depth == 1`` and ``next_tokens`` (the tokens one place
@@ -329,8 +620,8 @@ class TransformerLM(nn.Module):
         if attn_fn is None:
             # the model layer is the perf path: opt into the fused TPU flash
             # kernel whenever eligible (parity: tests/test_flash_attention.py)
-            attn_fn = lambda q, k, v: local_attention(q, k, v, causal=True,
-                                                      backend="auto")
+            attn_fn = lambda q, k, v, **mask: local_attention(
+                q, k, v, causal=True, backend="auto", **mask)
         if positions is None:
             positions = position_offset + jnp.arange(tokens.shape[1])[None, :]
         # else: explicit per-token global positions — required by layouts
@@ -344,12 +635,23 @@ class TransformerLM(nn.Module):
                              dtype=cfg.dtype, name="pos")(positions)
         block_cls = nn.remat(Block, static_argnums=(2,)) if cfg.remat else Block
         dense_blocks = cfg.experts.first_dense if cfg.experts else 0
+        carried = (None, None, None)     # memory, keys, values
         for i in range(cfg.num_layers):
+            if cfg.layer_types is not None:
+                x, carried = block_cls(
+                    cfg, mixer=cfg.layer_types[i],
+                    layer=cfg.hybrid.first_layer + i, name=f"block_{i}")(
+                        x, attn_fn, positions, carried)
+                continue
             x = block_cls(cfg, mlp=self.mlp,
                           ffn="swiglu" if i < dense_blocks else None,
                           name=f"block_{i}")(x, attn_fn, positions)
-        head = nn.Dense(cfg.vocab_size, dtype=jnp.float32, use_bias=False,
-                        name="lm_head")
+        if cfg.tie_head:
+            def head(h):    # f32 logits from the f32 leaf, as lm_head's
+                return jnp.einsum("...d,vd->...v", h, embed.embedding)
+        else:
+            head = nn.Dense(cfg.vocab_size, dtype=jnp.float32,
+                            use_bias=False, name="lm_head")
         logits = head(_norm(cfg, "ln_f")(x))
         if next_tokens is None:
             return logits

@@ -42,6 +42,7 @@ from bluefog_tpu.ops.ring_attention import (
     zigzag_shard,
     zigzag_unshard,
 )
+from bluefog_tpu.ops.selective_scan import selective_scan
 from bluefog_tpu.ops.moe import (
     RouterOutput,
     switch_router,

@@ -51,7 +51,10 @@ def _flash_eligible(q, k, causal, q_offset, k_offset, v=None) -> bool:
     one — *static* offsets with ``q_offset == k_offset`` when causal.
     The contract is ``q, k (..., D_qk)``, ``v (..., D_v)``: the kernel takes
     values of their own width (latent attention: 192-wide q and k, 128-wide
-    v), both at least 32.
+    v), both at least 32.  A ``window`` (``local_attention``) and keys or
+    values of fewer heads than the queries change nothing here: the window is
+    a mask of the kernel's own (``LocalMask``) on the same aligned diagonal,
+    and grouped heads are repeated before the kernel sees them.
     """
     if jax.default_backend() != "tpu":
         return False
@@ -120,16 +123,25 @@ def _name_kernels_flash():
 
 
 @functools.lru_cache(maxsize=32)
-def _splash_kernel(t: int, heads: int, causal: bool, interpret: bool):
-    """The splash kernel for one ``(H, T, D)`` example, built once per shape:
-    the block-sparse mask info is computed on the host here, so the kernels
-    skip the tiles above the diagonal and mask only the tiles on it.
-    ``interpret`` runs the kernel in the Pallas interpreter (CPU tests)."""
+def _splash_kernel(t: int, heads: int, mask, interpret: bool):
+    """The splash kernel for one ``(H, T, D)`` example, built once per shape
+    and mask: the block-sparse mask info is computed on the host here, so
+    the kernels skip the tiles no query of theirs sees and mask only the
+    tiles an edge crosses.  ``mask`` is ``"full"``, ``"causal"`` or
+    ``("window", w)``: causal, and key ``s`` visible from ``t`` only while
+    ``t - w < s``.  ``interpret`` runs the kernel in the Pallas interpreter
+    (CPU tests)."""
     from jax.experimental.pallas.ops.tpu.splash_attention import (
-        CausalMask, FullMask, MultiHeadMask, make_splash_mha_single_device)
+        CausalMask, FullMask, LocalMask, MultiHeadMask,
+        make_splash_mha_single_device)
 
     _name_kernels_flash()
-    head_mask = (CausalMask if causal else FullMask)((t, t))
+    if mask == "full":
+        head_mask = FullMask((t, t))
+    elif mask == "causal":
+        head_mask = CausalMask((t, t))
+    else:
+        head_mask = LocalMask((t, t), window_size=(mask[1] - 1, 0), offset=0)
     # the factory makes device arrays of the mask info: keep them concrete
     # when the first call happens under a trace, or the cache leaks tracers
     with jax.ensure_compile_time_eval():
@@ -138,13 +150,28 @@ def _splash_kernel(t: int, heads: int, causal: bool, interpret: bool):
             block_sizes=_splash_block_sizes(t), interpret=interpret)
 
 
+def _mask_kind(causal: bool, window):
+    """The hashable name :func:`_splash_kernel` caches a mask under."""
+    if window is not None:
+        return ("window", int(window))
+    return "causal" if causal else "full"
+
+
+def _repeat_heads(x, heads: int):
+    """``(B, T, G, D) -> (B, T, heads, D)``: query head ``h`` reads group
+    ``h // (heads / G)``."""
+    groups = x.shape[2]
+    return x if groups == heads else jnp.repeat(x, heads // groups, axis=2)
+
+
 def _splash_attention(q, k, v, *, causal: bool, scale: float,
-                      interpret: bool = False):
+                      window=None, interpret: bool = False):
     """:func:`local_attention` on the splash kernel: ``q, k (B, T, H, D_qk)``
     and ``v (B, T, H, D_v)`` in, ``(B, T, H, D_v)`` out, operands in their
     own dtype, scores and softmax in f32.  Mosaic takes ``D_qk = 192`` beside
     ``D_v = 128`` as it is (compiled ahead of time for a v5e): no padding."""
-    kernel = _splash_kernel(q.shape[1], q.shape[2], causal, interpret)
+    kernel = _splash_kernel(q.shape[1], q.shape[2],
+                            _mask_kind(causal, window), interpret)
     # the kernel takes no scale: fold it into q, once, in f32
     q = (q.astype(jnp.float32) * scale).astype(q.dtype)
     # kernel layout is (H, T, D) an example
@@ -155,15 +182,22 @@ def _splash_attention(q, k, v, *, causal: bool, scale: float,
 
 
 def local_attention(q, k, v, *, causal: bool = False, scale: Optional[float] = None,
-                    q_offset=0, k_offset=0, backend: str = "dense"):
+                    q_offset=0, k_offset=0, backend: str = "dense",
+                    window: Optional[int] = None):
     """Plain softmax attention on local blocks (also the Ulysses inner step).
 
-    Shapes: ``q (B, Tq, H, D_qk)``, ``k (B, Tk, H, D_qk)``,
-    ``v (B, Tk, H, D_v)`` → ``(B, Tq, H, D_v)``: the values may be narrower
+    Shapes: ``q (B, Tq, H, D_qk)``, ``k (B, Tk, H_k, D_qk)``,
+    ``v (B, Tk, H_v, D_v)`` → ``(B, Tq, H, D_v)``: the values may be narrower
     (or wider) than the queries and keys, on both backends; the default
-    ``scale`` is ``D_qk ** -0.5``.
+    ``scale`` is ``D_qk ** -0.5``.  Keys and values may come in fewer heads
+    than the queries (grouped-query attention), each count dividing ``H``:
+    query head ``h`` reads key head ``h // (H / H_k)`` and value head
+    ``h // (H / H_v)``; they are repeated up to ``H`` on both backends.
     ``q_offset``/``k_offset`` are the *global* positions of the first query /
     key row, used for causal masking of shifted blocks (may be traced).
+    ``window`` (needs ``causal``): a query at position ``t`` sees the keys
+    ``t - window < s <= t``, itself included; the dense path masks the
+    scores, the kernel skips the tiles outside the band (``LocalMask``).
 
     ``backend``: ``'dense'`` (default) materializes the (Tq, Tk) scores
     (portable, covered by CI); ``'flash'`` forces the fused Pallas TPU kernel
@@ -177,11 +211,17 @@ def local_attention(q, k, v, *, causal: bool = False, scale: Optional[float] = N
     """
     scale = scale if scale is not None else 1.0 / math.sqrt(q.shape[-1])
 
-    if q.shape[-1] != k.shape[-1] or k.shape[:-1] != v.shape[:-1]:
+    heads = q.shape[2]
+    if (q.shape[-1] != k.shape[-1] or k.shape[:2] != v.shape[:2]
+            or heads % k.shape[2] or heads % v.shape[2]):
         raise ValueError(
             "local_attention takes q, k (..., D_qk) and v (..., D_v) with k "
-            f"and v of one length and head count; got q {q.shape}, "
-            f"k {k.shape}, v {v.shape}")
+            "and v of one length and head counts that divide the queries'; "
+            f"got q {q.shape}, k {k.shape}, v {v.shape}")
+    if window is not None and (not causal or window < 1):
+        raise ValueError(f"window={window} needs causal=True and at least "
+                         "one key")
+    k, v = _repeat_heads(k, heads), _repeat_heads(v, heads)
     eligible = _flash_eligible(q, k, causal, q_offset, k_offset, v)
     if backend == "flash" and not eligible:
         raise ValueError(
@@ -194,7 +234,8 @@ def local_attention(q, k, v, *, causal: bool = False, scale: Optional[float] = N
             "kernel has no offset mask, so forcing it here would be "
             "silently wrong")
     if backend == "flash" or (backend == "auto" and eligible):
-        return _splash_attention(q, k, v, causal=causal, scale=scale)
+        return _splash_attention(q, k, v, causal=causal, scale=scale,
+                                 window=window)
     scores = jnp.einsum(
         "bqhd,bkhd->bhqk", q, k, preferred_element_type=jnp.float32
     ) * scale
@@ -202,6 +243,8 @@ def local_attention(q, k, v, *, causal: bool = False, scale: Optional[float] = N
         qpos = q_offset + jnp.arange(q.shape[1])
         kpos = k_offset + jnp.arange(k.shape[1])
         mask = qpos[:, None] >= kpos[None, :]
+        if window is not None:
+            mask &= kpos[None, :] > qpos[:, None] - window
         scores = jnp.where(mask[None, None], scores, _NEG_INF)
     p = jax.nn.softmax(scores, axis=-1)
     out = jnp.einsum(

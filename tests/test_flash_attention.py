@@ -117,9 +117,9 @@ def test_splash_kernel_is_cached():
     the first call happens under a trace."""
     _splash_kernel.cache_clear()
     made = []
-    jax.jit(lambda: made.append(_splash_kernel(256, 2, True, True)) or 0)()
-    assert _splash_kernel(256, 2, True, True) is made[0]
-    assert _splash_kernel(256, 2, False, True) is not made[0]
+    jax.jit(lambda: made.append(_splash_kernel(256, 2, "causal", True)) or 0)()
+    assert _splash_kernel(256, 2, "causal", True) is made[0]
+    assert _splash_kernel(256, 2, "full", True) is not made[0]
     assert _splash_kernel.cache_info().misses == 2
     # built under a trace, yet no tracer is kept: the mask info is concrete
     for leaf in jax.tree_util.tree_leaves(made[0]):

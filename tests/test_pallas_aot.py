@@ -375,3 +375,57 @@ def test_grouped_matmul_kernels_compile_for_v5e(tpu_aot_topology):
     # but the sort's own vectors
     assert "bf16[8192,768]" in txt
     assert not _re.findall(r"\[65536,\d{2,}\]", txt)
+
+
+def test_selective_scan_kernels_compile_for_v5e(tpu_aot_topology):
+    """The selective-scan kernels at the published Mamba mixer: 8,192
+    tokens, 5,120 channels in blocks of 1,024, 16 states, bf16 ``x`` beside
+    f32 ``delta``; value and all six gradients.  Mosaic takes ``B`` and
+    ``C`` a chunk at a time in SMEM and the chunk's 65 states of a channel
+    block (4 MiB) in VMEM; the names are the ones the trace shows."""
+    from bluefog_tpu.ops.selective_scan import selective_scan
+
+    one = _one_chip(tpu_aot_topology)
+
+    def shape(dims, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=one)
+
+    wide, narrow = (1, 8192, 5120), (1, 8192, 16)
+    args = (shape(wide, jnp.bfloat16), shape(wide), shape((5120, 16)),
+            shape(narrow, jnp.bfloat16), shape(narrow, jnp.bfloat16),
+            shape((5120,)))
+
+    def grads(*operands):
+        return jax.grad(lambda *a: selective_scan(
+            *a, backend="pallas").astype(jnp.float32).sum(),
+            argnums=tuple(range(6)))(*operands)
+
+    txt = jax.jit(grads).lower(*args).compile().as_text()
+    assert txt.count("tpu_custom_call") == 2
+    assert "bf_selective_scan_fwd" in txt and "bf_selective_scan_bwd" in txt
+
+
+@pytest.mark.parametrize("window", [512, None], ids=["window", "full"])
+def test_differential_attention_kernels_compile_for_v5e(window,
+                                                        tpu_aot_topology):
+    """The splash kernels at differential attention's published maps: 40
+    heads of 64-wide queries and keys beside 128-wide values, T=8192, under
+    the 512-key window (``LocalMask``) and in full, forward and fused
+    backward, keys and values repeated from 20 and 10 heads."""
+    from bluefog_tpu.ops.ring_attention import _repeat_heads, _splash_attention
+
+    one = _one_chip(tpu_aot_topology)
+    q = jax.ShapeDtypeStruct((1, 8192, 40, 64), jnp.bfloat16, sharding=one)
+    k = jax.ShapeDtypeStruct((1, 8192, 20, 64), jnp.bfloat16, sharding=one)
+    v = jax.ShapeDtypeStruct((1, 8192, 10, 128), jnp.bfloat16, sharding=one)
+
+    def grads(q, k, v):
+        return jax.grad(lambda q, k, v: _splash_attention(
+            q, _repeat_heads(k, 40), _repeat_heads(v, 40), causal=True,
+            scale=0.125, window=window).astype(jnp.float32).sum(),
+            argnums=(0, 1, 2))(q, k, v)
+
+    txt = jax.jit(grads).lower(q, k, v).compile().as_text()
+    assert txt.count("tpu_custom_call") >= 2
+    assert "flash_attention_splash_mha_fwd" in txt
+    assert "flash_mha_bwd_splash_mha_dkv" in txt
