@@ -228,6 +228,94 @@ def _norm(cfg: GPTConfig, name: str) -> nn.Module:
     return nn.LayerNorm(epsilon=cfg.norm_eps, dtype=jnp.float32, name=name)
 
 
+_LANES = 128     # of a TPU tile: a head this wide fills a matmul's output
+
+
+class HeadDense(nn.Module):
+    """``nn.Dense`` for a projection whose output (or, ``inward``, input)
+    axis is split into heads, written so that the attention kernel's
+    ``(B, H, T, D)`` operands cost as few passes over them as the chip
+    allows (PERF.md, PR 33).
+
+    It owns ``nn.Dense``'s two leaves under ``nn.Dense``'s names, shapes,
+    dtypes and initialisers (``kernel (in, features)``, ``bias
+    (features,)``), so a parameter tree does not see the difference, and
+    applies them as ``nn.Dense`` does (operands in ``dtype``, the bias added
+    after the dot).  Heads out: ``(..., T, in) -> (..., T, *heads)`` with
+    ``features = parts * prod(heads)``, as a tuple of ``parts`` such tensors
+    where a projection is fused (``q, k, v``).  Heads in: ``(..., T,
+    *heads) -> (..., T, features)`` with ``in = prod(heads)``.  What it
+    does depends on the head's width ``heads[-1]``, which is all that
+    decided on the chip:
+
+    - **at least a tile's 128 lanes** (latent attention: 192, 256, 128):
+      the head axes are dimensions of the dot itself (the kernel reshaped to
+      ``(in, *heads)`` or ``(*heads, features)``).  XLA lays a dot's output
+      dimension out as its consumer wants it, so the ``reshape`` and
+      ``transpose`` copies between projection and kernel go, and the dots
+      keep their speed.
+    - **narrower** (64): such a dot fills half of the matrix unit's output
+      and runs at half its rate, which costs more than the copies it saves.
+      Heads out, the 2-D matmul is instead written channel-major, ``(...,
+      features, T)``, from where one transpose reaches the kernel's layout
+      (after ``nn.Dense`` XLA takes two, a ``split`` and a scale pass); the
+      barrier pins that layout (without it XLA folds the transpose away
+      again where the batch is small).  Heads in, it is ``nn.Dense`` on
+      the flattened heads.
+    """
+
+    features: int
+    heads: Tuple[int, ...]
+    inward: bool = False
+    parts: int = 1       # heads out: this many tensors of ``heads`` each
+    use_bias: bool = True
+    dtype: jnp.dtype = jnp.float32
+
+    @nn.compact
+    def __call__(self, x):
+        heads, n = tuple(self.heads), len(self.heads)
+        fan_in = math.prod(heads) if self.inward else x.shape[-1]
+        kernel = self.param("kernel", nn.linear.default_kernel_init,
+                            (fan_in, self.features), jnp.float32)
+        bias = self.param("bias", nn.initializers.zeros_init(),
+                          (self.features,), jnp.float32) if (
+                              self.use_bias) else None
+        wide = heads[-1] >= _LANES
+        # Reshapes come before the cast: the kernel's cotangent then goes
+        # dot -> f32 -> reshape and XLA keeps the f32 accumulator (cast
+        # first, the weight gradient is rounded to bf16 on its way back).
+        contracted = 1
+        if wide and self.inward:
+            kernel = kernel.reshape(heads + (self.features,))
+            contracted = n
+        elif wide:
+            kernel = kernel.reshape((fan_in, self.parts) + heads)
+            bias = None if bias is None else bias.reshape(
+                (self.parts,) + heads)
+        elif self.inward:
+            x = x.reshape(x.shape[:-n] + (fan_in,))
+        x, kernel, bias = nn.dtypes.promote_dtype(x, kernel, bias,
+                                                  dtype=self.dtype)
+        if wide or self.inward:
+            y = jax.lax.dot_general(
+                x, kernel, ((tuple(range(x.ndim - contracted, x.ndim)),
+                             tuple(range(contracted))), ((), ())))
+            y = y if bias is None else y + bias
+            if self.inward:
+                return y
+            y = jnp.moveaxis(y, -n - 1, 0)             # the parts lead
+        else:
+            # the weights as the einsum's left operand: written the other
+            # way round XLA compiles the program nn.Dense gives
+            y = jnp.einsum("...td,dc->...ct", x, kernel)
+            y = jax.lax.optimization_barrier(
+                y if bias is None else y + bias[:, None])
+            y = [jnp.moveaxis(p.reshape(p.shape[:-2] + heads + p.shape[-1:]),
+                              -1, -n - 1)              # (..., T, *heads)
+                 for p in jnp.split(y, self.parts, axis=-2)]
+        return tuple(y) if self.parts > 1 else y[0]
+
+
 def rotary(x, positions, theta: float):
     """Rotate the pairs ``(0, 1), (2, 3), ...`` of the last axis (interleaved
     as stored) by ``position * theta ** (-2i / width)``.  ``x (B, T, H, R)``,
@@ -260,6 +348,8 @@ class LatentAttention(nn.Module):
         cfg, la, h = self.cfg, self.cfg.latent, self.cfg.num_heads
         nope, rope = la.qk_nope_head_dim, la.qk_rope_head_dim
         dense = functools.partial(nn.Dense, use_bias=False, dtype=cfg.dtype)
+        head_dense = functools.partial(HeadDense, use_bias=False,
+                                       dtype=cfg.dtype)
 
         def rms(name):
             return nn.RMSNorm(epsilon=cfg.norm_eps, dtype=jnp.float32,
@@ -267,14 +357,14 @@ class LatentAttention(nn.Module):
 
         with jax.named_scope("bf.mla.project"):
             cq = rms("q_norm")(dense(la.q_lora_rank, name="q_down")(y))
-            q = dense(h * (nope + rope), name="q_up")(cq.astype(cfg.dtype))
-            q = q.reshape(q.shape[:-1] + (h, nope + rope))
+            q = head_dense(h * (nope + rope), (h, nope + rope), name="q_up")(
+                cq.astype(cfg.dtype))
             ckv = dense(la.kv_lora_rank + rope, name="kv_down")(y)
             k_rope = ckv[..., None, la.kv_lora_rank:]        # (B, T, 1, rope)
             ckv = rms("kv_norm")(ckv[..., :la.kv_lora_rank])
-            kv = dense(h * (nope + la.v_head_dim), name="kv_up")(
-                ckv.astype(cfg.dtype))
-            kv = kv.reshape(kv.shape[:-1] + (h, nope + la.v_head_dim))
+            kv = head_dense(h * (nope + la.v_head_dim),
+                            (h, nope + la.v_head_dim), name="kv_up")(
+                                ckv.astype(cfg.dtype))
             q = jnp.concatenate(
                 [q[..., :nope], rotary(q[..., nope:], positions,
                                        la.rope_theta)], axis=-1)
@@ -284,8 +374,8 @@ class LatentAttention(nn.Module):
                  jnp.broadcast_to(k_rope, kv.shape[:-1] + (rope,))], axis=-1)
         a = attn_fn(q, k, kv[..., nope:])
         with jax.named_scope("bf.mla.project"):
-            return dense(cfg.hidden_size, name="o")(
-                a.reshape(a.shape[:-2] + (h * la.v_head_dim,)))
+            return head_dense(cfg.hidden_size, (h, la.v_head_dim),
+                              inward=True, name="o")(a)
 
 
 def lambda_init(layer: int) -> float:
@@ -580,17 +670,11 @@ class Block(nn.Module):
         if cfg.attention == "latent":
             a = LatentAttention(cfg, name="attn")(y, attn_fn, positions)
         else:
-            head_dim = cfg.hidden_size // cfg.num_heads
-            qkv = nn.Dense(3 * cfg.hidden_size, dtype=cfg.dtype,
-                           name="qkv")(y)
-            q, k, v = jnp.split(qkv, 3, axis=-1)
-
-            def heads(t):
-                return t.reshape(t.shape[:-1] + (cfg.num_heads, head_dim))
-
-            a = attn_fn(heads(q), heads(k), heads(v))
-            a = a.reshape(a.shape[:-2] + (cfg.hidden_size,))
-            a = nn.Dense(cfg.hidden_size, dtype=cfg.dtype, name="proj")(a)
+            heads = (cfg.num_heads, cfg.hidden_size // cfg.num_heads)
+            q, k, v = HeadDense(3 * cfg.hidden_size, heads, parts=3,
+                                dtype=cfg.dtype, name="qkv")(y)
+            a = HeadDense(cfg.hidden_size, heads, inward=True,
+                          dtype=cfg.dtype, name="proj")(attn_fn(q, k, v))
         return _feed_forward(self, x + a)
 
 

@@ -17,7 +17,7 @@ from typing import Any, Dict, Mapping, Optional
 import jax
 
 __all__ = ["collective_census", "compiled_flops", "collective_overlap_report",
-           "parse_overlap_windows", "transfer_schedule"]
+           "layout_copies", "parse_overlap_windows", "transfer_schedule"]
 
 _COLLECTIVE_OPS = (
     "collective-permute",
@@ -118,6 +118,26 @@ _ITEMSIZE = {"pred": 1, "s8": 1, "u8": 1, "bf16": 2, "f16": 2, "s16": 2,
              "u64": 8}
 
 
+def _entry_lines(hlo: str):
+    """The instruction lines of a module's ENTRY computation (its text is
+    the schedule; the fused and nested computations around it are not)."""
+    entry = hlo[hlo.index("ENTRY "):] if "ENTRY " in hlo else hlo
+    lines = entry.splitlines()
+    end = next((i for i, line in enumerate(lines) if line.rstrip() == "}"),
+               len(lines))
+    return lines[:end]
+
+
+_NAME_RE = re.compile(r"^\s*(?:ROOT )?%?([\w.\-]+) = ")
+_SHAPE_RE = re.compile(r"= \(?(\w+)\[([\d,]*)\]")
+
+
+def _output_bytes(line: str) -> int:
+    dtype, dims = _SHAPE_RE.search(line).groups()
+    size = math.prod(int(d) for d in dims.split(",") if d)
+    return size * _ITEMSIZE.get(dtype, 4)
+
+
 def transfer_schedule(hlo: str,
                       marks: Optional[Mapping[str, str]] = None) -> Dict[str, Any]:
     """Where each asynchronous collective-permute opens and closes in the
@@ -138,16 +158,13 @@ def transfer_schedule(hlo: str,
     five before the forward pass and every other one where an earlier one
     closes, next to the weight-gradient fusion that consumes it.
     """
-    entry = hlo[hlo.index("ENTRY "):] if "ENTRY " in hlo else hlo
     compute_re = re.compile(r"\b(fusion|convolution|dot|custom-call)\(")
-    name_re = re.compile(r"^\s*(?:ROOT )?%?([\w.\-]+) = ")
-    shape_re = re.compile(r"= \(?(\w+)\[([\d,]*)\]")
     done_re = re.compile(r"collective-permute-done\(%?([\w.\-]+)\)")
     mark_res = {k: re.compile(v) for k, v in (marks or {}).items()}
     n, opened, transfers = 0, {}, []
     found: Dict[str, list] = {k: [] for k in mark_res}
-    for line in entry.splitlines():
-        m = name_re.match(line)
+    for line in _entry_lines(hlo):
+        m = _NAME_RE.match(line)
         if not m:
             continue
         if compute_re.search(line):
@@ -156,15 +173,40 @@ def transfer_schedule(hlo: str,
             if r.search(line):
                 found[k].append(n)
         if "collective-permute-start(" in line:
-            dtype, dims = shape_re.search(line).groups()
-            size = math.prod(int(d) for d in dims.split(",") if d)
-            opened[m.group(1)] = (n, size * _ITEMSIZE.get(dtype, 4))
+            opened[m.group(1)] = (n, _output_bytes(line))
             continue
         d = done_re.search(line)
         if d and d.group(1) in opened:
             at, nbytes = opened.pop(d.group(1))
             transfers.append((at, n, nbytes))
     return {"compute_ops": n, "transfers": transfers, "marks": found}
+
+
+def layout_copies(hlo: str, largest: int = 8) -> Dict[str, Any]:
+    """The stand-alone ``copy`` and ``transpose`` instructions of the
+    compiled program's ENTRY computation: passes over a buffer that move
+    every byte of it and compute nothing, which XLA schedules where a
+    producer's layout is not its consumer's (a head axis made by reshaping a
+    matmul's output, on the TPU's tiled layouts).  A copy folded into a
+    fusion, or inside a nested computation (a ``while`` body), is not one.
+
+    Returns ``{"count": n, "bytes": the bytes they write, "largest":
+    [(nbytes, instruction, op_name), ...]}``, the ``largest`` few first.
+    On the chip the same instructions are the ``copy`` line of a traced
+    run's ``breakdown`` (PERF.md, PR 33)."""
+    op_re = re.compile(r"[\]}] (?:copy|transpose)\(")
+    op_name_re = re.compile(r'op_name="([^"]*)"')
+    found = []
+    for line in _entry_lines(hlo):
+        m = _NAME_RE.match(line)
+        if not m or not op_re.search(line):
+            continue
+        name = op_name_re.search(line)
+        found.append((_output_bytes(line), m.group(1),
+                      name.group(1) if name else ""))
+    found.sort(key=lambda f: -f[0])
+    return {"count": len(found), "bytes": sum(f[0] for f in found),
+            "largest": found[:largest]}
 
 
 def compiled_flops(fn, *args, **lower_kwargs) -> float:

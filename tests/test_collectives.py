@@ -752,3 +752,60 @@ class TestTransferSchedule:
         rep = transfer_schedule(
             "%pp = f32[8] collective-permute(%x)\n%f = f32[8] fusion(%x)")
         assert rep["transfers"] == [] and rep["compute_ops"] == 1
+
+
+class TestLayoutCopies:
+    """layout_copies counts the stand-alone copy / transpose instructions of
+    the ENTRY computation, with the bytes they write: the passes that move a
+    buffer into another layout and compute nothing (PERF.md, PR 33)."""
+
+    HLO = "\n".join([
+        "%fused_computation.7 {",
+        "  %copy.90 = bf16[64,64]{0,1} copy(%p)",          # inside a fusion
+        "}",
+        "%while_body.3 {",
+        "  %copy.91 = f32[1024,1024]{1,0} copy(%q)",       # nested: not ENTRY
+        "}",
+        "ENTRY %main {",
+        '  %copy.1 = bf16[8,12,2048,64]{3,2,1,0:T(8,128)(2,1)} copy(%fusion.4), metadata={op_name="jit(step)/jvp(M)/block_0/transpose"}',
+        "  %transpose.2 = f32[768,2304]{0,1:T(8,128)} transpose(%p1), dimensions={1,0}",
+        "  %fusion.5 = bf16[8,2048,768]{2,1,0} fusion(%copy.1), kind=kLoop, calls=%fused_computation.7",
+        "  %copy-start.1 = (f32[16]{0}, f32[16]{0}, u32[]) copy-start(%p2)",
+        "  %copy-done.1 = f32[16]{0} copy-done(%copy-start.1)",
+        "  %while.1 = (f32[1024,1024]{1,0}) while(%t), body=%while_body.3",
+        '  ROOT %copy.3 = s32[4]{0} copy(%p3), metadata={op_name="jit(step)/tail"}',
+        "}",
+        "%later_computation.9 {",
+        "  %copy.92 = f32[4096]{0} copy(%r)",              # after ENTRY's end
+        "}",
+    ])
+
+    def test_counts_the_entry_computations_copies_and_transposes(self):
+        from bluefog_tpu.utils.inspect import layout_copies
+
+        rep = layout_copies(self.HLO)
+        activation, weight = 8 * 12 * 2048 * 64 * 2, 768 * 2304 * 4
+        assert rep["count"] == 3
+        assert rep["bytes"] == activation + weight + 16
+        assert rep["largest"] == [
+            (activation, "copy.1", "jit(step)/jvp(M)/block_0/transpose"),
+            (weight, "transpose.2", ""), (16, "copy.3", "jit(step)/tail")]
+
+    @pytest.mark.parametrize("absent", ["copy.90", "copy.91", "copy.92",
+                                        "fusion.5", "copy-start.1",
+                                        "copy-done.1", "while.1"])
+    def test_fusions_nested_computations_and_async_copies_do_not_count(
+            self, absent):
+        from bluefog_tpu.utils.inspect import layout_copies
+
+        names = [name for _, name, _ in layout_copies(
+            self.HLO, largest=100)["largest"]]
+        assert absent not in names and len(names) == 3
+
+    def test_largest_bounds_the_list_and_not_the_totals(self):
+        from bluefog_tpu.utils.inspect import layout_copies
+
+        rep = layout_copies(self.HLO, largest=1)
+        assert rep["count"] == 3 and len(rep["largest"]) == 1
+        assert layout_copies("%f = f32[8] fusion(%x)") == {
+            "count": 0, "bytes": 0, "largest": []}

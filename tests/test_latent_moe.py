@@ -713,20 +713,39 @@ def _decoder_as_it_was():
 
 
 @pytest.mark.parametrize("remat", [False, True], ids=["plain", "remat"])
-def test_gpt_decoder_parameter_tree_and_lowered_program_unchanged(remat):
+def test_gpt_decoder_parameter_tree_and_values_unchanged(remat):
+    """From PR 33 the block's projections make the head axis inside their
+    matmul, so the lowered text differs from the old decoder's by design.
+    What a checkpoint, the optimizer and the benchmark's reference see does
+    not: the same tree (paths, shapes, dtypes), from one key the same
+    leaves, and on them the same logits and the same gradient of every leaf
+    (f32: the same products summed in another order, so to ~1e-6 of the
+    leaf's largest entry and not to the bit)."""
     cfg = dataclasses.replace(GPTConfig.tiny(), remat=remat)
-    tokens = jnp.zeros((2, 32), jnp.int32)
+    tokens = jax.random.randint(jax.random.PRNGKey(1), (2, 32), 0,
+                                cfg.vocab_size)
+    now, was = TransformerLM(cfg), _decoder_as_it_was()(cfg)
+    params = now.init(jax.random.PRNGKey(0), tokens)
+    params_was = was.init(jax.random.PRNGKey(0), tokens)
 
-    def lowered(model):
-        shapes = jax.eval_shape(
-            lambda: model.init(jax.random.PRNGKey(0), tokens))
-        step = jax.jit(jax.grad(lambda p, t: model.apply(p, t).sum()))
-        return (jax.tree_util.tree_map(lambda s: (s.shape, s.dtype), shapes),
-                step.lower(shapes, tokens).as_text())
+    def signature(tree):
+        return jax.tree_util.tree_map(lambda t: (t.shape, t.dtype), tree)
 
-    now, was = lowered(TransformerLM(cfg)), lowered(_decoder_as_it_was()(cfg))
-    assert now[0] == was[0]
-    assert now[1] == was[1]
+    assert signature(params) == signature(params_was)
+    for (path, a), b in zip(jax.tree_util.tree_leaves_with_path(params),
+                            jax.tree_util.tree_leaves(params_was)):
+        np.testing.assert_array_equal(a, b, err_msg=str(path))
+
+    def value_and_grads(model):
+        return jax.jit(jax.value_and_grad(lambda p: (
+            model.apply(p, tokens) ** 2).sum(), has_aux=False))(params)
+
+    np.testing.assert_allclose(now.apply(params, tokens),
+                               was.apply(params, tokens), atol=2e-6)
+    (loss, grads), (loss_was, grads_was) = (value_and_grads(now),
+                                            value_and_grads(was))
+    np.testing.assert_allclose(loss, loss_was, rtol=1e-6)
+    close(grads, grads_was, tol=2e-6)
 
 
 # ---- the configuration file and the counting functions ---------------------

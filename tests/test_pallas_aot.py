@@ -429,3 +429,49 @@ def test_differential_attention_kernels_compile_for_v5e(window,
     assert txt.count("tpu_custom_call") >= 2
     assert "flash_attention_splash_mha_fwd" in txt
     assert "flash_mha_bwd_splash_mha_dkv" in txt
+
+
+def test_gpt2_attention_sublayer_keeps_its_layout_copies_few_on_v5e(
+        tpu_aot_topology):
+    """One GPT-2 block of ``gpt2s.t2048.solo`` (batch 8, T=2048, 768 wide,
+    12 heads of 64, bf16) around the splash kernels, value and gradients:
+    how many stand-alone copies of an activation (25 MB) XLA schedules
+    between the projections and the kernels.  With ``nn.Dense`` -> ``split``
+    -> ``reshape`` -> ``transpose`` there were 14 inside the block (two
+    relayouts a tensor each way, and more around the split); ``HeadDense``
+    writes the fused projection channel-major and leaves 8, one a tensor
+    each way and the output's two (PERF.md, PR 33: none at all is possible,
+    with the heads inside the dot, and at 64-wide heads the chip then runs
+    the dots at half rate).  This keeps the others from coming back.  (Two
+    more sit at this function's own boundary, where a parameter and a
+    result have the default layout: ``x`` in, ``dx`` out.)"""
+    from bluefog_tpu.models.transformer import Block, GPTConfig
+    from bluefog_tpu.ops.ring_attention import _splash_attention
+    from bluefog_tpu.utils.inspect import layout_copies
+
+    one = _one_chip(tpu_aot_topology)
+    cfg = GPTConfig(dtype=jnp.bfloat16)
+    block = Block(cfg)
+
+    def attn_fn(q, k, v):
+        return _splash_attention(q, k, v, causal=True, scale=64 ** -0.5)
+
+    x = jax.ShapeDtypeStruct((8, 2048, 768), jnp.bfloat16, sharding=one)
+    params = jax.tree_util.tree_map(
+        lambda t: jax.ShapeDtypeStruct(t.shape, t.dtype, sharding=one),
+        jax.eval_shape(lambda: block.init(
+            jax.random.PRNGKey(0), jnp.zeros((1, 128, 768), jnp.bfloat16),
+            lambda q, k, v: q)))
+
+    def grads(params, x):
+        return jax.grad(lambda p, x: block.apply(p, x, attn_fn).astype(
+            jnp.float32).sum(), argnums=(0, 1))(params, x)
+
+    txt = jax.jit(grads).lower(params, x).compile().as_text()
+    assert "flash_attention_splash_mha_fwd" in txt
+    activation = 8 * 2048 * 768 * 2
+    large = [c for c in layout_copies(txt, largest=64)["largest"]
+             if c[0] >= activation]
+    inside = [c for c in large if "Block" in c[2]]
+    assert len(inside) <= 8, inside
+    assert len(large) - len(inside) <= 2, large
