@@ -20,30 +20,48 @@ GPT-2 decoder this file always built, parameter for parameter:
 
 ===============  ===========================================================
 ``attention``    ``fused_qkv`` (one biased projection to q, k, v of one
-                 width) or ``latent`` (:class:`LatentAttention`: low-rank
+                 width), ``latent`` (:class:`LatentAttention`: low-rank
                  queries and keys/values, a rotary part of the key shared
-                 by the heads, values narrower than keys)
+                 by the heads, values narrower than keys) or
+                 ``grouped_query`` (:class:`GroupedQueryAttention`: unbiased
+                 projections to ``num_heads`` query and ``grouped.kv_heads``
+                 key/value heads of ``grouped.head_dim``, which need not be
+                 ``hidden_size / num_heads``; what each layer attends over
+                 is its entry of ``layer_types``)
 ``ffn``          ``gelu`` (biased, ``mlp_ratio`` wide), ``swiglu``
                  (:class:`GatedMLP`, ``ffn_width`` wide) or
-                 ``routed+shared`` (:class:`RoutedSharedFFN`; the first
-                 ``experts.first_dense`` blocks take ``swiglu``)
+                 ``routed+shared`` (:class:`RoutedFFN`, stated by
+                 :class:`ExpertSizes`: the router, whether it trains, the
+                 gate's activation, shared experts or none, and whether the
+                 router reads the feed-forward's input or the block's; the
+                 first ``experts.first_dense`` blocks take ``swiglu``)
 ``norm``         ``layernorm`` or ``rmsnorm`` (scale only, ``norm_eps``)
-``position``     ``learned`` (a table added to the embedding) or ``rotary``
-                 (no table; the attention turns its rotary part)
+``position``     ``learned`` (a table added to the embedding), ``rotary``
+                 (no table; the latent attention turns its rotary part) or
+                 ``none`` (with ``layer_types``: each layer's type says
+                 whether it turns its queries and keys)
 ``mtp_depth``    0, or 1 for one multi-token-prediction module sharing the
                  embedding and the head (:func:`next_token_loss`)
-``layer_types``  ``None``, or **the token mixer of each block** (a
-                 decoder-hybrid-decoder, SambaY, arXiv:2507.06607; sizes in
-                 :class:`HybridSizes`): ``mamba`` (:class:`MambaMixer`, a
-                 selective state space), ``diff_attention`` and
-                 ``diff_attention_window`` (:class:`DiffAttention`: two
-                 softmax maps a head pair, over all keys or the last
-                 ``window``), ``gmu`` (:class:`GatedMemoryUnit`, which reads
-                 the memory the last ``mamba`` block left) and
-                 ``cross_diff_attention`` (queries of its own over the keys
-                 and values of the last ``diff_attention`` block).  Blocks
-                 hand these tensors on; with it come ``position="none"``,
-                 ``ffn="swiglu"`` and ``norm_eps`` for the LayerNorms
+``layer_types``  ``None``, or **what each block mixes its tokens with**.
+                 Either plain attention layers of the ``grouped_query``
+                 kind (sizes in :class:`GroupedSizes`):
+                 ``full_attention`` (causal over all keys, **no positional
+                 encoding**) and ``window_rotary_attention`` (the last
+                 ``grouped.window`` keys, queries and keys turned by rotary
+                 over the whole head); any ``ffn`` and ``norm`` go with
+                 them.  Or the mixers of a decoder-hybrid-decoder (SambaY,
+                 arXiv:2507.06607; sizes in :class:`HybridSizes`): ``mamba``
+                 (:class:`MambaMixer`, a selective state space),
+                 ``diff_attention`` and ``diff_attention_window``
+                 (:class:`DiffAttention`: two softmax maps a head pair, over
+                 all keys or the last ``window``), ``gmu``
+                 (:class:`GatedMemoryUnit`, which reads the memory the last
+                 ``mamba`` block left) and ``cross_diff_attention`` (queries
+                 of its own over the keys and values of the last
+                 ``diff_attention`` block).  Those blocks hand these tensors
+                 on, and with those mixers come ``ffn="swiglu"`` and
+                 ``norm_eps`` for the LayerNorms.  Either way
+                 ``position="none"``
 ``tie_head``     the head is the embedding's transpose: one leaf, whose
                  gradient is the sum of both uses
 ===============  ===========================================================
@@ -61,7 +79,8 @@ import jax
 import jax.numpy as jnp
 
 from bluefog_tpu.metrics import comm as metrics_comm
-from bluefog_tpu.ops.moe import routed_experts, sigmoid_topk_router
+from bluefog_tpu.ops.moe import (
+    ACTIVATIONS, routed_experts, sigmoid_topk_router, softmax_topk_router)
 from bluefog_tpu.ops.ring_attention import local_attention
 from bluefog_tpu.ops.selective_scan import selective_scan
 
@@ -84,22 +103,68 @@ class LatentSizes:
 
 
 @dataclasses.dataclass(frozen=True)
+class GroupedSizes:
+    """Grouped-query attention: ``kv_heads`` key/value heads serve
+    ``num_heads`` query heads (head ``h`` reads ``h // (num_heads /
+    kv_heads)``), every head ``head_dim`` wide whatever ``hidden_size /
+    num_heads`` is.  ``window`` and ``rope_theta`` are the
+    ``window_rotary_attention`` layers'."""
+
+    kv_heads: int
+    head_dim: int
+    window: int
+    rope_theta: float
+
+
+ROUTERS = ("sigmoid_noaux_tc", "softmax_topk")
+ROUTER_INPUTS = ("ffn", "block")
+
+
+@dataclasses.dataclass(frozen=True)
 class ExpertSizes:
-    """A routed-and-shared expert layer (DeepSeek-V3, arXiv:2412.19437
-    §2.1.2): the router scores all ``num_experts``, a token takes ``top_k``,
-    and this chip computes the experts ``held = (first, count)``."""
+    """An expert layer, stated whole: the router scores all ``num_experts``,
+    a token takes ``top_k``, and this chip computes the experts ``held =
+    (first, count)`` for the tokens routed to them (what the absent ones
+    would add is left out).
+
+    ``router``: ``sigmoid_noaux_tc`` (DeepSeek-V3, arXiv:2412.19437 §2.1.2:
+    sigmoid scores, a selection-bias buffer, the chosen scores normalised
+    and times ``scale``) or ``softmax_topk`` (a softmax over the chosen
+    logits; no bias, no buffer, no ``scale``).  ``activation`` of an
+    expert's gate: ``silu`` or ``relu`` (ReGLU).  ``num_shared`` experts
+    every token takes, as one gated MLP of that many widths; 0 builds none.
+    ``first_dense`` leading blocks keep the dense ``swiglu``; 0 for none.
+    ``router_input``: ``ffn``, the feed-forward's own normed input, or
+    ``block``, the block's normed input that the attention reads too (the
+    routing of a block is then known before its attention has run;
+    SmallThinker, arXiv:2507.20984).
+
+    ``train_router=False`` makes the routing weights constants of the
+    backward pass, so the router gets no gradient (weight decay still
+    reaches it).  For a chip that holds a share of the experts with no
+    exchange behind it: a weight's gradient is formed from the outputs of
+    all the chosen experts once the exchange has brought them back, and
+    formed from the held ones alone it pulls the assignments onto them
+    (PERF.md section 6, PR 34: a held share of 0.25 became 0.88 in 38
+    steps)."""
 
     num_experts: int = 256
     top_k: int = 8
     width: int = 768               # of one expert, routed or shared
     num_shared: int = 1
-    scale: float = 2.5             # routed_scaling_factor
+    scale: float = 2.5             # routed_scaling_factor (sigmoid router)
     held: Tuple[int, int] = (0, 256)
     first_dense: int = 1           # leading blocks with the dense swiglu
+    router: str = "sigmoid_noaux_tc"
+    activation: str = "silu"
+    router_input: str = "ffn"
+    train_router: bool = True
 
 
 MIXERS = ("mamba", "diff_attention", "diff_attention_window", "gmu",
           "cross_diff_attention")
+ATTENTION_LAYERS = ("full_attention", "window_rotary_attention")
+ROUTED = "routed+shared"
 
 
 @dataclasses.dataclass(frozen=True)
@@ -145,47 +210,85 @@ class GPTConfig:
     layer_types: Optional[Tuple[str, ...]] = None   # a mixer a block
     hybrid: Optional[HybridSizes] = None
     tie_head: bool = False
+    grouped: Optional[GroupedSizes] = None
 
     def __post_init__(self):
-        for field, kinds in (("attention", ("fused_qkv", "latent")),
-                             ("ffn", ("gelu", "swiglu", "routed+shared")),
+        for field, kinds in (("attention", ("fused_qkv", "latent",
+                                            "grouped_query")),
+                             ("ffn", ("gelu", "swiglu", ROUTED)),
                              ("norm", ("layernorm", "rmsnorm")),
                              ("position", ("learned", "rotary", "none"))):
             if getattr(self, field) not in kinds:
                 raise ValueError(f"unknown {field} {getattr(self, field)!r};"
                                  f" expected one of {kinds}")
-        if (self.layer_types is None) != (self.hybrid is None):
-            raise ValueError("`layer_types` and the `hybrid` sizes come "
-                             "together")
+        types = () if self.layer_types is None else tuple(self.layer_types)
+        if self.layer_types is not None and len(types) != self.num_layers:
+            raise ValueError(f"{len(types)} layer_types for "
+                             f"{self.num_layers} layers")
+        mixers = [kind in MIXERS for kind in types]
+        for kind in types:
+            if kind not in MIXERS + ATTENTION_LAYERS:
+                raise ValueError(
+                    f"unknown layer type {kind!r} in layer_types; expected "
+                    f"mixers {MIXERS} or attention layers {ATTENTION_LAYERS}")
+        if any(mixers) and not all(mixers):
+            raise ValueError("layer_types mixes the SambaY mixers with plain "
+                             "attention layers; a model takes one or the "
+                             "other")
+        if any(mixers) != (self.hybrid is not None):
+            raise ValueError("the `hybrid` sizes and the SambaY mixers in "
+                             "`layer_types` come together")
         if (self.position == "none") != (self.layer_types is not None):
-            raise ValueError("position='none' is for `layer_types` (the "
-                             "recurrence orders the tokens); fused_qkv and "
-                             "latent heads need positions")
-        if self.layer_types is not None:
-            self._check_layer_types()
+            raise ValueError("position='none' is for `layer_types` (a "
+                             "recurrence orders the tokens, or the layer's "
+                             "type says whether it turns its keys); fused_qkv "
+                             "and latent heads need positions")
+        if any(mixers):
+            self._check_mixers()
             return
         if (self.attention == "latent") != (self.latent is not None):
             raise ValueError("attention='latent' and the `latent` sizes come "
                              "together")
+        if (self.attention == "grouped_query") != (self.grouped is not None):
+            raise ValueError("attention='grouped_query' and the `grouped` "
+                             "sizes come together")
+        if (self.attention == "grouped_query") != bool(types):
+            raise ValueError("the attention layers of `layer_types` are the "
+                             "grouped_query attention's, and it needs them: "
+                             "each says whether it is windowed and rotary")
         if (self.position == "rotary") != (self.attention == "latent"):
             raise ValueError("position='rotary' is the latent attention's "
                              "(its keys carry the rotary part); fused_qkv "
                              "heads take position='learned'")
-        if (self.ffn == "routed+shared") != (self.experts is not None):
+        if (self.ffn == ROUTED) != (self.experts is not None):
             raise ValueError("ffn='routed+shared' and the `experts` sizes "
                              "come together")
-        if self.mtp_depth not in (0, 1):
-            raise ValueError(f"mtp_depth {self.mtp_depth}: one module or none")
+        if self.mtp_depth not in (0, 1) or (self.mtp_depth and types):
+            raise ValueError(f"mtp_depth {self.mtp_depth}: one module or "
+                             "none, and none with `layer_types` (the module's "
+                             "block has no type)")
+        if self.grouped and self.num_heads % self.grouped.kv_heads:
+            raise ValueError(f"{self.num_heads} query heads do not divide "
+                             f"over {self.grouped.kv_heads} key/value heads")
+        if self.experts is not None:
+            self._check_experts()
 
-    def _check_layer_types(self):
+    def _check_experts(self):
+        ex = self.experts
+        for field, kinds in (("router", ROUTERS),
+                             ("activation", tuple(ACTIVATIONS)),
+                             ("router_input", ROUTER_INPUTS)):
+            if getattr(ex, field) not in kinds:
+                raise ValueError(f"unknown experts.{field} "
+                                 f"{getattr(ex, field)!r}; expected one of "
+                                 f"{kinds}")
+        if ex.num_shared < 0 or ex.first_dense < 0:
+            raise ValueError("experts.num_shared and experts.first_dense "
+                             "count experts and blocks: 0 or more")
+
+    def _check_mixers(self):
         types, hy = tuple(self.layer_types), self.hybrid
-        if len(types) != self.num_layers:
-            raise ValueError(f"{len(types)} layer_types for "
-                             f"{self.num_layers} layers")
         for i, kind in enumerate(types):
-            if kind not in MIXERS:
-                raise ValueError(f"unknown mixer {kind!r} in layer_types; "
-                                 f"expected one of {MIXERS}")
             if kind == "gmu" and "mamba" not in types[:i]:
                 raise ValueError(f"block {i} is a gmu with no mamba block "
                                  "before it to read the memory of")
@@ -196,10 +299,10 @@ class GPTConfig:
                     "diff_attention block (full, not windowed) before it "
                     "to read keys and values of")
         if (self.attention, self.ffn, self.norm, self.mtp_depth, self.latent,
-                self.experts) != ("fused_qkv", "swiglu", "layernorm", 0,
-                                  None, None):
+                self.experts, self.grouped) != (
+                    "fused_qkv", "swiglu", "layernorm", 0, None, None, None):
             raise ValueError(
-                "`layer_types` blocks are built with ffn='swiglu', "
+                "the SambaY mixers' blocks are built with ffn='swiglu', "
                 "norm='layernorm', no MTP module, no `latent` or `experts` "
                 "sizes and `attention` left at its default")
         heads, groups = self.num_heads, hy.kv_heads
@@ -316,15 +419,23 @@ class HeadDense(nn.Module):
         return tuple(y) if self.parts > 1 else y[0]
 
 
-def rotary(x, positions, theta: float):
-    """Rotate the pairs ``(0, 1), (2, 3), ...`` of the last axis (interleaved
-    as stored) by ``position * theta ** (-2i / width)``.  ``x (B, T, H, R)``,
-    ``positions (B or 1, T)``; computed in f32."""
+def rotary(x, positions, theta: float, interleaved: bool = True):
+    """Rotate pair ``i`` of the last axis by ``position * theta ** (-2i /
+    width)``: the pairs are ``(0, 1), (2, 3), ...`` as stored
+    (``interleaved``), or ``(i, i + width / 2)``, the two halves of the head
+    (the half-split pairing).  ``x (B, T, H, R)``, ``positions (B or 1,
+    T)``; computed in f32."""
     r = x.shape[-1]
     inv_freq = theta ** (-jnp.arange(0, r, 2, dtype=jnp.float32) / r)
     angle = positions[..., None].astype(jnp.float32) * inv_freq
     cos, sin = jnp.cos(angle)[:, :, None, :], jnp.sin(angle)[:, :, None, :]
-    pairs = x.astype(jnp.float32).reshape(x.shape[:-1] + (r // 2, 2))
+    x32 = x.astype(jnp.float32)
+    if not interleaved:
+        first, second = x32[..., :r // 2], x32[..., r // 2:]
+        return jnp.concatenate([first * cos - second * sin,
+                                first * sin + second * cos],
+                               axis=-1).astype(x.dtype)
+    pairs = x32.reshape(x.shape[:-1] + (r // 2, 2))
     even, odd = pairs[..., 0], pairs[..., 1]
     turned = jnp.stack([even * cos - odd * sin, even * sin + odd * cos], -1)
     return turned.reshape(x.shape).astype(x.dtype)
@@ -375,6 +486,49 @@ class LatentAttention(nn.Module):
         a = attn_fn(q, k, kv[..., nope:])
         with jax.named_scope("bf.mla.project"):
             return head_dense(cfg.hidden_size, (h, la.v_head_dim),
+                              inward=True, name="o")(a)
+
+
+class GroupedQueryAttention(nn.Module):
+    """Grouped-query attention without biases: ``(B, T, D) -> (B, T, D)``.
+
+    ``q = W_q y`` in ``num_heads`` heads, ``k = W_k y`` and ``v = W_v y`` in
+    ``grouped.kv_heads`` heads, all ``grouped.head_dim`` wide; query head
+    ``h`` reads key/value head ``h // (num_heads / kv_heads)`` (the grouped
+    heads of :func:`~bluefog_tpu.ops.ring_attention.local_attention`, which
+    ``attn_fn`` is handed as they are).  ``kind`` is the layer's type:
+    ``full_attention`` attends causally over every key and turns nothing
+    (no positional encoding); ``window_rotary_attention`` turns ``q`` and
+    ``k`` by rotary over the whole head (half-split pairs, ``rope_theta``)
+    and sees the last ``window`` keys.  Scores scale by ``head_dim ** -0.5``
+    (``attn_fn``'s default for that width)."""
+
+    cfg: GPTConfig
+    kind: str
+
+    @nn.compact
+    def __call__(self, y, attn_fn: AttnFn, positions):
+        cfg, gq = self.cfg, self.cfg.grouped
+        windowed = self.kind == "window_rotary_attention"
+        head_dense = functools.partial(HeadDense, use_bias=False,
+                                       dtype=cfg.dtype)
+        with jax.named_scope("bf.attn.project"):
+            q = head_dense(cfg.num_heads * gq.head_dim,
+                           (cfg.num_heads, gq.head_dim), name="q")(y)
+            k = head_dense(gq.kv_heads * gq.head_dim,
+                           (gq.kv_heads, gq.head_dim), name="k")(y)
+            v = head_dense(gq.kv_heads * gq.head_dim,
+                           (gq.kv_heads, gq.head_dim), name="v")(y)
+        if windowed:
+            with jax.named_scope("bf.attn.rotary"):
+                q = rotary(q, positions, gq.rope_theta, interleaved=False)
+                k = rotary(k, positions, gq.rope_theta, interleaved=False)
+        a = attn_fn(q, k, v, **({"window": gq.window} if windowed else {}))
+        a = metrics_comm.count(a, [
+            ("bf_attn_window_calls_total", 1.0) if windowed
+            else ("bf_attn_full_calls_total", 1.0)])
+        with jax.named_scope("bf.attn.project"):
+            return head_dense(cfg.hidden_size, (cfg.num_heads, gq.head_dim),
                               inward=True, name="o")(a)
 
 
@@ -555,43 +709,73 @@ class GatedMLP(nn.Module):
             gated * dense(self.width, name="up")(y))
 
 
-class RoutedSharedFFN(nn.Module):
-    """A shared expert every token takes plus this chip's share of the
-    routed experts (:func:`bluefog_tpu.ops.moe.routed_experts`: dropless,
-    grouped matmuls over the held experts).  The parameters hold the held
-    experts only; the router scores all of them.  The selection bias is a
-    buffer (collection ``buffers``, no gradient).  The routing record is
-    sown into the collection ``moe_metrics``."""
+def _route(ex: ExpertSizes, flat, router, bias):
+    """``(idx, weights)`` of ``cfg.experts``' router over ``flat (T, D)``.
+    A plain function, as :func:`_mix`."""
+    if ex.router == "softmax_topk":
+        idx, weights = softmax_topk_router(flat, router, top_k=ex.top_k)
+    else:
+        idx, weights = sigmoid_topk_router(
+            flat, router, bias.value, top_k=ex.top_k, scale=ex.scale)
+    if not ex.train_router:
+        weights = jax.lax.stop_gradient(weights)
+    return idx, weights
+
+
+class RoutedFFN(nn.Module):
+    """This chip's share of the routed experts
+    (:func:`bluefog_tpu.ops.moe.routed_experts`: dropless, grouped matmuls
+    over the held experts) plus, where ``experts.num_shared`` is not 0, a
+    shared expert every token takes.  The parameters hold the held experts
+    only; the router scores all of them.  The sigmoid router's selection
+    bias is a buffer (collection ``buffers``, no gradient); the softmax
+    router has none, and a layer without a shared expert no ``shared``
+    module.  The routing record is sown into the collection ``moe_metrics``.
+
+    ``__call__(y)`` routes on ``y`` itself.  Where the router reads the
+    block's input instead (``experts.router_input == "block"``) the block
+    asks for ``routing = route(block_input)`` before its attention and hands
+    it to ``__call__(y, routing)`` after."""
 
     cfg: GPTConfig
 
-    @nn.compact
-    def __call__(self, y):
+    def setup(self):
         cfg, ex = self.cfg, self.cfg.experts
         d, count = cfg.hidden_size, ex.held[1]
         per_expert = nn.initializers.lecun_normal(
             in_axis=1, out_axis=2, batch_axis=(0,))
-        router = self.param("router", nn.initializers.lecun_normal(),
-                            (d, ex.num_experts), jnp.float32)
-        bias = self.variable("buffers", "selection_bias", jnp.zeros,
-                             (ex.num_experts,), jnp.float32)
-        w_gate = self.param("w_gate", per_expert, (count, d, ex.width),
-                            jnp.float32)
-        w_up = self.param("w_up", per_expert, (count, d, ex.width),
-                          jnp.float32)
-        w_down = self.param("w_down", per_expert, (count, ex.width, d),
-                            jnp.float32)
-        flat = y.reshape(-1, d)
-        idx, weights = sigmoid_topk_router(
-            flat, router, bias.value, top_k=ex.top_k, scale=ex.scale)
+        self.router = self.param("router", nn.initializers.lecun_normal(),
+                                 (d, ex.num_experts), jnp.float32)
+        self.bias = self.variable(
+            "buffers", "selection_bias", jnp.zeros, (ex.num_experts,),
+            jnp.float32) if ex.router == "sigmoid_noaux_tc" else None
+        self.w_gate = self.param("w_gate", per_expert, (count, d, ex.width),
+                                 jnp.float32)
+        self.w_up = self.param("w_up", per_expert, (count, d, ex.width),
+                               jnp.float32)
+        self.w_down = self.param("w_down", per_expert, (count, ex.width, d),
+                                 jnp.float32)
+        self.shared = GatedMLP(ex.num_shared * ex.width,
+                               cfg.dtype) if ex.num_shared else None
+
+    def route(self, y):
+        """``(idx, weights)`` over ``y (B, T, D)``'s tokens, flattened."""
+        return _route(self.cfg.experts, y.reshape(-1, y.shape[-1]),
+                      self.router, self.bias)
+
+    def __call__(self, y, routing=None):
+        ex = self.cfg.experts
+        flat = y.reshape(-1, y.shape[-1])
+        idx, weights = (_route(ex, flat, self.router, self.bias)
+                        if routing is None else routing)
         routed, record = routed_experts(
-            flat, idx, weights, w_gate, w_up, w_down,
-            num_experts=ex.num_experts, held=ex.held)
+            flat, idx, weights, self.w_gate, self.w_up, self.w_down,
+            num_experts=ex.num_experts, held=ex.held,
+            activation=ex.activation)
         for name, value in record.items():
             self.sow("moe_metrics", name, value)
-        shared = GatedMLP(ex.num_shared * ex.width, cfg.dtype,
-                          name="shared")(y)
-        return shared + routed.reshape(y.shape)
+        routed = routed.reshape(y.shape)
+        return routed if self.shared is None else self.shared(y) + routed
 
 
 def _mix(block, y, attn_fn, carried):
@@ -617,15 +801,27 @@ def _mix(block, y, attn_fn, carried):
     return a, (memory, keys, values)
 
 
-def _feed_forward(block, x):
-    """``x + FFN(norm(x))`` of the block's feed-forward kind."""
+def _early_routing(block, y):
+    """The expert layer's module and, where its router reads the block's
+    normed input ``y``, the routing made from it: before the attention."""
+    cfg = block.cfg
+    if block.mlp is not None or (block.ffn or cfg.ffn) != ROUTED:
+        return None, None
+    moe = RoutedFFN(cfg, name="moe")
+    return moe, (moe.route(y) if cfg.experts.router_input == "block"
+                 else None)
+
+
+def _feed_forward(block, x, moe=None, routing=None):
+    """``x + FFN(norm(x))`` of the block's feed-forward kind; ``moe`` and
+    ``routing`` as :func:`_early_routing` gave them."""
     cfg = block.cfg
     y = _norm(cfg, "ln2")(x).astype(cfg.dtype)
     ffn = block.ffn or cfg.ffn
     if block.mlp is not None:
         return x + block.mlp()(y)
-    if ffn == "routed+shared":
-        return x + RoutedSharedFFN(cfg, name="moe")(y)
+    if ffn == ROUTED:
+        return x + moe(y, routing)
     width = cfg.ffn_width or cfg.mlp_ratio * cfg.hidden_size
     if ffn == "swiglu":
         return x + GatedMLP(width, cfg.dtype, name="mlp")(y)
@@ -644,9 +840,12 @@ class Block(nn.Module):
     instead of duplicating the attention trunk).  ``ffn`` overrides
     ``cfg.ffn`` for this block (the leading dense blocks of an expert model).
 
-    ``mixer`` (one of ``cfg.layer_types``) replaces the attention with that
-    token mixer.  Such a block takes and returns ``carried = (memory, keys,
-    values)`` beside ``x``: what the last Mamba block and the last full
+    ``mixer`` is the block's entry of ``cfg.layer_types``.  One of
+    ``ATTENTION_LAYERS`` says what the grouped-query attention attends over
+    and whether it turns its keys.  One of ``MIXERS`` replaces the attention
+    with that token mixer; such a block takes and returns ``carried =
+    (memory, keys, values)`` beside ``x``: what the last Mamba block and the
+    last full
     differential-attention block left for the gated memory units and the
     cross-attention layers after them.  Under ``nn.remat`` they are a
     block's outputs and the next blocks' inputs, so they are saved and not
@@ -664,26 +863,31 @@ class Block(nn.Module):
     def __call__(self, x, attn_fn: AttnFn, positions=None, carried=None):
         cfg = self.cfg
         y = _norm(cfg, "ln1")(x).astype(cfg.dtype)
-        if self.mixer is not None:
+        if self.mixer in MIXERS:
             a, carried = _mix(self, y, attn_fn, carried)
             return _feed_forward(self, x + a), carried
+        moe, routing = _early_routing(self, y)
         if cfg.attention == "latent":
             a = LatentAttention(cfg, name="attn")(y, attn_fn, positions)
+        elif cfg.attention == "grouped_query":
+            a = GroupedQueryAttention(cfg, self.mixer, name="attn")(
+                y, attn_fn, positions)
         else:
             heads = (cfg.num_heads, cfg.hidden_size // cfg.num_heads)
             q, k, v = HeadDense(3 * cfg.hidden_size, heads, parts=3,
                                 dtype=cfg.dtype, name="qkv")(y)
             a = HeadDense(cfg.hidden_size, heads, inward=True,
                           dtype=cfg.dtype, name="proj")(attn_fn(q, k, v))
-        return _feed_forward(self, x + a)
+        return _feed_forward(self, x + a, moe, routing)
 
 
 class TransformerLM(nn.Module):
     """Tokens → logits.  ``attn_fn(q, k, v) -> out`` defaults to full causal
     attention; inject a sequence-parallel attention inside ``shard_map`` and
     pass this rank's global ``position_offset``.  (A windowed block calls
-    ``attn_fn(q, k, v, window=w)``, and the differential blocks hand it
-    grouped key/value heads: an injected ``attn_fn`` has to take both.)  ``mlp`` (a sublayer factory,
+    ``attn_fn(q, k, v, window=w)``, and the differential and the
+    grouped-query blocks hand it grouped key/value heads: an injected
+    ``attn_fn`` has to take both.)  ``mlp`` (a sublayer factory,
     see :class:`Block`) swaps every block's MLP — e.g. for Switch-MoE.
 
     With ``cfg.mtp_depth == 1`` and ``next_tokens`` (the tokens one place
@@ -721,7 +925,7 @@ class TransformerLM(nn.Module):
         dense_blocks = cfg.experts.first_dense if cfg.experts else 0
         carried = (None, None, None)     # memory, keys, values
         for i in range(cfg.num_layers):
-            if cfg.layer_types is not None:
+            if cfg.hybrid is not None:
                 x, carried = block_cls(
                     cfg, mixer=cfg.layer_types[i],
                     layer=cfg.hybrid.first_layer + i, name=f"block_{i}")(
@@ -729,6 +933,8 @@ class TransformerLM(nn.Module):
                 continue
             x = block_cls(cfg, mlp=self.mlp,
                           ffn="swiglu" if i < dense_blocks else None,
+                          mixer=cfg.layer_types[i] if cfg.layer_types
+                          else None,
                           name=f"block_{i}")(x, attn_fn, positions)
         if cfg.tie_head:
             def head(h):    # f32 logits from the f32 leaf, as lm_head's

@@ -12,9 +12,11 @@ Pieces:
   capacity: returns dense dispatch/combine tensors.
 - :func:`expert_parallel_ffn` — dispatch → all_to_all → local expert FFNs →
   reverse all_to_all → combine, inside ``shard_map``.
-- :func:`sigmoid_topk_router` and :func:`routed_experts` — the dropless
-  layer for many small experts: sigmoid scores with a selection bias, top-k
-  of all the experts, and this chip's share of the result over the experts
+- :func:`sigmoid_topk_router`, :func:`softmax_topk_router` and
+  :func:`routed_experts` — the dropless layer for many small experts: a
+  router over all the experts (sigmoid scores with a selection bias, or a
+  softmax over the chosen logits), top-k of them, and this chip's share of
+  the result over the experts
   it is told it holds: sort by expert, then passes of a row buffer sized
   from the held share (gather the rows, grouped matmuls over the held
   groups, weighted scatter-add back) until the held rows are done.  No
@@ -53,6 +55,7 @@ __all__ = [
     "expert_parallel_ffn",
     "moe_ffn_reference",
     "sigmoid_topk_router",
+    "softmax_topk_router",
     "routed_experts",
 ]
 
@@ -287,6 +290,26 @@ def sigmoid_topk_router(x, router_kernel, bias, *, top_k: int,
     return idx, weights
 
 
+def softmax_topk_router(x, router_kernel, *, top_k: int):
+    """Softmax routing with the chosen weights renormalised: ``l = x @ W_r``
+    in f32, the chosen set is the ``top_k`` largest ``l``, the weights are
+    ``exp(l_i) / sum of the chosen exp(l)``.  A softmax over all the experts
+    followed by renormalising over the chosen gives the same weights, so the
+    order of the two is no choice.  No bias, no scale.
+
+    ``x (T, D)``, ``router_kernel (D, E)`` → ``idx (T, top_k)`` int32,
+    ``weights (T, top_k)`` f32; the matmul at ``highest`` precision, as
+    :func:`sigmoid_topk_router`'s and for its reason.
+    """
+    with jax.named_scope("bf.moe.route"):
+        logits = jnp.dot(
+            x.astype(jnp.float32), router_kernel.astype(jnp.float32),
+            precision=lax.Precision.HIGHEST)
+        chosen, idx = lax.top_k(logits, top_k)
+        weights = jax.nn.softmax(chosen, axis=-1)
+    return idx, weights
+
+
 def _ceil_div(a, b):
     return -(-a // b)
 
@@ -359,8 +382,11 @@ def _grouped_products(sizes, backend):
     return product, transposes
 
 
-def _gate(gate, up):
-    return jax.nn.silu(gate) * up
+ACTIVATIONS = {"silu": jax.nn.silu, "relu": jax.nn.relu}
+
+
+def _gate(activation, gate, up):
+    return ACTIVATIONS[activation](gate) * up
 
 
 def _window(j, weights, order, ends, k, c):
@@ -384,9 +410,9 @@ def _cast_experts(x, *ws):
         return tuple(w.astype(x.dtype) for w in ws)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(7, 8, 9))
+@functools.partial(jax.custom_vjp, nondiff_argnums=(7, 8, 9, 10))
 def _held_experts(x, weights, order, ends, w_gate, w_up, w_down, k, c,
-                  backend):
+                  backend, activation):
     """``y (T, D)``: every held assignment's ``weight * E(x[token])``,
     summed by token in f32, in passes of ``c`` rows over the held head of
     ``order`` (``ends``: the held groups' cumulative sizes).  One
@@ -400,7 +426,8 @@ def _held_experts(x, weights, order, ends, w_gate, w_up, w_down, k, c,
             rows = x[tokens]
         with jax.named_scope("bf.moe.experts"):
             product, _ = _grouped_products(sizes, backend)
-            out = product(_gate(product(rows, wg), product(rows, wu)), wd)
+            out = product(_gate(activation, product(rows, wg),
+                                product(rows, wu)), wd)
         with jax.named_scope("bf.moe.combine"):
             return y.at[tokens].add(out.astype(jnp.float32) * w[:, None])
 
@@ -410,13 +437,13 @@ def _held_experts(x, weights, order, ends, w_gate, w_up, w_down, k, c,
 
 
 def _held_experts_fwd(x, weights, order, ends, w_gate, w_up, w_down, k, c,
-                      backend):
+                      backend, activation):
     return (_held_experts(x, weights, order, ends, w_gate, w_up, w_down, k,
-                          c, backend),
+                          c, backend, activation),
             (x, weights, order, ends, w_gate, w_up, w_down))
 
 
-def _held_experts_bwd(k, c, backend, res, g):
+def _held_experts_bwd(k, c, backend, activation, res, g):
     """The same passes: a pass's rows and products again, then their
     transposes; ``d_x``, ``d_weights`` and the experts' gradients add up
     over the passes in f32."""
@@ -431,7 +458,8 @@ def _held_experts_bwd(k, c, backend, res, g):
         with jax.named_scope("bf.moe.experts"):
             product, transposes = _grouped_products(sizes, backend)
             hidden, gate_transpose = jax.vjp(
-                _gate, product(rows, wg), product(rows, wu))
+                functools.partial(_gate, activation), product(rows, wg),
+                product(rows, wu))
             out = product(hidden, wd)
         with jax.named_scope("bf.moe.combine"):
             g_rows = g[tokens].astype(jnp.float32)
@@ -466,13 +494,15 @@ _held_experts.defvjp(_held_experts_fwd, _held_experts_bwd)
 
 def routed_experts(x, idx, weights, w_gate, w_up, w_down, *,
                    num_experts: int, held: Tuple[int, int],
-                   backend: str = "auto"):
+                   backend: str = "auto", activation: str = "silu"):
     """This chip's share of a routed expert layer, without dropping a token.
 
     ``x (T, D)`` tokens; ``idx``/``weights (T, k)`` from the router, over
     all ``num_experts``; ``w_gate``/``w_up (count, D, F)`` and
-    ``w_down (count, F, D)`` the gated-SiLU experts ``held = (first,
-    count)`` names: global experts ``first .. first + count - 1``.  Returns
+    ``w_down (count, F, D)`` the gated experts ``E(x) = W_down
+    (activation(W_gate x) * W_up x)`` (``activation``: ``'silu'`` or
+    ``'relu'``) that ``held = (first, count)`` names: global experts
+    ``first .. first + count - 1``.  Returns
     ``(y, record)``: ``y[t] = sum over the chosen i that are held of
     weights[t, i] * E_i(x[t])`` in ``x.dtype`` — what the absent experts
     would add is left out, for the caller's exchange (or nothing, on one
@@ -511,6 +541,9 @@ def routed_experts(x, idx, weights, w_gate, w_up, w_down, *,
         backend = "gmm" if jax.default_backend() == "tpu" else "ragged"
     if backend not in ("gmm", "gmm_interpret", "ragged"):
         raise ValueError(f"unknown backend {backend!r}")
+    if activation not in ACTIVATIONS:
+        raise ValueError(f"unknown activation {activation!r}; expected one "
+                         f"of {sorted(ACTIVATIONS)}")
     t, k = idx.shape
     n_rows = t * k
     c = _row_buffer(n_rows, count, num_experts)
@@ -525,7 +558,7 @@ def routed_experts(x, idx, weights, w_gate, w_up, w_down, *,
         ends = (group[None, :] <= jnp.arange(count)[:, None]).sum(
             axis=1, dtype=jnp.int32)                 # held groups, cumulative
     y = _held_experts(x, weights, order, ends, w_gate, w_up, w_down, k, c,
-                      backend)
+                      backend, activation)
     row_passes = _ceil_div(ends[-1], c)
     record = {"rows_per_expert": jnp.diff(ends, prepend=0),
               "held_share": ends[-1].astype(jnp.float32) / n_rows,

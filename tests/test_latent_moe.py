@@ -19,7 +19,7 @@ import pytest
 
 from bluefog_tpu.models.transformer import (
     Block, ExpertSizes, GPTConfig, LatentAttention, LatentSizes,
-    RoutedSharedFFN, TransformerLM, next_token_loss, rotary)
+    RoutedFFN, TransformerLM, next_token_loss, rotary)
 from bluefog_tpu.ops import moe as moe_ops
 from bluefog_tpu.ops.moe import routed_experts, sigmoid_topk_router
 from bluefog_tpu.ops.ring_attention import _splash_attention, local_attention
@@ -477,7 +477,7 @@ def test_the_shares_of_every_chip_add_up_to_the_uncut_layer(count):
     the experts computes, and to the plain reference's uncut layer."""
     whole_cfg = tiny(held=(0, E))
     y = rand((2, 16, 64), 5)
-    whole = RoutedSharedFFN(whole_cfg)
+    whole = RoutedFFN(whole_cfg)
     variables = whole.init(jax.random.PRNGKey(0), y)
     variables = {"params": variables["params"], "buffers": {
         "selection_bias": rand((E,), 6, 0.1)}}
@@ -488,7 +488,7 @@ def test_the_shares_of_every_chip_add_up_to_the_uncut_layer(count):
     for first in range(0, E, count):
         share = {**params, **{name: params[name][first:first + count]
                               for name in ("w_gate", "w_up", "w_down")}}
-        out = RoutedSharedFFN(tiny(held=(first, count))).apply(
+        out = RoutedFFN(tiny(held=(first, count))).apply(
             {"params": share, "buffers": variables["buffers"]}, y)
         total = total + (out - shared_once)
     np.testing.assert_allclose(total, uncut, atol=2e-5)

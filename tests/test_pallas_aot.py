@@ -431,6 +431,31 @@ def test_differential_attention_kernels_compile_for_v5e(window,
     assert "flash_mha_bwd_splash_mha_dkv" in txt
 
 
+@pytest.mark.parametrize("window", [4096, None], ids=["window", "full"])
+def test_grouped_query_attention_kernels_compile_for_v5e(window,
+                                                         tpu_aot_topology):
+    """The splash kernels at the grouped-query layers' published heads: 28
+    query heads over 4 key/value heads of 128, T=16,384 (the published
+    context), under the 4,096-key window (``LocalMask``) and in full, forward
+    and fused backward, keys and values repeated from 4 heads."""
+    from bluefog_tpu.ops.ring_attention import _repeat_heads, _splash_attention
+
+    one = _one_chip(tpu_aot_topology)
+    q = jax.ShapeDtypeStruct((1, 16384, 28, 128), jnp.bfloat16, sharding=one)
+    kv = jax.ShapeDtypeStruct((1, 16384, 4, 128), jnp.bfloat16, sharding=one)
+
+    def grads(q, k, v):
+        return jax.grad(lambda q, k, v: _splash_attention(
+            q, _repeat_heads(k, 28), _repeat_heads(v, 28), causal=True,
+            scale=128 ** -0.5, window=window).astype(jnp.float32).sum(),
+            argnums=(0, 1, 2))(q, k, v)
+
+    txt = jax.jit(grads).lower(q, kv, kv).compile().as_text()
+    assert txt.count("tpu_custom_call") >= 2
+    assert "flash_attention_splash_mha_fwd" in txt
+    assert "flash_mha_bwd_splash_mha_dkv" in txt
+
+
 def test_gpt2_attention_sublayer_keeps_its_layout_copies_few_on_v5e(
         tpu_aot_topology):
     """One GPT-2 block of ``gpt2s.t2048.solo`` (batch 8, T=2048, 768 wide,
