@@ -19,8 +19,9 @@ Pieces:
   the result over the experts
   it is told it holds: sort by expert, then passes of a row buffer sized
   from the held share (gather the rows, grouped matmuls over the held
-  groups, weighted scatter-add back) until the held rows are done.  No
-  capacity, no ``(T, E, C)`` tensor, no drop.
+  groups, then the held rows added to their tokens', weighted, in f32: in
+  VMEM by a Pallas kernel on a TPU, by scatter-add elsewhere) until the
+  held rows are done.  No capacity, no ``(T, E, C)`` tensor, no drop.
   The one-hot routers above stay for the ``all_to_all`` path, which needs
   the static per-expert capacity they provide.
 
@@ -349,16 +350,20 @@ def _gmm_tiling(m: int, k: int, n: int) -> Tuple[int, int, int]:
 
 
 def _grouped_products(sizes, backend):
-    """``(product, transposes)`` over rows sorted by group; ``sizes
-    (G + 1,)`` int32, the last group the rows no held expert takes.
-    ``product(rows (R, K), w (G, K, N)) -> (R, N)``: row ``r`` of group
-    ``g < G`` times ``w[g]``, zero for the last group (the Pallas kernel,
-    ``megablox.gmm``, visits the row tiles of the first ``G`` groups only).
+    """``(product, transposes)`` over rows sorted by group; ``sizes (G,)``
+    int32, the held groups' rows, which lie first: the rows past their sum
+    are no group's.  ``product(rows (R, K), w (G, K, N)) -> (R, N)``: row
+    ``r`` of group ``g`` times ``w[g]``; **what a row of no group gets is
+    unspecified** (``lax.ragged_dot`` writes zeros, the Pallas kernel,
+    ``megablox.gmm``, visits the groups' row tiles only and leaves the rest
+    of its output as it found it: handing it the ``G`` sizes alone spares
+    the pass over the whole output that zeroes the rows of a trailing
+    group), so whoever reads a product selects the groups' rows.
     ``transposes(rows, w, g) -> (d_rows, d_w)`` for a cotangent ``g (R,
-    N)``."""
+    N)``; ``d_w`` sums a group's rows alone."""
     if backend == "ragged":
         def product(rows, w):
-            return lax.ragged_dot(rows, w, sizes[:-1])
+            return lax.ragged_dot(rows, w, sizes)
 
         def transposes(rows, w, g):
             return jax.vjp(product, rows, w)[1](g)
@@ -378,7 +383,7 @@ def _grouped_products(sizes, backend):
             return (gmm(g, w, sizes, rows.dtype, tiling, transpose_rhs=True,
                         interpret=interpret),
                     tgmm(rows.swapaxes(0, 1), g, sizes, w.dtype, tiling,
-                         num_actual_groups=w.shape[0], interpret=interpret))
+                         interpret=interpret))
     return product, transposes
 
 
@@ -391,18 +396,167 @@ def _gate(activation, gate, up):
 
 def _window(j, weights, order, ends, k, c):
     """Pass ``j`` of the sorted assignments: ``order[j*c : (j+1)*c]``.
-    Returns the assignment ids, their tokens, the window's group sizes
-    ``(count + 1,)`` (the held groups' cumulative ``ends`` clipped to the
-    window; what is left of ``c`` is the trailing group the kernels skip)
-    and each row's f32 weight, 0 past the last held row."""
+    Returns the assignment ids, their tokens, the held groups' sizes in the
+    window ``(count,)`` (their cumulative ``ends`` clipped to it; what is
+    left of ``c`` is no group's, and the kernels skip it), which rows are a
+    group's (``live (c,)``: the first ``sizes.sum()``) and each row's f32
+    weight, 0 on the others."""
     start = j * c
     ids = lax.dynamic_slice(order, (start,), (c,))
     inside = jnp.clip(ends - start, 0, c)
-    sizes = jnp.diff(jnp.concatenate(
-        [jnp.zeros(1, jnp.int32), inside, jnp.full(1, c, jnp.int32)]))
-    live = start + jnp.arange(c, dtype=jnp.int32) < ends[-1]
+    sizes = jnp.diff(inside, prepend=0)
+    live = jnp.arange(c, dtype=jnp.int32) < inside[-1]
     w = jnp.where(live, weights.reshape(-1)[ids].astype(jnp.float32), 0.0)
-    return ids, ids // k, sizes, w
+    return ids, ids // k, sizes, live, w
+
+
+_VMEM_SUMS = 21 * 2 ** 19     # 10.5 MB of the sums in VMEM a kernel step
+
+
+def _sums_tile(t: int, d: int):
+    """Tokens of the ``(t, d)`` f32 sums that :func:`_add_rows_by_token`
+    holds in VMEM at a time, whole rows of them, or ``None`` where no tile
+    fits: the most that divide ``t`` in whole sublanes of 8 (or all ``t``)
+    within ``_VMEM_SUMS`` (1,024 of 16,384 x 2,560 and of 8,192 x 2,048).
+    **10.5 MB, so that the kernel stays within the 16 MB of VMEM every
+    kernel may take unasked**: XLA keeps the layer's input, 84 MB at 16,384
+    x 2,560 bf16, in VMEM across the passes, where the gather of its rows
+    takes 0.39 ms against 2.0 from HBM, and a kernel that asks for more
+    evicts it (PERF.md section 6, PR 35)."""
+    heights = [t] + [ts for ts in range(8, t, 8) if t % ts == 0]
+    fits = [ts for ts in heights if ts * d * 4 <= _VMEM_SUMS]
+    return max(fits, default=None)
+
+
+def _sums_in_vmem(t: int, d: int, backend: str) -> bool:
+    """Whether a pass's rows reach their tokens through
+    :func:`_add_rows_by_token` (on a TPU, wherever :func:`_sums_tile` finds
+    a tile) or through XLA's scatter-add (the portable ``'ragged'``
+    backend, and shapes the kernel cannot tile)."""
+    return backend != "ragged" and _sums_tile(t, d) is not None
+
+
+def _add_rows_by_token(acc, tokens, live, rows, w, interpret):
+    """``acc (T, D)`` f32 with the first ``live`` rows of the buffer added
+    at their tokens: ``acc[tokens[r]] += w[r] * (rows[0][r] + rows[1][r] +
+    ..)`` in f32, in the buffer's order.  ``tokens (c,)`` int32, ``rows``
+    one or two ``(c, D)`` arrays, ``w (c,)`` f32 or ``None``.
+
+    A Pallas kernel in place of XLA's scatter-add, which moves a row in
+    160 ns whatever it holds (PERF.md section 6, PR 35).  A step holds the
+    sums of ``ts`` tokens (:func:`_sums_tile`) in VMEM and reads the blocks
+    of ``rb`` buffer rows that hold a token of its tile, one while the one
+    before is summed: each live row of the tile is added to its token's by
+    one read-modify-write of a sublane.  The sort leaves a group's rows in
+    token order, so a tile meets a few blocks of every group and no more;
+    which, XLA lists beforehand from each block's first and last token.
+    Blocks past ``live`` are neither read nor touched, whatever they
+    hold."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    t, d = acc.shape
+    c = tokens.shape[0]
+    ts = _sums_tile(t, d)
+    tiles = t // ts
+    rb = 128
+    while c % rb:
+        rb //= 2
+    if rb < 16:
+        rb = c
+    blocks = c // rb
+    weighted = w is not None
+    # every tile's blocks: those with a live row whose token it holds
+    held = jnp.arange(c, dtype=jnp.int32) < live
+    first = jnp.where(held, tokens, t).reshape(blocks, rb).min(axis=1)
+    last = jnp.where(held, tokens, -1).reshape(blocks, rb).max(axis=1)
+    edges = jnp.arange(tiles, dtype=jnp.int32)[:, None] * ts
+    meets = (first < edges + ts) & (last >= edges)          # (tiles, blocks)
+    todo = jnp.argsort(~meets, axis=1, stable=True).astype(jnp.int32)
+    scalars = (live.reshape(1), meets.sum(axis=1, dtype=jnp.int32),
+               todo.reshape(-1), tokens) + ((w,) if weighted else ())
+
+    def kernel(*refs):
+        live_ref, count_ref, todo_ref, tok_ref = refs[:4]
+        w_ref = refs[4] if weighted else None
+        acc_ref, *rows_refs = refs[len(scalars):-5]
+        out_ref, sums, stage, buf, sems = refs[-5:]
+        tile = pl.program_id(0)
+        base = tile * ts
+        mine = pl.ds(pl.multiple_of(base, 8) if ts % 8 == 0 else base, ts)
+        count = count_ref[tile]
+
+        def first_row(j):       # of the tile's j-th block
+            return todo_ref[tile * blocks + j] * rb
+
+        def fetch(j, slot):
+            at = first_row(j)
+            at = pl.ds(pl.multiple_of(at, rb) if rb % 16 == 0 else at, rb)
+            return [pltpu.make_async_copy(ref.at[at], stage.at[a, slot],
+                                          sems.at[a, slot])
+                    for a, ref in enumerate(rows_refs)]
+
+        @pl.when(count > 0)
+        def _():
+            for copy in fetch(0, 0):
+                copy.start()
+            own = pltpu.make_async_copy(acc_ref.at[mine], sums, sems.at[0, 2])
+            own.start()
+            own.wait()
+
+            def one_block(j, carry):
+                slot = j % 2
+
+                @pl.when(j + 1 < count)
+                def _():
+                    for copy in fetch(j + 1, 1 - slot):
+                        copy.start()
+
+                for copy in fetch(j, slot):
+                    copy.wait()
+                total = stage[0, slot].astype(jnp.float32)
+                for a in range(1, len(rows_refs)):
+                    total = total + stage[a, slot].astype(jnp.float32)
+                buf[...] = total
+                start = first_row(j)
+
+                def one_row(i, carry):
+                    u = tok_ref[start + i] - base
+
+                    @pl.when((u >= 0) & (u < ts))
+                    def _():
+                        row = buf[pl.ds(i, 1), :]
+                        if weighted:
+                            row = row * w_ref[start + i]
+                        sums[pl.ds(u, 1), :] = sums[pl.ds(u, 1), :] + row
+                    return carry
+
+                lax.fori_loop(0, jnp.clip(live_ref[0] - start, 0, rb),
+                              one_row, jnp.int32(0))
+                return carry
+
+            lax.fori_loop(0, count, one_block, jnp.int32(0))
+            back = pltpu.make_async_copy(sums, out_ref.at[mine],
+                                         sems.at[0, 2])
+            back.start()
+            back.wait()
+
+    return pl.pallas_call(
+        kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=len(scalars), grid=(tiles,),
+            in_specs=[pl.BlockSpec(memory_space=pl.ANY)] * (1 + len(rows)),
+            out_specs=pl.BlockSpec(memory_space=pl.ANY),
+            scratch_shapes=[pltpu.VMEM((ts, d), jnp.float32),
+                            pltpu.VMEM((len(rows), 2, rb, d), rows[0].dtype),
+                            pltpu.VMEM((rb, d), jnp.float32),
+                            pltpu.SemaphoreType.DMA((len(rows), 3))]),
+        out_shape=jax.ShapeDtypeStruct(acc.shape, jnp.float32),
+        input_output_aliases={len(scalars): 0},
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)),
+        interpret=interpret,
+        name="bf_moe_add_rows_by_token")(*scalars, acc, *rows)
 
 
 def _cast_experts(x, *ws):
@@ -415,21 +569,28 @@ def _held_experts(x, weights, order, ends, w_gate, w_up, w_down, k, c,
                   backend, activation):
     """``y (T, D)``: every held assignment's ``weight * E(x[token])``,
     summed by token in f32, in passes of ``c`` rows over the held head of
-    ``order`` (``ends``: the held groups' cumulative sizes).  One
-    differentiation rule of its own: a loop with a traced trip count has no
-    reverse mode, and the rule keeps nothing of a pass but the inputs."""
+    ``order`` (``ends``: the held groups' cumulative sizes); the sums by
+    :func:`_add_rows_by_token` or by scatter-add (:func:`_sums_in_vmem`).
+    One differentiation rule of its own: a loop with a traced trip count has
+    no reverse mode, and the rule keeps nothing of a pass but the inputs."""
     wg, wu, wd = _cast_experts(x, w_gate, w_up, w_down)
+    in_vmem = _sums_in_vmem(*x.shape, backend)
 
     def one_pass(j, y):
         with jax.named_scope("bf.moe.dispatch"):
-            _, tokens, sizes, w = _window(j, weights, order, ends, k, c)
+            _, tokens, sizes, live, w = _window(j, weights, order, ends, k,
+                                                c)
             rows = x[tokens]
         with jax.named_scope("bf.moe.experts"):
             product, _ = _grouped_products(sizes, backend)
             out = product(_gate(activation, product(rows, wg),
                                 product(rows, wu)), wd)
         with jax.named_scope("bf.moe.combine"):
-            return y.at[tokens].add(out.astype(jnp.float32) * w[:, None])
+            if in_vmem:
+                return _add_rows_by_token(y, tokens, sizes.sum(), (out,), w,
+                                          backend == "gmm_interpret")
+            return y.at[tokens].add(jnp.where(
+                live[:, None], out.astype(jnp.float32) * w[:, None], 0.0))
 
     y = lax.fori_loop(0, _ceil_div(ends[-1], c), one_pass,
                       jnp.zeros(x.shape, jnp.float32))
@@ -446,14 +607,16 @@ def _held_experts_fwd(x, weights, order, ends, w_gate, w_up, w_down, k, c,
 def _held_experts_bwd(k, c, backend, activation, res, g):
     """The same passes: a pass's rows and products again, then their
     transposes; ``d_x``, ``d_weights`` and the experts' gradients add up
-    over the passes in f32."""
+    over the passes in f32, ``d_x`` by token in the forward's form."""
     x, weights, order, ends, *experts = res
     wg, wu, wd = _cast_experts(x, *experts)
+    in_vmem = _sums_in_vmem(*x.shape, backend)
 
     def one_pass(j, carry):
         d_x, d_weights, d_ws = carry
         with jax.named_scope("bf.moe.dispatch"):
-            ids, tokens, sizes, w = _window(j, weights, order, ends, k, c)
+            ids, tokens, sizes, live, w = _window(j, weights, order, ends, k,
+                                                  c)
             rows = x[tokens]
         with jax.named_scope("bf.moe.experts"):
             product, transposes = _grouped_products(sizes, backend)
@@ -463,9 +626,8 @@ def _held_experts_bwd(k, c, backend, activation, res, g):
             out = product(hidden, wd)
         with jax.named_scope("bf.moe.combine"):
             g_rows = g[tokens].astype(jnp.float32)
-            # rows past the last held one: `out` is zero there
-            d_weights = d_weights.at[ids].add(
-                (out.astype(jnp.float32) * g_rows).sum(axis=1))
+            d_weights = d_weights.at[ids].add(jnp.where(
+                live, (out.astype(jnp.float32) * g_rows).sum(axis=1), 0.0))
             d_out = (g_rows * w[:, None]).astype(x.dtype)
         with jax.named_scope("bf.moe.experts"):
             d_hidden, d_wd = transposes(hidden, wd, d_out)
@@ -475,8 +637,14 @@ def _held_experts_bwd(k, c, backend, activation, res, g):
             d_ws = tuple(acc + d.astype(jnp.float32)
                          for acc, d in zip(d_ws, (d_wg, d_wu, d_wd)))
         with jax.named_scope("bf.moe.dispatch"):
-            d_x = d_x.at[tokens].add(d_rows_gate.astype(jnp.float32)
-                                     + d_rows_up.astype(jnp.float32))
+            if in_vmem:
+                d_x = _add_rows_by_token(
+                    d_x, tokens, sizes.sum(), (d_rows_gate, d_rows_up), None,
+                    backend == "gmm_interpret")
+            else:
+                d_x = d_x.at[tokens].add(jnp.where(
+                    live[:, None], d_rows_gate.astype(jnp.float32)
+                    + d_rows_up.astype(jnp.float32), 0.0))
         return d_x, d_weights, d_ws
 
     d_x, d_weights, d_ws = lax.fori_loop(
@@ -508,23 +676,35 @@ def routed_experts(x, idx, weights, w_gate, w_up, w_down, *,
     would add is left out, for the caller's exchange (or nothing, on one
     chip) to supply — and the routing record, not differentiated:
     ``rows_per_expert (count,)``, ``held_share`` (held assignments over
-    all ``T * k``) and ``row_passes`` (int32, below).  With metrics on, the
-    record feeds the counters ``bf_moe_assignments_total``,
-    ``bf_moe_assignments_held_total`` and ``bf_moe_row_passes_total``.
+    all ``T * k``), ``row_passes`` and ``vmem_passes`` (int32, below).
+    With metrics on, the record feeds the counters
+    ``bf_moe_assignments_total``, ``bf_moe_assignments_held_total``,
+    ``bf_moe_row_passes_total`` and ``bf_moe_vmem_passes_total``.
 
     The ``T * k`` assignments are sorted by expert (held experts first, the
     rest as one trailing group).  **The row buffer is ``C`` rows**
     (:func:`_row_buffer`: twice what uniform routing sends to the held
     experts, from the shapes alone; all ``T * k`` where every expert is
     held).  A pass gathers the next ``C`` sorted rows' tokens, runs the
-    three grouped matmuls and the gate at that height, and scatter-adds
-    ``weight * out`` into ``y`` in f32; ``row_passes = ceil(held rows /
-    C)`` passes run, from the router's own counts: one while the router
-    sends this chip at most twice its share, ``T * k / C`` if every
+    three grouped matmuls and the gate at that height, and adds
+    ``weight * out`` into ``y`` by token in f32; ``row_passes = ceil(held
+    rows / C)`` passes run, from the router's own counts: one while the
+    router sends this chip at most twice its share, ``T * k / C`` if every
     assignment of every token lands here, none if no row is held (``y`` is
     then exactly zero).  Any routing fits, none is dropped, and the cost
     follows the rows the router sent.  The gradient runs the same passes
     (:func:`_held_experts`).
+
+    **The sums by token** (``y``, and ``d_x`` in the gradient) **take one
+    of two forms** (:func:`_sums_in_vmem`, from the backend and the shapes;
+    ``vmem_passes`` is ``row_passes`` under the first and 0 under the
+    second).  *In VMEM* (:func:`_add_rows_by_token`, the Pallas backends):
+    the sums of a tile of tokens stay in VMEM while the blocks of the
+    buffer that hold its rows are read, and each held row is added to its
+    token's there; nothing is read past the last held row, and no f32 copy
+    of the buffer is made.  *Scatter-add* of the pass's ``C`` rows at their
+    tokens in HBM, which the TPU executes a row at a time: the portable
+    backend's, and the fallback for a shape the kernel cannot tile.
 
     ``backend``: ``'gmm'`` the Pallas kernel (``megablox.gmm``), ``'ragged'``
     ``lax.ragged_dot`` (portable, what CI runs), ``'auto'`` the kernel on a
@@ -560,10 +740,13 @@ def routed_experts(x, idx, weights, w_gate, w_up, w_down, *,
     y = _held_experts(x, weights, order, ends, w_gate, w_up, w_down, k, c,
                       backend, activation)
     row_passes = _ceil_div(ends[-1], c)
+    vmem_passes = (row_passes if _sums_in_vmem(*x.shape, backend)
+                   else jnp.zeros_like(row_passes))
     record = {"rows_per_expert": jnp.diff(ends, prepend=0),
               "held_share": ends[-1].astype(jnp.float32) / n_rows,
-              "row_passes": row_passes}
+              "row_passes": row_passes, "vmem_passes": vmem_passes}
     y = metrics_comm.count(y, [("bf_moe_assignments_total", float(n_rows)),
                                ("bf_moe_assignments_held_total", ends[-1]),
-                               ("bf_moe_row_passes_total", row_passes)])
+                               ("bf_moe_row_passes_total", row_passes),
+                               ("bf_moe_vmem_passes_total", vmem_passes)])
     return y, record

@@ -143,9 +143,20 @@ def _plain_share(activation, x, idx, weights, wg, wu, wd, first):
     return out
 
 
-@pytest.mark.parametrize("backend", ["ragged", "gmm_interpret"])
+@pytest.mark.parametrize("backend", ["ragged", "gmm_interpret",
+                                     "gmm_interpret+scatter"])
 @pytest.mark.parametrize("activation", ["relu", "silu"])
-def test_gate_activation_forward_and_custom_backward(activation, backend):
+def test_gate_activation_forward_and_custom_backward(activation, backend,
+                                                     monkeypatch):
+    """Both gates through both ways a pass's rows reach their tokens: the
+    kernel that sums in VMEM (what the Pallas backends take where the shape
+    fits) and XLA's scatter-add (the portable backend's; asked for under
+    the interpreter as well)."""
+    from bluefog_tpu.ops import moe as moe_ops
+
+    backend, _, scatter = backend.partition("+")
+    if scatter:
+        monkeypatch.setattr(moe_ops, "_sums_in_vmem", lambda t, d, b: False)
     x, kernel = rand((128, 64), 0), rand((64, E), 1)
     wg, wu, wd = (rand((4, 64, 32), 2, 0.2), rand((4, 64, 32), 3, 0.2),
                   rand((4, 32, 64), 4, 0.2))
@@ -687,6 +698,27 @@ def test_the_routing_script_reads_the_held_share_a_layer(tmp_path,
         assert len(summary[key]) == 4
         assert all(0.3 < share < 0.7 for share in summary[key]), summary
     assert summary["row_passes_max"] == [1, 1, 1, 1]
+
+
+def test_the_combine_script_runs_both_forms_on_a_tiny_layer(tmp_path):
+    """``benchmarks/moe_combine_bench.py``, which read the expert layer's
+    sums by token as XLA's scatter-adds and through the kernel on the chip
+    at both cells' shapes (PERF.md section 6, PR 35), at its tiny shape:
+    both forms run (the kernel in the interpreter), the record says which,
+    and a CPU run names itself and gives no device time."""
+    sys.path.insert(0, os.path.join(REPO, "benchmarks"))
+    import moe_combine_bench
+
+    out = moe_combine_bench.main(
+        ["--shapes", "tiny", "--out", str(tmp_path / "bench.json")])
+    assert out["platform"] == "cpu"
+    for form in ("scatter", "kernel"):
+        entry = out[f"tiny.{form}"]
+        assert entry["row_buffer"] == 128 and entry["row_passes"] == 1
+        assert 0 < entry["held_rows"] < 128
+        assert len(entry["wall_ms"]) == 3 and entry["device_ms"] is None
+    with open(tmp_path / "bench.json") as f:
+        assert json.load(f) == json.loads(json.dumps(out))
 
 
 @pytest.mark.duration_budget(90)   # the cell, the reference's step twice

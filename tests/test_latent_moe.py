@@ -230,17 +230,28 @@ def _dense_share(x, kernel, bias, wg, wu, wd, first):
                for i in range(wg.shape[0]))
 
 
+@pytest.fixture(params=["ragged", "gmm_interpret", "gmm_interpret+scatter"])
+def sums(request, monkeypatch):
+    """``(backend, in_vmem)``: the two ways a pass's rows reach their tokens
+    (``_sums_in_vmem``): XLA's scatter-add, which the portable backend runs
+    and which a shape too tall for VMEM falls back to on a TPU, and the
+    Pallas kernel, here in the interpreter."""
+    backend, _, forced = request.param.partition("+")
+    if forced:
+        monkeypatch.setattr(moe_ops, "_sums_in_vmem", lambda t, d, b: False)
+    return backend, request.param == "gmm_interpret"
+
+
 def _routed(x, kernel, bias, wg, wu, wd, held, backend):
     idx, weights = sigmoid_topk_router(x, kernel, bias, top_k=K, scale=2.5)
     return routed_experts(x, idx, weights, wg, wu, wd, num_experts=E,
                           held=held, backend=backend)
 
 
-@pytest.mark.parametrize("backend", ["ragged", "gmm_interpret"])
 @pytest.mark.parametrize("held", [(0, 4), (4, 8), (12, 4), (0, 16)],
                          ids=lambda h: f"held{h[0]}+{h[1]}")
-def test_routed_experts_match_the_dense_share_loss_and_gradients(held,
-                                                                 backend):
+def test_routed_experts_match_the_dense_share_loss_and_gradients(held, sums):
+    backend, in_vmem = sums
     x, kernel = rand((64, 64), 0), rand((64, E), 1, 0.2)
     bias = rand((E,), 2, 0.1)
     w = _expert_weights(held[1])
@@ -252,16 +263,19 @@ def test_routed_experts_match_the_dense_share_loss_and_gradients(held,
     def want(x, kernel, wg, wu, wd):
         return _dense_share(x, kernel, bias, wg, wu, wd, held[0])
 
-    np.testing.assert_allclose(got(*args), want(*args), atol=2e-5)
+    y, record = _routed(x, kernel, bias, *w, held, backend)
+    assert int(record["vmem_passes"]) == in_vmem
+    np.testing.assert_allclose(y, want(*args), atol=2e-5)
     grads = [jax.grad(lambda *a: jnp.sum(f(*a) ** 2), argnums=range(5))(*args)
              for f in (got, want)]
     close(grads[0], grads[1])
 
 
-@pytest.mark.parametrize("backend", ["ragged", "gmm_interpret"])
-def test_no_token_is_dropped_when_every_one_routes_to_held_experts(backend):
-    """All 16 experts held, and a bias that sends every token to the same
-    four: 64 rows an expert where a balanced router would send 16."""
+def test_no_token_is_dropped_when_every_one_routes_to_held_experts(sums):
+    """All 16 experts held (``C = T * k``: one pass by construction), and a
+    bias that sends every token to the same four: 64 rows an expert where a
+    balanced router would send 16."""
+    backend, _ = sums
     x, kernel = rand((64, 64), 0), rand((64, E), 1, 0.2)
     bias = jnp.zeros(E).at[jnp.array([1, 2, 3, 5])].set(10.0)
     w = _expert_weights(E)
@@ -274,8 +288,8 @@ def test_no_token_is_dropped_when_every_one_routes_to_held_experts(backend):
     assert (rows[[1, 2, 3, 5]] == 64).all()
 
 
-@pytest.mark.parametrize("backend", ["ragged", "gmm_interpret"])
-def test_routed_output_is_zero_when_no_token_routes_to_a_held_expert(backend):
+def test_routed_output_is_zero_when_no_token_routes_to_a_held_expert(sums):
+    backend, _ = sums
     x, kernel = rand((64, 64), 0), rand((64, E), 1, 0.2)
     bias = jnp.zeros(E).at[8:12].set(10.0)        # every token takes 8..11
     w = _expert_weights(4)
@@ -284,6 +298,7 @@ def test_routed_output_is_zero_when_no_token_routes_to_a_held_expert(backend):
     assert float(record["held_share"]) == 0.0
     assert int(record["rows_per_expert"].sum()) == 0
     assert int(record["row_passes"]) == 0       # no pass: y is the zeros
+    assert int(record["vmem_passes"]) == 0
     grads = jax.grad(lambda x, *w: jnp.sum(_routed(
         x, kernel, bias, *w, (0, 4), backend)[0]), argnums=range(4))(x, *w)
     assert all(float(jnp.abs(g).max()) == 0.0 for g in grads)
@@ -312,14 +327,13 @@ def _all_to_the_held_four():
     return jnp.zeros(E).at[:4].set(10.0)
 
 
-@pytest.mark.parametrize("backend", ["ragged", "gmm_interpret"])
 @pytest.mark.parametrize("tokens", [256, 200], ids=["whole", "padded"])
-def test_two_passes_match_the_dense_share_loss_and_gradients(tokens,
-                                                             backend):
+def test_two_passes_match_the_dense_share_loss_and_gradients(tokens, sums):
     """Held ``(0, 4)`` of 16: ``C`` = 512 of the ``T * K`` = 1,024 (800)
     rows, and a selection bias that sends every token's four choices to the
     held four: twice ``C`` held rows (the second window runs past the 800).
     Nothing is dropped: the second pass takes what the first left."""
+    backend, in_vmem = sums
     x, kernel = rand((tokens, 64), 0), rand((64, E), 1, 0.2)
     bias, w = _all_to_the_held_four(), _expert_weights(4)
     args = (x, kernel, *w)
@@ -333,6 +347,7 @@ def test_two_passes_match_the_dense_share_loss_and_gradients(tokens,
     y, record = _routed(x, kernel, bias, *w, (0, 4), backend)
     assert int(record["rows_per_expert"].sum()) == tokens * K
     assert int(record["row_passes"]) == 2
+    assert int(record["vmem_passes"]) == (2 if in_vmem else 0)
     np.testing.assert_allclose(y, want(*args), atol=2e-5)
     grads = [jax.grad(lambda *a: jnp.sum(f(*a) ** 2), argnums=range(5))(*args)
              for f in (got, want)]
@@ -347,12 +362,12 @@ def _dense_from_the_routing(x, idx, weights, wg, wu, wd, first):
         for i in range(wg.shape[0]))
 
 
-@pytest.mark.parametrize("backend", ["ragged", "gmm_interpret"])
 @pytest.mark.parametrize("held_rows,passes", [(512, 1), (513, 2), (1, 1)],
                          ids=["C", "C+1", "one"])
-def test_the_pass_boundary_loss_and_gradients(held_rows, passes, backend):
+def test_the_pass_boundary_loss_and_gradients(held_rows, passes, sums):
     """256 tokens, ``C`` = 512: the first tokens take the held experts
     0..3, one more token takes expert 0 and three absent ones."""
+    backend, _ = sums
     full, extra = divmod(held_rows, K)
     idx = jnp.tile(jnp.arange(4, 8), (256, 1))
     idx = idx.at[:full].set(jnp.arange(4)).at[full, :extra].set(
@@ -388,6 +403,162 @@ def test_row_passes_follow_the_rows_the_router_sent(skewed, passes):
     assert record["row_passes"].dtype == jnp.int32
     assert int(record["row_passes"]) == passes == -(-held // 512)
     assert (held == 1024) == skewed
+
+
+def test_one_expert_a_token_loss_and_gradients(sums):
+    """``k = 1``: a token has one row in the whole sort; here 96 of 128
+    tokens take a held expert (0, 2, 4; 6 is absent) and ``C`` is all 128."""
+    backend, in_vmem = sums
+    idx = (jnp.arange(128, dtype=jnp.int32) % 4 * 2)[:, None]   # 0 2 4 6
+    x, w = rand((128, 64), 0), _expert_weights(6)
+    weights = 0.5 + jax.random.uniform(jax.random.PRNGKey(7), (128, 1))
+    args = (x, weights, *w)
+
+    def got(x, weights, wg, wu, wd):
+        return routed_experts(x, idx, weights, wg, wu, wd, num_experts=E,
+                              held=(0, 6), backend=backend)
+
+    def want(x, weights, wg, wu, wd):
+        return _dense_from_the_routing(x, idx, weights, wg, wu, wd, 0)
+
+    y, record = got(*args)
+    assert moe_ops._row_buffer(128, 6, E) == 128
+    assert int(record["rows_per_expert"].sum()) == 96
+    assert int(record["vmem_passes"]) == in_vmem
+    np.testing.assert_allclose(y, want(*args), atol=2e-5)
+    grads = [jax.grad(lambda *a: jnp.sum(f(*a) ** 2), argnums=range(5))(*args)
+             for f in (lambda *a: got(*a)[0], want)]
+    close(grads[0], grads[1])
+
+
+@pytest.mark.parametrize("t,d,backend,tile", [
+    (16384, 2560, "gmm", 1024),             # smallthinker: 10.5 MB of VMEM
+    (8192, 2048, "gmm", 1024),              # joyai: 8.4 MB
+    (16384, 2560, "ragged", None),          # no kernel off the TPU
+    (64, 64, "gmm_interpret", 64),
+    (200, 64, "gmm_interpret", 200),        # tokens no multiple of 8: all
+    (1 << 20, 1024, "gmm", 2048),
+    (6 * 1031, 2560, "gmm", None),          # no whole-sublane divisor fits
+    (4096, 1 << 20, "gmm", None)])          # nor eight rows this wide
+def test_the_form_follows_the_shapes_and_the_backend_alone(t, d, backend,
+                                                           tile):
+    """The sums run in VMEM wherever a tile of them (whole rows, of whole
+    sublanes of tokens that divide ``t``) fits the VMEM they may take;
+    other shapes, and the portable backend, scatter-add."""
+    assert moe_ops._sums_in_vmem(t, d, backend) == (tile is not None)
+    if backend != "ragged":
+        assert moe_ops._sums_tile(t, d) == tile
+    if tile is not None:
+        assert t % tile == 0 and tile * d * 4 <= moe_ops._VMEM_SUMS
+
+
+@pytest.mark.parametrize("rows", [(1, True), (2, False)],
+                         ids=["weighted", "two-arrays"])
+@pytest.mark.parametrize("live", [0, 1, 150, 256])
+def test_the_kernel_adds_the_live_rows_tile_by_tile(live, rows, monkeypatch):
+    """``_add_rows_by_token`` with the sums in four tiles of 16 tokens and
+    the 256 buffer rows in two blocks (tokens ascending in each half, as
+    the sort leaves a group's), so every tile meets both: every live row is
+    added once, in the tile that holds its token; the rows past ``live``
+    hold NaN and stay unread."""
+    arrays, weighted = rows
+    monkeypatch.setattr(moe_ops, "_VMEM_SUMS", 16 * 256 * 4)
+    assert moe_ops._sums_tile(64, 256) == 16
+    acc = rand((64, 256), 0)
+    tokens = jnp.concatenate([jnp.sort(jax.random.randint(
+        jax.random.PRNGKey(i), (128,), 0, 64)) for i in (1, 2)]).astype(
+            jnp.int32)
+    held = (jnp.arange(256) < live)[:, None]
+    buffers = tuple(jnp.where(held, rand((256, 256), 3 + i), jnp.nan).astype(
+        jnp.bfloat16) for i in range(arrays))
+    w = 0.5 + jax.random.uniform(jax.random.PRNGKey(5), (256,))
+    got = moe_ops._add_rows_by_token(acc, tokens, jnp.int32(live), buffers,
+                                     w if weighted else None, True)
+    value = sum(b.astype(jnp.float32) for b in buffers)
+    if weighted:
+        value = value * w[:, None]
+    want = acc.at[tokens].add(jnp.where(held, value, 0.0))
+    np.testing.assert_allclose(got, want, rtol=1e-6, atol=1e-6)
+
+
+@pytest.mark.parametrize("backend,in_vmem", [("gmm_interpret", True),
+                                             ("ragged", False)])
+def test_the_record_and_the_counter_say_which_form_ran(backend, in_vmem):
+    """256 tokens of four choices, 4 of 16 held: one pass, through the
+    kernel under the Pallas interpreter and through XLA's scatter-add on the
+    portable backend."""
+    from bluefog_tpu.metrics import registry
+
+    x, kernel = rand((256, 64), 0), rand((64, E), 1, 0.2)
+    w = _expert_weights(4)
+    assert moe_ops._sums_in_vmem(256, 64, backend) == in_vmem
+    registry.metrics_stop()
+    registry._STOPPED = False
+    reg = registry.metrics_start()
+    try:
+        _, record = jax.jit(lambda x: _routed(
+            x, kernel, jnp.zeros(E), *w, (0, 4), backend))(x)
+        jax.effects_barrier()
+        snap = reg.snapshot()
+    finally:
+        registry.metrics_stop()
+        registry._STOPPED = False
+    assert record["vmem_passes"].dtype == jnp.int32
+    assert int(record["row_passes"]) == snap["bf_moe_row_passes_total"] == 1
+    assert int(record["vmem_passes"]) == snap.get(
+        "bf_moe_vmem_passes_total", 0) == int(in_vmem)
+
+
+@pytest.mark.parametrize("passes", [1, 2])
+def test_what_lies_past_the_last_held_row_never_reaches_a_token(monkeypatch,
+                                                                passes, sums):
+    """The pass's products with NaN planted in every buffer row past the
+    last held one (the grouped matmul on a TPU leaves those rows as it found
+    them): the kernel adds the held rows alone and the scatter-add selects
+    them, so ``y``, ``d_x`` and ``d_weights`` are the dense share's, where a
+    product with a zero weight would be NaN.  256 tokens, ``C`` = 512: one
+    pass with about half the buffer held; two, the second part full."""
+    backend, in_vmem = sums
+    real = moe_ops._grouped_products
+
+    def poisoned(sizes, backend):
+        product, transposes = real(sizes, backend)
+        held = jnp.sum(sizes)
+
+        def poison(rows):
+            past = jnp.arange(rows.shape[0])[:, None] >= held
+            return jnp.where(past, jnp.nan, rows)
+
+        return (lambda rows, w: poison(product(rows, w)),
+                lambda rows, w, g: tuple(
+                    poison(d) if d.ndim == 2 else d
+                    for d in transposes(jnp.nan_to_num(rows), w, g)))
+
+    monkeypatch.setattr(moe_ops, "_grouped_products", poisoned)
+    x, w = rand((256, 64), 0), _expert_weights(4)
+    bias = rand((E,), 2, 0.1)
+    if passes == 2:     # 160 tokens' four choices are the held four
+        bias = jnp.where(jnp.arange(256)[:, None] < 160,
+                         _all_to_the_held_four(), bias)
+    idx, weights = sigmoid_topk_router(x, rand((64, E), 1, 0.2), bias,
+                                       top_k=K)
+
+    def got(x, weights):
+        return routed_experts(x, idx, weights, *w, num_experts=E,
+                              held=(0, 4), backend=backend)
+
+    def want(x, weights):
+        return _dense_from_the_routing(x, idx, weights, *w, 0)
+
+    y, record = got(x, weights)
+    held_rows = int(record["rows_per_expert"].sum())
+    assert int(record["row_passes"]) == passes == -(-held_rows // 512)
+    assert int(record["vmem_passes"]) == (passes if in_vmem else 0)
+    assert held_rows % 512 != 0
+    np.testing.assert_allclose(y, want(x, weights), atol=2e-5)
+    grads = [jax.grad(lambda *a: jnp.sum(f(*a) ** 2), argnums=(0, 1))(
+        x, weights) for f in (lambda *a: got(*a)[0], want)]
+    close(grads[0], grads[1])
 
 
 def _equations(jaxpr):

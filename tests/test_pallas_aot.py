@@ -377,6 +377,65 @@ def test_grouped_matmul_kernels_compile_for_v5e(tpu_aot_topology):
     assert not _re.findall(r"\[65536,\d{2,}\]", txt)
 
 
+def test_the_sums_by_token_hold_no_row_wide_scatter_on_v5e(tpu_aot_topology,
+                                                          monkeypatch):
+    """``routed_experts`` at SmallThinker's published shapes: 16,384 tokens
+    of 2,560 choosing 6 of 64 experts, 16 held of width 768, so a row buffer
+    of 49,152 of the 98,304 sorted rows.  The sums by token run in
+    ``bf_moe_add_rows_by_token``, twice (``y``; ``d_x`` from the gate's and
+    the up projection's rows): the compiled value and gradient holds no
+    scatter of rows (the scalar ``d_weights`` one and the kernels' tile
+    metadata remain), the nine ``gmm`` and three ``tgmm`` it held before,
+    and fewer temporaries than the scatter form at the same shapes (no f32
+    copy of the buffer is made for a scatter to read), which is asked for in
+    turn."""
+    from bluefog_tpu.ops import moe
+
+    one = _one_chip(tpu_aot_topology)
+    assert moe._row_buffer(16384 * 6, 16, 64) == 49152
+    assert moe._sums_in_vmem(16384, 2560, "gmm")
+
+    def shape(dims, dtype):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=one)
+
+    def compiled(in_vmem):
+        monkeypatch.setattr(moe, "_sums_in_vmem", lambda t, d, b: in_vmem)
+
+        # a function of its own a compile: the form is read as it is traced
+        def value_and_grads(x, idx, weights, wg, wu, wd):
+            def total(x, weights, wg, wu, wd):
+                return (moe.routed_experts(
+                    x, idx, weights, wg, wu, wd, num_experts=64,
+                    held=(0, 16), backend="gmm",
+                    activation="relu")[0].astype(jnp.float32) ** 2).sum()
+            return jax.value_and_grad(total, argnums=(0, 1, 2, 3, 4))(
+                x, weights, wg, wu, wd)
+
+        return jax.jit(value_and_grads).lower(
+            shape((16384, 2560), jnp.bfloat16), shape((16384, 6), jnp.int32),
+            shape((16384, 6), jnp.float32),
+            shape((16, 2560, 768), jnp.float32),
+            shape((16, 2560, 768), jnp.float32),
+            shape((16, 768, 2560), jnp.float32)).compile()
+
+    def row_scatters(txt):
+        return [s for s in _re.findall(r"= (\S+) scatter\(", txt)
+                if ",2560]" in s]
+
+    kernel, scatter = compiled(True), compiled(False)
+    txt = kernel.as_text()
+    assert _re.findall(r" scatter\(", txt) and not row_scatters(txt)
+    assert len(row_scatters(scatter.as_text())) == 2
+    assert not _re.findall(r"\[98304,\d{2,}\]", txt)
+    assert "bf16[49152,768]" in txt and "bf16[49152,2560]" in txt
+    assert len(_re.findall(r"%gmm(\.\d+)? = ", txt)) == 3 + 6
+    assert len(_re.findall(r"%tgmm(\.\d+)? = ", txt)) == 3
+    assert len(_re.findall(r"%bf_moe_add_rows_by_token(\.\d+)? = ",
+                           txt)) == 2
+    assert (kernel.memory_analysis().temp_size_in_bytes
+            < scatter.memory_analysis().temp_size_in_bytes)
+
+
 def test_selective_scan_kernels_compile_for_v5e(tpu_aot_topology):
     """The selective-scan kernels at the published Mamba mixer: 8,192
     tokens, 5,120 channels in blocks of 1,024, 16 states, bf16 ``x`` beside
