@@ -324,11 +324,15 @@ class GPTConfig:
                          num_heads=4, max_position=512, dtype=jnp.float32)
 
 
-def _norm(cfg: GPTConfig, name: str) -> nn.Module:
-    """The configuration's norm, computing and returning f32."""
-    if cfg.norm == "rmsnorm":
-        return nn.RMSNorm(epsilon=cfg.norm_eps, dtype=jnp.float32, name=name)
-    return nn.LayerNorm(epsilon=cfg.norm_eps, dtype=jnp.float32, name=name)
+def _norm(cfg: GPTConfig, name: str, x, dtype=jnp.float32):
+    """The configuration's norm ``name`` of ``x``, computed in f32 and
+    returned in ``dtype``, under the layer scope ``bf.block.norm`` (the
+    cast with it: where XLA makes it a fusion's root, the fusion is the
+    norm's)."""
+    norm = nn.RMSNorm if cfg.norm == "rmsnorm" else nn.LayerNorm
+    with jax.named_scope("bf.block.norm"):
+        return norm(epsilon=cfg.norm_eps, dtype=jnp.float32, name=name)(
+            x).astype(dtype)
 
 
 _LANES = 128     # of a TPU tile: a head this wide fills a matmul's output
@@ -775,7 +779,10 @@ class RoutedFFN(nn.Module):
         for name, value in record.items():
             self.sow("moe_metrics", name, value)
         routed = routed.reshape(y.shape)
-        return routed if self.shared is None else self.shared(y) + routed
+        if self.shared is None:
+            return routed
+        with jax.named_scope("bf.mlp.dense"):
+            return self.shared(y) + routed
 
 
 def _mix(block, y, attn_fn, carried):
@@ -816,18 +823,21 @@ def _feed_forward(block, x, moe=None, routing=None):
     """``x + FFN(norm(x))`` of the block's feed-forward kind; ``moe`` and
     ``routing`` as :func:`_early_routing` gave them."""
     cfg = block.cfg
-    y = _norm(cfg, "ln2")(x).astype(cfg.dtype)
+    y = _norm(cfg, "ln2", x, cfg.dtype)
     ffn = block.ffn or cfg.ffn
     if block.mlp is not None:
         return x + block.mlp()(y)
     if ffn == ROUTED:
         return x + moe(y, routing)
     width = cfg.ffn_width or cfg.mlp_ratio * cfg.hidden_size
-    if ffn == "swiglu":
-        return x + GatedMLP(width, cfg.dtype, name="mlp")(y)
-    y = nn.Dense(width, dtype=cfg.dtype, name="up")(y)
-    y = nn.gelu(y)
-    return x + nn.Dense(cfg.hidden_size, dtype=cfg.dtype, name="down")(y)
+    with jax.named_scope("bf.mlp.dense"):
+        if ffn == "swiglu":
+            y = GatedMLP(width, cfg.dtype, name="mlp")(y)
+        else:
+            y = nn.Dense(width, dtype=cfg.dtype, name="up")(y)
+            y = nn.gelu(y)
+            y = nn.Dense(cfg.hidden_size, dtype=cfg.dtype, name="down")(y)
+    return x + y
 
 
 class Block(nn.Module):
@@ -862,7 +872,7 @@ class Block(nn.Module):
     @nn.compact
     def __call__(self, x, attn_fn: AttnFn, positions=None, carried=None):
         cfg = self.cfg
-        y = _norm(cfg, "ln1")(x).astype(cfg.dtype)
+        y = _norm(cfg, "ln1", x, cfg.dtype)
         if self.mixer in MIXERS:
             a, carried = _mix(self, y, attn_fn, carried)
             return _feed_forward(self, x + a), carried
@@ -874,10 +884,13 @@ class Block(nn.Module):
                 y, attn_fn, positions)
         else:
             heads = (cfg.num_heads, cfg.hidden_size // cfg.num_heads)
-            q, k, v = HeadDense(3 * cfg.hidden_size, heads, parts=3,
-                                dtype=cfg.dtype, name="qkv")(y)
-            a = HeadDense(cfg.hidden_size, heads, inward=True,
-                          dtype=cfg.dtype, name="proj")(attn_fn(q, k, v))
+            with jax.named_scope("bf.attn.project"):
+                q, k, v = HeadDense(3 * cfg.hidden_size, heads, parts=3,
+                                    dtype=cfg.dtype, name="qkv")(y)
+            a = attn_fn(q, k, v)
+            with jax.named_scope("bf.attn.project"):
+                a = HeadDense(cfg.hidden_size, heads, inward=True,
+                              dtype=cfg.dtype, name="proj")(a)
         return _feed_forward(self, x + a, moe, routing)
 
 
@@ -917,10 +930,11 @@ class TransformerLM(nn.Module):
         # where a rank holds a front chunk and its mirrored back chunk)
         embed = nn.Embed(cfg.vocab_size, cfg.hidden_size, dtype=cfg.dtype,
                          name="tok")
-        x = embed(tokens)
-        if cfg.position == "learned":
-            x = x + nn.Embed(cfg.max_position, cfg.hidden_size,
-                             dtype=cfg.dtype, name="pos")(positions)
+        with jax.named_scope("bf.embed.lookup"):
+            x = embed(tokens)
+            if cfg.position == "learned":
+                x = x + nn.Embed(cfg.max_position, cfg.hidden_size,
+                                 dtype=cfg.dtype, name="pos")(positions)
         block_cls = nn.remat(Block, static_argnums=(2,)) if cfg.remat else Block
         dense_blocks = cfg.experts.first_dense if cfg.experts else 0
         carried = (None, None, None)     # memory, keys, values
@@ -937,24 +951,31 @@ class TransformerLM(nn.Module):
                           else None,
                           name=f"block_{i}")(x, attn_fn, positions)
         if cfg.tie_head:
-            def head(h):    # f32 logits from the f32 leaf, as lm_head's
+            def project(h):    # f32 logits from the f32 leaf, as lm_head's
                 return jnp.einsum("...d,vd->...v", h, embed.embedding)
         else:
-            head = nn.Dense(cfg.vocab_size, dtype=jnp.float32,
-                            use_bias=False, name="lm_head")
-        logits = head(_norm(cfg, "ln_f")(x))
+            project = nn.Dense(cfg.vocab_size, dtype=jnp.float32,
+                               use_bias=False, name="lm_head")
+
+        def head(h):
+            with jax.named_scope("bf.head.logits"):
+                return project(h)
+
+        logits = head(_norm(cfg, "ln_f", x))
         if next_tokens is None:
             return logits
         if not cfg.mtp_depth:
             raise ValueError("next_tokens needs cfg.mtp_depth == 1")
-        merged = jnp.concatenate(
-            [_norm(cfg, "mtp_enorm")(embed(next_tokens)),
-             _norm(cfg, "mtp_hnorm")(x)], axis=-1).astype(cfg.dtype)
-        z = nn.Dense(cfg.hidden_size, use_bias=False, dtype=cfg.dtype,
-                     name="mtp_proj")(merged)
+        with jax.named_scope("bf.embed.mtp_merge"):
+            e = embed(next_tokens)
+        e, h = _norm(cfg, "mtp_enorm", e), _norm(cfg, "mtp_hnorm", x)
+        with jax.named_scope("bf.embed.mtp_merge"):
+            merged = jnp.concatenate([e, h], axis=-1).astype(cfg.dtype)
+            z = nn.Dense(cfg.hidden_size, use_bias=False, dtype=cfg.dtype,
+                         name="mtp_proj")(merged)
         z = block_cls(cfg, mlp=self.mlp, name="mtp_block")(
             z, attn_fn, positions)
-        return logits, head(_norm(cfg, "mtp_norm")(z))
+        return logits, head(_norm(cfg, "mtp_norm", z))
 
 
 def next_token_loss(model: TransformerLM, params, model_state, tokens, *,
@@ -971,8 +992,9 @@ def next_token_loss(model: TransformerLM, params, model_state, tokens, *,
     variables = {"params": params, **model_state}
 
     def cross_entropy(logits, targets):
-        return optax.softmax_cross_entropy_with_integer_labels(
-            logits.astype(jnp.float32), targets).mean()
+        with jax.named_scope("bf.head.loss"):
+            return optax.softmax_cross_entropy_with_integer_labels(
+                logits.astype(jnp.float32), targets).mean()
 
     if not depth:
         logits = model.apply(variables, tokens[:, :t], attn_fn=attn_fn)

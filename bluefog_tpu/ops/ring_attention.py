@@ -229,6 +229,19 @@ def local_attention(q, k, v, *, causal: bool = False, scale: Optional[float] = N
     if window is not None and (not causal or window < 1):
         raise ValueError(f"window={window} needs causal=True and at least "
                          "one key")
+    # one layer scope around the kernel and the XLA ops it needs (head
+    # repeats, q * scale, transposes; in the backward pass di, the sum of
+    # the dq partials, the residual slice): the kernels keep their own names
+    with jax.named_scope("bf.attn.kernel" if window is None
+                         else "bf.attn.window_kernel"):
+        return _attend(q, k, v, causal=causal, scale=scale,
+                       q_offset=q_offset, k_offset=k_offset, backend=backend,
+                       window=window)
+
+
+def _attend(q, k, v, *, causal, scale, q_offset, k_offset, backend, window):
+    """:func:`local_attention` on checked arguments."""
+    heads = q.shape[2]
     k, v = _repeat_heads(k, heads), _repeat_heads(v, heads)
     eligible = _flash_eligible(q, k, causal, q_offset, k_offset, v)
     if backend == "flash" and not eligible:

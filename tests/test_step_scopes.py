@@ -167,3 +167,165 @@ def test_expert_layer_scopes_in_both_rules_and_never_nested(backend):
         (backward if "transpose(" in name else forward).update(scopes)
     want = {"bf.moe.dispatch", "bf.moe.experts", "bf.moe.combine"}
     assert forward == backward == want
+
+
+# ---- the layer scopes of the decoder step -----------------------------------
+
+LAYER_SCOPE = re.compile(
+    r"bf\.(?:embed|block|attn|mla|mlp|moe|ssm|gmu|head)\.\w+")
+# instructions that do a layer's work; what XLA fuses keeps its op_name
+# inside the fused computation, so the whole text is read
+HEAVY = re.compile(
+    r" (dot|convolution|gather|scatter|reduce|custom-call)\(")
+PHASE_OF = {
+    "bf.embed.lookup": "embed", "bf.embed.mtp_merge": "embed",
+    "bf.block.norm": "norm", "bf.mlp.dense": "mlp",
+    "bf.attn.kernel": "attention_wrap",
+    "bf.attn.window_kernel": "attention_wrap",
+    "bf.attn.project": "attention_project",
+    "bf.attn.rotary": "attention_project",
+    "bf.attn.diff": "attention_project",
+    "bf.mla.project": "attention_project",
+    "bf.head.logits": "head_loss", "bf.head.loss": "head_loss",
+    "bf.moe.route": "expert_dispatch", "bf.moe.dispatch": "expert_dispatch",
+    "bf.moe.combine": "expert_dispatch", "bf.moe.experts": "expert_ffn",
+    "bf.ssm.scan": "ssm_scan", "bf.ssm.project": "ssm_mix",
+    "bf.ssm.conv": "ssm_mix", "bf.gmu.gate": "ssm_mix"}
+TRUNK = {"bf.embed.lookup", "bf.block.norm", "bf.attn.kernel",
+         "bf.head.logits", "bf.head.loss"}
+MOE = {"bf.moe.route", "bf.moe.dispatch", "bf.moe.experts", "bf.moe.combine"}
+
+
+def family_config(family):
+    """A tiny configuration of each family on the one decoder path, and the
+    layer scopes its step opens."""
+    from bluefog_tpu.models.transformer import (
+        ExpertSizes, GPTConfig, GroupedSizes, HybridSizes, LatentSizes)
+
+    tiny = dict(vocab_size=96, hidden_size=64, dtype=jnp.float32)
+    if family == "fused_qkv":
+        return dict(tiny, num_layers=2, num_heads=4, max_position=64), (
+            TRUNK | {"bf.attn.project", "bf.mlp.dense"})
+    if family == "latent_moe":
+        return dict(
+            tiny, num_layers=2, num_heads=4, attention="latent",
+            ffn="routed+shared", norm="rmsnorm", position="rotary",
+            ffn_width=96, mtp_depth=1,
+            latent=LatentSizes(q_lora_rank=48, kv_lora_rank=32,
+                               qk_nope_head_dim=16, qk_rope_head_dim=8,
+                               v_head_dim=16),
+            experts=ExpertSizes(num_experts=16, top_k=4, width=32,
+                                held=(4, 8), first_dense=1)), (
+            TRUNK | MOE | {"bf.mla.project", "bf.mlp.dense",
+                           "bf.embed.mtp_merge"})
+    if family == "sambay":
+        return dict(
+            tiny, num_layers=6, num_heads=8, ffn="swiglu", position="none",
+            ffn_width=96, norm_eps=1e-5, tie_head=True,
+            layer_types=("mamba", "diff_attention_window", "mamba",
+                         "diff_attention", "gmu", "cross_diff_attention"),
+            hybrid=HybridSizes(d_inner=128, d_state=4, d_conv=4, dt_rank=4,
+                               kv_heads=4, window=5, first_layer=14)), (
+            TRUNK | {"bf.attn.window_kernel", "bf.attn.project",
+                     "bf.attn.diff", "bf.mlp.dense", "bf.ssm.project",
+                     "bf.ssm.conv", "bf.ssm.scan", "bf.gmu.gate"})
+    return dict(
+        tiny, num_layers=2, num_heads=6, attention="grouped_query",
+        ffn="routed+shared", norm="rmsnorm", position="none",
+        layer_types=("full_attention", "window_rotary_attention"),
+        grouped=GroupedSizes(kv_heads=2, head_dim=16, window=7,
+                             rope_theta=1e4),
+        experts=ExpertSizes(num_experts=8, top_k=3, width=32, num_shared=0,
+                            scale=1.0, held=(2, 4), first_dense=0,
+                            router="softmax_topk", activation="relu",
+                            router_input="block")), (
+        TRUNK | MOE | {"bf.attn.window_kernel", "bf.attn.project",
+                       "bf.attn.rotary"})
+
+
+@functools.lru_cache(maxsize=None)
+def compiled_loss_and_gradient(family, remat):
+    """The compiled text of ``next_token_loss`` and its gradient."""
+    from bluefog_tpu.models.transformer import (
+        GPTConfig, TransformerLM, next_token_loss)
+
+    sizes, opens = family_config(family)
+    cfg = GPTConfig(remat=remat, **sizes)
+    model = TransformerLM(cfg)
+    tokens = jnp.zeros((2, 16 + 1 + cfg.mtp_depth), jnp.int32)
+    # shapes alone: an eager init costs more than the compile
+    state = jax.eval_shape(lambda: model.init(
+        jax.random.PRNGKey(0), tokens[:, :16],
+        **({"next_tokens": tokens[:, 1:17]} if cfg.mtp_depth else {})))
+    params = state.pop("params")
+
+    def loss(params, state):
+        return next_token_loss(model, params, state, tokens, mtp_weight=0.1)
+
+    return jax.jit(jax.value_and_grad(loss)).lower(
+        params, state).compile().as_text(), opens
+
+
+def pass_of(op_name):
+    if "rematted_computation" in op_name:
+        return "recompute"
+    return "backward" if "transpose(" in op_name else "forward"
+
+
+FAMILIES = pytest.mark.parametrize(
+    "family", ["fused_qkv", "latent_moe", "sambay", "gqa_moe"])
+REMAT = pytest.mark.parametrize("remat", [False, True],
+                                ids=["saved", "remat"])
+
+
+@REMAT
+@FAMILIES
+def test_every_heavy_op_of_the_decoder_step_is_under_one_layer_scope(
+        family, remat):
+    """(a) Every dot, convolution, gather, scatter, reduction and custom
+    call traced under ``TransformerLM`` or ``next_token_loss`` carries a
+    layer scope, in the forward, the backward and the recomputed pass alike:
+    a module that lands without one fails here.  (b) No op carries two (the
+    layers would not add up); ``bf.neighbor_allreduce.slot{k}`` inside
+    ``bf.gossip.exchange`` is a Perfetto aid outside this pattern."""
+    text, opens = compiled_loss_and_gradient(family, remat)
+    found = {"forward": set(), "backward": set(), "recompute": set()}
+    for line in text.splitlines():
+        named = re.search(r'op_name="([^"]*)"', line)
+        if named is None:
+            continue
+        for one_op in named.group(1).split(";"):
+            scopes = set(LAYER_SCOPE.findall(one_op))
+            assert len(scopes) <= 1, one_op
+            found[pass_of(one_op)] |= scopes
+        if HEAVY.search(line):
+            assert LAYER_SCOPE.search(named.group(1)), line.strip()[:400]
+    assert found["forward"] | found["backward"] | found["recompute"] == opens
+    assert found["backward"] >= opens - {"bf.moe.route"}
+    assert bool(found["recompute"]) == remat
+
+
+@REMAT
+@FAMILIES
+def test_every_layer_scope_falls_to_its_phase_of_the_layer_table(family,
+                                                                 remat):
+    """(c) Read as the benchmark reads it (``chipbench/reducers/scope_ms.py``
+    with ``phases/step_layers.json``): an instruction under a layer scope
+    falls to the phase PHASE_OF names, and none to ``other``."""
+    import sys
+
+    from tests._util import REPO
+    if REPO not in sys.path:
+        sys.path.insert(0, REPO)
+    from chipbench.reducers import scope_ms
+
+    text, opens = compiled_loss_and_gradient(family, remat)
+    program = scope_ms.Program(text, scope_ms.load_rules("step_layers"))
+    phases = {}
+    for name in program.lines:
+        # an op XLA merged from two layers' ops carries both names and goes
+        # to the earlier row: not a question this test asks
+        scopes = set(LAYER_SCOPE.findall(program.op_name(name) or ""))
+        if len(scopes) == 1:
+            phases.setdefault(scopes.pop(), set()).add(program.phase(name))
+    assert phases == {scope: {PHASE_OF[scope]} for scope in opens}
