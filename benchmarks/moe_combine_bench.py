@@ -3,7 +3,7 @@ shapes of the two cells that run ``ops/moe.py::routed_experts``: the sums by
 token (``y`` forward, ``d_x`` backward) as XLA's **scatter-adds** of the
 pass's ``C`` sorted rows against the **Pallas kernel** that keeps the sums in
 VMEM a chunk of columns at a time and adds the live rows as they stream
-through (``ops/moe.py::_add_rows_by_token``).
+through (``ops/row_sums.py::add_rows_at``).
 
 ``smallthinker``: 16,384 tokens of 2,560, 6 of 64 experts, 16 held of width
 768, ReGLU, routing weights constant in the backward pass: ``C`` = 49,152 of
@@ -79,8 +79,21 @@ def layer(name, backend):
     return step, (x, weights, *experts), moe._row_buffer(t * k, count, e)
 
 
-def device_times(step, args, trace_dir):
-    """Device ms a call by scope and by instruction, or ``None`` where the
+def wall_times(step, args):
+    """The three fastest of six wall times of a call, ms."""
+    import jax
+
+    wall = []
+    for _ in range(6):
+        start = time.perf_counter()
+        jax.block_until_ready(step(*args))
+        wall.append((time.perf_counter() - start) * 1e3)
+    return sorted(wall)[:3]
+
+
+def device_times(step, args, trace_dir, scope_of=SCOPE):
+    """Device ms a call by scope (the last match of ``scope_of`` in an
+    instruction's ``op_name``) and by instruction, or ``None`` where the
     trace holds no device lane (a CPU)."""
     import jax
 
@@ -102,7 +115,7 @@ def device_times(step, args, trace_dir):
     scopes, instructions = collections.Counter(), collections.Counter()
     for events in trace.lanes.values():
         for name, ns in xplane.self_times(events):
-            found = SCOPE.findall(program.op_name(name) or "")
+            found = scope_of.findall(program.op_name(name) or "")
             scope = found[-1] if found else "(none)"
             ms = ns / 1e6 / TRACED_CALLS
             scopes[scope] += ms
@@ -119,7 +132,7 @@ def main(argv=None):
     args = ap.parse_args(argv)
     import jax
 
-    from bluefog_tpu.ops import moe
+    from bluefog_tpu.ops import moe, row_sums
 
     out = {"platform": jax.devices()[0].platform,
            "device_kind": jax.devices()[0].device_kind}
@@ -137,11 +150,7 @@ def main(argv=None):
                 try:
                     step, operands, c = layer(name, backend)
                     (_, record), _ = jax.block_until_ready(step(*operands))
-                    wall = []
-                    for _ in range(6):
-                        start = time.perf_counter()
-                        jax.block_until_ready(step(*operands))
-                        wall.append((time.perf_counter() - start) * 1e3)
+                    wall = wall_times(step, operands)
                     device_ms = device_times(step, operands, trace_dir)
                 finally:
                     moe._sums_in_vmem = chosen
@@ -149,10 +158,10 @@ def main(argv=None):
                     0 if form == "scatter" else int(record["row_passes"]))
                 entry = {"row_buffer": c,
                          "sums_tile": (None if form == "scatter" else
-                                       moe._sums_tile(*operands[0].shape)),
+                                       row_sums.sums_tile(*operands[0].shape)),
                          "held_rows": int(record["rows_per_expert"].sum()),
                          "row_passes": int(record["row_passes"]),
-                         "wall_ms": sorted(wall)[:3], "device_ms": device_ms}
+                         "wall_ms": wall, "device_ms": device_ms}
                 out[f"{name}.{form}"] = entry
                 print(name, form, json.dumps(entry), flush=True)
     finally:

@@ -82,6 +82,7 @@ from bluefog_tpu.metrics import comm as metrics_comm
 from bluefog_tpu.ops.moe import (
     ACTIVATIONS, routed_experts, sigmoid_topk_router, softmax_topk_router)
 from bluefog_tpu.ops.ring_attention import local_attention
+from bluefog_tpu.ops.row_sums import take_rows
 from bluefog_tpu.ops.selective_scan import selective_scan
 
 AttnFn = Callable[..., jnp.ndarray]  # (q, k, v) -> (B, T, H, D)
@@ -931,7 +932,7 @@ class TransformerLM(nn.Module):
         embed = nn.Embed(cfg.vocab_size, cfg.hidden_size, dtype=cfg.dtype,
                          name="tok")
         with jax.named_scope("bf.embed.lookup"):
-            x = embed(tokens)
+            x = take_rows(embed.embedding, tokens, cfg.dtype)
             if cfg.position == "learned":
                 x = x + nn.Embed(cfg.max_position, cfg.hidden_size,
                                  dtype=cfg.dtype, name="pos")(positions)
@@ -967,7 +968,7 @@ class TransformerLM(nn.Module):
         if not cfg.mtp_depth:
             raise ValueError("next_tokens needs cfg.mtp_depth == 1")
         with jax.named_scope("bf.embed.mtp_merge"):
-            e = embed(next_tokens)
+            e = take_rows(embed.embedding, next_tokens, cfg.dtype)
         e, h = _norm(cfg, "mtp_enorm", e), _norm(cfg, "mtp_hnorm", x)
         with jax.named_scope("bf.embed.mtp_merge"):
             merged = jnp.concatenate([e, h], axis=-1).astype(cfg.dtype)

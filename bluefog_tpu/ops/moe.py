@@ -47,6 +47,7 @@ import jax.numpy as jnp
 from jax import lax
 
 from bluefog_tpu.metrics import comm as metrics_comm
+from bluefog_tpu.ops import row_sums
 
 __all__ = [
     "RouterOutput",
@@ -410,153 +411,19 @@ def _window(j, weights, order, ends, k, c):
     return ids, ids // k, sizes, live, w
 
 
-_VMEM_SUMS = 21 * 2 ** 19     # 10.5 MB of the sums in VMEM a kernel step
-
-
-def _sums_tile(t: int, d: int):
-    """Tokens of the ``(t, d)`` f32 sums that :func:`_add_rows_by_token`
-    holds in VMEM at a time, whole rows of them, or ``None`` where no tile
-    fits: the most that divide ``t`` in whole sublanes of 8 (or all ``t``)
-    within ``_VMEM_SUMS`` (1,024 of 16,384 x 2,560 and of 8,192 x 2,048).
-    **10.5 MB, so that the kernel stays within the 16 MB of VMEM every
-    kernel may take unasked**: XLA keeps the layer's input, 84 MB at 16,384
-    x 2,560 bf16, in VMEM across the passes, where the gather of its rows
-    takes 0.39 ms against 2.0 from HBM, and a kernel that asks for more
-    evicts it (PERF.md section 6, PR 35)."""
-    heights = [t] + [ts for ts in range(8, t, 8) if t % ts == 0]
-    fits = [ts for ts in heights if ts * d * 4 <= _VMEM_SUMS]
-    return max(fits, default=None)
-
-
 def _sums_in_vmem(t: int, d: int, backend: str) -> bool:
     """Whether a pass's rows reach their tokens through
-    :func:`_add_rows_by_token` (on a TPU, wherever :func:`_sums_tile` finds
-    a tile) or through XLA's scatter-add (the portable ``'ragged'``
-    backend, and shapes the kernel cannot tile)."""
-    return backend != "ragged" and _sums_tile(t, d) is not None
+    :func:`row_sums.add_rows_at` (on a TPU, wherever
+    :func:`row_sums.sums_tile` finds a tile) or through XLA's scatter-add
+    (the portable ``'ragged'`` backend, and shapes the kernel cannot
+    tile)."""
+    return backend != "ragged" and row_sums.sums_tile(t, d) is not None
 
 
-def _add_rows_by_token(acc, tokens, live, rows, w, interpret):
-    """``acc (T, D)`` f32 with the first ``live`` rows of the buffer added
-    at their tokens: ``acc[tokens[r]] += w[r] * (rows[0][r] + rows[1][r] +
-    ..)`` in f32, in the buffer's order.  ``tokens (c,)`` int32, ``rows``
-    one or two ``(c, D)`` arrays, ``w (c,)`` f32 or ``None``.
-
-    A Pallas kernel in place of XLA's scatter-add, which moves a row in
-    160 ns whatever it holds (PERF.md section 6, PR 35).  A step holds the
-    sums of ``ts`` tokens (:func:`_sums_tile`) in VMEM and reads the blocks
-    of ``rb`` buffer rows that hold a token of its tile, one while the one
-    before is summed: each live row of the tile is added to its token's by
-    one read-modify-write of a sublane.  The sort leaves a group's rows in
-    token order, so a tile meets a few blocks of every group and no more;
-    which, XLA lists beforehand from each block's first and last token.
-    Blocks past ``live`` are neither read nor touched, whatever they
-    hold."""
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
-
-    t, d = acc.shape
-    c = tokens.shape[0]
-    ts = _sums_tile(t, d)
-    tiles = t // ts
-    rb = 128
-    while c % rb:
-        rb //= 2
-    if rb < 16:
-        rb = c
-    blocks = c // rb
-    weighted = w is not None
-    # every tile's blocks: those with a live row whose token it holds
-    held = jnp.arange(c, dtype=jnp.int32) < live
-    first = jnp.where(held, tokens, t).reshape(blocks, rb).min(axis=1)
-    last = jnp.where(held, tokens, -1).reshape(blocks, rb).max(axis=1)
-    edges = jnp.arange(tiles, dtype=jnp.int32)[:, None] * ts
-    meets = (first < edges + ts) & (last >= edges)          # (tiles, blocks)
-    todo = jnp.argsort(~meets, axis=1, stable=True).astype(jnp.int32)
-    scalars = (live.reshape(1), meets.sum(axis=1, dtype=jnp.int32),
-               todo.reshape(-1), tokens) + ((w,) if weighted else ())
-
-    def kernel(*refs):
-        live_ref, count_ref, todo_ref, tok_ref = refs[:4]
-        w_ref = refs[4] if weighted else None
-        acc_ref, *rows_refs = refs[len(scalars):-5]
-        out_ref, sums, stage, buf, sems = refs[-5:]
-        tile = pl.program_id(0)
-        base = tile * ts
-        mine = pl.ds(pl.multiple_of(base, 8) if ts % 8 == 0 else base, ts)
-        count = count_ref[tile]
-
-        def first_row(j):       # of the tile's j-th block
-            return todo_ref[tile * blocks + j] * rb
-
-        def fetch(j, slot):
-            at = first_row(j)
-            at = pl.ds(pl.multiple_of(at, rb) if rb % 16 == 0 else at, rb)
-            return [pltpu.make_async_copy(ref.at[at], stage.at[a, slot],
-                                          sems.at[a, slot])
-                    for a, ref in enumerate(rows_refs)]
-
-        @pl.when(count > 0)
-        def _():
-            for copy in fetch(0, 0):
-                copy.start()
-            own = pltpu.make_async_copy(acc_ref.at[mine], sums, sems.at[0, 2])
-            own.start()
-            own.wait()
-
-            def one_block(j, carry):
-                slot = j % 2
-
-                @pl.when(j + 1 < count)
-                def _():
-                    for copy in fetch(j + 1, 1 - slot):
-                        copy.start()
-
-                for copy in fetch(j, slot):
-                    copy.wait()
-                total = stage[0, slot].astype(jnp.float32)
-                for a in range(1, len(rows_refs)):
-                    total = total + stage[a, slot].astype(jnp.float32)
-                buf[...] = total
-                start = first_row(j)
-
-                def one_row(i, carry):
-                    u = tok_ref[start + i] - base
-
-                    @pl.when((u >= 0) & (u < ts))
-                    def _():
-                        row = buf[pl.ds(i, 1), :]
-                        if weighted:
-                            row = row * w_ref[start + i]
-                        sums[pl.ds(u, 1), :] = sums[pl.ds(u, 1), :] + row
-                    return carry
-
-                lax.fori_loop(0, jnp.clip(live_ref[0] - start, 0, rb),
-                              one_row, jnp.int32(0))
-                return carry
-
-            lax.fori_loop(0, count, one_block, jnp.int32(0))
-            back = pltpu.make_async_copy(sums, out_ref.at[mine],
-                                         sems.at[0, 2])
-            back.start()
-            back.wait()
-
-    return pl.pallas_call(
-        kernel,
-        grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=len(scalars), grid=(tiles,),
-            in_specs=[pl.BlockSpec(memory_space=pl.ANY)] * (1 + len(rows)),
-            out_specs=pl.BlockSpec(memory_space=pl.ANY),
-            scratch_shapes=[pltpu.VMEM((ts, d), jnp.float32),
-                            pltpu.VMEM((len(rows), 2, rb, d), rows[0].dtype),
-                            pltpu.VMEM((rb, d), jnp.float32),
-                            pltpu.SemaphoreType.DMA((len(rows), 3))]),
-        out_shape=jax.ShapeDtypeStruct(acc.shape, jnp.float32),
-        input_output_aliases={len(scalars): 0},
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("arbitrary",)),
-        interpret=interpret,
-        name="bf_moe_add_rows_by_token")(*scalars, acc, *rows)
+# ``acc[tokens[r]] += w[r] * (rows[0][r] + ..)`` for the first ``live`` rows of
+# the buffer, under the expert layer's name in the trace
+_add_rows_by_token = functools.partial(row_sums.add_rows_at,
+                                       name="bf_moe_add_rows_by_token")
 
 
 def _cast_experts(x, *ws):
@@ -587,8 +454,9 @@ def _held_experts(x, weights, order, ends, w_gate, w_up, w_down, k, c,
                                 product(rows, wu)), wd)
         with jax.named_scope("bf.moe.combine"):
             if in_vmem:
-                return _add_rows_by_token(y, tokens, sizes.sum(), (out,), w,
-                                          backend == "gmm_interpret")
+                return _add_rows_by_token(
+                    y, tokens, sizes.sum(), (out,), w,
+                    interpret=backend == "gmm_interpret")
             return y.at[tokens].add(jnp.where(
                 live[:, None], out.astype(jnp.float32) * w[:, None], 0.0))
 
@@ -640,7 +508,7 @@ def _held_experts_bwd(k, c, backend, activation, res, g):
             if in_vmem:
                 d_x = _add_rows_by_token(
                     d_x, tokens, sizes.sum(), (d_rows_gate, d_rows_up), None,
-                    backend == "gmm_interpret")
+                    interpret=backend == "gmm_interpret")
             else:
                 d_x = d_x.at[tokens].add(jnp.where(
                     live[:, None], d_rows_gate.astype(jnp.float32)

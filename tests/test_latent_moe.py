@@ -438,18 +438,20 @@ def test_one_expert_a_token_loss_and_gradients(sums):
     (64, 64, "gmm_interpret", 64),
     (200, 64, "gmm_interpret", 200),        # tokens no multiple of 8: all
     (1 << 20, 1024, "gmm", 2048),
-    (6 * 1031, 2560, "gmm", None),          # no whole-sublane divisor fits
+    (6 * 1031, 2560, "gmm", None),          # tokens no multiple of 8: no tile
     (4096, 1 << 20, "gmm", None)])          # nor eight rows this wide
 def test_the_form_follows_the_shapes_and_the_backend_alone(t, d, backend,
                                                            tile):
-    """The sums run in VMEM wherever a tile of them (whole rows, of whole
-    sublanes of tokens that divide ``t``) fits the VMEM they may take;
-    other shapes, and the portable backend, scatter-add."""
+    """The sums run in VMEM wherever a tile of them (whole rows, a power of
+    two of them or all ``t``: ``ops/row_sums.py::sums_tile``) fits the VMEM
+    they may take; other shapes, and the portable backend, scatter-add.  At
+    these token counts the tile divides ``t``, as it did before the last
+    tile could be short."""
     assert moe_ops._sums_in_vmem(t, d, backend) == (tile is not None)
     if backend != "ragged":
-        assert moe_ops._sums_tile(t, d) == tile
+        assert moe_ops.row_sums.sums_tile(t, d) == tile
     if tile is not None:
-        assert t % tile == 0 and tile * d * 4 <= moe_ops._VMEM_SUMS
+        assert t % tile == 0 and tile * d * 4 <= moe_ops.row_sums._VMEM_SUMS
 
 
 @pytest.mark.parametrize("rows", [(1, True), (2, False)],
@@ -462,8 +464,8 @@ def test_the_kernel_adds_the_live_rows_tile_by_tile(live, rows, monkeypatch):
     added once, in the tile that holds its token; the rows past ``live``
     hold NaN and stay unread."""
     arrays, weighted = rows
-    monkeypatch.setattr(moe_ops, "_VMEM_SUMS", 16 * 256 * 4)
-    assert moe_ops._sums_tile(64, 256) == 16
+    monkeypatch.setattr(moe_ops.row_sums, "_VMEM_SUMS", 16 * 256 * 4)
+    assert moe_ops.row_sums.sums_tile(64, 256) == 16
     acc = rand((64, 256), 0)
     tokens = jnp.concatenate([jnp.sort(jax.random.randint(
         jax.random.PRNGKey(i), (128,), 0, 64)) for i in (1, 2)]).astype(
@@ -473,7 +475,7 @@ def test_the_kernel_adds_the_live_rows_tile_by_tile(live, rows, monkeypatch):
         jnp.bfloat16) for i in range(arrays))
     w = 0.5 + jax.random.uniform(jax.random.PRNGKey(5), (256,))
     got = moe_ops._add_rows_by_token(acc, tokens, jnp.int32(live), buffers,
-                                     w if weighted else None, True)
+                                     w if weighted else None, interpret=True)
     value = sum(b.astype(jnp.float32) for b in buffers)
     if weighted:
         value = value * w[:, None]

@@ -436,6 +436,123 @@ def test_the_sums_by_token_hold_no_row_wide_scatter_on_v5e(tpu_aot_topology,
             < scatter.memory_analysis().temp_size_in_bytes)
 
 
+@pytest.mark.parametrize("ids,rows,width,tiles", [
+    (8192, 25008, 2560, 25),        # phi-4-mini-flash's slice
+    (16384, 18992, 2560, 19),       # smallthinker's
+    (8192, 16160, 2048, 16),        # joyai's
+    (16384, 50304, 768, 0)])        # gpt2-small's
+def test_the_lookup_s_gradient_holds_no_row_wide_scatter_on_v5e(
+        ids, rows, width, tiles, tpu_aot_topology, monkeypatch):
+    """``take_rows`` at the decoder cells' tables, as a TPU takes it (the
+    backend alone is patched: the rule reads the shapes).  From 2,048 columns
+    on the compiled gradient holds one ``bf_embed_add_rows_by_id`` (the
+    short last tile compiles), no scatter and no f32 zero table for the
+    kernel to read, and the ids' sort; GPT-2's narrower table keeps
+    ``jnp.take``'s scatter-add and no kernel (``tiles`` 0)."""
+    from bluefog_tpu.ops import row_sums
+
+    one = _one_chip(tpu_aot_topology)
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    assert row_sums._lookup_form(rows, width) == (
+        "vmem" if tiles else "scatter")
+
+    def gradient(table, at, probe):
+        def total(table):
+            with jax.named_scope("bf.embed.lookup"):
+                x = row_sums.take_rows(table, at, jnp.bfloat16)
+            return (x * probe).astype(jnp.float32).sum()
+        return jax.grad(total)(table)
+
+    txt = jax.jit(gradient).lower(
+        jax.ShapeDtypeStruct((rows, width), jnp.float32, sharding=one),
+        jax.ShapeDtypeStruct((1, ids), jnp.int32, sharding=one),
+        jax.ShapeDtypeStruct((1, ids, width), jnp.bfloat16,
+                             sharding=one)).compile().as_text()
+    kernels = len(_re.findall(r"%bf_embed_add_rows_by_id(\.\d+)? = ", txt))
+    if not tiles:
+        assert kernels == 0 and len(_re.findall(r" scatter\(", txt)) == 1
+        return
+    assert -(-rows // row_sums.sums_tile(rows, width)) == tiles
+    assert kernels == 1 and not _re.findall(r" scatter\(", txt)
+    assert not _re.findall(rf"f32\[{rows},{width}\]\S* broadcast\(", txt)
+    assert _re.findall(rf"s32\[{ids}\]\S*\) sort\(", txt)
+    assert "custom_call_has_side_effect=true" not in txt
+
+
+@pytest.mark.parametrize("cell,kernels,parent_temp", [
+    ("phi4flash.t8192.solo", 1, 3_265_160_704),
+    ("smallthinker.t16384.solo", 1, 3_452_474_880),
+    ("joyai.t4096.solo", 2, 2_713_568_768),
+    ("gpt2s.t2048.solo", 0, 8_057_458_688),
+    ("gpt2s.t8192.solo", 0, 3_754_795_520),
+    ("gpt2s.t2048.exp2x4", 0, 8_667_998_720)])
+def test_the_decoder_steps_sum_the_table_s_gradient_in_vmem_on_v5e(
+        cell, kernels, parent_temp, monkeypatch):
+    """The benchmark's six decoder steps, built as ``chipbench/run.py``
+    builds them and compiled for v5e (one chip; the 2x2 ring for the
+    four-rank cell): one ``bf_embed_add_rows_by_id`` a lookup of a table
+    of 2,048 columns or more (the MTP model looks its table up twice), no
+    scatter under ``bf.embed.`` and no side effect on the kernel; GPT-2's
+    tables keep XLA's scatter-adds and no kernel.  ``temp_size_in_bytes`` within 0.5 % of what the step took
+    before the lookup had a rule of its own (PR 36's tree; GPT-2's to the
+    byte)."""
+    import importlib
+    import os
+    import types
+
+    from tests._util import REPO
+    from chipbench import cell as cells
+    from bluefog_tpu.topology.mapping import ici_ring_order
+
+    topo = _aot_topo("v5e:2x2")
+    manifest = cells.Manifest.load(os.path.join(REPO, "BENCHMARK.json"))
+    config, traffic = cells.open_cell(manifest, cell)
+    n = traffic["ranks"]
+    devices = ici_ring_order(topo.devices) if n == 4 else [topo.devices[0]]
+    mesh = Mesh(np.array(devices), ("bf",))
+    family = manifest.module("families", config["family"]).build(config,
+                                                                 traffic)
+    ctx = types.SimpleNamespace(
+        schedule=build_schedule(ExponentialTwoGraph(n)), axis_name="bf",
+        mesh=mesh)
+    opt, step = cells.build_step(family, config, traffic, ctx)
+
+    def init(key):
+        params, model_state = family.init(key)
+        return ((params, model_state, opt.init(params)),
+                family.make_batch(key))
+
+    # what a process on the chip answers, asked where the steps ask it
+    monkeypatch.setattr(pg, "on_tpu_platform", lambda: True)
+    if cell.startswith("gpt2s"):
+        monkeypatch.setattr(      # the package exports a function by
+            importlib.import_module(        # the module's name
+                "bluefog_tpu.ops.ring_attention"),
+            "_flash_eligible", lambda *a, **k: True)
+    else:
+        monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    sharding = NamedSharding(mesh, P("bf"))
+    state, batch = jax.tree_util.tree_map(
+        lambda t: jax.ShapeDtypeStruct((n,) + t.shape, t.dtype,
+                                       sharding=sharding),
+        jax.eval_shape(init, jax.random.PRNGKey(0)))
+    compiled = step.lower(state, batch).compile()
+    txt = compiled.as_text()
+    assert len(_re.findall(r"%bf_embed_add_rows_by_id(\.\d+)? = ",
+                           txt)) == kernels
+    scatters = [line for line in txt.splitlines()
+                if _re.search(r" scatter\(", line) and "bf.embed." in line]
+    assert bool(scatters) == (kernels == 0), scatters
+    sides = _re.findall(
+        r"%(\S+) = [^\n]*custom_call_has_side_effect=true", txt)
+    assert not [name for name in sides if name.startswith("bf_embed")]
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    if kernels:
+        assert temp <= parent_temp * 1.005, (temp, parent_temp)
+    else:
+        assert temp == parent_temp
+
+
 def test_selective_scan_kernels_compile_for_v5e(tpu_aot_topology):
     """The selective-scan kernels at the published Mamba mixer: 8,192
     tokens, 5,120 channels in blocks of 1,024, 16 states, bf16 ``x`` beside

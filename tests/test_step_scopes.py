@@ -244,10 +244,12 @@ def family_config(family):
 
 
 @functools.lru_cache(maxsize=None)
-def compiled_loss_and_gradient(family, remat):
-    """The compiled text of ``next_token_loss`` and its gradient."""
+def compiled_loss_and_gradient(family, remat, lookup_form="scatter"):
+    """The compiled text of ``next_token_loss`` and its gradient, the
+    lookup's gradient in the form a CPU takes or in the one asked for."""
     from bluefog_tpu.models.transformer import (
         GPTConfig, TransformerLM, next_token_loss)
+    from bluefog_tpu.ops import row_sums
 
     sizes, opens = family_config(family)
     cfg = GPTConfig(remat=remat, **sizes)
@@ -262,8 +264,13 @@ def compiled_loss_and_gradient(family, remat):
     def loss(params, state):
         return next_token_loss(model, params, state, tokens, mtp_weight=0.1)
 
-    return jax.jit(jax.value_and_grad(loss)).lower(
-        params, state).compile().as_text(), opens
+    chosen = row_sums._lookup_form
+    row_sums._lookup_form = lambda v, d: lookup_form
+    try:
+        return jax.jit(jax.value_and_grad(loss)).lower(
+            params, state).compile().as_text(), opens
+    finally:
+        row_sums._lookup_form = chosen
 
 
 def pass_of(op_name):
@@ -278,17 +285,8 @@ REMAT = pytest.mark.parametrize("remat", [False, True],
                                 ids=["saved", "remat"])
 
 
-@REMAT
-@FAMILIES
-def test_every_heavy_op_of_the_decoder_step_is_under_one_layer_scope(
-        family, remat):
-    """(a) Every dot, convolution, gather, scatter, reduction and custom
-    call traced under ``TransformerLM`` or ``next_token_loss`` carries a
-    layer scope, in the forward, the backward and the recomputed pass alike:
-    a module that lands without one fails here.  (b) No op carries two (the
-    layers would not add up); ``bf.neighbor_allreduce.slot{k}`` inside
-    ``bf.gossip.exchange`` is a Perfetto aid outside this pattern."""
-    text, opens = compiled_loss_and_gradient(family, remat)
+def scopes_by_pass(text):
+    """Every layer scope the text names, by pass; (a) and (b) below."""
     found = {"forward": set(), "backward": set(), "recompute": set()}
     for line in text.splitlines():
         named = re.search(r'op_name="([^"]*)"', line)
@@ -300,9 +298,45 @@ def test_every_heavy_op_of_the_decoder_step_is_under_one_layer_scope(
             found[pass_of(one_op)] |= scopes
         if HEAVY.search(line):
             assert LAYER_SCOPE.search(named.group(1)), line.strip()[:400]
+    return found
+
+
+@REMAT
+@FAMILIES
+def test_every_heavy_op_of_the_decoder_step_is_under_one_layer_scope(
+        family, remat):
+    """(a) Every dot, convolution, gather, scatter, reduction and custom
+    call traced under ``TransformerLM`` or ``next_token_loss`` carries a
+    layer scope, in the forward, the backward and the recomputed pass alike:
+    a module that lands without one fails here.  (b) No op carries two (the
+    layers would not add up); ``bf.neighbor_allreduce.slot{k}`` inside
+    ``bf.gossip.exchange`` is a Perfetto aid outside this pattern."""
+    text, opens = compiled_loss_and_gradient(family, remat)
+    found = scopes_by_pass(text)
     assert found["forward"] | found["backward"] | found["recompute"] == opens
     assert found["backward"] >= opens - {"bf.moe.route"}
     assert bool(found["recompute"]) == remat
+
+
+@REMAT
+@FAMILIES
+def test_the_lookup_s_own_gradient_rule_stays_under_the_embedding_s_scopes(
+        family, remat):
+    """The step with the table's gradient summed by the kernel
+    (``ops/row_sums.py::take_rows``, in the interpreter here): (a) and (b)
+    hold as they do for ``jnp.take``'s transpose: what the rule traces
+    carries the scope of the call, the kernel ``bf.embed.lookup`` (the MTP
+    model's second lookup ``bf.embed.mtp_merge``) in the backward pass; and
+    no scatter is left under either scope but the learned positions'."""
+    text, opens = compiled_loss_and_gradient(family, remat, "vmem_interpret")
+    found = scopes_by_pass(text)
+    assert found["backward"] >= opens - {"bf.moe.route"}
+    for scope in {"bf.embed.lookup"} | (opens & {"bf.embed.mtp_merge"}):
+        assert f"))/{scope}/bf_embed_add_rows_by_id/" in text, scope
+    scatters = [line for line in text.splitlines()
+                if re.search(r" scatter\(", line) and "bf.embed." in line]
+    assert all("/pos/" in line for line in scatters), scatters
+    assert len(scatters) == (family == "fused_qkv")
 
 
 @REMAT
