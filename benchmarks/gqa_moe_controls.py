@@ -19,6 +19,7 @@ readings.
 """
 
 import argparse
+import functools
 import json
 import os
 import sys
@@ -85,8 +86,11 @@ def check(name, cell, state, preroll, started, seed, out=None):
 
 def rounded_to_bf16(step):
     """``step`` followed by rounding every parameter to bf16's mantissa
-    (``reduce_precision``: a cast there and back is removed on the TPU)."""
-    @jax.jit
+    (``reduce_precision``: a cast there and back is removed on the TPU),
+    in place: the check's steps are dispatched ahead of the device, and a
+    fresh copy of the parameters for each of them did not fit beside
+    ``ling-3.0-flash``'s state (PR 41)."""
+    @functools.partial(jax.jit, donate_argnums=0)
     def round_params(state):
         return (jax.tree_util.tree_map(
             lambda a: jax.lax.reduce_precision(a, exponent_bits=8,

@@ -1,4 +1,6 @@
-"""How a ``gqa_moe`` cell's routed load moves while it trains: the share of
+"""How an expert cell's routed load moves while it trains (``gqa_moe``'s,
+and from PR 41 ``linear_latent_moe``'s with ``--workload``; the leading
+dense blocks have no load to read): the share of
 each layer's assignments that falls on the held experts, the passes of the
 row buffer, the fullest and emptiest held expert, and the step's time, every
 ``--every`` steps from the cell's own initial state.
@@ -64,19 +66,21 @@ def main(argv=None):
     cell = cells.build_cell(cells.Manifest.load(args.manifest),
                             args.workload, args.seed)
     model = cell.family.model
-    blocks = [f"block_{i}" for i in range(model.cfg.num_layers)]
+    blocks = [f"block_{i}" for i in range(model.cfg.experts.first_dense,
+                                          model.cfg.num_layers)]
 
     @jax.jit
-    def record(params, batch):
-        rank0 = jax.tree_util.tree_map(lambda t: t[0], params)
-        _, sown = model.apply({"params": rank0}, batch[0][:, :-1],
-                              mutable=["moe_metrics"])
+    def record(params, model_state, batch):
+        params, model_state = jax.tree_util.tree_map(
+            lambda t: t[0], (params, model_state))      # rank 0's
+        _, sown = model.apply({"params": params, **model_state},
+                              batch[0][:, :-1], mutable=["moe_metrics"])
         return [{k: sown["moe_metrics"][b]["moe"][k][0]
                  for k in ("held_share", "row_passes", "rows_per_expert")}
                 for b in blocks]
 
     def reading(step, state):
-        layers = jax.device_get(record(state[0], cell.ring[step % len(
+        layers = jax.device_get(record(*state[:2], cell.ring[step % len(
             cell.ring)]))
         return {"held_share": [round(float(r["held_share"]), 4)
                                for r in layers],

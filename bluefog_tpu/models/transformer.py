@@ -49,7 +49,15 @@ GPT-2 decoder this file always built, parameter for parameter:
                  encoding**) and ``window_rotary_attention`` (the last
                  ``grouped.window`` keys, queries and keys turned by rotary
                  over the whole head); any ``ffn`` and ``norm`` go with
-                 them.  Or the mixers of a decoder-hybrid-decoder (SambaY,
+                 them.  Or a linear-attention / latent-attention hybrid
+                 (Kimi Linear, arXiv:2510.26692; sizes in :class:`KdaSizes`
+                 and :class:`LatentSizes`): ``kda`` (:class:`KdaMixer`, the
+                 delta rule with a per-channel decay) and
+                 ``latent_attention`` (:class:`LatentAttention`); with them
+                 ``attention="latent"`` and ``position="rotary"`` (the
+                 latent layers turn their rotary part, the recurrence
+                 orders the tokens elsewhere) and any ``ffn`` and ``norm``.
+                 Or the mixers of a decoder-hybrid-decoder (SambaY,
                  arXiv:2507.06607; sizes in :class:`HybridSizes`): ``mamba``
                  (:class:`MambaMixer`, a selective state space),
                  ``diff_attention`` and ``diff_attention_window``
@@ -60,11 +68,30 @@ GPT-2 decoder this file always built, parameter for parameter:
                  of its own over the keys and values of the last
                  ``diff_attention`` block).  Those blocks hand these tensors
                  on, and with those mixers come ``ffn="swiglu"`` and
-                 ``norm_eps`` for the LayerNorms.  Either way
-                 ``position="none"``
+                 ``norm_eps`` for the LayerNorms.  The attention layers and
+                 the SambaY mixers take ``position="none"``
 ``tie_head``     the head is the embedding's transpose: one leaf, whose
                  gradient is the sum of both uses
+``heads_held``   ``None``, or this chip's share of the heads (below)
 ===============  ===========================================================
+
+**A chip's share of a layer** (the one place that states it).  A layer
+divided over several chips is built here as what one of them holds, and
+what the absent parts would add to a token is left out, with no code
+standing in for them.  *Experts*: ``experts.held = (first, count)`` names the
+global experts ``first .. first + count - 1`` whose weights the parameters
+hold; the router keeps all ``experts.num_experts`` outputs and its
+``top_k``, and the layer's result is the sum over the chosen experts that
+are held (a shared expert is every chip's alike).  *Heads*: ``heads_held =
+(first, count)`` names the heads ``first .. first + count - 1`` of
+``num_heads`` (the ``kda`` and ``latent_attention`` layers): the parameters
+hold those heads' columns of every projection out of the model width
+(queries, keys, values, decay, ``beta``, the output gate, the latent
+up-projection) and their rows of the output projection, while what every
+head reads stays whole (the latent down-projection, the per-head norms'
+scales).  The layer's result is those heads' part of the output
+projection's sum; the shares of all the chips add up to the uncut layer's
+(``tests/test_linear_latent_moe.py``).
 """
 
 from __future__ import annotations
@@ -79,6 +106,7 @@ import jax
 import jax.numpy as jnp
 
 from bluefog_tpu.metrics import comm as metrics_comm
+from bluefog_tpu.ops.kda import LOWER as KDA_LOWER, kda
 from bluefog_tpu.ops.moe import (
     ACTIVATIONS, routed_experts, sigmoid_topk_router, softmax_topk_router)
 from bluefog_tpu.ops.ring_attention import local_attention
@@ -93,14 +121,38 @@ class LatentSizes:
     """Multi-head latent attention (DeepSeek-V2, arXiv:2405.04434 §2.1):
     the ranks of the query and key/value bottlenecks and a head's widths.
     A query and a key are ``qk_nope_head_dim + qk_rope_head_dim`` wide, a
-    value ``v_head_dim``."""
+    value ``v_head_dim``.  ``q_lora_rank=None``: no query bottleneck, ``q =
+    W_q y``.  ``qk_norm``: an RMSNorm over each head's whole query and key
+    (one scale a side, shared by the heads) before the rotary.
+    ``head_gate``: the heads' outputs times ``sigmoid(W_g y)``, one scalar a
+    head, before the output projection.  With ``GPTConfig.heads_held`` the
+    layer holds a share of the heads (module docstring, "A chip's share")."""
 
-    q_lora_rank: int = 1536
+    q_lora_rank: Optional[int] = 1536
     kv_lora_rank: int = 512
     qk_nope_head_dim: int = 128
     qk_rope_head_dim: int = 64
     v_head_dim: int = 128
     rope_theta: float = 10000.0
+    qk_norm: bool = False
+    head_gate: bool = False
+
+
+@dataclasses.dataclass(frozen=True)
+class KdaSizes:
+    """A ``kda`` layer (Kimi Delta Attention, arXiv:2510.26692 section 3):
+    keys, queries and values ``head_dim`` wide, ``conv`` taps of the causal
+    depthwise convolutions on them, and ``lower_bound``, the least log-decay
+    a token and channel (``g = lower_bound * sigmoid(exp(A_log) (W_f y +
+    dt_bias))``, the safe gate; the chunked kernel is built for no less
+    than :data:`bluefog_tpu.ops.kda.LOWER`).  The decay projection is full
+    rank and the output gate one scalar a head.  With
+    ``GPTConfig.heads_held`` the layer holds a share of the heads (module
+    docstring, "A chip's share")."""
+
+    head_dim: int = 128
+    conv: int = 4
+    lower_bound: float = -5.0
 
 
 @dataclasses.dataclass(frozen=True)
@@ -125,13 +177,16 @@ ROUTER_INPUTS = ("ffn", "block")
 class ExpertSizes:
     """An expert layer, stated whole: the router scores all ``num_experts``,
     a token takes ``top_k``, and this chip computes the experts ``held =
-    (first, count)`` for the tokens routed to them (what the absent ones
-    would add is left out).
+    (first, count)`` for the tokens routed to them (module docstring, "A
+    chip's share").
 
     ``router``: ``sigmoid_noaux_tc`` (DeepSeek-V3, arXiv:2412.19437 §2.1.2:
     sigmoid scores, a selection-bias buffer, the chosen scores normalised
-    and times ``scale``) or ``softmax_topk`` (a softmax over the chosen
-    logits; no bias, no buffer, no ``scale``).  ``activation`` of an
+    and times ``scale``; with ``n_group > 1`` the selection is
+    group-limited: the experts in ``n_group`` equal groups, a group's score
+    the sum of its two best, the ``topk_group`` best groups kept and the
+    ``top_k`` taken among theirs) or ``softmax_topk`` (a softmax over the
+    chosen logits; no bias, no buffer, no ``scale``, one group).  ``activation`` of an
     expert's gate: ``silu`` or ``relu`` (ReGLU).  ``num_shared`` experts
     every token takes, as one gated MLP of that many widths; 0 builds none.
     ``first_dense`` leading blocks keep the dense ``swiglu``; 0 for none.
@@ -160,11 +215,14 @@ class ExpertSizes:
     activation: str = "silu"
     router_input: str = "ffn"
     train_router: bool = True
+    n_group: int = 1               # sigmoid router: groups of experts,
+    topk_group: int = 1            # and how many of them a token keeps
 
 
 MIXERS = ("mamba", "diff_attention", "diff_attention_window", "gmu",
           "cross_diff_attention")
 ATTENTION_LAYERS = ("full_attention", "window_rotary_attention")
+LINEAR_LAYERS = ("kda", "latent_attention")
 ROUTED = "routed+shared"
 
 
@@ -212,6 +270,8 @@ class GPTConfig:
     hybrid: Optional[HybridSizes] = None
     tie_head: bool = False
     grouped: Optional[GroupedSizes] = None
+    kda: Optional[KdaSizes] = None
+    heads_held: Optional[Tuple[int, int]] = None    # (first, count)
 
     def __post_init__(self):
         for field, kinds in (("attention", ("fused_qkv", "latent",
@@ -227,23 +287,39 @@ class GPTConfig:
             raise ValueError(f"{len(types)} layer_types for "
                              f"{self.num_layers} layers")
         mixers = [kind in MIXERS for kind in types]
+        linear = [kind in LINEAR_LAYERS for kind in types]
         for kind in types:
-            if kind not in MIXERS + ATTENTION_LAYERS:
+            if kind not in MIXERS + ATTENTION_LAYERS + LINEAR_LAYERS:
                 raise ValueError(
                     f"unknown layer type {kind!r} in layer_types; expected "
-                    f"mixers {MIXERS} or attention layers {ATTENTION_LAYERS}")
-        if any(mixers) and not all(mixers):
-            raise ValueError("layer_types mixes the SambaY mixers with plain "
-                             "attention layers; a model takes one or the "
-                             "other")
+                    f"mixers {MIXERS}, attention layers {ATTENTION_LAYERS} "
+                    f"or linear/latent layers {LINEAR_LAYERS}")
+        for family in (mixers, linear):
+            if any(family) and not all(family):
+                raise ValueError(
+                    "layer_types mixes layer families; a model takes the "
+                    f"SambaY mixers {MIXERS}, the attention layers "
+                    f"{ATTENTION_LAYERS} or the linear/latent layers "
+                    f"{LINEAR_LAYERS}")
         if any(mixers) != (self.hybrid is not None):
             raise ValueError("the `hybrid` sizes and the SambaY mixers in "
                              "`layer_types` come together")
-        if (self.position == "none") != (self.layer_types is not None):
+        if ("kda" in types) != (self.kda is not None):
+            raise ValueError("the `kda` sizes and the 'kda' layers of "
+                             "`layer_types` come together")
+        if any(linear) and (self.attention, self.position) != (
+                "latent", "rotary"):
+            raise ValueError(
+                "the linear/latent layers of `layer_types` are built with "
+                "attention='latent' and position='rotary' (the latent "
+                "layers turn their rotary part; the recurrence orders the "
+                "tokens elsewhere)")
+        if not any(linear) and (self.position == "none") != bool(types):
             raise ValueError("position='none' is for `layer_types` (a "
                              "recurrence orders the tokens, or the layer's "
                              "type says whether it turns its keys); fused_qkv "
                              "and latent heads need positions")
+        self._check_heads(any(linear))
         if any(mixers):
             self._check_mixers()
             return
@@ -253,7 +329,8 @@ class GPTConfig:
         if (self.attention == "grouped_query") != (self.grouped is not None):
             raise ValueError("attention='grouped_query' and the `grouped` "
                              "sizes come together")
-        if (self.attention == "grouped_query") != bool(types):
+        if (self.attention == "grouped_query") != (
+                bool(types) and not any(linear)):
             raise ValueError("the attention layers of `layer_types` are the "
                              "grouped_query attention's, and it needs them: "
                              "each says whether it is windowed and rotary")
@@ -274,6 +351,28 @@ class GPTConfig:
         if self.experts is not None:
             self._check_experts()
 
+    def _check_heads(self, linear: bool):
+        held, kda_sizes = self.heads_held, self.kda
+        if held is not None and not linear:
+            raise ValueError("heads_held is the linear/latent layers' (the "
+                             "other attentions hold every head)")
+        if held is not None and not (
+                0 <= held[0] and held[1] >= 1
+                and held[0] + held[1] <= self.num_heads):
+            raise ValueError(f"heads_held={held} is not a range of the "
+                             f"{self.num_heads} heads")
+        if kda_sizes is not None and not (
+                KDA_LOWER <= kda_sizes.lower_bound < 0):
+            raise ValueError(
+                f"kda.lower_bound {kda_sizes.lower_bound}: the chunked "
+                f"delta rule is built for log-decays in [{KDA_LOWER}, 0)")
+        la = self.latent
+        if la is not None and not linear and (
+                la.q_lora_rank is None or la.qk_norm or la.head_gate):
+            raise ValueError(
+                "latent.q_lora_rank=None, qk_norm and head_gate are the "
+                "'latent_attention' layers' of `layer_types`")
+
     def _check_experts(self):
         ex = self.experts
         for field, kinds in (("router", ROUTERS),
@@ -286,6 +385,20 @@ class GPTConfig:
         if ex.num_shared < 0 or ex.first_dense < 0:
             raise ValueError("experts.num_shared and experts.first_dense "
                              "count experts and blocks: 0 or more")
+        if not (1 <= ex.topk_group <= ex.n_group) or (
+                ex.num_experts % ex.n_group) or (
+                    ex.n_group > 1 and ex.router != "sigmoid_noaux_tc"):
+            raise ValueError(
+                f"experts.n_group {ex.n_group} / topk_group {ex.topk_group}:"
+                f" equal groups of the {ex.num_experts} experts, some of "
+                "them kept, under the sigmoid router")
+        if ex.n_group > 1 and (
+                ex.num_experts // ex.n_group < 2
+                or ex.topk_group * (ex.num_experts // ex.n_group)
+                < ex.top_k):
+            raise ValueError(
+                "experts.n_group: a group's score is the sum of its two "
+                "best, and the kept groups must hold top_k experts")
 
     def _check_mixers(self):
         types, hy = tuple(self.layer_types), self.hybrid
@@ -446,22 +559,39 @@ def rotary(x, positions, theta: float, interleaved: bool = True):
     return turned.reshape(x.shape).astype(x.dtype)
 
 
+def _heads(cfg: GPTConfig) -> int:
+    """The heads this chip's ``kda`` and ``latent_attention`` layers hold."""
+    return cfg.num_heads if cfg.heads_held is None else cfg.heads_held[1]
+
+
+def _head_gate(a, gate):
+    """``a (B, T, H, d)`` times ``sigmoid(gate (B, T, H))``, one scalar a
+    head, in f32 and returned in ``gate``'s dtype."""
+    return (a.astype(jnp.float32) * jax.nn.sigmoid(
+        gate.astype(jnp.float32))[..., None]).astype(gate.dtype)
+
+
 class LatentAttention(nn.Module):
     """Multi-head latent attention, as trained (keys and values are
     materialised; no matrix absorption): ``(B, T, D) -> (B, T, D)``.
 
-    ``cq = RMSNorm(W_dq y)``, ``q = W_uq cq`` per head ``[q_nope; q_rope]``;
+    ``cq = RMSNorm(W_dq y)``, ``q = W_uq cq`` per head ``[q_nope; q_rope]``
+    (or ``q = W_q y`` without the bottleneck: ``latent.q_lora_rank=None``);
     ``[ckv; k_rope] = W_dkv y``, ``[k_nope; v] = W_ukv RMSNorm(ckv)`` per
-    head; rotary on ``q_rope`` and on the one ``k_rope`` a token, which every
-    head shares.  ``attn_fn`` sees ``q, k (B, T, H, nope + rope)`` and
-    ``v (B, T, H, v_head_dim)`` and scales by the query's width.  No bias.
+    head; with ``latent.qk_norm`` an RMSNorm over each head's whole query
+    and whole key ``[k_nope; k_rope]``; rotary on ``q_rope`` and on
+    ``k_rope`` (one a token, which every head shares, unless the key norm
+    has scaled it head by head).  ``attn_fn`` sees ``q, k (B, T, H, nope +
+    rope)`` and ``v (B, T, H, v_head_dim)`` and scales by the query's width.
+    With ``latent.head_gate`` the heads' outputs are gated (:func:`_head_gate`)
+    before ``W_o``.  No bias.  ``H`` is the heads held (:func:`_heads`).
     """
 
     cfg: GPTConfig
 
     @nn.compact
     def __call__(self, y, attn_fn: AttnFn, positions):
-        cfg, la, h = self.cfg, self.cfg.latent, self.cfg.num_heads
+        cfg, la, h = self.cfg, self.cfg.latent, _heads(self.cfg)
         nope, rope = la.qk_nope_head_dim, la.qk_rope_head_dim
         dense = functools.partial(nn.Dense, use_bias=False, dtype=cfg.dtype)
         head_dense = functools.partial(HeadDense, use_bias=False,
@@ -472,26 +602,112 @@ class LatentAttention(nn.Module):
                               name=name)
 
         with jax.named_scope("bf.mla.project"):
-            cq = rms("q_norm")(dense(la.q_lora_rank, name="q_down")(y))
-            q = head_dense(h * (nope + rope), (h, nope + rope), name="q_up")(
-                cq.astype(cfg.dtype))
+            if la.q_lora_rank is None:
+                q = head_dense(h * (nope + rope), (h, nope + rope),
+                               name="q")(y)
+            else:
+                cq = rms("q_norm")(dense(la.q_lora_rank, name="q_down")(y))
+                q = head_dense(h * (nope + rope), (h, nope + rope),
+                               name="q_up")(cq.astype(cfg.dtype))
             ckv = dense(la.kv_lora_rank + rope, name="kv_down")(y)
             k_rope = ckv[..., None, la.kv_lora_rank:]        # (B, T, 1, rope)
             ckv = rms("kv_norm")(ckv[..., :la.kv_lora_rank])
             kv = head_dense(h * (nope + la.v_head_dim),
                             (h, nope + la.v_head_dim), name="kv_up")(
                                 ckv.astype(cfg.dtype))
+            if la.qk_norm:
+                q = rms("q_head_norm")(q).astype(cfg.dtype)
             q = jnp.concatenate(
                 [q[..., :nope], rotary(q[..., nope:], positions,
                                        la.rope_theta)], axis=-1)
-            k_rope = rotary(k_rope, positions, la.rope_theta)
+            if not la.qk_norm:      # one turn a token serves every head
+                k_rope = rotary(k_rope, positions, la.rope_theta)
             k = jnp.concatenate(
                 [kv[..., :nope],
                  jnp.broadcast_to(k_rope, kv.shape[:-1] + (rope,))], axis=-1)
+            if la.qk_norm:          # the norm scales k_rope head by head
+                k = rms("k_head_norm")(k).astype(cfg.dtype)
+                k = jnp.concatenate(
+                    [k[..., :nope],
+                     rotary(k[..., nope:], positions, la.rope_theta)],
+                    axis=-1)
         a = attn_fn(q, k, kv[..., nope:])
         with jax.named_scope("bf.mla.project"):
+            if la.head_gate:
+                a = _head_gate(a, dense(h, name="head_gate")(y))
             return head_dense(cfg.hidden_size, (h, la.v_head_dim),
                               inward=True, name="o")(a)
+
+
+class KdaMixer(nn.Module):
+    """Kimi Delta Attention (arXiv:2510.26692 section 3; the layer of
+    ``fla/layers/kda.py``): ``(B, T, D) -> (B, T, D)``.  Per head of width
+    ``d = kda.head_dim``:
+
+    ``q~, k~, v = silu(conv(W_q y)), silu(conv(W_k y)), silu(conv(W_v y))``
+    (causal depthwise convolutions of ``kda.conv`` taps, no bias, f32);
+    ``q = l2norm(q~) d^-1/2``, ``k = l2norm(k~)``; ``beta = sigmoid(W_b y)``
+    a head; the log-decay a channel ``g = lower_bound sigmoid(exp(A_log_h)
+    (W_f y + dt_bias))`` in f32; ``o = kda(q, k, v, g, beta)``
+    (:func:`bluefog_tpu.ops.kda.kda`); output ``W_o [RMSNorm_head(o)
+    sigmoid(W_g y)_h]``, the norm over a head's ``d`` with one scale shared
+    by the heads, the gate one scalar a head.  No bias but ``dt_bias``.
+    The heads are the ones held (:func:`_heads`).  Initialisers: Kimi
+    Linear's for the recurrence, ``A_log = log U(1, 16)`` a head and
+    ``dt_bias`` the inverse softplus of a step log-uniform in [1e-3, 1e-1];
+    the taps uniform within ``conv ** -0.5``; flax's elsewhere."""
+
+    cfg: GPTConfig
+
+    @nn.compact
+    def __call__(self, y):
+        cfg, sizes, h = self.cfg, self.cfg.kda, _heads(self.cfg)
+        d = sizes.head_dim
+        dense = functools.partial(nn.Dense, use_bias=False, dtype=cfg.dtype)
+        lead = y.shape[:-1]
+
+        def dt_bias(key, shape, dtype):
+            dt = jnp.exp(jax.random.uniform(key, shape, dtype)
+                         * (math.log(0.1) - math.log(1e-3)) + math.log(1e-3))
+            return dt + jnp.log(-jnp.expm1(-dt))      # softplus's inverse
+
+        def taps(key, shape, dtype=jnp.float32):
+            bound = sizes.conv ** -0.5
+            return jax.random.uniform(key, shape, dtype, -bound, bound)
+
+        def a_log(key, shape, dtype):
+            return jnp.log(jax.random.uniform(key, shape, dtype, 1.0, 16.0))
+
+        with jax.named_scope("bf.kda.project"):
+            projected = [dense(h * d, name=name)(y) for name in "qkv"]
+            decay = dense(h * d, name="f")(y)
+            beta = jax.nn.sigmoid(dense(h, name="b")(y).astype(jnp.float32))
+            gate = dense(h, name="head_gate")(y)
+        with jax.named_scope("bf.kda.conv"):
+            q, k, v = (nn.silu(causal_depthwise_conv(
+                x.astype(jnp.float32),
+                self.param(f"{name}_conv", taps, (sizes.conv, h * d),
+                           jnp.float32), 0.0)).reshape(lead + (h, d))
+                       for name, x in zip("qkv", projected))
+        with jax.named_scope("bf.kda.project"):
+            q = (q * jax.lax.rsqrt(jnp.sum(q * q, -1, keepdims=True) + 1e-6)
+                 * d ** -0.5).astype(cfg.dtype)
+            k = (k * jax.lax.rsqrt(jnp.sum(k * k, -1, keepdims=True) + 1e-6)
+                 ).astype(cfg.dtype)
+            rate = jnp.exp(self.param("A_log", a_log, (h,), jnp.float32))
+            bias = self.param("dt_bias", dt_bias, (h * d,), jnp.float32)
+            g = sizes.lower_bound * jax.nn.sigmoid(
+                rate[:, None] * (decay.astype(jnp.float32) + bias).reshape(
+                    lead + (h, d)))
+        with jax.named_scope("bf.kda.scan"):
+            o = kda(q, k, v.astype(cfg.dtype), g, beta)
+        with jax.named_scope("bf.kda.norm_gate"):
+            o = nn.RMSNorm(epsilon=cfg.norm_eps, dtype=jnp.float32,
+                           name="o_norm")(o)
+            o = _head_gate(o, gate)
+        with jax.named_scope("bf.kda.project"):
+            return dense(cfg.hidden_size, name="o")(
+                o.reshape(lead + (h * d,)))
 
 
 class GroupedQueryAttention(nn.Module):
@@ -721,7 +937,8 @@ def _route(ex: ExpertSizes, flat, router, bias):
         idx, weights = softmax_topk_router(flat, router, top_k=ex.top_k)
     else:
         idx, weights = sigmoid_topk_router(
-            flat, router, bias.value, top_k=ex.top_k, scale=ex.scale)
+            flat, router, bias.value, top_k=ex.top_k, scale=ex.scale,
+            n_group=ex.n_group, topk_group=ex.topk_group)
     if not ex.train_router:
         weights = jax.lax.stop_gradient(weights)
     return idx, weights
@@ -878,7 +1095,9 @@ class Block(nn.Module):
             a, carried = _mix(self, y, attn_fn, carried)
             return _feed_forward(self, x + a), carried
         moe, routing = _early_routing(self, y)
-        if cfg.attention == "latent":
+        if self.mixer == "kda":
+            a = KdaMixer(cfg, name="attn")(y)
+        elif cfg.attention == "latent":
             a = LatentAttention(cfg, name="attn")(y, attn_fn, positions)
         elif cfg.attention == "grouped_query":
             a = GroupedQueryAttention(cfg, self.mixer, name="attn")(

@@ -269,26 +269,49 @@ def moe_ffn_reference(x, router_kernel, wi, wo, *, num_experts: int,
 
 
 def sigmoid_topk_router(x, router_kernel, bias, *, top_k: int,
-                        scale: float = 1.0):
-    """DeepSeek-V3's ``noaux_tc`` routing with one group (arXiv:2412.19437
-    section 2.1.2): ``s = sigmoid(x @ W_g)`` in f32, the chosen set is the
-    ``top_k`` largest of ``s + bias``, the weights are ``scale * s_i / sum of
-    the chosen s`` — from ``s`` **without** the bias, which only steers the
-    selection and takes no gradient.
+                        scale: float = 1.0, n_group: int = 1,
+                        topk_group: int = 1):
+    """DeepSeek-V3's ``noaux_tc`` routing (arXiv:2412.19437 section 2.1.2):
+    ``s = sigmoid(x @ W_g)`` in f32, the chosen set is the ``top_k`` largest
+    of ``s + bias``, the weights are ``scale * s_i / sum of the chosen s`` —
+    from ``s`` **without** the bias, which only steers the selection and
+    takes no gradient.
+
+    With ``n_group > 1`` the selection is **group-limited** (the
+    node-limited routing of the same section): the experts lie in
+    ``n_group`` equal groups in index order, a group's score is the sum of
+    its two largest ``s + bias``, the ``topk_group`` best groups are kept,
+    and the ``top_k`` are taken among the kept groups' experts (the others
+    at minus infinity).  Ties go to the lower index, groups and experts
+    alike (``lax.top_k``).  ``n_group = 1`` is the selection above,
+    instruction for instruction.
 
     ``x (T, D)``, ``router_kernel (D, E)``, ``bias (E,)`` →
     ``idx (T, top_k)`` int32 expert ids, ``weights (T, top_k)`` f32.  The
     matmul runs at ``highest`` precision: on a TPU an f32 product is
     otherwise rounded to bf16, and the chosen set flips on that rounding.
+    With metrics on, a grouped call adds its kept groups (``T *
+    topk_group``) to ``bf_moe_groups_kept_total``.
     """
     with jax.named_scope("bf.moe.route"):
         s = jax.nn.sigmoid(jnp.dot(
             x.astype(jnp.float32), router_kernel.astype(jnp.float32),
             precision=lax.Precision.HIGHEST))
-        _, idx = lax.top_k(s + lax.stop_gradient(bias.astype(jnp.float32)),
-                           top_k)
+        steer = s + lax.stop_gradient(bias.astype(jnp.float32))
+        if n_group > 1:
+            t, e = steer.shape
+            grouped = steer.reshape(t, n_group, e // n_group)
+            _, kept = lax.top_k(lax.top_k(grouped, 2)[0].sum(-1), topk_group)
+            open_groups = jnp.any(
+                kept[..., None] == jnp.arange(n_group), axis=1)
+            steer = jnp.where(open_groups[..., None], grouped,
+                              -jnp.inf).reshape(t, e)
+        _, idx = lax.top_k(steer, top_k)
         chosen = jnp.take_along_axis(s, idx, axis=-1)
         weights = scale * chosen / jnp.sum(chosen, axis=-1, keepdims=True)
+    if n_group > 1:
+        weights = metrics_comm.count(weights, [(
+            "bf_moe_groups_kept_total", float(x.shape[0] * topk_group))])
     return idx, weights
 
 

@@ -581,6 +581,41 @@ def test_selective_scan_kernels_compile_for_v5e(tpu_aot_topology):
     assert "bf_selective_scan_fwd" in txt and "bf_selective_scan_bwd" in txt
 
 
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32],
+                         ids=["bf16", "f32"])
+def test_kda_kernels_compile_for_v5e(dtype, tpu_aot_topology):
+    """The delta-rule kernels at the published KDA layer's share: 8,192
+    tokens, 16 heads of 128, an f32 log-decay a channel beside bf16 (or
+    f32) ``q, k, v``; value and all five gradients.  Mosaic takes the
+    chunk's ``jax.vjp`` as the backward kernel's body (transposed products,
+    the f32 inverse's products at ``highest``), reads the operands as
+    ``(64, 128)`` blocks of ``(B, T, H * d)`` with no relayout before
+    them, and the names are the ones the trace shows."""
+    from bluefog_tpu.ops.kda import kda
+
+    one = _one_chip(tpu_aot_topology)
+
+    def shape(dims, kind=jnp.float32):
+        return jax.ShapeDtypeStruct(dims, kind, sharding=one)
+
+    wide = (1, 8192, 16, 128)
+    args = (shape(wide, dtype), shape(wide, dtype), shape(wide, dtype),
+            shape(wide), shape(wide[:3]))
+
+    def grads(*operands):
+        return jax.grad(lambda *a: kda(
+            *a, backend="pallas").astype(jnp.float32).sum(),
+            argnums=tuple(range(5)))(*operands)
+
+    txt = jax.jit(grads).lower(*args).compile().as_text()
+    assert txt.count("tpu_custom_call") == 2
+    assert "bf_kda_fwd" in txt and "bf_kda_bwd_chunks" in txt
+    copies = [line for line in txt.splitlines()
+              if _re.search(r" (copy|transpose)\(", line)
+              and "8192,16,128" in line.replace(" ", "")]
+    assert not copies, copies
+
+
 @pytest.mark.parametrize("window", [512, None], ids=["window", "full"])
 def test_differential_attention_kernels_compile_for_v5e(window,
                                                         tpu_aot_topology):
