@@ -41,7 +41,17 @@ its inverse and ``W`` are f32, the inverse's products at ``highest``.
 
 The backward pass of a chunk is ``jax.vjp`` of that chunk function, taken
 where the chunk is computed (inside the Pallas kernel too), walking the
-chunks in reverse time with the state's cotangent carried.
+chunks in reverse time with the state's cotangent carried.  One step of the
+chunk carries a rule of its own: the solve ``W = (I + A)^-1 R`` has the
+closed-form adjoint ``dR = (I + A)^-T dW``, ``dA = -dR W^T`` (two f32
+products, which the chunk's own mask of ``A`` then masks), where autodiff
+would walk back through the ten products of the inverse with twenty.  And
+the inverse is not built a second time: the forward pass saves it beside the
+chunk-start states (``T / 64`` matrices of 64 x 64 f32 a head) and the
+backward pass hands it to the chunk function.  With bf16 operands that
+leaves a chunk's backward 3 f32 products at ``highest`` (``W`` again, the
+adjoint's two) beside 21 others, where autodiff alone took 33; the chunk
+function is still the only place the mathematics lives.
 
 Backends (``backend=``):
 
@@ -125,17 +135,70 @@ def kda(q, k, v, g, beta, *, backend="auto"):
                                    float(b * h * chunks))])
 
 
+# ---- the chunk's triangular solve, with its adjoint --------------------------
+
+_EXACT = dict(precision=lax.Precision.HIGHEST,
+              preferred_element_type=jnp.float32)
+
+
+def _inverse(a):
+    """``(I + a)^-1`` of a strictly lower-triangular f32 ``a (n, n)``, ``n`` a
+    multiple of :data:`SUB`: ten products, exact (module docstring)."""
+    n = a.shape[0]
+    rows = lax.broadcasted_iota(jnp.int32, (n, 1), 0)
+    cols = lax.broadcasted_iota(jnp.int32, (1, n), 1)
+    eye = (rows == cols).astype(jnp.float32)
+    d1 = jnp.where(rows // SUB == cols // SUB, a, 0.0)
+    rest = a - d1
+    d2 = jnp.dot(d1, d1, **_EXACT)
+    d4 = jnp.dot(d2, d2, **_EXACT)
+    d8 = jnp.dot(d4, d4, **_EXACT)
+    inv = jnp.dot(eye - d1, eye + d2, **_EXACT)
+    inv = jnp.dot(inv, eye + d4, **_EXACT)
+    inv = jnp.dot(inv, eye + d8, **_EXACT)
+    m1 = jnp.dot(inv, rest, **_EXACT)
+    m2 = jnp.dot(m1, m1, **_EXACT)
+    inv = inv - jnp.dot(m1, inv, **_EXACT)
+    return inv + jnp.dot(m2, inv, **_EXACT)
+
+
+@jax.custom_vjp
+def _solve(inv, a, r):
+    """``w = (I + a)^-1 r``, f32, with ``inv = (I + a)^-1`` handed in: built
+    by the forward pass, read by the backward one.  Its cotangents are closed
+    form, ``dr = (I + a)^-T dw`` and ``da = -dr w^T``: two products, where
+    autodiff through :func:`_inverse` takes twenty.  ``inv`` takes none: it
+    is no free variable, ``da`` is the whole of ``a``'s."""
+    return _solve_fwd(inv, a, r)[0]
+
+
+def _solve_fwd(inv, a, r):
+    w = jnp.dot(inv, r, **_EXACT)
+    return w, (inv, w)
+
+
+def _solve_bwd(residuals, dw):
+    inv, w = residuals
+    dr = lax.dot_general(inv, dw, (((0,), (0,)), ((), ())), **_EXACT)
+    da = -lax.dot_general(dr, w, (((1,), (1,)), ((), ())), **_EXACT)
+    return None, da, dr
+
+
+_solve.defvjp(_solve_fwd, _solve_bwd)
+
+
 # ---- one chunk ------------------------------------------------------------
 
-def _chunk(state, q, k, kb, v, decay):
+def _chunk(state, q, k, kb, v, decay, inv=None):
     """One head's chunk: ``state (d_v, d_k)`` f32 (the transpose of ``S``,
     so that the decay runs along lanes), ``q, k, kb, decay (CHUNK, d_k)``,
-    ``v (CHUNK, d_v)`` -> ``o (CHUNK, d_v)`` f32 and the state after the
-    chunk.  Plain ``jax.numpy`` on values: the ``chunked`` backend maps it
-    over batch and heads, the kernels call it on what they loaded."""
+    ``v (CHUNK, d_v)`` -> ``(o (CHUNK, d_v)`` f32, the state after the
+    chunk``)`` and ``(I + A)^-1 (CHUNK, CHUNK)`` f32, which the forward pass
+    builds (``inv=None``) and saves and the backward pass hands back in.
+    Plain ``jax.numpy`` on values: the ``chunked`` backend maps it over
+    batch and heads, the kernels call it on what they loaded."""
     dtype = q.dtype
     f32 = jnp.float32
-    exact = dict(precision=lax.Precision.HIGHEST, preferred_element_type=f32)
 
     def dot(a, b, contract):
         """Operands in the inputs' dtype, f32 accumulation."""
@@ -168,30 +231,17 @@ def _chunk(state, q, k, kb, v, decay):
     a = jnp.where(rows > cols, ab[:n], 0.0)
     b = jnp.where(rows >= cols, ab[n:], 0.0)
 
-    # (I + a)^-1, exactly (module docstring)
-    eye = (rows == cols).astype(f32)
-    d1 = jnp.where(block == cols // SUB, a, 0.0)
-    rest = a - d1
-    d2 = jnp.dot(d1, d1, **exact)
-    d4 = jnp.dot(d2, d2, **exact)
-    d8 = jnp.dot(d4, d4, **exact)
-    inv = jnp.dot(eye - d1, eye + d2, **exact)
-    inv = jnp.dot(inv, eye + d4, **exact)
-    inv = jnp.dot(inv, eye + d8, **exact)
-    m1 = jnp.dot(inv, rest, **exact)
-    m2 = jnp.dot(m1, m1, **exact)
-    inv = inv - jnp.dot(m1, inv, **exact)
-    inv = inv + jnp.dot(m2, inv, **exact)
-
     through = jnp.exp(decay)
     carried = dot(jnp.concatenate([k32 * through, q32 * through], axis=0),
                   state, (1, 1))            # what the state gives k and q
-    w = jnp.dot(inv, v.astype(f32) - carried[:n], **exact)
+    if inv is None:
+        inv = lax.stop_gradient(_inverse(a))
+    w = _solve(inv, a, v.astype(f32) - carried[:n])
     o = carried[n:] + dot(b, w, (1, 0))
     last = row(n - 1)
     state = state * jnp.exp(last) + dot(w, kb32 * jnp.exp(last - decay),
                                         (0, 0))
-    return o, state
+    return (o, state), inv
 
 
 # ---- the scan over chunks, with its backward --------------------------------
@@ -203,11 +253,11 @@ def _scan(q, k, kb, v, decay, heads, backend):
 
 def _scan_fwd(q, k, kb, v, decay, heads, backend):
     if backend == "chunked":
-        o, starts = _chunked_fwd(q, k, kb, v, decay, heads)
+        o, starts, inverses = _chunked_fwd(q, k, kb, v, decay, heads)
     else:
-        o, starts = _pallas_fwd(q, k, kb, v, decay, heads,
-                                backend == "pallas_interpret")
-    return o, (q, k, kb, v, decay, starts)
+        o, starts, inverses = _pallas_fwd(q, k, kb, v, decay, heads,
+                                          backend == "pallas_interpret")
+    return o, (q, k, kb, v, decay, starts, inverses)
 
 
 def _scan_bwd(heads, backend, residuals, do):
@@ -240,34 +290,38 @@ _heads_chunk = jax.vmap(jax.vmap(_chunk))      # over batch, then heads
 
 def _chunked_fwd(q, k, kb, v, decay, heads):
     def one_chunk(state, inputs):
-        o, after = _heads_chunk(state, *inputs)
-        return after, (o, state)
+        (o, after), inv = _heads_chunk(state, *inputs)
+        return after, (o, state, inv)
 
     b, d_k, d_v = q.shape[0], q.shape[2] // heads, v.shape[2] // heads
     zero = jnp.zeros((b, heads, d_v, d_k), jnp.float32)
-    _, (o, starts) = lax.scan(
+    _, (o, starts, inverses) = lax.scan(
         one_chunk, zero, tuple(_by_chunk(x, heads)
                                for x in (q, k, kb, v, decay)))
-    return _from_chunks(o).astype(v.dtype), starts
+    return _from_chunks(o).astype(v.dtype), starts, inverses
 
 
-def _chunked_bwd(q, k, kb, v, decay, starts, do, heads):
+def _chunked_bwd(q, k, kb, v, decay, starts, inverses, do, heads):
     def one_chunk(d_after, inputs):
-        *operands, start, d_o = inputs
-        _, pull = jax.vjp(_heads_chunk, start, *operands)
+        *operands, start, inv, d_o = inputs
+        _, pull, _ = jax.vjp(lambda *a: _heads_chunk(*a, inv), start,
+                             *operands, has_aux=True)
         d_start, *d_operands = pull((d_o.astype(jnp.float32), d_after))
         return d_start, tuple(d_operands)
 
     operands = tuple(_by_chunk(x, heads) for x in (q, k, kb, v, decay, do))
     _, grads = lax.scan(one_chunk, jnp.zeros_like(starts[0]),
-                        operands[:5] + (starts, operands[5]), reverse=True)
+                        operands[:5] + (starts, inverses, operands[5]),
+                        reverse=True)
     return tuple(_from_chunks(x) for x in grads)
 
 
 # ---- 'pallas': the TPU kernels ----------------------------------------------
 # Grid (batch, block of heads, chunk); every operand with a time axis is read
-# as the (CHUNK, heads a block * d) block of its (B, T, H * d) array; the
-# chunk-start states are (B, H, T / CHUNK, d_v, d_k) f32.  A grid step works
+# as the (CHUNK, heads a block * d) block of its (B, T, H * d) array, the
+# chunks' inverses among them ((B, T, H * CHUNK) f32: a head's 64 columns of
+# its chunk's 64 rows, so that the lanes are full); the chunk-start states
+# are (B, H, T / CHUNK, d_v, d_k) f32.  A grid step works
 # its heads' chunks one after the other in one basic block: the chains of
 # dependent products of different heads are independent, and the scheduler
 # overlaps them.
@@ -287,7 +341,7 @@ def _head(ref, j, heads):
 
 
 def _fwd_kernel(q_ref, k_ref, kb_ref, v_ref, decay_ref, o_ref, start_ref,
-                state_ref):
+                inv_ref, state_ref):
     from jax.experimental import pallas as pl
 
     @pl.when(pl.program_id(2) == 0)
@@ -299,14 +353,16 @@ def _fwd_kernel(q_ref, k_ref, kb_ref, v_ref, decay_ref, o_ref, start_ref,
     for j in range(heads):
         state = state_ref[j]
         start_ref[j] = state
-        o, after = _chunk(state, *(_head(ref, j, heads) for ref in (
+        (o, after), inv = _chunk(state, *(_head(ref, j, heads) for ref in (
             q_ref, k_ref, kb_ref, v_ref, decay_ref)))
         o_ref[:, j * d_v:(j + 1) * d_v] = o.astype(o_ref.dtype)
+        inv_ref[:, j * CHUNK:(j + 1) * CHUNK] = inv
         state_ref[j] = after
 
 
 def _bwd_kernel(q_ref, k_ref, kb_ref, v_ref, decay_ref, do_ref, start_ref,
-                dq_ref, dk_ref, dkb_ref, dv_ref, ddecay_ref, carried_ref):
+                inv_ref, dq_ref, dk_ref, dkb_ref, dv_ref, ddecay_ref,
+                carried_ref):
     from jax.experimental import pallas as pl
 
     @pl.when(pl.program_id(2) == 0)    # the last chunk: nothing follows it
@@ -315,9 +371,10 @@ def _bwd_kernel(q_ref, k_ref, kb_ref, v_ref, decay_ref, do_ref, start_ref,
 
     heads = carried_ref.shape[0]
     for j in range(heads):
-        _, pull = jax.vjp(_chunk, start_ref[j], *(
+        inv = _head(inv_ref, j, heads)
+        _, pull, _ = jax.vjp(lambda *a: _chunk(*a, inv), start_ref[j], *(
             _head(ref, j, heads) for ref in (q_ref, k_ref, kb_ref, v_ref,
-                                             decay_ref)))
+                                             decay_ref)), has_aux=True)
         d_start, *grads = pull((_head(do_ref, j, heads).astype(jnp.float32),
                                 carried_ref[j]))
         for ref, grad in zip((dq_ref, dk_ref, dkb_ref, dv_ref, ddecay_ref),
@@ -328,9 +385,9 @@ def _bwd_kernel(q_ref, k_ref, kb_ref, v_ref, decay_ref, do_ref, start_ref,
 
 
 def _specs(block, d_k, d_v, chunk_of):
-    """Block specs of a ``d_k``-wide and a ``d_v``-wide operand and of the
-    chunk-start states, ``block`` heads a grid step; ``chunk_of(j)`` is the
-    chunk the grid's ``j``-th step works on."""
+    """Block specs of a ``d_k``-wide and a ``d_v``-wide operand, of the
+    chunks' inverses and of the chunk-start states, ``block`` heads a grid
+    step; ``chunk_of(j)`` is the chunk the grid's ``j``-th step works on."""
     from jax.experimental import pallas as pl
 
     def tokens(d):
@@ -339,7 +396,7 @@ def _specs(block, d_k, d_v, chunk_of):
 
     states = pl.BlockSpec((None, block, None, d_v, d_k),
                           lambda i, h, j: (i, h, chunk_of(j), 0, 0))
-    return tokens(d_k), tokens(d_v), states
+    return tokens(d_k), tokens(d_v), tokens(CHUNK), states
 
 
 def _pallas_fwd(q, k, kb, v, decay, heads, interpret):
@@ -349,14 +406,15 @@ def _pallas_fwd(q, k, kb, v, decay, heads, interpret):
     b, t, _ = q.shape
     d_k, d_v, chunks = q.shape[2] // heads, v.shape[2] // heads, t // CHUNK
     block = _heads_a_step(heads)
-    keyed, valued, states = _specs(block, d_k, d_v, lambda j: j)
+    keyed, valued, inverse, states = _specs(block, d_k, d_v, lambda j: j)
     return pl.pallas_call(
         _fwd_kernel, grid=(b, heads // block, chunks),
         in_specs=[keyed, keyed, keyed, valued, keyed],
-        out_specs=[valued, states],
+        out_specs=[valued, states, inverse],
         out_shape=[jax.ShapeDtypeStruct(v.shape, v.dtype),
                    jax.ShapeDtypeStruct((b, heads, chunks, d_v, d_k),
-                                        jnp.float32)],
+                                        jnp.float32),
+                   jax.ShapeDtypeStruct((b, t, heads * CHUNK), jnp.float32)],
         scratch_shapes=[pltpu.VMEM((block, d_v, d_k), jnp.float32)],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
@@ -364,18 +422,19 @@ def _pallas_fwd(q, k, kb, v, decay, heads, interpret):
     )(q, k, kb, v, decay)
 
 
-def _pallas_bwd(q, k, kb, v, decay, starts, do, heads, interpret):
+def _pallas_bwd(q, k, kb, v, decay, starts, inverses, do, heads, interpret):
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
     b, t, _ = q.shape
     d_k, d_v, chunks = q.shape[2] // heads, v.shape[2] // heads, t // CHUNK
     block = _heads_a_step(heads)
-    keyed, valued, states = _specs(block, d_k, d_v,
-                                   lambda j: chunks - 1 - j)
+    keyed, valued, inverse, states = _specs(block, d_k, d_v,
+                                            lambda j: chunks - 1 - j)
     return tuple(pl.pallas_call(
         _bwd_kernel, grid=(b, heads // block, chunks),
-        in_specs=[keyed, keyed, keyed, valued, keyed, valued, states],
+        in_specs=[keyed, keyed, keyed, valued, keyed, valued, states,
+                  inverse],
         out_specs=[keyed, keyed, keyed, valued, keyed],
         out_shape=[jax.ShapeDtypeStruct(x.shape, x.dtype)
                    for x in (q, k, kb, v, decay)],
@@ -383,4 +442,4 @@ def _pallas_bwd(q, k, kb, v, decay, starts, do, heads, interpret):
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret, name="bf_kda_bwd_chunks",
-    )(q, k, kb, v, decay, do, starts))
+    )(q, k, kb, v, decay, do, starts, inverses))

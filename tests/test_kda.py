@@ -3,7 +3,9 @@ recurrence it computes, one token at a time under ``lax.scan``: forward and
 the gradient of every operand, on both backends (the ``jax.numpy`` one and
 the Pallas kernels in the interpreter), at lengths that are and are not
 whole chunks, with the decay at its lower bound for a whole chunk (where
-``exp(-G)`` would overflow) and at 0."""
+``exp(-G)`` would overflow) and at 0; and the closed-form adjoint of the
+chunk's triangular solve against autodiff through the inverse's ten
+products, with a count of the backward's f32 products that holds it there."""
 
 import os
 import sys
@@ -11,6 +13,7 @@ import sys
 import jax
 import jax.numpy as jnp
 import pytest
+from jax import lax
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 if REPO not in sys.path:
@@ -121,6 +124,119 @@ def test_the_two_backends_agree_and_padding_changes_nothing():
     longer = tuple(jnp.pad(x, ((0, 0), (0, 30)) + ((0, 0),) * (x.ndim - 2))
                    for x in args)
     assert relative(kda(*longer, backend="chunked")[:, :70], a) < 1e-6
+
+
+# ---- the solve's adjoint ----------------------------------------------------
+
+def one_chunk(dtype, decay, seed=11):
+    """``_chunk``'s operands for one head's chunk of 128-wide tokens (a
+    state that earlier chunks left, ``q, k, kb, v`` rounded to ``dtype`` but
+    held in f32, so that a gradient is read before any rounding, the decay
+    summed from the chunk's start) and cotangents for its two results."""
+    q, k, v, g, beta = (x[0, :, 0] for x in operands(
+        CHUNK, 128, decay, heads=1, seed=seed))
+    rounded = tuple(x.astype(dtype).astype(jnp.float32)
+                    for x in (q, k, k * beta[:, None], v))
+    keys = jax.random.split(jax.random.PRNGKey(seed + 1), 3)
+    state = jax.random.normal(keys[0], (128, 128)) * 128 ** -0.5
+    cotangents = (jax.random.normal(keys[1], (CHUNK, 128)),
+                  jax.random.normal(keys[2], (128, 128)))
+    return (state, *rounded, jnp.cumsum(g, axis=0)), cotangents
+
+
+def chunk_in(dtype, inv=None):
+    """``_chunk``'s two results, of operands cast to ``dtype``; ``inv``: the
+    chunk's inverse handed in, as the backward pass hands it."""
+    def chunk(state, q, k, kb, v, decay):
+        return kda_ops._chunk(state, *(x.astype(dtype)
+                                       for x in (q, k, kb, v)), decay, inv)[0]
+    return chunk
+
+
+def built_inverse(dtype, args):
+    """The inverse the forward pass builds for ``one_chunk``'s ``args``."""
+    return kda_ops._chunk(args[0], *(x.astype(dtype) for x in args[1:5]),
+                          args[5])[1]
+
+
+def solve_by_autodiff(inv, a, r):
+    """What ``_solve`` computes, its gradient left to autodiff: through the
+    product and the ten of the inverse."""
+    return jnp.dot(kda_ops._inverse(a), r, **kda_ops._EXACT)
+
+
+@pytest.mark.parametrize("decay", ["random", "lower", "zero"])
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["f32", "bf16"])
+def test_the_solve_s_adjoint_is_autodiff_s_through_the_inverse(
+        dtype, decay, monkeypatch):
+    args, cotangents = one_chunk(dtype, decay)
+    out, pull = jax.vjp(chunk_in(dtype, built_inverse(dtype, args)), *args)
+    got = pull(cotangents)
+    # the inverse built in place gives what the saved one gives
+    for a, b in zip(got, jax.vjp(chunk_in(dtype), *args)[1](cotangents)):
+        assert bool(jnp.all(a == b))
+    monkeypatch.setattr(kda_ops, "_solve", solve_by_autodiff)
+    same, pull = jax.vjp(chunk_in(dtype), *args)
+    want = pull(cotangents)
+    for a, b in zip(out, same):      # the forward: the same products
+        assert bool(jnp.all(a == b))
+    # autodiff rounds the cotangent of a bf16 product's operand to bf16, so
+    # there a difference of 1e-7 now and then rounds one element of a leaf's
+    # 8,192 the other way: one bf16 unit of it, up to 2e-4 of the leaf
+    apart = 1e-5 if dtype == jnp.float32 else 1e-3
+    names = ("state", "q", "k", "kb", "v", "decay")
+    for name, a, b in zip(names, got, want):
+        assert bool(jnp.all(jnp.isfinite(a))), name
+        assert relative(a, b) < apart, name
+        over = jnp.abs(a - b) > 1e-5 * jnp.max(jnp.abs(b))
+        assert int(jnp.sum(over)) <= a.size // 512, name
+
+
+def exact_products(jaxpr):
+    """``dot_general``s at ``Precision.HIGHEST`` in ``jaxpr`` and whatever
+    it calls."""
+    def inner(value):
+        if hasattr(value, "eqns"):
+            yield value
+        elif hasattr(value, "jaxpr"):
+            yield value.jaxpr
+        elif isinstance(value, (tuple, list)):
+            for v in value:
+                yield from inner(v)
+
+    count = 0
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "dot_general":
+            precision = eqn.params["precision"]
+            count += precision is not None and all(
+                p == lax.Precision.HIGHEST for p in precision)
+        count += sum(exact_products(j) for value in eqn.params.values()
+                     for j in inner(value))
+    return count
+
+
+def test_the_chunk_s_backward_holds_three_exact_products(monkeypatch):
+    """With bf16 operands the f32 products at ``highest`` are the solve's
+    alone: ``inv . r`` forward again and two for the adjoint where the
+    inverse is handed in, ten more where it is built in place.  Autodiff
+    through the inverse would make them 33, each six passes of the matrix
+    unit where a bf16 product is one."""
+    args, cotangents = one_chunk(jnp.bfloat16, "random")
+    saved = built_inverse(jnp.bfloat16, args)
+
+    def products(inv):
+        def backward(*args):        # traced anew a call: no cached jaxpr
+            # not under this file's ``highest``, which every product takes
+            with jax.default_matmul_precision("default"):
+                return jax.vjp(chunk_in(jnp.bfloat16, inv),
+                               *args)[1](cotangents)
+        return exact_products(jax.make_jaxpr(backward)(*args).jaxpr)
+
+    assert products(saved) <= 3
+    assert products(None) <= 13
+    monkeypatch.setattr(kda_ops, "_solve", solve_by_autodiff)
+    assert products(saved) == 33
 
 
 @pytest.mark.parametrize("bad", ["shape", "backend", "width"])
