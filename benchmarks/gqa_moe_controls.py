@@ -47,11 +47,12 @@ def say(kind, **fields):
     print(kind + " " + json.dumps(fields), flush=True)
 
 
-def by_group(leaves):
+def by_group(leaves, groups=None):
     """Per group the worst leaf by ``difference / largest magnitude`` and by
-    difference; ``leaves`` as ``reference.compare`` returns them."""
+    difference; ``leaves`` as ``reference.compare`` returns them, ``groups``
+    another family's in place of :data:`GROUPS`."""
     out = {}
-    for group, pattern in GROUPS.items():
+    for group, pattern in (groups or GROUPS).items():
         chosen = [leaf for leaf in leaves if pattern in leaf[1]]
         if chosen:
             rel = max(chosen, key=lambda leaf: leaf[2] / max(leaf[3], 1e-30))
@@ -69,7 +70,7 @@ def left_by_the_window(cell, steps):
     return state
 
 
-def check(name, cell, state, preroll, started, seed, out=None):
+def check(name, cell, state, preroll, started, seed, out=None, groups=None):
     report = {}
     ok, leaves, loss_err = run.agreement(cell, state, preroll, report)
     if out:       # every leaf, to hold the readings against other limits
@@ -80,7 +81,8 @@ def check(name, cell, state, preroll, started, seed, out=None):
                        "leaves": [list(leaf[1:]) for leaf in leaves]}, f)
     say("AGREEMENT", control=name, seed=seed, ok=bool(ok),
         loss_rel_err=loss_err, model_loss=report.get("model_loss"),
-        worst=[list(leaf) for leaf in leaves[:4]], groups=by_group(leaves),
+        worst=[list(leaf) for leaf in leaves[:4]],
+        groups=by_group(leaves, groups),
         seconds=round(time.time() - started, 1))
 
 
@@ -101,6 +103,94 @@ def rounded_to_bf16(step):
         state, loss = step(state, batch)
         return round_params(state), loss
     return wrapped
+
+
+def run_one_seed(argv, *, description, workload, preroll, model_controls,
+                 altered, groups=None, reference_controls=()):
+    """The command line of the later families' control scripts
+    (``linear_latent_moe_controls.py``, ``conv_gqa_moe_controls.py``): one
+    seed; each of ``bf16_params`` and ``lr_1.25`` through :func:`check`
+    from the state ``--preroll`` steps leave, then each of
+    ``reference_controls`` the same way with ``altered(name)`` in force
+    while the reference takes its steps (the system's step is compiled by
+    then and keeps its program), then each of ``model_controls`` as the
+    plain model's loss beside the system's with ``altered(name)`` in force
+    (it returns what undoes it).  ``groups`` replaces :data:`GROUPS` for
+    the family's leaves."""
+    step_controls = STEP_CONTROLS + tuple(reference_controls)
+    ap = argparse.ArgumentParser(description=description)
+    ap.add_argument("--workload", default=workload)
+    ap.add_argument("--manifest", default=os.path.join(
+        os.path.dirname(__file__), "..", "BENCHMARK.json"))
+    ap.add_argument("--seed", type=int, required=True)
+    ap.add_argument("--controls", default="all",
+                    help="comma-separated, or 'all'")
+    ap.add_argument("--preroll", type=int, default=preroll,
+                    help="steps before the check: what the window completes")
+    ap.add_argument("--out", help="directory for every leaf's difference")
+    args = ap.parse_args(argv)
+    controls = (step_controls + tuple(model_controls)
+                if args.controls == "all"
+                else tuple(c for c in args.controls.split(",") if c))
+    unknown = set(controls) - set(step_controls) - set(model_controls)
+    if unknown:
+        raise SystemExit(f"unknown controls {sorted(unknown)}")
+
+    bf.configure_compile_cache()
+    manifest = cells.Manifest.load(args.manifest)
+    cell = cells.build_cell(manifest, args.workload, args.seed)
+    tolerance = cell.config["tolerance"]
+    opt, _ = cells.build_step(cell.family, cell.config, cell.traffic,
+                              cell.ctx)
+    init = cells.build_init(cell.family, opt, cell.ctx)
+    key = jax.device_put(jnp.uint32(args.seed), jax.sharding.NamedSharding(
+        cell.ctx.mesh, jax.sharding.PartitionSpec()))
+    sound_step = cell.step
+    first = True
+    for name in (c for c in controls if c in step_controls):
+        started = time.time()
+        if not first:
+            cell.state, _ = init(key)
+        first = False
+        state = left_by_the_window(cell, args.preroll)
+        if name == "bf16_params":
+            cell.step = rounded_to_bf16(sound_step)
+            check(name, cell, state, args.preroll, started, args.seed,
+                  args.out, groups)
+            cell.step = sound_step
+        elif name in reference_controls:
+            undo = altered(name)
+            check(name, cell, state, args.preroll, started, args.seed,
+                  args.out, groups)
+            undo()
+        else:
+            real = cells.base_optimizer
+            cells.base_optimizer = lambda c: real({**c, "optimizer": {
+                **c["optimizer"],
+                "learning_rate": 1.25 * c["optimizer"]["learning_rate"]}})
+            check(name, cell, state, args.preroll, started, args.seed,
+                  args.out, groups)
+            cells.base_optimizer = real
+    chosen = [c for c in controls if c in model_controls]
+    if not chosen:
+        return
+    if not first:
+        cell.state, _ = init(key)
+    state = left_by_the_window(cell, args.preroll)
+    params, model_state = reference.from_host(
+        reference.to_host(state[:2], cell.devices), cell.devices)[0]
+    del state
+    batch, = reference.per_rank(cell.ring[0], cell.devices[:1])
+    for name in chosen:
+        started = time.time()
+        undo = altered(name)
+        err, want, got = reference.model_loss_error(
+            cell.family, params, model_state, batch)
+        undo()
+        say("MODEL_LOSS", control=name, seed=args.seed,
+            ok=bool(err <= tolerance["model_loss_rtol"]), rel_err=err,
+            reference=want, system=got,
+            seconds=round(time.time() - started, 1))
 
 
 def interleaved_rotary(x, positions, theta):

@@ -19,10 +19,8 @@ PERF.md section 6 (PR 41) has the readings.
       --seed 2147489001 --controls all
 """
 
-import argparse
 import os
 import sys
-import time
 
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), ".."))
 sys.path.insert(0, os.path.dirname(__file__))
@@ -30,14 +28,10 @@ sys.path.insert(0, os.path.dirname(__file__))
 import jax
 import jax.numpy as jnp
 
-import bluefog_tpu as bf
 from bluefog_tpu.models import transformer
-from chipbench import cell as cells
 from chipbench import linear_latent_moe_reference as ref
-from chipbench import reference
-from gqa_moe_controls import check, left_by_the_window, rounded_to_bf16, say
+from gqa_moe_controls import STEP_CONTROLS, run_one_seed  # noqa: F401
 
-STEP_CONTROLS = ("bf16_params", "lr_1.25")
 MODEL_CONTROLS = ("none", "decay_bf16", "scalar_decay", "another_lower_bound",
                   "ungrouped_routing", "no_short_conv")
 
@@ -71,73 +65,9 @@ def altered(name):
 
 
 def main(argv=None):
-    ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
-    ap.add_argument("--workload", default="ling3flash.t8192.solo")
-    ap.add_argument("--manifest", default=os.path.join(
-        os.path.dirname(__file__), "..", "BENCHMARK.json"))
-    ap.add_argument("--seed", type=int, required=True)
-    ap.add_argument("--controls", default="all",
-                    help="comma-separated, or 'all'")
-    ap.add_argument("--preroll", type=int, default=50,
-                    help="steps before the check: what the window completes")
-    ap.add_argument("--out", help="directory for every leaf's difference")
-    args = ap.parse_args(argv)
-    controls = (STEP_CONTROLS + MODEL_CONTROLS if args.controls == "all"
-                else tuple(c for c in args.controls.split(",") if c))
-    unknown = set(controls) - set(STEP_CONTROLS + MODEL_CONTROLS)
-    if unknown:
-        raise SystemExit(f"unknown controls {sorted(unknown)}")
-
-    bf.configure_compile_cache()
-    manifest = cells.Manifest.load(args.manifest)
-    cell = cells.build_cell(manifest, args.workload, args.seed)
-    tolerance = cell.config["tolerance"]
-    opt, _ = cells.build_step(cell.family, cell.config, cell.traffic,
-                              cell.ctx)
-    init = cells.build_init(cell.family, opt, cell.ctx)
-    key = jax.device_put(jnp.uint32(args.seed), jax.sharding.NamedSharding(
-        cell.ctx.mesh, jax.sharding.PartitionSpec()))
-    sound_step = cell.step
-    first = True
-    for name in (c for c in controls if c in STEP_CONTROLS):
-        started = time.time()
-        if not first:
-            cell.state, _ = init(key)
-        first = False
-        state = left_by_the_window(cell, args.preroll)
-        if name == "bf16_params":
-            cell.step = rounded_to_bf16(sound_step)
-            check(name, cell, state, args.preroll, started, args.seed,
-                  args.out)
-            cell.step = sound_step
-        else:
-            real = cells.base_optimizer
-            cells.base_optimizer = lambda c: real({**c, "optimizer": {
-                **c["optimizer"],
-                "learning_rate": 1.25 * c["optimizer"]["learning_rate"]}})
-            check(name, cell, state, args.preroll, started, args.seed,
-                  args.out)
-            cells.base_optimizer = real
-    model_controls = [c for c in controls if c in MODEL_CONTROLS]
-    if not model_controls:
-        return
-    if not first:
-        cell.state, _ = init(key)
-    state = left_by_the_window(cell, args.preroll)
-    params, model_state = reference.from_host(
-        reference.to_host(state[:2], cell.devices), cell.devices)[0]
-    del state
-    batch, = reference.per_rank(cell.ring[0], cell.devices[:1])
-    for name in model_controls:
-        started = time.time()
-        undo = altered(name)
-        err, want, got = reference.model_loss_error(
-            cell.family, params, model_state, batch)
-        undo()
-        say("MODEL_LOSS", control=name, seed=args.seed,
-            ok=bool(err <= tolerance["model_loss_rtol"]), rel_err=err,
-            reference=want, system=got,
-            seconds=round(time.time() - started, 1))
+    run_one_seed(argv, description=__doc__.split("\n\n")[0],
+                 workload="ling3flash.t8192.solo", preroll=50,
+                 model_controls=MODEL_CONTROLS, altered=altered)
 
 
 if __name__ == "__main__":

@@ -44,12 +44,19 @@ GPT-2 decoder this file always built, parameter for parameter:
                  embedding and the head (:func:`next_token_loss`)
 ``layer_types``  ``None``, or **what each block mixes its tokens with**.
                  Either plain attention layers of the ``grouped_query``
-                 kind (sizes in :class:`GroupedSizes`):
+                 kind (sizes in :class:`GroupedSizes`; the three kinds are
+                 stated at :class:`GroupedQueryAttention`):
                  ``full_attention`` (causal over all keys, **no positional
-                 encoding**) and ``window_rotary_attention`` (the last
-                 ``grouped.window`` keys, queries and keys turned by rotary
-                 over the whole head); any ``ffn`` and ``norm`` go with
-                 them.  Or a linear-attention / latent-attention hybrid
+                 encoding**), ``full_rotary_attention`` (all keys, queries
+                 and keys turned by rotary over the whole head) and
+                 ``window_rotary_attention`` (the last ``grouped.window``
+                 keys, turned likewise), with ``grouped.qk_norm`` an
+                 RMSNorm a head on queries and keys before the turn; among
+                 them, or alone, ``short_conv`` (:class:`ShortConv`, a
+                 gated causal depthwise convolution of ``short_conv.taps``
+                 taps: LFM2's operator, neither an attention nor a
+                 recurrence; sizes in :class:`ShortConvSizes`); any ``ffn``
+                 and ``norm`` go with them.  Or a linear-attention / latent-attention hybrid
                  (Kimi Linear, arXiv:2510.26692; sizes in :class:`KdaSizes`
                  and :class:`LatentSizes`): ``kda`` (:class:`KdaMixer`, the
                  delta rule with a per-channel decay) and
@@ -91,7 +98,12 @@ up-projection) and their rows of the output projection, while what every
 head reads stays whole (the latent down-projection, the per-head norms'
 scales).  The layer's result is those heads' part of the output
 projection's sum; the shares of all the chips add up to the uncut layer's
-(``tests/test_linear_latent_moe.py``).
+(``tests/test_linear_latent_moe.py``).  *Where neither is cut*: the
+grouped-query and ``short_conv`` layers hold every head and every channel
+(``heads_held`` is refused there), so a model of those layers with routed
+experts is shared by its experts and its vocabulary alone: every chip
+computes the mixers alike, and the expert shares add up to the uncut
+layer's (``tests/test_conv_gqa_moe.py``).
 """
 
 from __future__ import annotations
@@ -112,6 +124,7 @@ from bluefog_tpu.ops.moe import (
 from bluefog_tpu.ops.ring_attention import local_attention
 from bluefog_tpu.ops.row_sums import take_rows
 from bluefog_tpu.ops.selective_scan import selective_scan
+from bluefog_tpu.ops.short_conv import gated_short_conv
 
 AttnFn = Callable[..., jnp.ndarray]  # (q, k, v) -> (B, T, H, D)
 
@@ -160,13 +173,24 @@ class GroupedSizes:
     """Grouped-query attention: ``kv_heads`` key/value heads serve
     ``num_heads`` query heads (head ``h`` reads ``h // (num_heads /
     kv_heads)``), every head ``head_dim`` wide whatever ``hidden_size /
-    num_heads`` is.  ``window`` and ``rope_theta`` are the
-    ``window_rotary_attention`` layers'."""
+    num_heads`` is.  ``window`` is the ``window_rotary_attention`` layers',
+    ``rope_theta`` theirs and the ``full_rotary_attention`` layers'.
+    ``qk_norm``: an RMSNorm over each head's ``head_dim`` on queries and on
+    keys (one scale a side, shared by the heads) before any turn."""
 
     kv_heads: int
     head_dim: int
     window: int
     rope_theta: float
+    qk_norm: bool = False
+
+
+@dataclasses.dataclass(frozen=True)
+class ShortConvSizes:
+    """A ``short_conv`` layer (:class:`ShortConv`): ``taps`` of the causal
+    depthwise convolution over the model width (LFM2's ``conv_L_cache``)."""
+
+    taps: int = 3
 
 
 ROUTERS = ("sigmoid_noaux_tc", "softmax_topk")
@@ -182,7 +206,8 @@ class ExpertSizes:
 
     ``router``: ``sigmoid_noaux_tc`` (DeepSeek-V3, arXiv:2412.19437 §2.1.2:
     sigmoid scores, a selection-bias buffer, the chosen scores normalised
-    and times ``scale``; with ``n_group > 1`` the selection is
+    over their sum plus ``weight_eps`` and times ``scale``; with
+    ``n_group > 1`` the selection is
     group-limited: the experts in ``n_group`` equal groups, a group's score
     the sum of its two best, the ``topk_group`` best groups kept and the
     ``top_k`` taken among theirs) or ``softmax_topk`` (a softmax over the
@@ -217,11 +242,14 @@ class ExpertSizes:
     train_router: bool = True
     n_group: int = 1               # sigmoid router: groups of experts,
     topk_group: int = 1            # and how many of them a token keeps
+    weight_eps: float = 0.0        # sigmoid router: added to the chosen sum
 
 
 MIXERS = ("mamba", "diff_attention", "diff_attention_window", "gmu",
           "cross_diff_attention")
-ATTENTION_LAYERS = ("full_attention", "window_rotary_attention")
+ATTENTION_LAYERS = ("full_attention", "window_rotary_attention",
+                    "full_rotary_attention")
+CONV_LAYERS = ("short_conv",)      # built beside the attention layers
 LINEAR_LAYERS = ("kda", "latent_attention")
 ROUTED = "routed+shared"
 
@@ -272,6 +300,7 @@ class GPTConfig:
     grouped: Optional[GroupedSizes] = None
     kda: Optional[KdaSizes] = None
     heads_held: Optional[Tuple[int, int]] = None    # (first, count)
+    short_conv: Optional[ShortConvSizes] = None
 
     def __post_init__(self):
         for field, kinds in (("attention", ("fused_qkv", "latent",
@@ -289,24 +318,29 @@ class GPTConfig:
         mixers = [kind in MIXERS for kind in types]
         linear = [kind in LINEAR_LAYERS for kind in types]
         for kind in types:
-            if kind not in MIXERS + ATTENTION_LAYERS + LINEAR_LAYERS:
+            if kind not in (MIXERS + ATTENTION_LAYERS + CONV_LAYERS
+                            + LINEAR_LAYERS):
                 raise ValueError(
                     f"unknown layer type {kind!r} in layer_types; expected "
                     f"mixers {MIXERS}, attention layers {ATTENTION_LAYERS} "
-                    f"or linear/latent layers {LINEAR_LAYERS}")
+                    f"with {CONV_LAYERS} among them, or linear/latent "
+                    f"layers {LINEAR_LAYERS}")
         for family in (mixers, linear):
             if any(family) and not all(family):
                 raise ValueError(
                     "layer_types mixes layer families; a model takes the "
                     f"SambaY mixers {MIXERS}, the attention layers "
-                    f"{ATTENTION_LAYERS} or the linear/latent layers "
-                    f"{LINEAR_LAYERS}")
+                    f"{ATTENTION_LAYERS} with {CONV_LAYERS} among them, or "
+                    f"the linear/latent layers {LINEAR_LAYERS}")
         if any(mixers) != (self.hybrid is not None):
             raise ValueError("the `hybrid` sizes and the SambaY mixers in "
                              "`layer_types` come together")
         if ("kda" in types) != (self.kda is not None):
             raise ValueError("the `kda` sizes and the 'kda' layers of "
                              "`layer_types` come together")
+        if ("short_conv" in types) != (self.short_conv is not None):
+            raise ValueError("the `short_conv` sizes and the 'short_conv' "
+                             "layers of `layer_types` come together")
         if any(linear) and (self.attention, self.position) != (
                 "latent", "rotary"):
             raise ValueError(
@@ -348,6 +382,10 @@ class GPTConfig:
         if self.grouped and self.num_heads % self.grouped.kv_heads:
             raise ValueError(f"{self.num_heads} query heads do not divide "
                              f"over {self.grouped.kv_heads} key/value heads")
+        if self.grouped and self.grouped.qk_norm and not any(
+                kind in ATTENTION_LAYERS for kind in types):
+            raise ValueError("grouped.qk_norm norms the queries and keys of "
+                             "the attention layers; `layer_types` has none")
         if self.experts is not None:
             self._check_experts()
 
@@ -392,6 +430,11 @@ class GPTConfig:
                 f"experts.n_group {ex.n_group} / topk_group {ex.topk_group}:"
                 f" equal groups of the {ex.num_experts} experts, some of "
                 "them kept, under the sigmoid router")
+        if ex.weight_eps < 0 or (
+                ex.weight_eps and ex.router != "sigmoid_noaux_tc"):
+            raise ValueError(
+                f"experts.weight_eps {ex.weight_eps}: 0 or more, added to "
+                "the sum the sigmoid router's chosen scores are divided by")
         if ex.n_group > 1 and (
                 ex.num_experts // ex.n_group < 2
                 or ex.topk_group * (ex.num_experts // ex.n_group)
@@ -717,12 +760,23 @@ class GroupedQueryAttention(nn.Module):
     ``grouped.kv_heads`` heads, all ``grouped.head_dim`` wide; query head
     ``h`` reads key/value head ``h // (num_heads / kv_heads)`` (the grouped
     heads of :func:`~bluefog_tpu.ops.ring_attention.local_attention`, which
-    ``attn_fn`` is handed as they are).  ``kind`` is the layer's type:
-    ``full_attention`` attends causally over every key and turns nothing
-    (no positional encoding); ``window_rotary_attention`` turns ``q`` and
-    ``k`` by rotary over the whole head (half-split pairs, ``rope_theta``)
-    and sees the last ``window`` keys.  Scores scale by ``head_dim ** -0.5``
-    (``attn_fn``'s default for that width)."""
+    ``attn_fn`` is handed as they are).  With ``grouped.qk_norm``, ``q`` and
+    ``k`` first go through an RMSNorm over each head's ``head_dim`` (scales
+    ``q_norm``, ``k_norm``, one a side shared by the heads; ``norm_eps``;
+    f32).  ``kind`` is the layer's type, **one of three** (what it sees,
+    whether it turns ``q`` and ``k``):
+
+    ==========================  ===========================  ===============
+    ``full_attention``          every key ``s <= t``         turns nothing
+                                                             (no positional
+                                                             encoding)
+    ``full_rotary_attention``   every key ``s <= t``         rotary
+    ``window_rotary_attention`` the last ``window`` keys     rotary
+    ==========================  ===========================  ===============
+
+    The rotary is over the whole head, half-split pairs, ``rope_theta``.
+    Scores scale by ``head_dim ** -0.5`` (``attn_fn``'s default for that
+    width)."""
 
     cfg: GPTConfig
     kind: str
@@ -731,6 +785,7 @@ class GroupedQueryAttention(nn.Module):
     def __call__(self, y, attn_fn: AttnFn, positions):
         cfg, gq = self.cfg, self.cfg.grouped
         windowed = self.kind == "window_rotary_attention"
+        turned = windowed or self.kind == "full_rotary_attention"
         head_dense = functools.partial(HeadDense, use_bias=False,
                                        dtype=cfg.dtype)
         with jax.named_scope("bf.attn.project"):
@@ -740,7 +795,11 @@ class GroupedQueryAttention(nn.Module):
                            (gq.kv_heads, gq.head_dim), name="k")(y)
             v = head_dense(gq.kv_heads * gq.head_dim,
                            (gq.kv_heads, gq.head_dim), name="v")(y)
-        if windowed:
+            if gq.qk_norm:
+                q, k = (nn.RMSNorm(epsilon=cfg.norm_eps, dtype=jnp.float32,
+                                   name=name)(x).astype(cfg.dtype)
+                        for name, x in (("q_norm", q), ("k_norm", k)))
+        if turned:
             with jax.named_scope("bf.attn.rotary"):
                 q = rotary(q, positions, gq.rope_theta, interleaved=False)
                 k = rotary(k, positions, gq.rope_theta, interleaved=False)
@@ -766,6 +825,43 @@ def causal_depthwise_conv(x, kernel, bias):
     taps, t = kernel.shape[0], x.shape[1]
     padded = jnp.pad(x, ((0, 0), (taps - 1, 0), (0, 0)))
     return sum(kernel[j] * padded[:, j:j + t] for j in range(taps)) + bias
+
+
+class ShortConv(nn.Module):
+    """LFM2's gated short convolution (``Lfm2ShortConv`` of the published
+    modelling code): ``(B, T, D) -> (B, T, D)``, no bias, no activation.
+
+    ``[b; c; z] = W_in y`` (three thirds of ``3 D``, in that order);
+    ``s = b * z``; ``conv_t = sum_j k_j * s_{t - (taps - 1) + j}`` a channel
+    with zeros before the sequence (``taps = short_conv.taps``); output
+    ``W_out (c * conv)``.  The two gates and the taps are f32 between the
+    projections' ``dtype``
+    (:func:`bluefog_tpu.ops.short_conv.gated_short_conv`: on a TPU one
+    kernel a direction, one pass over ``b``, ``z`` and ``c`` in and the
+    gated result out; elsewhere ``jax.numpy``).  The taps start uniform
+    within ``taps ** -0.5`` (a depthwise ``Conv1d``'s default), the
+    projections at flax's."""
+
+    cfg: GPTConfig
+
+    @nn.compact
+    def __call__(self, y):
+        cfg, taps = self.cfg, self.cfg.short_conv.taps
+        dense = functools.partial(nn.Dense, use_bias=False, dtype=cfg.dtype)
+
+        def within(key, shape, dtype=jnp.float32):
+            bound = taps ** -0.5
+            return jax.random.uniform(key, shape, dtype, -bound, bound)
+
+        with jax.named_scope("bf.sconv.project"):
+            bcz = dense(3 * cfg.hidden_size, name="in_proj")(y)
+        with jax.named_scope("bf.sconv.gate_conv"):
+            kernel = self.param("conv_kernel", within,
+                                (taps, cfg.hidden_size), jnp.float32)
+            gated = gated_short_conv(bcz, kernel)
+        with jax.named_scope("bf.sconv.project"):
+            out = dense(cfg.hidden_size, name="out_proj")(gated)
+        return metrics_comm.count(out, [("bf_sconv_calls_total", 1.0)])
 
 
 class MambaMixer(nn.Module):
@@ -938,7 +1034,8 @@ def _route(ex: ExpertSizes, flat, router, bias):
     else:
         idx, weights = sigmoid_topk_router(
             flat, router, bias.value, top_k=ex.top_k, scale=ex.scale,
-            n_group=ex.n_group, topk_group=ex.topk_group)
+            n_group=ex.n_group, topk_group=ex.topk_group,
+            eps=ex.weight_eps)
     if not ex.train_router:
         weights = jax.lax.stop_gradient(weights)
     return idx, weights
@@ -1070,7 +1167,8 @@ class Block(nn.Module):
 
     ``mixer`` is the block's entry of ``cfg.layer_types``.  One of
     ``ATTENTION_LAYERS`` says what the grouped-query attention attends over
-    and whether it turns its keys.  One of ``MIXERS`` replaces the attention
+    and whether it turns its keys; ``short_conv`` puts :class:`ShortConv`
+    (parameters ``conv``) in the attention's place.  One of ``MIXERS`` replaces the attention
     with that token mixer; such a block takes and returns ``carried =
     (memory, keys, values)`` beside ``x``: what the last Mamba block and the
     last full
@@ -1097,6 +1195,8 @@ class Block(nn.Module):
         moe, routing = _early_routing(self, y)
         if self.mixer == "kda":
             a = KdaMixer(cfg, name="attn")(y)
+        elif self.mixer == "short_conv":
+            a = ShortConv(cfg, name="conv")(y)
         elif cfg.attention == "latent":
             a = LatentAttention(cfg, name="attn")(y, attn_fn, positions)
         elif cfg.attention == "grouped_query":

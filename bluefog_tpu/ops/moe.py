@@ -270,12 +270,14 @@ def moe_ffn_reference(x, router_kernel, wi, wo, *, num_experts: int,
 
 def sigmoid_topk_router(x, router_kernel, bias, *, top_k: int,
                         scale: float = 1.0, n_group: int = 1,
-                        topk_group: int = 1):
+                        topk_group: int = 1, eps: float = 0.0):
     """DeepSeek-V3's ``noaux_tc`` routing (arXiv:2412.19437 section 2.1.2):
     ``s = sigmoid(x @ W_g)`` in f32, the chosen set is the ``top_k`` largest
     of ``s + bias``, the weights are ``scale * s_i / sum of the chosen s`` —
     from ``s`` **without** the bias, which only steers the selection and
-    takes no gradient.
+    takes no gradient.  ``eps`` is added to that sum where a model's
+    normaliser has one (LFM2's ``s_i / (sum + 1e-6)``); at 0 nothing is
+    added and the program is the one without it.
 
     With ``n_group > 1`` the selection is **group-limited** (the
     node-limited routing of the same section): the experts lie in
@@ -308,7 +310,9 @@ def sigmoid_topk_router(x, router_kernel, bias, *, top_k: int,
                               -jnp.inf).reshape(t, e)
         _, idx = lax.top_k(steer, top_k)
         chosen = jnp.take_along_axis(s, idx, axis=-1)
-        weights = scale * chosen / jnp.sum(chosen, axis=-1, keepdims=True)
+        weights = scale * chosen
+        total = jnp.sum(chosen, axis=-1, keepdims=True)
+        weights = weights / (total + eps if eps else total)
     if n_group > 1:
         weights = metrics_comm.count(weights, [(
             "bf_moe_groups_kept_total", float(x.shape[0] * topk_group))])

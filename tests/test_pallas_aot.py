@@ -667,6 +667,93 @@ def test_grouped_query_attention_kernels_compile_for_v5e(window,
     assert "flash_mha_bwd_splash_mha_dkv" in txt
 
 
+def _lfm2_shape(one, dims, dtype=jnp.bfloat16):
+    return jax.ShapeDtypeStruct(dims, dtype, sharding=one)
+
+
+def test_full_attention_at_64_wide_grouped_heads_compiles_for_v5e(
+        tpu_aot_topology):
+    """``lfm2-8b-a1b``'s attention layer at its cell's shape: four sequences
+    of 8,192, 32 query over 8 key/value heads of **64**, keys and values
+    repeated, causal over every key, forward and fused backward."""
+    from bluefog_tpu.ops.ring_attention import _repeat_heads, _splash_attention
+
+    one = _one_chip(tpu_aot_topology)
+
+    def grads(q, k, v):
+        return jax.grad(lambda q, k, v: _splash_attention(
+            q, _repeat_heads(k, 32), _repeat_heads(v, 32), causal=True,
+            scale=64 ** -0.5).astype(jnp.float32).sum(),
+            argnums=(0, 1, 2))(q, k, v)
+
+    kv = _lfm2_shape(one, (4, 8192, 8, 64))
+    txt = jax.jit(grads).lower(_lfm2_shape(one, (4, 8192, 32, 64)), kv,
+                               kv).compile().as_text()
+    assert "flash_attention_splash_mha_fwd" in txt
+    assert "flash_mha_bwd_splash_mha_dkv" in txt
+
+
+@pytest.mark.duration_budget(60)   # nine grouped products at 65,536 rows
+def test_grouped_matmuls_at_the_deployment_s_load_compile_for_v5e(
+        tpu_aot_topology):
+    """``routed_experts`` at ``lfm2moe.t8192.solo``'s layer: 32,768 tokens of
+    2,048 choosing 4 of 32 experts, 8 held of width **1,792** (an 896 tile,
+    seven lanes' worth), so a row buffer of 65,536 of the 131,072 sorted
+    rows and 32 tiles of 1,024 tokens' sums; value and gradient."""
+    from bluefog_tpu.ops.moe import _gmm_tiling, _row_buffer, routed_experts
+    from bluefog_tpu.ops.row_sums import sums_tile
+
+    one = _one_chip(tpu_aot_topology)
+    assert _row_buffer(32768 * 4, 8, 32) == 65536
+    assert _gmm_tiling(65536, 2048, 1792) == (256, 1024, 896)
+    assert sums_tile(32768, 2048) == 1024
+
+    def value_and_grads(x, idx, weights, wg, wu, wd):
+        def total(x, weights, wg, wu, wd):
+            return (routed_experts(
+                x, idx, weights, wg, wu, wd, num_experts=32, held=(0, 8),
+                backend="gmm")[0].astype(jnp.float32) ** 2).sum()
+        return jax.value_and_grad(total, argnums=(0, 1, 2, 3, 4))(
+            x, weights, wg, wu, wd)
+
+    f32 = jnp.float32
+    txt = jax.jit(value_and_grads).lower(
+        _lfm2_shape(one, (32768, 2048)),
+        _lfm2_shape(one, (32768, 4), jnp.int32),
+        _lfm2_shape(one, (32768, 4), f32),
+        _lfm2_shape(one, (8, 2048, 1792), f32),
+        _lfm2_shape(one, (8, 2048, 1792), f32),
+        _lfm2_shape(one, (8, 1792, 2048), f32)).compile().as_text()
+    assert len(_re.findall(r"%gmm(\.\d+)? = ", txt)) == 3 + 6
+    assert len(_re.findall(r"%tgmm(\.\d+)? = ", txt)) == 3
+    assert "bf16[65536,1792]" in txt
+    assert txt.count("bf_moe_add_rows_by_token") >= 2
+
+
+def test_short_convolution_kernels_compile_for_v5e(tpu_aot_topology):
+    """The gate-convolution-gate kernels at the cell's shape: they read the
+    in projection's ``(4, 8192, 3 * 2048)`` output as it lies (no slice of
+    it is copied for them) and write one cotangent of that shape back."""
+    from bluefog_tpu.ops.short_conv import gated_short_conv
+
+    one = _one_chip(tpu_aot_topology)
+
+    def value_and_grads(bcz, kernel):
+        return jax.value_and_grad(lambda bcz, kernel: gated_short_conv(
+            bcz, kernel, backend="pallas").astype(jnp.float32).sum(),
+            argnums=(0, 1))(bcz, kernel)
+
+    txt = jax.jit(value_and_grads).lower(
+        _lfm2_shape(one, (4, 8192, 3 * 2048)),
+        _lfm2_shape(one, (3, 2048), jnp.float32)).compile().as_text()
+    assert txt.count("tpu_custom_call") == 2
+    assert "bf_sconv_fwd" in txt and "bf_sconv_bwd" in txt
+    copies = [line for line in txt.splitlines()
+              if _re.search(r" (copy|transpose|slice)\(", line)
+              and "4,8192," in line.replace(" ", "")]
+    assert not copies, copies
+
+
 def test_gpt2_attention_sublayer_keeps_its_layout_copies_few_on_v5e(
         tpu_aot_topology):
     """One GPT-2 block of ``gpt2s.t2048.solo`` (batch 8, T=2048, 768 wide,
