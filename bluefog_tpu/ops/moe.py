@@ -40,6 +40,7 @@ tests/test_moe.py::test_expert_parallel_grads_match_reference and the
 from __future__ import annotations
 
 import functools
+import math
 from typing import NamedTuple, Tuple
 
 import jax
@@ -268,6 +269,212 @@ def moe_ffn_reference(x, router_kernel, wi, wo, *, num_experts: int,
 # ---------------------------------------------------------------------------
 
 
+_LANES = 128                # tokens a step of the selection kernel ranks
+_SELECT_TILE_BYTES = 2 ** 20    # of f32 scores in a grid step's block
+_SELECT_MOST_EXPERTS = 1024   # rows of a (E, 128) slab the kernel unrolls
+
+
+def _select_form(t: int, e: int, k: int, n_group: int = 1) -> str:
+    """How :func:`_top_k` ranks ``(t, e)`` scores: ``'kernel'`` (the Pallas
+    kernel ``bf_moe_select``) on a TPU where its tile serves the shape,
+    ``'sorted'`` (``lax.top_k`` and ``take_along_axis``) elsewhere: the
+    portable backend, the CPU tests, a token count that is not whole
+    128-lane slabs (a model's 16-token init pass), experts or groups that
+    are not whole sublanes of 8, more than ``_SELECT_MOST_EXPERTS``
+    columns.  From the backend and the shape alone, as
+    :func:`_sums_in_vmem` and ``row_sums._lookup_form``; tests ask for
+    ``'kernel_interpret'``, the kernel in the Pallas interpreter, by
+    patching this function."""
+    tiled = (t % _LANES == 0 and e % (8 * n_group) == 0
+             and k <= e <= _SELECT_MOST_EXPERTS)
+    return ("kernel" if tiled and jax.default_backend() == "tpu"
+            else "sorted")
+
+
+def _keep_groups(scores, n_group: int, topk_group: int):
+    """``scores (T, E)`` with the experts outside the ``topk_group`` best of
+    ``n_group`` equal groups at minus infinity; a group's score is the sum
+    of its two largest.  The sorted form's group stage."""
+    t, e = scores.shape
+    grouped = scores.reshape(t, n_group, e // n_group)
+    _, kept = lax.top_k(lax.top_k(grouped, 2)[0].sum(-1), topk_group)
+    open_groups = jnp.any(kept[..., None] == jnp.arange(n_group), axis=1)
+    return jnp.where(open_groups[..., None], grouped, -jnp.inf).reshape(t, e)
+
+
+def _best(x, ids, dead):
+    """A slab's maximum over its rows and the lowest of ``ids`` that holds
+    it, ``(1, lanes)`` each; ``ids`` reads ``dead`` where a row is out."""
+    m = jnp.max(x, axis=0, keepdims=True)
+    return m, jnp.min(jnp.where(x == m, ids, dead), axis=0, keepdims=True)
+
+
+def _select_in_vmem(scores, values, k, n_group, topk_group, interpret):
+    """The kernel form of :func:`_top_k`: ``scores (T, E)`` f32 (and
+    ``values``, or ``None`` for the scores themselves) → ``idx (T, k)``
+    int32, ``chosen (T, k)`` f32.
+
+    **Tokens lie on the lanes**: the kernel reads ``(E, T)`` (XLA turns the
+    matmul's output) in blocks of ``(E, tt)`` and ranks 128 tokens at a
+    time, an ``(E, 128)`` slab in which a reduction over the experts is an
+    element-wise pass over ``E / 8`` vregs and one over a vreg's sublanes.
+    A round: the slab's maximum, the lowest live row that holds it (ties to
+    the lower index), the value at that row summed through the row's own
+    mask (one term that is not zero: the element to the bit), the row dead
+    for the next round: minus infinity in the scores and ``E`` in the row
+    ids, so a score that *is* minus infinity is still told from a dead row
+    and listed in index order, as the sorted form lists it.  With groups
+    a slab first gives each group the sum of its two best and counts, for
+    each group, the groups that come before it; the rows of the groups not
+    kept start dead.  Ids and values are written once, ``(k, tt)`` blocks
+    that XLA turns back."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    t, e = scores.shape
+    g = e // n_group
+    tt = min(t, max(_LANES, _SELECT_TILE_BYTES // (4 * e) // _LANES * _LANES))
+    own = values is None
+    neg = -jnp.inf
+
+    def kernel(*refs):
+        s_ref, v_ref = (refs[0], None) if own else refs[:2]
+        idx_ref, out_ref = refs[-2:]
+        rows = lax.broadcasted_iota(jnp.int32, (e, _LANES), 0)
+
+        def kept_groups(s):
+            """``(e, 128)`` bool: the rows of the ``topk_group`` best
+            groups, a group's score the sum of its two largest.  A group is
+            kept where fewer than ``topk_group`` others come before it: a
+            higher score, or the same at a lower index."""
+            local = rows[:g]
+            sums = []
+            for j in range(n_group):
+                sg = s[j * g:(j + 1) * g]
+                m1, at = _best(sg, local, g)
+                m2 = jnp.max(jnp.where(local == at, neg, sg), axis=0,
+                             keepdims=True)
+                sums.append(m1 + m2)
+            kept = []
+            for j in range(n_group):
+                before = sum(jnp.where(
+                    sums[i] >= sums[j] if i < j else sums[i] > sums[j], 1, 0)
+                    for i in range(n_group) if i != j)
+                kept.append(jnp.broadcast_to(before < topk_group,
+                                             (g, _LANES)))
+            return jnp.concatenate(kept, axis=0)
+
+        def one_slab(c, carry):
+            lanes = pl.ds(pl.multiple_of(c * _LANES, _LANES), _LANES)
+            s, ids = s_ref[:, lanes], rows
+            if n_group > 1:
+                keep = kept_groups(s)
+                s, ids = jnp.where(keep, s, neg), jnp.where(keep, rows, e)
+            for i in range(k):
+                m, at = _best(s, ids, e)
+                mine = ids == at
+                idx_ref[pl.ds(i, 1), lanes] = at
+                out_ref[pl.ds(i, 1), lanes] = m if own else jnp.sum(
+                    jnp.where(mine, v_ref[:, lanes], 0.0), axis=0,
+                    keepdims=True)
+                s, ids = jnp.where(mine, neg, s), jnp.where(mine, e, ids)
+            return carry
+
+        lax.fori_loop(0, tt // _LANES, one_slab, jnp.int32(0))
+
+    block = pl.BlockSpec((e, tt), lambda i: (0, i))
+    idx, chosen = pl.pallas_call(
+        kernel, grid=(_ceil_div(t, tt),),
+        in_specs=[block] * (1 if own else 2),
+        out_specs=[pl.BlockSpec((k, tt), lambda i: (0, i))] * 2,
+        out_shape=[jax.ShapeDtypeStruct((k, t), jnp.int32),
+                   jax.ShapeDtypeStruct((k, t), jnp.float32)],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel",)),
+        interpret=interpret, name="bf_moe_select",
+    )(*((scores.T,) if own else (scores.T, values.T)))
+    return idx.T, chosen.T
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(2, 3, 4, 5))
+def _select(scores, values, k, n_group, topk_group, interpret):
+    """:func:`_select_in_vmem` with the selection's gradient rule: the
+    cotangent of ``chosen`` written densely at the chosen ids into
+    ``values`` (into ``scores`` where they are their own values), one
+    fusion and no scatter; the ids take none."""
+    return _select_in_vmem(scores, values, k, n_group, topk_group, interpret)
+
+
+def _select_fwd(scores, values, k, n_group, topk_group, interpret):
+    idx, chosen = _select_in_vmem(scores, values, k, n_group, topk_group,
+                                  interpret)
+    return (idx, chosen), (idx, scores.shape[-1], values is None)
+
+
+def _select_bwd(k, n_group, topk_group, interpret, res, g):
+    idx, e, own = res
+    # written as the kernel reads it, experts by tokens: the turn is free
+    d = jnp.sum(jnp.where(
+        idx.T[:, None] == jnp.arange(e, dtype=idx.dtype)[:, None],
+        g[1].T[:, None], 0.0), axis=0).T
+    return (d, None) if own else (None, d)
+
+
+_select.defvjp(_select_fwd, _select_bwd)
+
+
+def _top_k(scores, k: int, values=None, *, n_group: int = 1,
+           topk_group: int = 1):
+    """``(idx, chosen)``: the ids ``(…, k)`` int32 of the ``k`` largest
+    ``scores (…, E)`` along the last axis in descending order, **ties to
+    the lower index** (``lax.top_k``'s order), and ``values`` (default: the
+    scores) at those ids.  The ids take no gradient and ``scores`` none
+    through them; ``values`` take the cotangent of ``chosen`` at the chosen
+    ids.  With ``n_group > 1`` the selection is group-limited first: only
+    the experts of the ``topk_group`` best of ``n_group`` equal groups in
+    index order (a group's score: the sum of its two largest) can be
+    chosen.
+
+    Two forms with one result to the bit (:func:`_select_form`, from the
+    backend and the shape).  *The kernel* ``bf_moe_select``
+    (:func:`_select_in_vmem`), one ``pallas_call`` a call, groups
+    included: rounds of max over a slab of tokens in VMEM, so the scores
+    are read from HBM once and nothing is sorted, gathered or scattered;
+    its gradient is a dense write.  *Sorted*: ``lax.top_k`` over whole rows
+    and ``take_along_axis``, whose gradient is a scatter-add; the portable
+    form, the fallback for shapes no tile serves, and the reference the
+    tests hold the kernel's bits against.  Scores that are NaN have no
+    order in either form."""
+    e = scores.shape[-1]
+    lead = scores.shape[:-1]
+    form = _select_form(math.prod(lead), e, k, n_group)
+    if form == "sorted":
+        if n_group > 1:
+            scores = _keep_groups(scores.reshape(-1, e), n_group,
+                                  topk_group).reshape(scores.shape)
+        if values is None:
+            chosen, idx = lax.top_k(scores, k)
+            return idx, chosen
+        _, idx = lax.top_k(scores, k)
+        return idx, jnp.take_along_axis(values, idx, axis=-1)
+    flat = None if values is None else values.reshape(-1, e)
+    idx, chosen = _select(scores.reshape(-1, e), flat, k, n_group,
+                          topk_group, form == "kernel_interpret")
+    return idx.reshape(lead + (k,)), chosen.reshape(lead + (k,))
+
+
+def _count_routing(weights, t, e, top_k, n_group=1, topk_group=1):
+    """The routers' counters, with metrics on: a grouped call's kept groups
+    and the rows of scores a ``bf_moe_select`` kernel ranked (none where
+    the call took the sorted form)."""
+    counters = []
+    if n_group > 1:
+        counters.append(("bf_moe_groups_kept_total", float(t * topk_group)))
+    if _select_form(t, e, top_k, n_group) != "sorted":
+        counters.append(("bf_moe_select_kernel_rows_total", float(t)))
+    return metrics_comm.count(weights, counters)
+
+
 def sigmoid_topk_router(x, router_kernel, bias, *, top_k: int,
                         scale: float = 1.0, n_group: int = 1,
                         topk_group: int = 1, eps: float = 0.0):
@@ -285,58 +492,61 @@ def sigmoid_topk_router(x, router_kernel, bias, *, top_k: int,
     its two largest ``s + bias``, the ``topk_group`` best groups are kept,
     and the ``top_k`` are taken among the kept groups' experts (the others
     at minus infinity).  Ties go to the lower index, groups and experts
-    alike (``lax.top_k``).  ``n_group = 1`` is the selection above,
-    instruction for instruction.
+    alike, and the chosen are listed by descending ``s + bias``
+    (``lax.top_k``'s order).
+
+    **How the set is picked** (:func:`_top_k`): on a TPU one Pallas kernel a
+    call, ``bf_moe_select`` — ``top_k`` rounds of max over a slab of tokens
+    in VMEM, the group stage in the same kernel, ``s`` read through each
+    round's own mask — and elsewhere (the portable backend, a token count
+    that is not whole 128-token slabs) ``lax.top_k`` over whole rows with
+    ``take_along_axis``; the two agree to the bit in ids, weights and
+    gradients, and nothing but the backend and the shape chooses.
 
     ``x (T, D)``, ``router_kernel (D, E)``, ``bias (E,)`` →
     ``idx (T, top_k)`` int32 expert ids, ``weights (T, top_k)`` f32.  The
     matmul runs at ``highest`` precision: on a TPU an f32 product is
     otherwise rounded to bf16, and the chosen set flips on that rounding.
     With metrics on, a grouped call adds its kept groups (``T *
-    topk_group``) to ``bf_moe_groups_kept_total``.
+    topk_group``) to ``bf_moe_groups_kept_total`` and a call the kernel
+    served its ``T`` rows to ``bf_moe_select_kernel_rows_total``.
     """
     with jax.named_scope("bf.moe.route"):
         s = jax.nn.sigmoid(jnp.dot(
             x.astype(jnp.float32), router_kernel.astype(jnp.float32),
             precision=lax.Precision.HIGHEST))
         steer = s + lax.stop_gradient(bias.astype(jnp.float32))
-        if n_group > 1:
-            t, e = steer.shape
-            grouped = steer.reshape(t, n_group, e // n_group)
-            _, kept = lax.top_k(lax.top_k(grouped, 2)[0].sum(-1), topk_group)
-            open_groups = jnp.any(
-                kept[..., None] == jnp.arange(n_group), axis=1)
-            steer = jnp.where(open_groups[..., None], grouped,
-                              -jnp.inf).reshape(t, e)
-        _, idx = lax.top_k(steer, top_k)
-        chosen = jnp.take_along_axis(s, idx, axis=-1)
+        idx, chosen = _top_k(steer, top_k, s, n_group=n_group,
+                             topk_group=topk_group)
         weights = scale * chosen
         total = jnp.sum(chosen, axis=-1, keepdims=True)
         weights = weights / (total + eps if eps else total)
-    if n_group > 1:
-        weights = metrics_comm.count(weights, [(
-            "bf_moe_groups_kept_total", float(x.shape[0] * topk_group))])
-    return idx, weights
+    return idx, _count_routing(weights, *s.shape, top_k, n_group, topk_group)
 
 
 def softmax_topk_router(x, router_kernel, *, top_k: int):
     """Softmax routing with the chosen weights renormalised: ``l = x @ W_r``
-    in f32, the chosen set is the ``top_k`` largest ``l``, the weights are
-    ``exp(l_i) / sum of the chosen exp(l)``.  A softmax over all the experts
-    followed by renormalising over the chosen gives the same weights, so the
-    order of the two is no choice.  No bias, no scale.
+    in f32, the chosen set is the ``top_k`` largest ``l`` (ties to the lower
+    index, listed by descending ``l``), the weights are ``exp(l_i) / sum of
+    the chosen exp(l)``.  A softmax over all the experts followed by
+    renormalising over the chosen gives the same weights, so the order of
+    the two is no choice.  No bias, no scale.
 
     ``x (T, D)``, ``router_kernel (D, E)`` → ``idx (T, top_k)`` int32,
     ``weights (T, top_k)`` f32; the matmul at ``highest`` precision, as
-    :func:`sigmoid_topk_router`'s and for its reason.
+    :func:`sigmoid_topk_router`'s and for its reason; the set is picked as
+    there (:func:`_top_k`: the kernel ``bf_moe_select`` on a TPU, the
+    round's maximum being the chosen logit; ``lax.top_k`` elsewhere), and a
+    call the kernel served counts its ``T`` rows in
+    ``bf_moe_select_kernel_rows_total``.
     """
     with jax.named_scope("bf.moe.route"):
         logits = jnp.dot(
             x.astype(jnp.float32), router_kernel.astype(jnp.float32),
             precision=lax.Precision.HIGHEST)
-        chosen, idx = lax.top_k(logits, top_k)
+        idx, chosen = _top_k(logits, top_k)
         weights = jax.nn.softmax(chosen, axis=-1)
-    return idx, weights
+    return idx, _count_routing(weights, *logits.shape, top_k)
 
 
 def _ceil_div(a, b):

@@ -798,3 +798,74 @@ def test_gpt2_attention_sublayer_keeps_its_layout_copies_few_on_v5e(
     inside = [c for c in large if "Block" in c[2]]
     assert len(inside) <= 8, inside
     assert len(large) - len(inside) <= 2, large
+
+
+# tokens, width, experts, top_k, groups, kept, router: the routers of
+# ling3flash, joyai, lfm2moe and smallthinker as their cells call them
+_ROUTERS = {
+    "ling3flash": (8192, 2560, 512, 8, 8, 4, "sigmoid"),
+    "joyai": (8192, 2048, 256, 8, 1, 1, "sigmoid"),
+    "lfm2moe": (32768, 2048, 32, 4, 1, 1, "sigmoid"),
+    "smallthinker": (16384, 2560, 64, 6, 1, 1, "softmax"),
+}
+
+
+@pytest.mark.parametrize("cell", sorted(_ROUTERS))
+def test_the_routers_selection_is_one_kernel_and_no_larger_on_v5e(
+        cell, tpu_aot_topology, monkeypatch):
+    """A router call at a cell's shape, value and gradient (by ``x``, the
+    router's kernel and the bias), compiled for a v5e as the chip's rule
+    takes it: one ``bf_moe_select`` custom call — the group stage of
+    ``ling3flash``'s 8 groups inside it — and under it no sort, no gather
+    and no scatter; then in the sorted form, asked for in turn, which holds
+    the row sort.  **The guard on the size**: the kernel form's serialized
+    executable, what a warm start reads and loads, is within 1.5 times the
+    sorted form's (PR 44's unrolled XLA rounds were 7 times it over twelve
+    calls and were refused for the set-up seconds that cost, PERF.md
+    section 6)."""
+    from jax.experimental import serialize_executable
+
+    from bluefog_tpu.ops import moe
+
+    t, d, e, k, n_group, topk_group, kind = _ROUTERS[cell]
+    one = _one_chip(tpu_aot_topology)
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    assert moe._select_form(t, e, k, n_group) == "kernel"
+
+    def compiled():
+        # a function of its own a compile: the form is read as it is traced
+        def value_and_grads(x, w, bias, probe):
+            def total(x, w, bias):
+                if kind == "softmax":
+                    idx, weights = moe.softmax_topk_router(x, w, top_k=k)
+                else:
+                    idx, weights = moe.sigmoid_topk_router(
+                        x, w, bias, top_k=k, scale=2.5, n_group=n_group,
+                        topk_group=topk_group)
+                return jnp.sum(weights * probe), idx
+            return jax.value_and_grad(total, argnums=(0, 1, 2),
+                                      has_aux=True)(x, w, bias)
+
+        def shape(dims, dtype=jnp.float32):
+            return jax.ShapeDtypeStruct(dims, dtype, sharding=one)
+
+        return jax.jit(value_and_grads).lower(
+            shape((t, d), jnp.bfloat16), shape((d, e)), shape((e,)),
+            shape((t, k))).compile()
+
+    def size(executable):
+        return len(serialize_executable.serialize(executable)[0])
+
+    kernel = compiled()
+    txt = kernel.as_text()
+    assert len(_re.findall(r"%bf_moe_select(\.\d+)? = ", txt)) == 1
+    assert _re.findall(r"bf\.moe\.route\)?/bf_moe_select", txt)   # its scope
+    for op in ("sort", "gather", "scatter"):
+        assert not _re.findall(rf" {op}\(", txt), op
+    monkeypatch.setattr(moe, "_select_form", lambda *a, **kw: "sorted")
+    sorted_form = compiled()
+    assert _re.findall(r" sort\(", sorted_form.as_text())
+    assert "bf_moe_select" not in sorted_form.as_text()
+    assert size(kernel) <= 1.5 * size(sorted_form)
+    assert (kernel.memory_analysis().temp_size_in_bytes
+            <= sorted_form.memory_analysis().temp_size_in_bytes)
