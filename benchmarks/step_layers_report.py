@@ -21,6 +21,7 @@ harness's; one ``step_layers_report:`` line a rule table goes before it):
 import collections
 import json
 import os
+import re
 import sys
 
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), ".."))
@@ -63,6 +64,35 @@ def layers(measured, rules_name, att, floor_ms=FLOOR_MS):
             "below_floor_ms": dict(small)}
 
 
+def head_loss_chunks(cell):
+    """How ``ops/head_loss.py`` cut each ``head_loss`` call of the cell's
+    loss: ``{site: {"chunks": n, "chunk_rows": rows}}`` from the gauges the
+    call sets as it is traced, read off one more trace of the loss with
+    metrics on (shapes alone, nothing runs: the step itself cannot be built
+    with metrics on).  Empty where the loss makes no such call."""
+    import jax
+
+    from bluefog_tpu.metrics import registry
+
+    key = jax.random.PRNGKey(0)
+    family = cell.family
+    params, model_state = jax.eval_shape(family.init, key)
+    batch = jax.eval_shape(family.make_batch, key)
+    reg = registry.metrics_start()
+    try:
+        jax.eval_shape(family.loss, params, model_state, batch)
+        snapshot = reg.snapshot()
+    finally:
+        registry.metrics_stop()
+    sites = collections.defaultdict(dict)
+    for series, value in snapshot.items():
+        found = re.fullmatch(r'bf_head_loss_(chunks|chunk_rows)'
+                             r'\{site="(\w+)"\}', series)
+        if found:
+            sites[found.group(2)][found.group(1)] = int(value)
+    return dict(sites)
+
+
 def main(argv=None):
     harness_argv = sys.argv[1:] if argv is None else list(argv)
     attribute = scope_ms.attribute
@@ -70,6 +100,9 @@ def main(argv=None):
     def attribute_and_write(measured, rules_name):
         att = attribute(measured, rules_name)
         table = layers(measured, rules_name, att)
+        chunks = ({"head_loss_chunks": head_loss_chunks(measured.cell)}
+                  if "head_loss" in table["phases_ms"] else {})
+        table.update(chunks)
         seed = scope_ms.seed_of_this_run()
         path = os.path.join(REPO, "chipbench_out", (
             f"{measured.cell.name}.seed{seed}.{rules_name}.layers.json"))
@@ -80,7 +113,8 @@ def main(argv=None):
             {"rules": rules_name, "file": os.path.relpath(path, REPO),
              "reducer_seconds": round(att["seconds"], 3),
              "phases_ms": {p: round(v, 3)
-                           for p, v in table["phases_ms"].items()}}),
+                           for p, v in table["phases_ms"].items()},
+             **chunks}),
             flush=True)
         return att
 

@@ -118,6 +118,7 @@ import jax
 import jax.numpy as jnp
 
 from bluefog_tpu.metrics import comm as metrics_comm
+from bluefog_tpu.ops.head_loss import head_loss
 from bluefog_tpu.ops.kda import LOWER as KDA_LOWER, kda
 from bluefog_tpu.ops.moe import (
     ACTIVATIONS, routed_experts, sigmoid_topk_router, softmax_topk_router)
@@ -1229,14 +1230,17 @@ class TransformerLM(nn.Module):
     ``h'_i = M [norm(Emb(t_{i+1})); norm(h_i)]`` with ``h_i`` the trunk's
     output before its final norm, one more block, and the trunk's own
     embedding and head (one leaf each, used twice); ``mtp_logits`` predicts
-    ``t_{i+2}``."""
+    ``t_{i+2}``.  ``head=False`` is :func:`next_token_loss`'s: the normed
+    hidden states the head would read, in place of each logits (an ``init``
+    runs with the head, which makes an untied head's leaf)."""
 
     cfg: GPTConfig
     mlp: Optional[Callable[[], nn.Module]] = None
 
     @nn.compact
     def __call__(self, tokens, *, attn_fn: Optional[AttnFn] = None,
-                 position_offset=0, positions=None, next_tokens=None):
+                 position_offset=0, positions=None, next_tokens=None,
+                 head: bool = True):
         cfg = self.cfg
         if attn_fn is None:
             # the model layer is the perf path: opt into the fused TPU flash
@@ -1277,11 +1281,13 @@ class TransformerLM(nn.Module):
             project = nn.Dense(cfg.vocab_size, dtype=jnp.float32,
                                use_bias=False, name="lm_head")
 
-        def head(h):
+        def logits_of(h):
+            if not head:     # next_token_loss's: the head goes with the loss
+                return h
             with jax.named_scope("bf.head.logits"):
                 return project(h)
 
-        logits = head(_norm(cfg, "ln_f", x))
+        logits = logits_of(_norm(cfg, "ln_f", x))
         if next_tokens is None:
             return logits
         if not cfg.mtp_depth:
@@ -1295,7 +1301,7 @@ class TransformerLM(nn.Module):
                          name="mtp_proj")(merged)
         z = block_cls(cfg, mlp=self.mlp, name="mtp_block")(
             z, attn_fn, positions)
-        return logits, head(_norm(cfg, "mtp_norm", z))
+        return logits, logits_of(_norm(cfg, "mtp_norm", z))
 
 
 def next_token_loss(model: TransformerLM, params, model_state, tokens, *,
@@ -1304,23 +1310,29 @@ def next_token_loss(model: TransformerLM, params, model_state, tokens, *,
     the ``B * T`` positions of the cross entropy of the main head against
     ``t_{i+1}`` plus, with a multi-token-prediction module, ``mtp_weight``
     times that of the module's head against ``t_{i+2}``; logits in f32.
-    ``model_state`` holds the non-parameter collections (``buffers``)."""
-    import optax
+    ``model_state`` holds the non-parameter collections (``buffers``).
 
-    depth = model.cfg.mtp_depth
+    No whole ``(B, T, V)`` logits and no gradient of them are made: the
+    model hands back its normed hidden states and ``ops/head_loss.py`` takes
+    the head's leaf (``lm_head/kernel``, or the tied ``tok/embedding``)
+    through the matmul, the cross entropy and both of the head's gradients
+    a chunk of token rows at a time.  The MTP pair shares the leaf: two
+    calls, whose gradients of it add."""
+    cfg = model.cfg
+    depth = cfg.mtp_depth
     t = tokens.shape[1] - 1 - depth
     variables = {"params": params, **model_state}
+    leaf = (params["tok"]["embedding"] if cfg.tie_head
+            else params["lm_head"]["kernel"])
 
-    def cross_entropy(logits, targets):
-        with jax.named_scope("bf.head.loss"):
-            return optax.softmax_cross_entropy_with_integer_labels(
-                logits.astype(jnp.float32), targets).mean()
+    def cross_entropy(h, targets, site):
+        return head_loss(h, leaf, targets, tied=cfg.tie_head, site=site)
 
     if not depth:
-        logits = model.apply(variables, tokens[:, :t], attn_fn=attn_fn)
-        return cross_entropy(logits, tokens[:, 1:])
-    logits, mtp_logits = model.apply(
+        h = model.apply(variables, tokens[:, :t], attn_fn=attn_fn, head=False)
+        return cross_entropy(h, tokens[:, 1:], "main")
+    h, mtp_h = model.apply(
         variables, tokens[:, :t], attn_fn=attn_fn,
-        next_tokens=tokens[:, 1:t + 1])
-    return (cross_entropy(logits, tokens[:, 1:t + 1])
-            + mtp_weight * cross_entropy(mtp_logits, tokens[:, 2:]))
+        next_tokens=tokens[:, 1:t + 1], head=False)
+    return (cross_entropy(h, tokens[:, 1:t + 1], "main")
+            + mtp_weight * cross_entropy(mtp_h, tokens[:, 2:], "mtp"))

@@ -479,23 +479,10 @@ def test_the_lookup_s_gradient_holds_no_row_wide_scatter_on_v5e(
     assert "custom_call_has_side_effect=true" not in txt
 
 
-@pytest.mark.parametrize("cell,kernels,parent_temp", [
-    ("phi4flash.t8192.solo", 1, 3_265_160_704),
-    ("smallthinker.t16384.solo", 1, 3_452_474_880),
-    ("joyai.t4096.solo", 2, 2_713_568_768),
-    ("gpt2s.t2048.solo", 0, 8_057_458_688),
-    ("gpt2s.t8192.solo", 0, 3_754_795_520),
-    ("gpt2s.t2048.exp2x4", 0, 8_667_998_720)])
-def test_the_decoder_steps_sum_the_table_s_gradient_in_vmem_on_v5e(
-        cell, kernels, parent_temp, monkeypatch):
-    """The benchmark's six decoder steps, built as ``chipbench/run.py``
-    builds them and compiled for v5e (one chip; the 2x2 ring for the
-    four-rank cell): one ``bf_embed_add_rows_by_id`` a lookup of a table
-    of 2,048 columns or more (the MTP model looks its table up twice), no
-    scatter under ``bf.embed.`` and no side effect on the kernel; GPT-2's
-    tables keep XLA's scatter-adds and no kernel.  ``temp_size_in_bytes`` within 0.5 % of what the step took
-    before the lookup had a rule of its own (PR 36's tree; GPT-2's to the
-    byte)."""
+def _compile_cell_step(cell, monkeypatch):
+    """A benchmark cell's step, built as ``chipbench/run.py`` builds it and
+    compiled for v5e (one chip; the 2x2 ring for the four-rank cell), with
+    what a process on the chip answers patched in where the steps ask."""
     import importlib
     import os
     import types
@@ -522,7 +509,8 @@ def test_the_decoder_steps_sum_the_table_s_gradient_in_vmem_on_v5e(
         return ((params, model_state, opt.init(params)),
                 family.make_batch(key))
 
-    # what a process on the chip answers, asked where the steps ask it
+    # the shapes first: an init pass is too short for the chip's kernels
+    shapes = jax.eval_shape(init, jax.random.PRNGKey(0))
     monkeypatch.setattr(pg, "on_tpu_platform", lambda: True)
     if cell.startswith("gpt2s"):
         monkeypatch.setattr(      # the package exports a function by
@@ -534,9 +522,28 @@ def test_the_decoder_steps_sum_the_table_s_gradient_in_vmem_on_v5e(
     sharding = NamedSharding(mesh, P("bf"))
     state, batch = jax.tree_util.tree_map(
         lambda t: jax.ShapeDtypeStruct((n,) + t.shape, t.dtype,
-                                       sharding=sharding),
-        jax.eval_shape(init, jax.random.PRNGKey(0)))
-    compiled = step.lower(state, batch).compile()
+                                       sharding=sharding), shapes)
+    return step.lower(state, batch).compile()
+
+
+@pytest.mark.parametrize("cell,kernels,parent_temp", [
+    ("phi4flash.t8192.solo", 1, 3_265_160_704),
+    ("smallthinker.t16384.solo", 1, 3_452_474_880),
+    ("joyai.t4096.solo", 2, 2_713_568_768),
+    ("gpt2s.t2048.solo", 0, 8_057_458_688),
+    ("gpt2s.t8192.solo", 0, 3_754_795_520),
+    ("gpt2s.t2048.exp2x4", 0, 8_667_998_720)])
+def test_the_decoder_steps_sum_the_table_s_gradient_in_vmem_on_v5e(
+        cell, kernels, parent_temp, monkeypatch):
+    """The benchmark's six decoder steps, built as ``chipbench/run.py``
+    builds them and compiled for v5e (one chip; the 2x2 ring for the
+    four-rank cell): one ``bf_embed_add_rows_by_id`` a lookup of a table
+    of 2,048 columns or more (the MTP model looks its table up twice), no
+    scatter under ``bf.embed.`` and no side effect on the kernel; GPT-2's
+    tables keep XLA's scatter-adds and no kernel.  ``temp_size_in_bytes`` within 0.5 % of what the step took
+    before the lookup had a rule of its own (PR 36's tree; GPT-2's to the
+    byte)."""
+    compiled = _compile_cell_step(cell, monkeypatch)
     txt = compiled.as_text()
     assert len(_re.findall(r"%bf_embed_add_rows_by_id(\.\d+)? = ",
                            txt)) == kernels
@@ -551,6 +558,39 @@ def test_the_decoder_steps_sum_the_table_s_gradient_in_vmem_on_v5e(
         assert temp <= parent_temp * 1.005, (temp, parent_temp)
     else:
         assert temp == parent_temp
+
+
+# the step's temporaries with whole f32 logits (PR 45's tree), batch, rows,
+# V, chunks of head_loss, head_loss calls
+@pytest.mark.parametrize("cell,whole_temp,batch,rows,vocab,chunks,heads", [
+    ("lfm2moe.t8192.solo", 7_175_690_752, 4, 32768, 16384, 8, 1),
+    ("joyai.t4096.solo", 2_669_011_968, 2, 8192, 16160, 4, 2)])
+def test_the_step_holds_no_whole_logits_on_v5e(
+        cell, whole_temp, batch, rows, vocab, chunks, heads, monkeypatch):
+    """The head and the loss in token chunks (``ops/head_loss.py``), in the
+    fullest cell's step and in the MTP pair's, optimizer included: no f32
+    array of ``rows x V`` elements in any shape, a head's three matmuls in
+    the chunk loop's body (and, untied, once more for the last chunk: a
+    fourth in either place would be the logits made again for the backward
+    pass), and the temporaries that whole logits cost stay gone:
+    ``lfm2moe``'s step under 5.2 GiB where it took 6.68."""
+    compiled = _compile_cell_step(cell, monkeypatch)
+    txt = compiled.as_text()
+    whole = [line.strip()[:200] for line in txt.splitlines()
+             if _re.search(rf"f32\[(1,)?({rows}|{batch},{rows // batch}|"
+                           rf"{chunks},{rows // chunks}),{vocab}\]", line)]
+    assert not whole, whole[:3]
+    matmuls = [line for line in txt.splitlines()
+               if _re.search(r" convolution\(", line) and "bf.head." in line]
+    if cell.startswith("lfm2moe"):      # tied: every chunk in the loop
+        assert len(matmuls) == 3 and all(
+            "/while/body/" in line for line in matmuls), matmuls
+    else:       # untied, four chunks a head: the loop of three and the last
+        assert len(matmuls) == 2 * 3 * heads, matmuls
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    assert temp < whole_temp
+    if cell.startswith("lfm2moe"):
+        assert temp < 5.2 * 2 ** 30, temp
 
 
 def test_selective_scan_kernels_compile_for_v5e(tpu_aot_topology):

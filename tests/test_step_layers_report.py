@@ -64,3 +64,39 @@ def test_every_instruction_is_listed_with_its_layer_and_its_pass(report):
         table["below_floor_ms"].values()) == pytest.approx(
         table["device_ms_per_step"])
     assert table["below_floor_ms"] == {"other|add": 1 / per_step}
+
+
+def test_the_head_s_chunks_are_read_off_one_trace_of_the_cell_s_loss(report,
+                                                                     monkeypatch):
+    """The gauges ``ops/head_loss.py`` sets as a call is traced, a call site
+    a row, at the shapes the cell's family makes; a loss that never calls
+    ``head_loss`` gives no row."""
+    import jax
+    import jax.numpy as jnp
+
+    from bluefog_tpu.models.transformer import (
+        GPTConfig, TransformerLM, next_token_loss)
+    from bluefog_tpu.ops import head_loss as hl
+
+    monkeypatch.setattr(hl, "_CHUNK_ELEMENTS", 64 * 50)
+    monkeypatch.setattr(hl, "_MIN_ROWS", 8)
+    model = TransformerLM(GPTConfig(
+        vocab_size=50, hidden_size=16, num_layers=1, num_heads=2,
+        max_position=128, mtp_depth=1))
+    tokens = jnp.zeros((1, 8), jnp.int32)
+
+    def loss(params, model_state, batch):
+        return next_token_loss(model, params, model_state, batch,
+                               mtp_weight=0.1), model_state
+
+    cell = types.SimpleNamespace(family=types.SimpleNamespace(
+        init=lambda key: (model.init(key, tokens,
+                                     next_tokens=tokens)["params"], {}),
+        make_batch=lambda key: jnp.zeros((3, 102), jnp.int32),   # 300 rows
+        loss=loss))
+    assert report.head_loss_chunks(cell) == {
+        "main": {"chunks": 5, "chunk_rows": 64},
+        "mtp": {"chunks": 5, "chunk_rows": 64}}
+    cell.family.loss = lambda params, model_state, batch: (
+        jnp.float32(0), model_state)
+    assert report.head_loss_chunks(cell) == {}
