@@ -45,7 +45,7 @@ def census(n: int, param_bytes: int):
     sched = build_schedule(ExponentialTwoGraph(n))
 
     fn = jax.jit(shard_map(
-        lambda v: C.neighbor_allreduce(v, sched, "bf", backend="xla"),
+        lambda v: C.neighbor_allreduce(v, sched, "bf"),
         mesh=mesh, in_specs=(P("bf"),), out_specs=P("bf"), check_vma=False))
     hlo = fn.lower(leaf).as_text()
     k = hlo.count("collective_permute") or hlo.count("collective-permute")

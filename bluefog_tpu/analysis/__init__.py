@@ -9,9 +9,9 @@ ranges must stay disjoint across concurrently-issued kernel families.
 This package checks all of that *before* anything runs:
 
 - :mod:`~bluefog_tpu.analysis.registry` — collective-id allocator /
-  auditor: declarative id-range families (gossip [1024, 2048), windows
-  [2048, ...)), per-caller ``(base, limit)`` leases, and an audit pass
-  that reports overlap between concurrent leases.
+  auditor: the window kernels' declarative id range ([2048, ...)),
+  per-caller ``(base, limit)`` leases, and an audit pass that reports
+  overlap between concurrent leases.
 - :mod:`~bluefog_tpu.analysis.topology_check` — topology verifier:
   row/column stochasticity, self-loop sanity, strong connectivity,
   spectral gap, and period-union connectivity for time-varying schedules.
@@ -34,7 +34,6 @@ from bluefog_tpu.analysis.registry import (
     GLOBAL_LEASES,
     CollectiveIdLease,
     LeaseRegistry,
-    plan_gossip_leases,
 )
 from bluefog_tpu.analysis.topology_check import (
     check_dynamic_schedules,
@@ -77,7 +76,6 @@ __all__ = [
     "GLOBAL_LEASES",
     "CollectiveIdLease",
     "LeaseRegistry",
-    "plan_gossip_leases",
     "check_dynamic_schedules",
     "check_mixing_matrix",
     "check_schedule",

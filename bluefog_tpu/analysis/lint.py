@@ -16,10 +16,8 @@ What it covers, deliberately the same surfaces the examples exercise:
 2. **dynamic** — the one-peer exponential-2 and ring periods, the
    generator-materialized dynamic topologies, and the jittable aperiodic
    mixing matrices: per-phase stochasticity + period-union connectivity.
-3. **collective-ids** — the gradient-tracking optimizer's declared
-   id split (``GT_COLLECTIVE_ID_RANGES``) audited against a
-   production-scale fused parameter buffer's chunk plan, and the window
-   family's bucket arithmetic.
+3. **collective-ids** — the window family's bucket arithmetic: a probe
+   window's name-derived lease audited against its family's range.
 4. **comm-lint** — traces gossip collectives and both distributed
    optimizers' update steps (``jax.make_jaxpr`` under ``shard_map``) and
    walks the jaxprs for permutation/axis/callback hazards; checks buffer
@@ -148,28 +146,12 @@ def dynamic_pass(report: LintReport, size: int) -> None:
 
 
 def collective_id_pass(report: LintReport, size: int) -> None:
-    import jax.numpy as jnp
-
-    from bluefog_tpu.analysis.registry import (GLOBAL_LEASES,
-                                               plan_gossip_leases)
-    from bluefog_tpu.optim.optimizers import GT_COLLECTIVE_ID_RANGES
+    from bluefog_tpu.analysis.registry import GLOBAL_LEASES
     from bluefog_tpu.ops import pallas_gossip
 
-    # gradient tracking's declared split, audited against the chunk plan
-    # of a production-scale fused buffer (ResNet-18-sized: ~11M f32
-    # params fused into one flat leaf -> ~11 kernel invocations at the
-    # default 4 MiB cap).  This is the exact configuration ADVICE.md's
-    # medium finding showed could silently overlap before the per-call
-    # limit existed.
-    fused = {"fused_f32": jnp.zeros((11_000_000,), jnp.float32)}
+    # the one kernel family that takes collective ids is the window deliver
+    # kernel: a window's name-derived bucket must stay in its family
     with GLOBAL_LEASES.scope() as reg:
-        plan_gossip_leases(
-            [("gradient_tracking/y_mix", fused,
-              GT_COLLECTIVE_ID_RANGES["y_mix"]),
-             ("gradient_tracking/params_mix", fused,
-              GT_COLLECTIVE_ID_RANGES["params_mix"])],
-            registry=reg)
-        # a window delivered in the same program must stay in its family
         win_base = pallas_gossip.window_collective_id_base(
             "lint_winput_probe")
         pallas_gossip.release_window_collective_id("lint_winput_probe")
@@ -181,10 +163,8 @@ def collective_id_pass(report: LintReport, size: int) -> None:
     if not any(d.severity == "error" for d in diags):
         report.add(Diagnostic(
             "info", "BF-ID100",
-            "gradient-tracking id split "
-            f"{GT_COLLECTIVE_ID_RANGES} is disjoint and fits the fused "
-            "chunk plan; window bucket stays in its family",
-            pass_name="collective-ids", subject="optimizers"))
+            "window bucket stays in its family",
+            pass_name="collective-ids", subject="windows"))
 
 
 def comm_lint_pass(report: LintReport, size: int) -> None:

@@ -1,29 +1,24 @@
 """Collective-id allocator / auditor.
 
 Pallas barrier semaphores are addressed by integer collective ids, and the
-whole correctness story of concurrently-issued kernel families rests on id
+whole correctness story of concurrently-issued kernels rests on id
 DISJOINTNESS: devices may be skewed in time across two data-independent
-kernels (rank A already inside the params-mix while rank B still runs the
-y-mix), and if both enumerate ids from overlapping ranges, one kernel's
-barrier handshake absorbs the other's signals — the job wedges or, worse,
-proceeds with a half-arrived payload.
+kernels (rank A already inside one window's delivery while rank B still
+runs another's), and if both enumerate ids from overlapping ranges, one
+kernel's barrier handshake absorbs the other's signals — the job wedges or,
+worse, proceeds with a half-arrived payload.
 
-The repo's conventions (``ops/collectives.py`` / ``ops/pallas_gossip.py``):
+The repo's one kernel family that takes such ids is the window deliver
+kernel (``ops/pallas_gossip.py::deliver_pallas``); gossip runs on XLA's
+collective-permutes and needs none (its fused kernel, which owned ids
+[1024, 2048), went with PR 47):
 
 ==========  =====================  =======================================
 family      id range               who enumerates inside it
 ==========  =====================  =======================================
-gossip      [1024, 2048)           ``neighbor_allreduce`` chunk kernels,
-                                   one id per kernel invocation from a
-                                   caller-chosen ``collective_id_base``
 windows     [2048, 2048 + 2^20 *   one CRC32-derived 1024-id bucket per
             1024)                  window name (``WINDOW_LEAF_CAP``)
 ==========  =====================  =======================================
-
-Before this module, only the *global* family bound was checked — a caller
-whose chunk plan overran its intended sub-range silently bled into a
-sibling's ids (ADVICE.md's medium finding against gradient tracking).  The
-registry turns that into a statically-caught class of error:
 
 1. **Declared leases** — each call site declares ``(base, limit)`` against
    a family; the registry validates the lease sits inside the family range
@@ -33,30 +28,23 @@ registry turns that into a statically-caught class of error:
    sees leases, not data dependence).  Leases sharing an
    ``exclusive_group`` are exempt from mutual overlap checks — the
    sanctioned marker for call sites that can never be in flight
-   together: the branches of one ``lax.switch``
-   (``neighbor_allreduce_dynamic`` sets it itself), or sequential calls
-   chained by data dependence (callers pass one ``collective_id_group``
-   to both).
+   together (the branches of one ``lax.switch``, or sequential calls
+   chained by data dependence).
 
-At trace time, ``neighbor_allreduce``'s pallas branch and the window
-deliver path record their leases into the process-global registry
-(:data:`GLOBAL_LEASES`).  The global registry collects only inside a
-:meth:`LeaseRegistry.scope` block — wrap one program's trace in a scope
-and the audit sees exactly the kernels that program will issue; outside a
-scope, op-layer leases are dropped so retraces and eager training loops
-neither accumulate unboundedly nor make unrelated programs look
-concurrent.  The lint CLI and tests audit this way.
-
-:func:`plan_gossip_leases` computes the same chunk plan as the op layer
-*without tracing anything* — the static entry point for auditing an
-optimizer's id budget against a parameter tree before the job launches.
+At trace time the window deliver path records its leases into the
+process-global registry (:data:`GLOBAL_LEASES`).  The global registry
+collects only inside a :meth:`LeaseRegistry.scope` block — wrap one
+program's trace in a scope and the audit sees exactly the kernels that
+program will issue; outside a scope, op-layer leases are dropped so
+retraces and eager training loops neither accumulate unboundedly nor make
+unrelated programs look concurrent.  The lint CLI and tests audit this way.
 """
 
 from __future__ import annotations
 
 import contextlib
 import dataclasses
-from typing import Dict, Iterator, List, Optional, Sequence, Tuple
+from typing import Dict, Iterator, List, Optional, Tuple
 
 from bluefog_tpu.analysis.report import Diagnostic
 from bluefog_tpu.utils import lockcheck as _lc
@@ -66,18 +54,15 @@ __all__ = [
     "CollectiveIdLease",
     "LeaseRegistry",
     "GLOBAL_LEASES",
-    "plan_gossip_leases",
 ]
 
 # Declarative family registry: family name -> [start, end) of the id space
 # it owns.  The window family's end bound mirrors
 # pallas_gossip.window_collective_id_base: 2^20 CRC32 buckets spaced
 # WINDOW_LEAF_CAP (1024) ids apart, starting at 2048.
-GOSSIP_IDS: Tuple[int, int] = (1024, 2048)
 WINDOW_IDS: Tuple[int, int] = (2048, 2048 + (1 << 20) * 1024)
 
 ID_FAMILIES: Dict[str, Tuple[int, int]] = {
-    "gossip": GOSSIP_IDS,
     "windows": WINDOW_IDS,
 }
 
@@ -89,17 +74,15 @@ class CollectiveIdLease:
     ``[base, base + used)`` is what the call actually consumes;
     ``[base, limit)`` is what it declared.  Disjointness is audited on the
     *declared* span: two leases whose declared ranges overlap are a latent
-    hazard even if today's ``used`` counts happen not to collide (the
-    chunk count grows with the parameter tree and shrinks with
-    ``BLUEFOG_TPU_PALLAS_MAX_BYTES`` — exactly how the gradient-tracking
-    overlap stayed hidden).
+    hazard even if today's ``used`` counts happen not to collide (a
+    window's count grows with its tree's leaves).
     """
 
     owner: str
     base: int
     used: int
     limit: int
-    family: str = "gossip"
+    family: str = "windows"
     exclusive_group: Optional[str] = None
 
     @property
@@ -168,7 +151,7 @@ class LeaseRegistry:
         base: int,
         used: int,
         limit: Optional[int] = None,
-        family: str = "gossip",
+        family: str = "windows",
         exclusive_group: Optional[str] = None,
     ) -> CollectiveIdLease:
         """Record a lease.  ``limit=None`` declares the family's end bound
@@ -260,34 +243,3 @@ class LeaseRegistry:
 #: leases are validated-and-dropped, so retraces and eager loops in a
 #: long-lived process neither grow the list nor cross-contaminate audits.
 GLOBAL_LEASES = LeaseRegistry(collect_only_in_scope=True)
-
-
-def plan_gossip_leases(
-    trees_with_ranges: Sequence[Tuple[str, object, Tuple[int, int]]],
-    *,
-    registry: Optional[LeaseRegistry] = None,
-    exclusive_group: Optional[str] = None,
-) -> List[CollectiveIdLease]:
-    """Statically compute the gossip-kernel id consumption of each
-    ``(owner, pytree, (base, limit))`` entry and record the leases.
-
-    Mirrors the op layer's chunk plan exactly (``fuse_apply`` callers
-    should pass the already-fused tree, or accept a conservative per-leaf
-    count): ``sum(leaf_chunk_count(leaf))`` kernel invocations, one id
-    each, enumerated from ``base``.  Nothing is traced and no TPU is
-    required — this is the "audit the job before submitting it" entry
-    point used by the lint CLI.
-    """
-    from bluefog_tpu.ops import pallas_gossip  # deferred: pulls in jax
-
-    import jax
-
-    reg = registry if registry is not None else GLOBAL_LEASES
-    out: List[CollectiveIdLease] = []
-    for owner, tree, (base, limit) in trees_with_ranges:
-        leaves = jax.tree_util.tree_leaves(tree)
-        used = sum(pallas_gossip.leaf_chunk_count(leaf) for leaf in leaves)
-        out.append(reg.lease(owner, base=base, used=used, limit=limit,
-                             family="gossip",
-                             exclusive_group=exclusive_group))
-    return out

@@ -127,7 +127,6 @@ def count(x, counters: Sequence[Tuple[str, object]],
 
 def record_collective(x, *, op: str, bytes_per_round, messages_per_round,
                       schedule: str = "", backend: str = "",
-                      chunks: int = 0,
                       extra: Optional[Dict[str, object]] = None):
     """Record one communication round at the program position where this
     is traced: ``bf_comm_rounds_total`` += 1, ``bf_comm_bytes_total`` +=
@@ -143,8 +142,6 @@ def record_collective(x, *, op: str, bytes_per_round, messages_per_round,
         ("bf_comm_bytes_total", bytes_per_round),
         ("bf_comm_messages_total", messages_per_round),
     ]
-    if chunks:
-        counters.append(("bf_comm_pallas_chunks_total", chunks))
     labels: Dict[str, object] = {"op": op}
     if schedule:
         labels["schedule"] = schedule

@@ -36,9 +36,7 @@ identical order.
 from __future__ import annotations
 
 import functools
-import itertools
-import warnings
-from typing import List, Optional, Sequence, Tuple
+from typing import Optional, Sequence
 
 import jax
 import jax.numpy as jnp
@@ -121,9 +119,6 @@ def fuse_apply(fn, x, *, threshold_bytes: int = 4 << 20):
                 for idxs in groups]
     # One fn call over {fused buffers} ∪ {large leaves}: fn is leaf-wise, so
     # large leaves ride the same collective unfused, with no extra copy.
-    # No scope around it: a Pallas kernel is named in the device trace by
-    # the innermost name-stack entry above its call, and the kernels of fn
-    # are found by that name (``shard_map.N``).
     out_all = fn({"fused": bufs, "big": [leaves[i] for i in big]})
     out = [None] * len(leaves)
     for i, leaf in zip(big, out_all["big"]):
@@ -136,13 +131,6 @@ def fuse_apply(fn, x, *, threshold_bytes: int = 4 << 20):
                 out[i] = buf[off:off + sz].reshape(jnp.shape(leaves[i]))
                 off += sz
     return jax.tree_util.tree_unflatten(treedef, out)
-
-
-# one group token per neighbor_allreduce_dynamic call site: the switch's
-# branches are mutually exclusive at runtime, so their (identical) id
-# leases must not be audited against each other — but two DIFFERENT
-# dynamic calls in one program still must not share ids
-_dynamic_group_counter = itertools.count()
 
 
 def _as_schedule(s) -> GossipSchedule:
@@ -190,10 +178,6 @@ def neighbor_allreduce(
     self_weight=None,
     recv_weights=None,
     send_weights=None,
-    backend: str = "auto",
-    collective_id_base: int = 1024,
-    collective_id_limit: Optional[int] = None,
-    collective_id_group: Optional[str] = None,
 ):
     """Weighted average with in-neighbors: ``out_i = w_ii x_i + sum_k w_ik x_k``.
 
@@ -212,75 +196,31 @@ def neighbor_allreduce(
         payload leaves this rank as ``send_weights[k] * x`` — or a
         ``(size, num_slots)`` table, from which each rank takes its own row.
         The receiver's ``recv_weights`` then apply on top, exactly as
-        upstream composes ``src_weights`` x ``dst_weights``.  Sender-side
-        scaling is an XLA-path feature: ``backend='auto'`` quietly keeps
-        XLA, and forcing ``backend='pallas'`` with it raises (the fused
-        kernel folds weights on the arrival path only).
+        upstream composes ``src_weights`` x ``dst_weights``.
 
-    Lowering (``backend``): ``'xla'`` is one ``lax.ppermute`` per schedule
-    slot and leaf (a single ICI rotation for circulant graphs) + fused
-    multiply-adds.  On a TPU each is a ``collective-permute-start`` /
-    ``-done`` pair: the DMA engines move the bytes while the TensorCore
-    runs whatever XLA schedules between the two, and the received buffers
-    land in HBM.  XLA:TPU keeps about five such transfers in flight: it
-    opens the first five at the top of the program and each further one
-    where an earlier one closes, next to the consumer of its result — so a
-    caller who wants the exchange hidden gives it heavy consumers (the
-    optimizers do: the mix is fused into each leaf's weight-gradient
-    fusion) and pieces of bounded size (:func:`fuse_apply`).  ``'pallas'``
-    is the fused RDMA kernel (:mod:`bluefog_tpu.ops.pallas_gossip`): it
-    folds the weighted reduction into the arrival path, in VMEM, but it IS
-    the core's program while its transfers fly, so none of it overlaps
-    compute; leaves beyond the per-invocation cap are split into cap-sized
-    chunks, one kernel each.  ``'auto'`` decides per call under the stated
-    conditions of :func:`bluefog_tpu.ops.pallas_gossip.auto_gossip_backend`:
-    the kernels for a payload one kernel carries on a real multi-device
-    TPU slice with a circulant schedule, XLA for everything else — any
-    optimizer tree among it (four v5e chips, GPT-2 small: 35.6 ms of a
-    186.7 ms step exposed under the kernels, 21.6 of 172.7 under XLA, and
-    0.22 GiB less memory; PERF.md, PR 31).
+    Lowering: one ``lax.ppermute`` per schedule slot and leaf (a single ICI
+    rotation for circulant graphs) + fused multiply-adds.  On a TPU each is
+    a ``collective-permute-start`` / ``-done`` pair: the DMA engines move
+    the bytes while the TensorCore runs whatever XLA schedules between the
+    two, and the received buffers land in HBM.  XLA:TPU keeps about five
+    such transfers in flight: it opens the first five at the top of the
+    program and each further one where an earlier one closes, next to the
+    consumer of its result — so a caller who wants the exchange hidden
+    gives it heavy consumers (the optimizers do: the mix is fused into each
+    leaf's weight-gradient fusion) and pieces of bounded size
+    (:func:`fuse_apply`).
 
-    ``collective_id_base`` / ``collective_id_limit``: the half-open id
-    range ``[base, limit)`` this call's pallas kernels enumerate
-    barrier-semaphore ids from (gossip owns [1024, 2048); ``limit=None``
-    declares the whole tail up to 2048).  A program that issues SEVERAL
-    pallas gossip calls over trees with no data dependency between them
-    (e.g. gradient tracking's y-mix and params-mix) must give each call a
-    DISJOINT range — devices may be skewed across the calls' kernels, and
-    sharing a barrier semaphore would let one call's handshake absorb
-    another's signals.  The chunk plan is validated against the CALLER'S
-    ``limit``, not just the family bound, so an oversized tree cannot
-    silently bleed into a sibling's ids; on ``backend='auto'`` an
-    over-limit plan falls back to XLA (slower, correct) while a forced
-    ``'pallas'`` raises.  Each pallas call records a
-    :class:`~bluefog_tpu.analysis.registry.CollectiveIdLease` at trace
-    time, so ``bluefog_tpu.analysis`` can audit the traced program for
-    overlaps.  The audit is CONSERVATIVE — it sees leases, not data
-    dependence, so it flags every same-family overlap as if the kernels
-    could run concurrently.  ``collective_id_group`` is the sanctioned
-    suppression: give the same group string to call sites that can never
-    be in flight together — the branches of one ``lax.switch``
-    (``neighbor_allreduce_dynamic`` does this itself), or sequential
-    calls chained by data dependence (the output of one feeding the
-    input of the next) — and the audit will not flag them against each
-    other.  Calls with NO data dependency between them (e.g. gradient
-    tracking's y-mix and params-mix) must instead use disjoint ranges.
+    This is the one transport.  Until PR 47 a ``backend=`` argument could
+    route the exchange to a fused Pallas RDMA kernel, which folded the
+    weighted sum into the arrival path in VMEM; but a Pallas kernel IS the
+    core's program while its transfers fly, so none of it overlapped
+    compute.  On four v5e chips with GPT-2 small's tree 35.6 ms of a
+    186.7 ms step were exposed under the kernels against 21.6 of 172.7
+    under the collective-permutes, which also held 0.22 GiB less memory; a
+    ResNet-50 tree showed 5.35 ms against 4.47 (PERF.md §6, PR 31).  No
+    payload a caller sent reached the kernel after that, and it went.
     """
     sched = _as_schedule(schedule)
-
-    from bluefog_tpu.ops import pallas_gossip
-
-    requested_backend = backend
-    if send_weights is not None and backend == "pallas":
-        raise NotImplementedError(
-            "backend='pallas' cannot honor send_weights: the fused RDMA "
-            "kernel folds weights on the ARRIVAL path only.  Use "
-            "backend='xla' (same math), or fold the sender scaling into "
-            "recv_weights when it is uniform per slot")
-    if send_weights is not None and backend == "auto":
-        backend = "xla"  # sender-side scaling is an XLA-path feature
-    else:
-        backend = pallas_gossip.resolve_backend(backend, sched, x)
     # runtime per-round spans (B once inputs are live, E once the weighted
     # merge materializes; per-rank lanes) — identity unless a timeline is
     # active at trace time.  The reference emits the analogous per-tensor
@@ -297,108 +237,6 @@ def neighbor_allreduce(
                  "schedule": sched.name, "bytes": _mt.tree_bytes(x)}
     x = _bb.traced_event(x, "collective_begin", fields=bb_fields,
                          axis_name=axis_name)
-
-    if backend == "pallas":
-        # distinct collective_id per kernel invocation: DEVICES may be
-        # skewed in time (device A already in chunk k+1's kernel while B is
-        # still in chunk k), so sharing one barrier semaphore would let one
-        # kernel's handshake absorb another's signals.  Gossip owns ids
-        # [1024, 2048); the window transport owns [2048, ...)
-        # (ops/windows.py), so the two kernel families can never share a
-        # barrier semaphore inside one program.  Aggregate VMEM stays
-        # bounded regardless of chunk count: a TensorCore executes one
-        # Mosaic kernel at a time, so at most (num_slots+2) cap-sized
-        # copies are ever resident.
-        #
-        # Leaves larger than the per-invocation cap (the kernel keeps
-        # (num_slots+2) whole-payload copies resident in VMEM) are CHUNKED
-        # into cap-sized pieces: a forced backend='pallas' runs at any
-        # size (auto sends such payloads to XLA), and every received chunk
-        # accumulates in VMEM on arrival instead of materializing in HBM
-        # like a ppermute output.
-        leaves, treedef = jax.tree_util.tree_flatten(x)
-        limit = pallas_gossip.auto_max_bytes()
-        n_invocations = sum(
-            pallas_gossip.leaf_chunk_count(leaf, limit) for leaf in leaves)
-        id_limit = 2048 if collective_id_limit is None else collective_id_limit
-        if not 1024 <= collective_id_base < 2048:
-            raise ValueError(
-                f"collective_id_base {collective_id_base} outside the "
-                "gossip id range [1024, 2048)")
-        if not collective_id_base < id_limit <= 2048:
-            raise ValueError(
-                f"collective_id_limit {id_limit} must lie in "
-                f"({collective_id_base}, 2048]")
-        if collective_id_base + n_invocations > id_limit:
-            if requested_backend == "pallas":
-                raise ValueError(
-                    f"pallas gossip needs {n_invocations} kernel "
-                    f"invocations ({len(leaves)} leaves after chunking) "
-                    f"from base {collective_id_base}, exceeding this "
-                    f"call's collective-id limit {id_limit}; fuse the "
-                    "tree first (fuse_apply), raise "
-                    "BLUEFOG_TPU_PALLAS_MAX_BYTES, or widen the caller's "
-                    "id lease")
-            # backend='auto': an over-limit chunk plan takes the (slower,
-            # always-correct) XLA path instead of hard-failing a run that
-            # the pre-chunking code would have completed — but audibly:
-            # the performance cliff must be visible to the user (warning
-            # dedup keeps this to once per call site)
-            warnings.warn(
-                f"neighbor_allreduce backend='auto': chunk plan needs "
-                f"{n_invocations} pallas kernel ids from base "
-                f"{collective_id_base}, exceeding the call's id limit "
-                f"{id_limit}; falling back to the XLA path (correct but "
-                "slower — no fused RDMA kernels). Fuse the tree "
-                "(fuse_apply), raise BLUEFOG_TPU_PALLAS_MAX_BYTES, or "
-                "widen the caller's id lease.",
-                stacklevel=3)
-            backend = "xla"
-        else:
-            from bluefog_tpu.analysis.registry import GLOBAL_LEASES
-
-            GLOBAL_LEASES.lease(
-                f"neighbor_allreduce[{sched.name}]@{collective_id_base}",
-                base=collective_id_base, used=n_invocations,
-                limit=id_limit, family="gossip",
-                exclusive_group=collective_id_group)
-
-    if backend == "pallas":
-        cid = collective_id_base
-        outs = []
-        for leaf in leaves:
-            n_chunks = pallas_gossip.leaf_chunk_count(leaf, limit)
-            if n_chunks == 1:
-                outs.append(pallas_gossip.neighbor_allreduce_pallas(
-                    leaf, sched, axis_name,
-                    self_weight=self_weight, recv_weights=recv_weights,
-                    collective_id=cid))
-                cid += 1
-                continue
-            with jax.named_scope("bf.gossip.pack"):
-                pieces = jnp.array_split(leaf.reshape(-1), n_chunks)
-            chunk_outs = []
-            for piece in pieces:
-                chunk_outs.append(pallas_gossip.neighbor_allreduce_pallas(
-                    piece, sched, axis_name,
-                    self_weight=self_weight, recv_weights=recv_weights,
-                    collective_id=cid))
-                cid += 1
-            with jax.named_scope("bf.gossip.unpack"):
-                outs.append(jnp.concatenate(chunk_outs).reshape(leaf.shape))
-        out = jax.tree_util.tree_unflatten(treedef, outs)
-        # per-round wire accounting (identity when metrics are off): each
-        # kernel invocation performs one transfer per schedule slot of its
-        # chunk; bytes = what this rank ships per round
-        out = _mt.record_collective(
-            out, op="neighbor_allreduce",
-            bytes_per_round=_mt.tree_bytes(x) * sched.num_slots,
-            messages_per_round=n_invocations * sched.num_slots,
-            schedule=sched.name, backend="pallas", chunks=n_invocations)
-        out = _bb.traced_event(out, "collective_end", fields=bb_fields,
-                               axis_name=axis_name)
-        return _tl.device_stage(out, "bf.neighbor_allreduce", phase="E",
-                                axis_name=axis_name)
 
     send_w = (None if send_weights is None
               else jnp.asarray(send_weights, jnp.float32))
@@ -537,33 +375,19 @@ def neighbor_allreduce_dynamic(
     schedules: Sequence,
     step,
     axis_name: str,
-    *,
-    backend: str = "auto",
-    collective_id_base: int = 1024,
-    collective_id_limit: Optional[int] = None,
 ):
     """Time-varying gossip: applies ``schedules[step % len(schedules)]``.
 
     ``step`` may be a traced integer (e.g. the optimizer step counter): the
     period's schedules are compiled once into a ``lax.switch`` — this is the
     recompilation-free answer to the reference's per-call ``src_weights``
-    dynamic-topology API (SURVEY.md §7 hard-part #2).  The switch branches
-    are mutually exclusive, so they may share ``collective_id_base``; their
-    id leases carry a shared ``collective_id_group`` so the analysis audit
-    knows not to flag them against each other.
+    dynamic-topology API (SURVEY.md §7 hard-part #2).
     """
     scheds = [_as_schedule(s) for s in schedules]
     if len(scheds) == 1:
-        return neighbor_allreduce(x, scheds[0], axis_name, backend=backend,
-                                  collective_id_base=collective_id_base,
-                                  collective_id_limit=collective_id_limit)
-    group = f"bf.dynamic_switch.{next(_dynamic_group_counter)}"
+        return neighbor_allreduce(x, scheds[0], axis_name)
     branches = [
-        functools.partial(neighbor_allreduce, schedule=s, axis_name=axis_name,
-                          backend=backend,
-                          collective_id_base=collective_id_base,
-                          collective_id_limit=collective_id_limit,
-                          collective_id_group=group)
+        functools.partial(neighbor_allreduce, schedule=s, axis_name=axis_name)
         for s in scheds
     ]
     # Timeline spans are hoisted OUTSIDE the switch: an ordered io_callback
@@ -594,23 +418,15 @@ def neighbor_allreduce_dynamic(
             _bb.suppress_blackbox():
         out = lax.switch(idx, branches, x)
     if _mreg.current() is not None:
-        from bluefog_tpu.ops import pallas_gossip
-
         payload = _mt.tree_bytes(x)
         leaves = _mt.tree_leaf_count(x)
-        # label the RESOLVED transport, not the literal 'auto' (which is
-        # never an actual wire) — resolution depends only on environment
-        # + schedule shape, and a dynamic period's schedules resolve
-        # uniformly in practice, so the first phase's answer stands for
-        # the period
-        resolved = pallas_gossip.resolve_backend(backend, scheds[0], x)
         out = _mt.record_collective(
             out, op="neighbor_allreduce_dynamic",
             bytes_per_round=jnp.asarray(
                 [payload * s.num_slots for s in scheds], jnp.float32)[idx],
             messages_per_round=jnp.asarray(
                 [leaves * s.num_slots for s in scheds], jnp.float32)[idx],
-            schedule=f"dynamic[{len(scheds)}]", backend=resolved)
+            schedule=f"dynamic[{len(scheds)}]", backend="xla")
     out = _bb.traced_event(out, "collective_end", fields=bb_fields,
                            traced=bb_step, axis_name=axis_name)
     return _tl.device_stage(out, "bf.neighbor_allreduce", phase="E",

@@ -217,15 +217,12 @@ def _deliver(state: WindowState, payload, axis_name: str, *, accumulate: bool,
                  "bytes": _mt.tree_bytes(payload)}
     payload = _bb.traced_event(payload, "collective_begin",
                                fields=bb_fields, axis_name=axis_name)
-    # same routing policy as gossip (auto_gossip_backend's stated
-    # conditions) — the window transport is the same fused RDMA kernel
-    # family in 'put'/'acc' mode.  chunkable=False: the landing buffers are
-    # persistent window state, so oversized payloads route to XLA here
-    # instead of chunking (the gossip path chunks).
+    # auto_window_backend's stated conditions: the landing buffers are
+    # persistent window state, so a payload with a leaf beyond one kernel's
+    # cap routes to XLA (nothing splits it)
     from bluefog_tpu.ops import pallas_gossip
 
-    backend = pallas_gossip.resolve_backend(backend, sched, payload,
-                                            chunkable=False)
+    backend = pallas_gossip.resolve_backend(backend, sched, payload)
     mask = _slot_mask(sched, axis_name)
 
     def per_leaf(peers, leaf):
@@ -249,8 +246,7 @@ def _deliver(state: WindowState, payload, axis_name: str, *, accumulate: bool,
         # hardware; each needs its own barrier semaphore), and a distinct
         # NAME-derived base per window — two windows delivered in one
         # jitted program (e.g. gradient-tracking's x and y windows) must
-        # not share semaphores either.  Windows own ids [2048, ...); gossip
-        # owns [1024, 2048) — see ops/collectives.py.
+        # not share semaphores either.  Windows own ids [2048, ...).
         base = pallas_gossip.window_collective_id_base(state.spec.name)
         peer_leaves, treedef = jax.tree_util.tree_flatten(state.peer_bufs)
         if len(peer_leaves) > pallas_gossip.WINDOW_LEAF_CAP:
@@ -261,7 +257,7 @@ def _deliver(state: WindowState, payload, axis_name: str, *, accumulate: bool,
                 "use backend='xla' or fuse leaves")
         payload_leaves = treedef.flatten_up_to(payload)
         # trace-time lease record: the analysis audit sees this window's
-        # id bucket next to every concurrent gossip/window lease in the
+        # id bucket next to every concurrent window lease in the
         # program (window buckets are disjoint by construction via the
         # CRC32 claim table; the lease makes that checkable, not assumed)
         from bluefog_tpu.analysis.registry import GLOBAL_LEASES

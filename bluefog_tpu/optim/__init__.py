@@ -9,7 +9,6 @@ adapt-with-combine modes, ``num_steps_per_communication`` (local SGD).
 """
 
 from bluefog_tpu.optim.optimizers import (
-    GT_COLLECTIVE_ID_RANGES,
     CommunicationType,
     decentralized_optimizer,
     optimizer_state_specs,

@@ -51,7 +51,6 @@ from bluefog_tpu.topology.graphs import Topology
 from bluefog_tpu.topology.schedule import GossipSchedule, build_schedule
 
 __all__ = [
-    "GT_COLLECTIVE_ID_RANGES",
     "CommunicationType",
     "decentralized_optimizer",
     "optimizer_state_specs",
@@ -178,12 +177,10 @@ def _as_schedules(topology) -> Sequence[GossipSchedule]:
     return [t if isinstance(t, GossipSchedule) else build_schedule(t) for t in topology]
 
 
-def _gossip(params, scheds, count, axis_name, backend="auto"):
+def _gossip(params, scheds, count, axis_name):
     if len(scheds) == 1:
-        return C.neighbor_allreduce(params, scheds[0], axis_name,
-                                    backend=backend)
-    return C.neighbor_allreduce_dynamic(params, scheds, count, axis_name,
-                                        backend=backend)
+        return C.neighbor_allreduce(params, scheds[0], axis_name)
+    return C.neighbor_allreduce_dynamic(params, scheds, count, axis_name)
 
 
 def decentralized_optimizer(
@@ -216,19 +213,17 @@ def decentralized_optimizer(
       communication_type: which combine to run (reference enum).
       atc: adapt-with-combine when False (the reference's default):
         ``W p + update``, where the exchange of ``p`` depends on nothing the
-        step computes, so the asynchronous path (``backend``) runs it
+        step computes, so XLA's asynchronous collective-permutes run it
         beside the weight-gradient and optimizer fusions.  Adapt-then-
         combine when True: ``W (p + update)``, a true dependency of every
-        transfer on its leaf's update — no backend can hide that exchange
+        transfer on its leaf's update — nothing can hide that exchange
         behind the step's own compute, and it keeps its order.
       num_steps_per_communication: gossip every k-th step (local SGD).
       local_size / machine_topology: for the hierarchical mode.
-      backend: gossip transport — 'xla' (asynchronous ppermutes, moved by
-        the DMA engines while the core computes), 'pallas' (fused RDMA
-        kernels, which occupy the core while they wait), or 'auto' (per
-        :func:`bluefog_tpu.ops.pallas_gossip.auto_gossip_backend`: XLA
-        for any parameter tree, the kernel for a payload of at most one
-        kernel's cap).
+      backend: does nothing.  Gossip has one transport from PR 47 on
+        (``ops/collectives.py::neighbor_allreduce``); the keyword stays,
+        accepting ``'auto'`` and ``'xla'``, only until ``chipbench/cell.py``
+        stops passing it (ROADMAP D6a).
       max_rotations: program-size cap for the CALLABLE-topology (aperiodic)
         mode at pod scale — D runtime-shift rotation slots instead of the
         full n-1 decomposition; exceeding D active rotations NaN-poisons
@@ -248,6 +243,10 @@ def decentralized_optimizer(
     ``params``; the returned updates fold the communication in, so plain
     ``optax.apply_updates(params, updates)`` yields the combined params.
     """
+    if backend not in ("auto", "xla"):
+        raise ValueError(
+            f"unknown backend {backend!r}: gossip runs on collective-permutes "
+            "alone; pass 'auto' or 'xla', or leave the keyword out")
     ct = communication_type
     scheds = None
     matrix_fn = None
@@ -306,8 +305,7 @@ def decentralized_optimizer(
                         t, matrix_fn(count), axis_name,
                         max_rotations=max_rotations), params)
             return C.fuse_apply(
-                lambda t: _gossip(t, scheds, count, axis_name, backend),
-                params)
+                lambda t: _gossip(t, scheds, count, axis_name), params)
         if ct == CommunicationType.hierarchical_neighbor_allreduce:
             if isinstance(axis_name, (tuple, list)):
                 # two-level (machine, local) mesh: the multi-slice form —
@@ -332,9 +330,7 @@ def decentralized_optimizer(
             grads = C.fuse_apply(
                 lambda t: C.allreduce(t, axis_name, average=True), grads)
         # Phase scopes (bf.<layer>.<phase>, docs/metrics.md "Reading a device
-        # trace"): trace-time metadata only, leaf-level and disjoint.  None may
-        # enclose _combine: a Pallas kernel takes its trace name from the
-        # innermost name-stack entry above it (ops/collectives.py).
+        # trace"): trace-time metadata only, leaf-level and disjoint.
         with jax.named_scope("bf.optim.base_update"):
             updates, base_state = base.update(grads, state.base_state, params)
 
@@ -419,7 +415,6 @@ def DistributedNeighborAllreduceOptimizer(
     axis_name: str,
     atc: bool = False,
     num_steps_per_communication: int = 1,
-    backend: str = "auto",
     max_rotations: Optional[int] = None,
     runtime_cadence: bool = False,
 ) -> optax.GradientTransformation:
@@ -429,8 +424,7 @@ def DistributedNeighborAllreduceOptimizer(
         base, topology, axis_name,
         communication_type=CommunicationType.neighbor_allreduce,
         atc=atc, num_steps_per_communication=num_steps_per_communication,
-        backend=backend, max_rotations=max_rotations,
-        runtime_cadence=runtime_cadence,
+        max_rotations=max_rotations, runtime_cadence=runtime_cadence,
     )
 
 
@@ -507,8 +501,8 @@ def DistributedWinPutOptimizer(
       :class:`~bluefog_tpu.runtime.async_windows.AsyncWinPutOptimizer` —
       rank loops on the host runtime stepping at **independent rates** over
       real model parameters, depositing into the native passive-target
-      window table with no barrier anywhere (the reference MPI backend's
-      actual execution model).  ``base`` is ignored in this mode (the
+      window table with no barrier anywhere (the reference's actual
+      execution model over MPI).  ``base`` is ignored in this mode (the
       subgradient-push update is plain SGD on the de-biased iterate); pass
       the learning rate via ``lr``.  The async mode's rank loops are
       THREADS of this process; for the reference's literal deployment shape
@@ -693,25 +687,10 @@ class _GTState(NamedTuple):
     prev_g: Any   # last step's local (post-base-transform) update direction
 
 
-# Gradient tracking issues TWO data-independent gossips per update (y-mix
-# and params-mix); on the pallas backend each needs its own DISJOINT
-# barrier-semaphore id range — devices may be skewed across the two kernel
-# families, and a shared id would let one family's handshake absorb the
-# other's signals.  Declared here (not inlined) so
-# ``bluefog_tpu.analysis`` can statically audit the split against a
-# parameter tree's chunk plan before a job launches.
-GT_COLLECTIVE_ID_RANGES = {
-    "y_mix": (1024, 1536),
-    "params_mix": (1536, 2048),
-}
-
-
 def DistributedGradientTrackingOptimizer(
     base: optax.GradientTransformation,
     topology: Union[Topology, GossipSchedule],
     axis_name: str,
-    *,
-    backend: str = "auto",
 ) -> optax.GradientTransformation:
     """Gradient tracking (DIGing / Aug-DGM family): decentralized training
     that converges to the GLOBAL optimum with a constant step size under
@@ -751,19 +730,9 @@ def DistributedGradientTrackingOptimizer(
                          "(time-varying W breaks the tracking invariant)")
     sched = scheds[0]
 
-    def _mix(tree, which="y_mix"):
-        # the y-mix and the params-mix in one update are data-INDEPENDENT
-        # gossips — each gets its own declared id lease
-        # (GT_COLLECTIVE_ID_RANGES) and neighbor_allreduce validates its
-        # chunk plan against the lease's LIMIT, not the family bound, so
-        # a huge fused buffer cannot silently bleed into the sibling's ids
-        base, id_limit = GT_COLLECTIVE_ID_RANGES[which]
+    def _mix(tree):
         return C.fuse_apply(
-            lambda t: C.neighbor_allreduce(t, sched, axis_name,
-                                           backend=backend,
-                                           collective_id_base=base,
-                                           collective_id_limit=id_limit),
-            tree)
+            lambda t: C.neighbor_allreduce(t, sched, axis_name), tree)
 
     def init_fn(params):
         zeros = jax.tree_util.tree_map(jnp.zeros_like, params)
@@ -786,7 +755,7 @@ def DistributedGradientTrackingOptimizer(
         new_p = jax.tree_util.tree_map(
             lambda xm, yt: (xm.astype(jnp.float32)
                             + yt.astype(jnp.float32)),
-            _mix(params, which="params_mix"), y)
+            _mix(params), y)
         new_updates = jax.tree_util.tree_map(
             lambda np_, p: (np_ - p.astype(jnp.float32)).astype(p.dtype),
             new_p, params)
@@ -811,8 +780,6 @@ def DistributedExactDiffusionOptimizer(
     base: optax.GradientTransformation,
     topology: Union[Topology, GossipSchedule],
     axis_name: str,
-    *,
-    backend: str = "auto",
 ) -> optax.GradientTransformation:
     """Exact diffusion / D² (Yuan, Ying, Zhao & Sayed, 2017): bias-free
     decentralized training with ONE gossip per step.
@@ -858,8 +825,7 @@ def DistributedExactDiffusionOptimizer(
 
     def _mix(tree):
         return C.fuse_apply(
-            lambda t: C.neighbor_allreduce(t, sched, axis_name,
-                                           backend=backend), tree)
+            lambda t: C.neighbor_allreduce(t, sched, axis_name), tree)
 
     def init_fn(params):
         # prev_psi and master live in float32 regardless of param dtype:

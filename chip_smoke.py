@@ -10,11 +10,11 @@ comes out by the repo's own means:
    finite loss on every rank, every parameter leaf sharded over all devices,
    and the restored checkpoint equal to what was saved.
 2. ``gossip``   — (more than one chip) ``neighbor_allreduce`` of
-   rank-distinct values against the closed form ``W @ x`` in NumPy, for
-   ``auto`` and for each backend it can resolve to, at 4 KiB, 1 MiB, the
-   per-kernel cap and a leaf large enough to be chunked, f32 and bf16; then
-   one window round (``win_create`` / ``win_put`` / ``win_accumulate`` /
-   ``win_update``) against its closed form.
+   rank-distinct values against the closed form ``W @ x`` in NumPy, through
+   ``bf.neighbor_allreduce`` and through ``ops.neighbor_allreduce`` in a
+   ``shard_map``, at 4 KiB, 1 MiB, 4 MiB and a ragged 9 MiB leaf, f32 and
+   bf16; then one window round (``win_create`` / ``win_put`` /
+   ``win_accumulate`` / ``win_update``) against its closed form.
 3. ``gpt``      — ``examples/synthetic_benchmark.py --model gpt-small --comm
    neighbor --seq-len 2048``: two decentralized steps.
 4. ``flash``    — forced ``local_attention(backend="flash")`` forward and
@@ -52,7 +52,7 @@ GPT_ARGS = ["--model", "gpt-small", "--comm", "neighbor", "--seq-len", "2048",
             "--warmup", "0"]
 FLASH_SHAPE = (2, 2048, 12, 64)  # (B, T, H, D): GPT-small's heads at T=2048
 MLA_SHAPE = (2, 1024, 32, 192, 128)  # (B, T, H, D_qk, D_v): latent attention
-CHUNKED_LEAF_BYTES = (9 << 20) + 12  # three kernel invocations, ragged tail
+GOSSIP_LEAF_BYTES = (4 << 10, 1 << 20, 4 << 20, (9 << 20) + 12)  # last: ragged
 
 _REPO = os.path.dirname(os.path.abspath(__file__))
 
@@ -162,24 +162,16 @@ def run_gossip():
           f"{[d.id for d in ctx.devices]}", flush=True)
     w = np.asarray(ctx.topology.weights, np.float64)
     sched, ax = ctx.schedule, ctx.axis_name
-    cap = pallas_gossip.auto_max_bytes()
-    resolved = pallas_gossip.resolve_backend(
-        "auto", sched, jnp.zeros((1024,), jnp.float32))
-    print(f"chip_smoke: gossip backend auto -> {resolved}", flush=True)
-
-    def forced(backend):
-        return jax.jit(shard_map(
-            lambda xs: ops.neighbor_allreduce(xs, sched, ax,
-                                              backend=backend),
+    runners = {
+        "bf": bf.neighbor_allreduce,
+        "ops": jax.jit(shard_map(
+            lambda xs: ops.neighbor_allreduce(xs, sched, ax),
             mesh=ctx.mesh, in_specs=(P(ax),), out_specs=P(ax),
-            check_vma=False))
-
-    runners = {"auto": bf.neighbor_allreduce}
-    for backend in sorted({"xla", resolved}):
-        runners[backend] = forced(backend)
+            check_vma=False)),
+    }
     seed = 0
     for dtype in (jnp.float32, jnp.bfloat16):
-        for nbytes in (4 << 10, 1 << 20, cap, CHUNKED_LEAF_BYTES):
+        for nbytes in GOSSIP_LEAF_BYTES:
             elems = nbytes // np.dtype(dtype).itemsize
             seed += 1
             x = bf.rank_shard(_rank_distinct(n, elems, dtype, seed))
@@ -187,7 +179,7 @@ def run_gossip():
             for name, run in runners.items():
                 _assert_close(
                     run(x), want, dtype,
-                    f"neighbor_allreduce backend={name} "
+                    f"{name}.neighbor_allreduce "
                     f"dtype={np.dtype(dtype).name} bytes={nbytes}")
             print(f"chip_smoke: gossip {np.dtype(dtype).name} {nbytes} B "
                   f"== W @ x on {sorted(runners)}", flush=True)
@@ -196,8 +188,7 @@ def run_gossip():
     d, o = np.diag(np.diag(w)), w - np.diag(np.diag(w))
     x, y, z = (bf.rank_shard(_rank_distinct(n, 1 << 18, jnp.float32, s))
                for s in (101, 102, 103))
-    win_backend = pallas_gossip.resolve_backend(
-        "auto", sched, x[0], chunkable=False)
+    win_backend = pallas_gossip.resolve_backend("auto", sched, x[0])
     print(f"chip_smoke: window backend auto -> {win_backend}", flush=True)
     xn, yn, zn = (np.asarray(t, np.float64) for t in (x, y, z))
     bf.win_create(x, "chip_smoke")

@@ -36,3 +36,17 @@ def load_script(relpath: str):
     mod = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(mod)
     return mod
+
+
+def walk_jaxpr(jaxpr, above=""):
+    """Every equation of ``jaxpr`` with the name stacks of the equations
+    enclosing it, nested jaxprs (switch and cond branches, a ``shard_map``
+    body) included."""
+    for eqn in jaxpr.eqns:
+        stack = f"{above}/{eqn.source_info.name_stack}"
+        yield eqn, stack
+        for value in eqn.params.values():
+            for sub in value if isinstance(value, (list, tuple)) else [value]:
+                sub = getattr(sub, "jaxpr", sub)
+                if hasattr(sub, "eqns"):
+                    yield from walk_jaxpr(sub, stack)

@@ -34,14 +34,12 @@ from bluefog_tpu.analysis import (
     check_schedule,
     check_topology,
     lint_step_fn,
-    plan_gossip_leases,
     spectral_gap,
 )
 from bluefog_tpu.analysis.lint import run_all
 from bluefog_tpu.ops import collectives as C
 from bluefog_tpu.ops import pallas_gossip
 from bluefog_tpu.optim import (
-    GT_COLLECTIVE_ID_RANGES,
     DistributedGradientTrackingOptimizer,
     DistributedNeighborAllreduceOptimizer,
 )
@@ -64,89 +62,131 @@ def _errors(diags):
 # ---------------------------------------------------------------------------
 
 
+W0, W1, W2 = (2048 + k * 1024 for k in range(3))  # three window buckets
+
+
 class TestLeaseRegistry:
     def test_overlapping_leases_caught(self):
         reg = LeaseRegistry()
-        reg.lease("y_mix", base=1024, used=10, limit=1600)
-        reg.lease("params_mix", base=1536, used=10, limit=2048)
+        reg.lease("window:x", base=W0, used=10, limit=W1 + 64)
+        reg.lease("window:y", base=W1, used=10, limit=W2)
         diags = reg.audit()
         assert "BF-ID010" in _codes(_errors(diags))
 
     def test_disjoint_leases_clean(self):
         reg = LeaseRegistry()
-        reg.lease("y_mix", base=1024, used=10, limit=1536)
-        reg.lease("params_mix", base=1536, used=10, limit=2048)
+        reg.lease("window:x", base=W0, used=10, limit=W1)
+        reg.lease("window:y", base=W1, used=10, limit=W2)
         assert not _errors(reg.audit())
 
     def test_exclusive_group_exempts_switch_branches(self):
         # the branches of one lax.switch are mutually exclusive at runtime
         # and legitimately share a base — same group, no overlap report
         reg = LeaseRegistry()
-        reg.lease("dyn[0]", base=1024, used=4, limit=1536,
+        reg.lease("dyn[0]", base=W0, used=4, limit=W1,
                   exclusive_group="switch0")
-        reg.lease("dyn[1]", base=1024, used=4, limit=1536,
+        reg.lease("dyn[1]", base=W0, used=4, limit=W1,
                   exclusive_group="switch0")
         assert not _errors(reg.audit())
         # ...but a DIFFERENT dynamic call sharing the base is still flagged
-        reg.lease("dyn2[0]", base=1024, used=4, limit=1536,
+        reg.lease("dyn2[0]", base=W0, used=4, limit=W1,
                   exclusive_group="switch1")
         assert "BF-ID010" in _codes(_errors(reg.audit()))
 
     def test_used_overrunning_limit_caught(self):
         reg = LeaseRegistry()
-        reg.lease("greedy", base=1024, used=600, limit=1536)
+        reg.lease("greedy", base=W0, used=1100, limit=W1)
         assert "BF-ID005" in _codes(_errors(reg.audit()))
 
     def test_base_outside_family_caught(self):
         reg = LeaseRegistry()
-        reg.lease("stray", base=100, used=1, limit=2048)
+        reg.lease("stray", base=100, used=1, limit=W1)
         assert "BF-ID002" in _codes(_errors(reg.audit()))
 
-    def test_window_family_disjoint_from_gossip(self):
+    def test_windows_are_the_one_family(self):
+        """The gossip kernels' ids [1024, 2048) went with the kernels
+        (PR 47): a lease there is outside every family, and the family's
+        name is unknown."""
+        from bluefog_tpu.analysis import ID_FAMILIES
+
+        assert sorted(ID_FAMILIES) == ["windows"]
         reg = LeaseRegistry()
-        reg.lease("gossip", base=1024, used=1024, limit=2048)
-        reg.lease("window:w0", base=2048, used=4, limit=3072,
-                  family="windows")
-        assert not _errors(reg.audit())
+        reg.lease("gossip", base=1024, used=1, limit=2048)
+        assert "BF-ID002" in _codes(_errors(reg.audit()))
+        reg = LeaseRegistry()
+        reg.lease("gossip", base=1024, used=1, limit=2048, family="gossip")
+        assert "BF-ID001" in _codes(_errors(reg.audit()))
 
     def test_scope_isolates_and_restores(self):
         reg = LeaseRegistry()
-        reg.lease("outer", base=1024, used=1, limit=2048)
+        reg.lease("outer", base=W0, used=1, limit=W1)
         with reg.scope():
             assert reg.leases == []
-            reg.lease("inner", base=1024, used=1, limit=2048)
+            reg.lease("inner", base=W0, used=1, limit=W1)
             assert [r.owner for r in reg.leases] == ["inner"]
         assert [r.owner for r in reg.leases] == ["outer"]
 
-    def test_plan_gossip_leases_matches_chunk_plan(self):
-        tree = {"w": jnp.zeros((1 << 20,), jnp.float32)}  # 4 MiB on wire
-        expected = sum(pallas_gossip.leaf_chunk_count(l)
-                       for l in jax.tree_util.tree_leaves(tree))
-        reg = LeaseRegistry()
-        (rec,) = plan_gossip_leases([("opt", tree, (1024, 1536))],
-                                    registry=reg)
-        assert rec.used == expected
-        assert not _errors(reg.audit())
 
+class TestWindowLeases:
+    """The lease audit on the one family left: what a traced window
+    delivery records, and what the lint pass audits."""
 
-class TestOptimizerLeases:
-    def test_gt_declared_ranges_disjoint(self):
-        (alo, ahi) = GT_COLLECTIVE_ID_RANGES["y_mix"]
-        (blo, bhi) = GT_COLLECTIVE_ID_RANGES["params_mix"]
-        assert min(ahi, bhi) <= max(alo, blo)  # no overlap
-        assert alo >= 1024 and bhi <= 2048
+    def test_two_windows_in_one_program_lease_disjoint_buckets(
+            self, devices8, monkeypatch):
+        from bluefog_tpu.ops import windows as W
 
-    def test_gt_split_audits_clean_at_scale(self):
-        # ResNet-18-sized fused buffer: the configuration ADVICE.md's
-        # medium finding showed could silently overlap pre-limit
-        fused = {"p": jnp.zeros((11_000_000,), jnp.float32)}
+        monkeypatch.setenv("BLUEFOG_TPU_PALLAS_INTERPRET", "1")
+        monkeypatch.setattr(pallas_gossip, "_claimed_bases",
+                            dict(pallas_gossip._claimed_bases))
+        sched = T.build_schedule(T.RingGraph(8))
+        tree = {"w": jnp.zeros((8, 5)), "b": jnp.zeros((8, 1))}
+
+        def body(xs):
+            sx = W.win_create(xs, sched, AXIS, name="lease_probe_x")
+            sy = W.win_create(xs, sched, AXIS, name="lease_probe_y")
+            sx = W.win_put(sx, xs, AXIS, backend="pallas")
+            sy = W.win_accumulate(sy, xs, AXIS, backend="pallas")
+            return W.win_update(sx, AXIS)[0], W.win_update(sy, AXIS)[0]
+
         with GLOBAL_LEASES.scope() as reg:
-            plan_gossip_leases(
-                [("gt/y_mix", fused, GT_COLLECTIVE_ID_RANGES["y_mix"]),
-                 ("gt/params_mix", fused,
-                  GT_COLLECTIVE_ID_RANGES["params_mix"])],
-                registry=reg)
+            jax.make_jaxpr(_smap(_mesh(devices8), body))(tree)
+            leases = {r.owner: r for r in reg.leases}
             assert not _errors(reg.audit())
+        assert sorted(leases) == ["window:lease_probe_x",
+                                  "window:lease_probe_y"]
+        for name, rec in leases.items():
+            assert rec.family == "windows" and rec.used == 2
+            assert rec.base == pallas_gossip.window_collective_id_base(
+                name.split(":")[1])
+            assert rec.limit == rec.base + pallas_gossip.WINDOW_LEAF_CAP
+        # the XLA transport takes no ids, and nothing is kept out of scope
+        with GLOBAL_LEASES.scope() as reg:
+            jax.make_jaxpr(_smap(_mesh(devices8), lambda xs: W.win_put(
+                W.win_create(xs, sched, AXIS, name="lease_probe_x"), xs,
+                AXIS, backend="xla").peer_bufs))(tree)
+            assert reg.leases == []
+        assert GLOBAL_LEASES.leases == []
+
+    def test_two_names_in_one_bucket_are_caught_by_the_audit(self):
+        # window_collective_id_base refuses the second claimant; a lease
+        # table built without it (two owners, one bucket) fails the audit
+        reg = LeaseRegistry()
+        for owner in ("window:a", "window:b"):
+            reg.lease(owner, base=W1, used=2,
+                      limit=W1 + pallas_gossip.WINDOW_LEAF_CAP)
+        assert "BF-ID010" in _codes(_errors(reg.audit()))
+
+    def test_the_lint_pass_audits_the_window_probe_alone(self):
+        from bluefog_tpu.analysis.lint import collective_id_pass
+
+        report = LintReport()
+        collective_id_pass(report, 8)
+        assert report.ok, report.format()
+        (diag,) = report.diagnostics
+        assert diag.code == "BF-ID100" and "gossip" not in diag.message
+        # the probe's bucket is released: linting claims nothing
+        assert "lint_winput_probe" not in \
+            pallas_gossip._claimed_bases.values()
 
 
 # ---------------------------------------------------------------------------
@@ -350,63 +390,6 @@ class TestJaxprLint:
         diags = lint_step_fn(_smap(mesh, body), jnp.zeros((8, 4)),
                              name="opt_step")
         assert not _errors(diags)
-
-
-# ---------------------------------------------------------------------------
-# op-layer integration: collective_id_limit (the ADVICE fixes)
-# ---------------------------------------------------------------------------
-
-
-class TestCollectiveIdLimit:
-    def test_forced_pallas_over_limit_raises(self, monkeypatch):
-        # a 2 KiB cap makes an 8K-float leaf need >1024 invocations: the
-        # plan can NEVER fit the gossip family, so forced pallas must
-        # refuse at trace time rather than bleed into sibling ids
-        monkeypatch.setenv("BLUEFOG_TPU_PALLAS_MAX_BYTES", "2048")
-        sched = T.build_schedule(T.RingGraph(8, 1))
-        x = jnp.zeros((1 << 20,), jnp.float32)
-        with pytest.raises(ValueError, match="collective-id limit"):
-            C.neighbor_allreduce(x, sched, AXIS, backend="pallas")
-
-    def test_forced_pallas_respects_caller_limit(self, monkeypatch):
-        # fits the family bound [1024, 2048) but NOT the caller's
-        # [1024, 1040) lease — the pre-fix code would accept this and
-        # overlap the sibling's ids (ADVICE medium)
-        monkeypatch.setenv("BLUEFOG_TPU_PALLAS_MAX_BYTES", str(64 << 10))
-        sched = T.build_schedule(T.RingGraph(8, 1))
-        x = jnp.zeros((1 << 20,), jnp.float32)  # 4 MiB -> 64 invocations
-        with pytest.raises(ValueError, match="collective-id limit"):
-            C.neighbor_allreduce(x, sched, AXIS, backend="pallas",
-                                 collective_id_base=1024,
-                                 collective_id_limit=1040)
-
-    def test_auto_over_limit_falls_back_to_xla(self, devices8, monkeypatch):
-        # on backend='auto' an over-limit chunk plan must take the
-        # (slower, correct) XLA path instead of hard-failing the run
-        # (ADVICE low).  CPU auto-resolves to XLA before the chunk plan,
-        # so force the pallas resolution to reach the fallback branch.
-        monkeypatch.setattr(pallas_gossip, "on_tpu_platform", lambda: True)
-        monkeypatch.setenv("BLUEFOG_TPU_PALLAS_MAX_BYTES", "2048")
-        mesh = _mesh(devices8)
-        sched = T.build_schedule(T.RingGraph(8, 1))
-        x = jnp.arange(8 * (1 << 20), dtype=jnp.float32)
-        x = x.reshape(8, -1) / x.size
-
-        out = _smap(mesh, lambda v: C.neighbor_allreduce(
-            v, sched, AXIS, backend="auto"))(x)
-        ref = _smap(mesh, lambda v: C.neighbor_allreduce(
-            v, sched, AXIS, backend="xla"))(x)
-        np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
-                                   rtol=1e-6)
-
-    def test_bad_limit_rejected(self, monkeypatch):
-        monkeypatch.setenv("BLUEFOG_TPU_PALLAS_MAX_BYTES", str(4 << 20))
-        sched = T.build_schedule(T.RingGraph(8, 1))
-        with pytest.raises(ValueError, match="must lie in"):
-            C.neighbor_allreduce(jnp.zeros(16), sched, AXIS,
-                                 backend="pallas",
-                                 collective_id_base=1536,
-                                 collective_id_limit=1536)
 
 
 # ---------------------------------------------------------------------------

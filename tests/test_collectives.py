@@ -17,9 +17,11 @@ from bluefog_tpu.topology import (
     MeshGrid2DGraph,
     RingGraph,
     StarGraph,
+    SymmetricExponentialGraph,
     build_schedule,
     one_peer_exponential_two_schedules,
 )
+from bluefog_tpu.topology.graphs import Topology
 
 N = 8
 DTYPES = [jnp.float32, jnp.float64, jnp.bfloat16]
@@ -133,37 +135,131 @@ def test_neighbor_allreduce_topology_override():
     np.testing.assert_allclose(np.asarray(out), expected_mix(topo2, x), rtol=1e-6)
 
 
-def test_dynamic_one_peer_period():
-    """One period of one-peer exp2 via lax.switch equals applying each phase's
-    mixing matrix in sequence."""
-    bf.init()
-    ctx = bf.get_context()
-    topos = one_peer_exponential_two_schedules(N)
-    scheds = [build_schedule(t) for t in topos]
+# What the retired Pallas gossip kernel's tests fed it (tests/test_pallas_
+# gossip.py, _op_layer.py, _routing.py before PR 47), on the one path left.
+# A gossip is the period of topologies it applies, one a call.
+GOSSIPS = {
+    "ring": [RingGraph(N)],
+    "exp2": [ExponentialTwoGraph(N)],
+    "symm_exp": [SymmetricExponentialGraph(N)],
+    # three phases of one slot through neighbor_allreduce_dynamic's switch;
+    # after the period every rank holds the exact global average
+    "one_peer_exp2": one_peer_exponential_two_schedules(N),
+    # no edge, so no slot: the self term alone
+    "no_slot": [Topology(weights=np.eye(N), name="identity8")],
+}
+PIECE = 4 << 20   # fuse_apply's piece size: a leaf over it ships unfused
+
+
+def _payload(kind, dtype):
+    """Rank-distinct values that also vary along each leaf."""
+    def leaf(*shape):
+        size = int(np.prod(shape))
+        return (jnp.arange(N, dtype=jnp.float32)[:, None]
+                + jnp.linspace(0.0, 1.0, size)[None, :]
+                ).astype(dtype).reshape((N,) + shape)
+
+    if kind == "aligned":       # whole (8, 128) tiles
+        return leaf(16, 128)
+    if kind == "unaligned":
+        return leaf(7, 13)
+    over = PIECE // np.dtype(dtype).itemsize + 5     # one leaf over a piece
+    return {"big": leaf(over), "small": [leaf(7, 13), leaf(5)]}
+
+
+@pytest.mark.parametrize("kind,dtype,gossip", [
+    *[(kind, dtype, gossip)
+      for gossip in ("ring", "exp2", "symm_exp")
+      for dtype in ("float32", "bfloat16")
+      for kind in ("aligned", "unaligned", "tree_over_a_piece")],
+    *[("tree_over_a_piece", dtype, gossip)
+      for gossip in ("one_peer_exp2", "no_slot")
+      for dtype in ("float32", "bfloat16")],
+])
+def test_gossip_equals_W_x_where_the_kernel_was_checked(kind, dtype, gossip):
+    """``fuse_apply`` over ``neighbor_allreduce`` (``_dynamic`` for a period
+    of schedules), as the optimizers call it, against ``W @ x`` in float64:
+    circulant topologies, both wire widths, a tile-aligned leaf, one that is
+    not, and a tree with a leaf above the piece size beside fused ones."""
     from jax.sharding import PartitionSpec as P
+    from bluefog_tpu.ops import collectives as C
     from bluefog_tpu.parallel.api import shard_map
 
-    x = rank_values((4,))
+    ctx = bf.init()
+    topos = GOSSIPS[gossip]
+    scheds = [build_schedule(t) for t in topos]
 
-    def step(xs, k):
-        return ops.neighbor_allreduce_dynamic(xs, scheds, k, ctx.axis_name)
+    def mix(t, k):
+        if len(scheds) == 1:
+            return C.neighbor_allreduce(t, scheds[0], "bf")
+        return C.neighbor_allreduce_dynamic(t, scheds, k, "bf")
 
-    f = jax.jit(
-        shard_map(
-            step, mesh=ctx.mesh, in_specs=(P("bf"), P()), out_specs=P("bf"),
-            check_vma=False,
-        )
-    )
-    cur = x
-    ref = np.asarray(x, dtype=np.float64)
-    for k in range(len(topos)):
-        cur = f(cur, jnp.asarray(k))
-        ref = (topos[k].weights @ ref.reshape(N, -1)).reshape(N, 4)
-    np.testing.assert_allclose(np.asarray(cur), ref, rtol=1e-5)
-    # after a full exp2 period every rank is the exact global average
-    np.testing.assert_allclose(
-        np.asarray(cur), np.broadcast_to(np.mean(np.arange(N)), (N, 4)), rtol=1e-5
-    )
+    step = jax.jit(shard_map(
+        lambda xs, k: C.fuse_apply(lambda t: mix(t, k), xs),
+        mesh=ctx.mesh, in_specs=(P("bf"), P()), out_specs=P("bf"),
+        check_vma=False))
+    x = _payload(kind, jnp.dtype(dtype))
+    out, mixing = x, np.eye(N)
+    for k, topo in enumerate(topos):
+        out = step(out, jnp.asarray(k))
+        mixing = topo.weights @ mixing
+    # bf16 is rounded once a call
+    tol = 1e-6 if dtype == "float32" else 1e-2 * len(topos)
+    for got, sent in zip(jax.tree_util.tree_leaves(out),
+                         jax.tree_util.tree_leaves(x)):
+        assert got.dtype == sent.dtype and got.shape == sent.shape
+        want = mixing @ np.asarray(sent, np.float64).reshape(N, -1)
+        np.testing.assert_allclose(
+            np.asarray(got, np.float64).reshape(N, -1), want,
+            rtol=tol, atol=tol)
+
+
+def _mixing_entry_points():
+    from bluefog_tpu import optim
+    from bluefog_tpu.ops import collectives as C
+    from bluefog_tpu.optim import optimizers
+
+    return [
+        C.neighbor_allreduce, C.sharded_neighbor_allreduce,
+        C.neighbor_allreduce_dynamic, C.neighbor_allreduce_aperiodic,
+        C._aperiodic_capped, C.hierarchical_neighbor_allreduce,
+        C.hierarchical_neighbor_allreduce_2d, C.pair_gossip,
+        optimizers._gossip, optim.DistributedNeighborAllreduceOptimizer,
+        optim.DistributedHierarchicalNeighborAllreduceOptimizer,
+        optim.DistributedChocoSGDOptimizer,
+        optim.DistributedGradientTrackingOptimizer,
+        optim.DistributedExactDiffusionOptimizer,
+    ]
+
+
+@pytest.mark.parametrize("fn", _mixing_entry_points(),
+                         ids=lambda fn: fn.__name__)
+def test_no_mixing_entry_point_takes_a_transport(fn):
+    """Gossip has one lowering, so nothing that mixes takes a ``backend`` or
+    a collective-id range (they went with the Pallas gossip kernel, PR 47)."""
+    import inspect
+
+    names = list(inspect.signature(fn).parameters)
+    assert not [n for n in names
+                if n == "backend" or n.startswith("collective_id")], names
+
+
+def test_the_one_backend_keyword_left_does_nothing_and_refuses_a_kernel():
+    """``decentralized_optimizer`` keeps ``backend`` while ``chipbench/
+    cell.py`` passes it (ROADMAP D6a): ``'auto'`` and ``'xla'`` build the
+    same transformation, anything else is refused."""
+    import inspect
+    import optax
+    from bluefog_tpu.optim import decentralized_optimizer
+
+    assert "backend" in inspect.signature(decentralized_optimizer).parameters
+    for backend in ("auto", "xla"):
+        decentralized_optimizer(optax.sgd(0.1), RingGraph(N), "bf",
+                                backend=backend)
+    for backend in ("pallas", "rdma"):
+        with pytest.raises(ValueError, match="unknown backend"):
+            decentralized_optimizer(optax.sgd(0.1), RingGraph(N), "bf",
+                                    backend=backend)
 
 
 def test_allreduce_average_and_sum():

@@ -26,6 +26,7 @@ from bluefog_tpu.topology import (
     RingGraph,
     one_peer_exponential_two_schedules,
 )
+from tests._util import walk_jaxpr
 
 N = 8
 DIM = 4
@@ -425,23 +426,71 @@ class TestExactDiffusion:
         assert np.abs(w - 3.5).max() < 0.1, w
 
 
-def test_gradient_tracking_mixes_use_distinct_collective_id_bases(monkeypatch):
-    """GT issues TWO data-independent gossips per update (y-mix and
-    params-mix); on the pallas backend each must claim its own barrier-
-    semaphore id range or one kernel's handshake could absorb the
-    other's signals (r5 review finding)."""
+def _gt():
     from bluefog_tpu.optim import DistributedGradientTrackingOptimizer
+    return DistributedGradientTrackingOptimizer(
+        optax.sgd(0.05), RingGraph(N), "bf")
+
+
+def _ed():
+    from bluefog_tpu.optim import DistributedExactDiffusionOptimizer
+    return DistributedExactDiffusionOptimizer(
+        optax.sgd(0.05), RingGraph(N), "bf")
+
+
+def _dnao(topology, **kw):
+    return DistributedNeighborAllreduceOptimizer(
+        optax.sgd(0.05), topology=topology, axis_name="bf", **kw)
+
+
+# (optimizer, mixes an update, the slots of its schedules added up)
+GOSSIP_OPTIMIZERS = {
+    "exp2_awc": (lambda: _dnao(ExponentialTwoGraph(N)), 1, 3),
+    "exp2_atc": (lambda: _dnao(ExponentialTwoGraph(N), atc=True), 1, 3),
+    # one-peer exponential-2: three phases of one slot, a lax.switch branch each
+    "one_peer_awc": (
+        lambda: _dnao(one_peer_exponential_two_schedules(N)), 1, 3),
+    "one_peer_atc": (
+        lambda: _dnao(one_peer_exponential_two_schedules(N), atc=True), 1, 3),
+    # the y-mix and the params-mix: two exchanges, independent dataflow
+    "gradient_tracking": (_gt, 2, 2),
+    "exact_diffusion": (_ed, 1, 2),
+    # the one entry point that still takes the keyword (chipbench/cell.py
+    # passes it): both values it accepts give the same exchange
+    "backend_auto": (lambda: decentralized_optimizer(
+        optax.sgd(0.05), RingGraph(N), "bf", backend="auto"), 1, 2),
+    "backend_xla_atc": (lambda: decentralized_optimizer(
+        optax.sgd(0.05), RingGraph(N), "bf", atc=True, backend="xla"), 1, 2),
+}
+
+
+@pytest.mark.parametrize("name", GOSSIP_OPTIMIZERS)
+def test_an_update_is_collective_permutes_and_no_kernel(name, monkeypatch):
+    """Gossip has one lowering (PR 47): an ``update`` holds ``slots x
+    pieces`` ppermutes a mix and no ``pallas_call``, whatever the optimizer.
+    Gradient tracking's two mixes cannot interfere, being dataflow: that is
+    what their separate collective-id ranges protected under the kernels."""
+    import functools
+
     from bluefog_tpu.ops import collectives as C
 
-    bases = []
-    real = C.neighbor_allreduce
+    # 3,000 bytes: w1 (4,096) ships alone, the rest in one buffer -> 2 pieces
+    monkeypatch.setattr(C, "fuse_apply", functools.partial(
+        C.fuse_apply, threshold_bytes=3000))
+    make, mixes, slots = GOSSIP_OPTIMIZERS[name]
+    opt = make()
+    ctx = bf.init()
+    params = {"w1": jnp.ones((N, 16, 64)), "b1": jnp.ones((N, 64)),
+              "w2": jnp.ones((N, 64, 8))}
 
-    def spy(x, sched, axis_name, **kw):
-        bases.append(kw.get("collective_id_base", 1024))
-        return real(x, sched, axis_name, **kw)
+    def update(p_blk):
+        p = jax.tree_util.tree_map(lambda t: t[0], p_blk)
+        updates, _ = opt.update(p, opt.init(p), p)
+        return jax.tree_util.tree_map(lambda t: t[None], updates)
 
-    monkeypatch.setattr(C, "neighbor_allreduce", spy)
-    opt = DistributedGradientTrackingOptimizer(
-        optax.sgd(0.05), RingGraph(N), "bf")
-    run_quadratic(opt, steps=2)
-    assert len(set(bases)) == 2, bases
+    fn = shard_map(update, mesh=ctx.mesh, in_specs=(P("bf"),),
+                   out_specs=P("bf"), check_vma=False)
+    found = [eqn.primitive.name for eqn, _ in walk_jaxpr(
+        jax.make_jaxpr(fn)(params).jaxpr)]
+    assert found.count("ppermute") == mixes * slots * 2
+    assert "pallas_call" not in found

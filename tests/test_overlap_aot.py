@@ -7,7 +7,7 @@ inside the async collective windows.  Skips cleanly when libtpu / the
 topology API is unavailable.
 
 Marked ``slow``: loading the AOT TPU topology costs ~8 minutes of fixture
-setup in this container — more than half the tier-1 870s budget for one
+setup in this container — a third of the tier-1 1,470 s budget for one
 module — so the budgeted run (``-m 'not slow'``) excludes it and the full
 suite (plain ``pytest``) keeps it.
 """
@@ -95,8 +95,8 @@ GB = 1e9
 def _compile_cell_step(monkeypatch, comm):
     """``chipbench/cell.py::build_step`` for ``gpt2s.t2048.exp2x4`` (the
     widths of ``chipbench/configs/gpt2-small.json``, T=2048, batch 8, AdamW,
-    ``ExponentialTwoGraph(4)``, adapt-with-combine, ``backend='auto'``),
-    lowered on shapes for ``v5e:2x2`` in the ring order ``bf.init`` uses."""
+    ``ExponentialTwoGraph(4)``, adapt-with-combine), lowered on shapes for
+    ``v5e:2x2`` in the ring order ``bf.init`` uses."""
     import importlib
     import os
     import sys
@@ -114,19 +114,17 @@ def _compile_cell_step(monkeypatch, comm):
     from bluefog_tpu.topology.schedule import build_schedule
 
     # what the chip would answer: a TPU backend for the attention kernel's
-    # eligibility and for auto's platform condition
+    # eligibility
     ring_attention = importlib.import_module("bluefog_tpu.ops.ring_attention")
-    pallas_gossip = importlib.import_module("bluefog_tpu.ops.pallas_gossip")
     monkeypatch.setattr(ring_attention, "_flash_eligible",
                         lambda *a, **k: True)
-    monkeypatch.setattr(pallas_gossip, "on_tpu_platform", lambda: True)
 
     devices = ici_ring_order(aot_topology("v5e:2x2").devices)
     n = len(devices)
     mesh = Mesh(np.array(devices), ("bf",))
     manifest = cells.Manifest.load(os.path.join(repo, "BENCHMARK.json"))
     config, traffic = cells.open_cell(manifest, "gpt2s.t2048.exp2x4")
-    assert traffic["backend"] == "auto" and not config["atc"]
+    assert not config["atc"]
     traffic = {**traffic, "comm": comm}
     family = manifest.module("families", config["family"]).build(
         config, traffic)
@@ -149,9 +147,8 @@ def _compile_cell_step(monkeypatch, comm):
 
 def test_gpt2_step_exchanges_in_bounded_async_pieces_on_v5e_2x2(monkeypatch):
     """The contract of PR 31, checked where no chip is needed (about three
-    minutes of compilation): on four ranks ``backend='auto'`` lowers the
-    parameter exchange to asynchronous collective-permutes and to no
-    core-blocking kernel; no piece is larger than the largest leaf; what
+    minutes of compilation): on four ranks the parameter exchange lowers to
+    asynchronous collective-permutes and to no core-blocking kernel; no piece is larger than the largest leaf; what
     XLA keeps in flight across the whole step (the handful it opens before
     the forward pass) is a few small pieces, every other transfer opens
     after the last attention backward kernel, where the loss's memory is
@@ -170,7 +167,7 @@ def test_gpt2_step_exchanges_in_bounded_async_pieces_on_v5e_2x2(monkeypatch):
     none = _compile_cell_step(monkeypatch, "none")
     text = comm.as_text()
     assert "custom_call_has_side_effect=true" not in text, (
-        "auto chose the Pallas gossip kernels for an optimizer tree")
+        "a core-blocking kernel in the exchange")
     sched = transfer_schedule(text, GPT2_MARKS)
     transfers = sched["transfers"]
     # 168,614,400 f32 parameters over two slots: gossip_bytes_per_step

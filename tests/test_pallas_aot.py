@@ -1,6 +1,6 @@
-"""AOT compilation of the Pallas RDMA kernels for a REAL TPU topology.
+"""AOT compilation of the Pallas kernels for a REAL TPU topology.
 
-The RDMA transport (ops/pallas_gossip.py) is interpret-validated for
+The window RDMA transport (ops/pallas_gossip.py) is interpret-validated for
 semantics here and executed on four v5e chips by ``chip_smoke.py``.  What
 the CPU sandbox can prove for topologies it has no chips for: Mosaic lowers
 and the XLA TPU backend **compiles** the kernels for a real v5e slice via
@@ -30,23 +30,6 @@ from bluefog_tpu.topology.schedule import build_schedule
 
 pytestmark = pytest.mark.slow
 
-@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
-                         ids=["f32_wire", "bf16_wire"])
-def test_gossip_kernel_compiles_for_v5e(dtype, tpu_aot_topology):
-    topo = tpu_aot_topology
-    n = len(topo.devices)
-    mesh = Mesh(np.array(topo.devices), ("bf",))
-    sched = build_schedule(ExponentialTwoGraph(n))
-
-    fn = jax.jit(shard_map(
-        lambda v: pg.neighbor_allreduce_pallas(v[0], sched, "bf")[None],
-        mesh=mesh, in_specs=(P("bf"),), out_specs=P("bf"), check_vma=False))
-    x = jax.ShapeDtypeStruct((n, 1024), dtype,
-                             sharding=NamedSharding(mesh, P("bf")))
-    txt = fn.lower(x).compile().as_text()
-    # the fused kernel survives into the final executable as a custom call
-    assert "tpu_custom_call" in txt, "RDMA kernel was not lowered"
-
 
 @pytest.mark.parametrize("accumulate", [False, True], ids=["put", "acc"])
 def test_deliver_kernel_compiles_for_v5e(accumulate, tpu_aot_topology):
@@ -73,14 +56,12 @@ def test_deliver_kernel_compiles_for_v5e(accumulate, tpu_aot_topology):
                          ids=["exp2_2slots", "full_3slots"])
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
                          ids=["f32_wire", "bf16_wire"])
-def test_largest_planner_chunk_compiles_for_v5e_2x2(dtype, graph):
-    """The four-chip host, snake-ordered as ``bf.init`` orders it: a leaf
-    of 2.5 caps chunks into three kernels, two of them at the largest size
-    the planner can emit — each must fit VMEM under the limit it states
-    (before the reduction was tiled, a two-slot f32 kernel was refused from
-    3.3 MiB and a bf16 one from 2.7 MiB).  The deliver kernel is compiled
-    at the window cutoff, the same size."""
-    from bluefog_tpu.ops import collectives as C
+def test_deliver_kernel_at_the_cutoff_compiles_for_v5e_2x2(dtype, graph):
+    """The four-chip host, snake-ordered as ``bf.init`` orders it: the
+    deliver kernel at the window cutoff, the largest payload ``auto`` routes
+    to it, must fit VMEM under the limit it states (before the stores were
+    tiled, a two-slot f32 kernel was refused from 3.3 MiB and a bf16 one
+    from 2.7 MiB)."""
     from bluefog_tpu.topology import FullyConnectedGraph
     from bluefog_tpu.topology.mapping import ici_ring_order
 
@@ -93,13 +74,6 @@ def test_largest_planner_chunk_compiles_for_v5e_2x2(dtype, graph):
     cap = pg.DEFAULT_AUTO_MAX_BYTES
     itemsize = np.dtype(dtype).itemsize
     sharding = NamedSharding(mesh, P("bf"))
-
-    elems = (2 * cap + cap // 2) // itemsize
-    fn = jax.jit(shard_map(
-        lambda v: C.neighbor_allreduce(v, sched, "bf", backend="pallas"),
-        mesh=mesh, in_specs=(P("bf"),), out_specs=P("bf"), check_vma=False))
-    x = jax.ShapeDtypeStruct((n, elems), dtype, sharding=sharding)
-    assert fn.lower(x).compile().as_text().count("tpu_custom_call") >= 3
 
     k = sched.num_slots
     deliver = jax.jit(shard_map(
@@ -164,53 +138,12 @@ def mosaic_modules(stablehlo_txt: str):
     return mods
 
 
-@pytest.mark.parametrize("topo_name", ["v5e:2x4", "v5e:4x4"])
-@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
-                         ids=["f32_wire", "bf16_wire"])
-def test_gossip_kernel_remote_dma_structure(topo_name, dtype):
-    """Per slot s (one ICI rotation): exactly one remote DMA enqueue and
-    its send+recv wait pair; one barrier signal per in-neighbor; ONE
-    barrier wait for all n_shifts signals; one get_barrier_semaphore.
-    This is the WinPut-path parity evidence the judge asked to strengthen
-    (upstream mpi_controller.cc Win* is the target)."""
-    topo = _aot_topo(topo_name)
-    n = len(topo.devices)
-    mesh = Mesh(np.array(topo.devices).reshape(n), ("bf",))
-    sched = build_schedule(ExponentialTwoGraph(n))
-    shifts = pg.circulant_shifts(sched)
-    s = len(shifts)
-
-    fn = jax.jit(shard_map(
-        lambda v: pg.neighbor_allreduce_pallas(v[0], sched, "bf")[None],
-        mesh=mesh, in_specs=(P("bf"),), out_specs=P("bf"), check_vma=False))
-    x = jax.ShapeDtypeStruct((n, 1024), dtype,
-                             sharding=NamedSharding(mesh, P("bf")))
-    mods = mosaic_modules(fn.lower(x).as_text())
-    assert len(mods) == 1, "expected exactly one gossip kernel"
-    _, text = mods[0]
-
-    assert text.count("tpu.enqueue_dma") == s, text.count("tpu.enqueue_dma")
-    # send-done + recv-done per slot
-    assert text.count("tpu.wait_dma") == 2 * s
-    # barrier handshake: one signal per in-neighbor, one aggregate wait
-    assert text.count("tpu.sem_signal") == s
-    assert text.count("tpu.sem_wait") == 1
-    assert text.count("tpu.sem_barrier") == 1
-    # every enqueue_dma is REMOTE: it carries a target device-id operand
-    # (5 operands: src, src_sem, dst, dst_sem, device_id — a local DMA has 4)
-    for line in text.splitlines():
-        if "tpu.enqueue_dma" in line:
-            args = line.split("tpu.enqueue_dma")[1].split("(")[1].split(")")[0]
-            assert len(args.split(",")) == 5, f"non-remote DMA: {line}"
-    # the DMA semaphores are a distinct type from the barrier semaphore
-    assert "tpu.dma_semaphore" in text and "tpu.semaphore" in text
-
-
 @pytest.mark.parametrize("accumulate", [False, True], ids=["put", "acc"])
 def test_deliver_kernel_remote_dma_structure(accumulate, tpu_aot_topology):
-    """Same structural contract for the win_put/win_accumulate transport
-    (ring: one slot -> one remote DMA + pair of waits + 1-signal
-    handshake)."""
+    """Per slot s (one ICI rotation): exactly one remote DMA enqueue and
+    its send+recv wait pair; one barrier signal per in-neighbor; ONE
+    barrier wait for all n_shifts signals; one get_barrier_semaphore
+    (upstream mpi_controller.cc Win* is the target)."""
     topo = tpu_aot_topology
     n = len(topo.devices)
     mesh = Mesh(np.array(topo.devices), ("bf",))
@@ -234,84 +167,6 @@ def test_deliver_kernel_remote_dma_structure(accumulate, tpu_aot_topology):
     assert text.count("tpu.sem_signal") == s
     assert text.count("tpu.sem_wait") == 1
     assert text.count("tpu.sem_barrier") == 1
-
-
-def test_chunked_gossip_aot_structure(tpu_aot_topology, monkeypatch):
-    """The round-5 chunked default path, compiled for real hardware: an
-    oversized leaf lowers to one kernel PER CHUNK, each with the full
-    per-slot RDMA structure and its OWN collective id (distinct barrier
-    semaphores — kernels of different chunks may skew across devices)."""
-    monkeypatch.setenv("BLUEFOG_TPU_PALLAS_MAX_BYTES", str(64 << 10))
-    from bluefog_tpu.ops import collectives as C
-
-    topo = tpu_aot_topology
-    n = len(topo.devices)
-    mesh = Mesh(np.array(topo.devices), ("bf",))
-    sched = build_schedule(ExponentialTwoGraph(n))
-    s = len(pg.circulant_shifts(sched))
-
-    elems = 40_000  # 160 KB f32 at a 64 KiB cap -> 3 chunks
-    fn = jax.jit(shard_map(
-        lambda v: C.neighbor_allreduce(v, sched, "bf", backend="pallas"),
-        mesh=mesh, in_specs=(P("bf"),), out_specs=P("bf"), check_vma=False))
-    x = jax.ShapeDtypeStruct((n, elems), jnp.float32,
-                             sharding=NamedSharding(mesh, P("bf")))
-    lowered = fn.lower(x)
-    mods = mosaic_modules(lowered.as_text())
-    assert len(mods) == 3, f"expected 3 chunk kernels, got {len(mods)}"
-    ids = []
-    for cfg, text in mods:
-        assert text.count("tpu.enqueue_dma") == s
-        assert text.count("tpu.wait_dma") == 2 * s
-        assert text.count("tpu.sem_signal") == s
-        cc = cfg["custom_call_config"]
-        assert cc["has_communication"] is True
-        ids.append(cc["collective_id"])
-    assert len(set(ids)) == 3 and all(
-        1024 <= i < 2048 for i in ids), f"bad collective ids: {ids}"
-    # and the whole chunked program still compiles for the real target
-    assert "tpu_custom_call" in lowered.compile().as_text()
-
-
-def test_chunked_gossip_keeps_its_trace_names_under_the_phase_scopes(
-        tpu_aot_topology, monkeypatch):
-    """Compiled for the chip, the chunked gossip under ``fuse_apply`` keeps
-    what the benchmark finds it by: every kernel is a side-effecting custom
-    call named ``shard_map.N`` (``gossip_kernel_ms_per_step`` matches
-    ``^shard_map\\.\\d+$`` in the device trace; a scope open above the call,
-    or a ``name=`` on it, would rename it), and the ops around the kernels
-    carry ``bf.gossip.pack`` / ``unpack`` / ``fuse`` / ``split`` in their
-    ``op_name`` (``chipbench/reducers/scope_ms.py`` reads those)."""
-    monkeypatch.setenv("BLUEFOG_TPU_PALLAS_MAX_BYTES", str(64 << 10))
-    from bluefog_tpu.ops import collectives as C
-
-    topo = tpu_aot_topology
-    n = len(topo.devices)
-    mesh = Mesh(np.array(topo.devices), ("bf",))
-    sched = build_schedule(ExponentialTwoGraph(n))
-    sharding = NamedSharding(mesh, P("bf"))
-    tree = {"big": (40_000,), "a": (300, 7), "b": (129,)}  # big: 3 chunks
-
-    def gossip(t):
-        t = jax.tree_util.tree_map(lambda v: v[0], t)
-        out = C.fuse_apply(
-            lambda u: C.neighbor_allreduce(u, sched, "bf", backend="pallas"),
-            t, threshold_bytes=100_000)
-        return jax.tree_util.tree_map(lambda v: v[None], out)
-
-    fn = jax.jit(shard_map(gossip, mesh=mesh, in_specs=(P("bf"),),
-                           out_specs=P("bf"), check_vma=False))
-    x = {k: jax.ShapeDtypeStruct((n,) + shape, jnp.float32, sharding=sharding)
-         for k, shape in tree.items()}
-    text = fn.lower(x).compile().as_text()
-    kernels = _re.findall(
-        r'%([\w.\-]+) = [^\n]*custom_call_target="tpu_custom_call"'
-        r"[^\n]*custom_call_has_side_effect=true", text)
-    assert len(kernels) == 4, kernels        # 3 chunks + the fused buffer
-    assert all(_re.fullmatch(r"shard_map\.\d+", k) for k in kernels), kernels
-    for scope in ("bf.gossip.pack", "bf.gossip.unpack", "bf.gossip.fuse",
-                  "bf.gossip.split"):
-        assert scope in text, scope
 
 
 def _one_chip(topo):
@@ -511,7 +366,6 @@ def _compile_cell_step(cell, monkeypatch):
 
     # the shapes first: an init pass is too short for the chip's kernels
     shapes = jax.eval_shape(init, jax.random.PRNGKey(0))
-    monkeypatch.setattr(pg, "on_tpu_platform", lambda: True)
     if cell.startswith("gpt2s"):
         monkeypatch.setattr(      # the package exports a function by
             importlib.import_module(        # the module's name

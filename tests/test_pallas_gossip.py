@@ -1,7 +1,9 @@
-"""Pallas RDMA kernel tests under TPU-interpret emulation
+"""The Pallas RDMA window-deliver kernel under TPU-interpret emulation
 (``pltpu.InterpretParams`` runs the Mosaic semantics — semaphores, remote
 DMAs — on the CPU mesh).  This validates the genuine TPU one-sided path
-(SURVEY.md §7 hard-part #1) without multi-chip hardware."""
+(SURVEY.md §7 hard-part #1) without multi-chip hardware.  The gossip kernel
+that stood beside it went with PR 47; its inputs are checked on the one
+gossip path in ``test_collectives.py``."""
 
 import jax
 import jax.numpy as jnp
@@ -17,7 +19,6 @@ from bluefog_tpu.topology import (
     MeshGrid2DGraph,
     RingGraph,
     build_schedule,
-    one_peer_exponential_two_schedules,
 )
 
 N = 8
@@ -37,45 +38,6 @@ def _run(body, *inputs, n_out=1):
 def rank_values(shape=(4,)):
     base = jnp.arange(N, dtype=jnp.float32).reshape((N,) + (1,) * len(shape))
     return jnp.broadcast_to(base, (N,) + shape)
-
-
-@pytest.mark.parametrize("topo_fn", [
-    lambda: RingGraph(N),
-    lambda: ExponentialTwoGraph(N),
-    lambda: one_peer_exponential_two_schedules(N)[1],
-], ids=["ring", "exp2", "one_peer_phase1"])
-def test_pallas_gossip_matches_closed_form(topo_fn):
-    topo = topo_fn()
-    sched = build_schedule(topo)
-
-    def body(xs):
-        return pallas_gossip.neighbor_allreduce_pallas(
-            xs[0], sched, "bf", interpret=True
-        )[None]
-
-    out = _run(body, rank_values((5,)))
-    ref = (topo.weights @ np.arange(N, dtype=np.float64)[:, None]).repeat(5, 1)
-    np.testing.assert_allclose(np.asarray(out), ref, rtol=1e-6)
-
-
-def test_pallas_gossip_unaligned_shape_and_bf16():
-    """Padding path: a (3, 7) bf16 tensor (not tile-aligned)."""
-    topo = RingGraph(N)
-    sched = build_schedule(topo)
-
-    def body(xs):
-        return pallas_gossip.neighbor_allreduce_pallas(
-            xs[0], sched, "bf", interpret=True
-        )[None]
-
-    x = rank_values((3, 7)).astype(jnp.bfloat16)
-    out = _run(body, x)
-    assert out.dtype == jnp.bfloat16
-    ref = (topo.weights @ np.arange(N, dtype=np.float64)).reshape(N, 1, 1)
-    np.testing.assert_allclose(
-        np.asarray(out, dtype=np.float64), np.broadcast_to(ref, (N, 3, 7)),
-        rtol=5e-2,
-    )
 
 
 def test_pallas_deliver_put_and_accumulate():
@@ -128,12 +90,9 @@ def test_pallas_deliver_bf16_wire():
                                        rtol=1e-2, atol=1e-2)
 
 
-def test_wire_dtype_selection_and_chunk_accounting():
-    """bf16 leaves are counted at 2 bytes (the wire is bf16): half the
-    chunks on the gossip path, and up to 2x the f32 cutoff still unchunked /
-    within the window transport's routing cutoff."""
-    import jax as _jax
-
+def test_wire_dtype_selection(monkeypatch):
+    """bf16 leaves are counted at 2 bytes (the wire is bf16): up to 2x the
+    f32 cutoff still within the window transport's routing cutoff."""
     assert pallas_gossip._wire_dtype(jnp.bfloat16) == jnp.bfloat16
     assert pallas_gossip._wire_dtype(jnp.float32) == jnp.float32
     assert pallas_gossip._wire_dtype(jnp.float16) == jnp.float32
@@ -142,30 +101,20 @@ def test_wire_dtype_selection_and_chunk_accounting():
     cutoff_elems = pallas_gossip.DEFAULT_AUTO_MAX_BYTES // 4
     f32_big = jnp.zeros((cutoff_elems + 1,), jnp.float32)
     bf16_same = jnp.zeros((cutoff_elems + 1,), jnp.bfloat16)
-    assert pallas_gossip.leaf_chunk_count(f32_big) == 2
-    assert pallas_gossip.leaf_chunk_count(bf16_same) == 1
-    try:
-        orig = _jax.default_backend
-        _jax.default_backend = lambda: "tpu"
-        # gossip: the wire width decides here too (one kernel's payload
-        # rides the kernel, anything larger the asynchronous path)
-        assert pallas_gossip.auto_gossip_backend(sched, f32_big) == "xla"
-        assert pallas_gossip.auto_gossip_backend(sched, bf16_same) == "pallas"
-        # window transport (non-chunkable): the wire width decides
-        assert pallas_gossip.auto_gossip_backend(
-            sched, f32_big, chunkable=False) == "xla"
-        assert pallas_gossip.auto_gossip_backend(
-            sched, bf16_same, chunkable=False) == "pallas"
-    finally:
-        _jax.default_backend = orig
+    assert pallas_gossip.leaf_wire_bytes(f32_big) == 4 * (cutoff_elems + 1)
+    assert pallas_gossip.leaf_wire_bytes(bf16_same) == 2 * (cutoff_elems + 1)
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    assert pallas_gossip.auto_window_backend(sched, f32_big) == "xla"
+    assert pallas_gossip.auto_window_backend(sched, bf16_same) == "pallas"
 
 
 def test_pallas_rejects_non_circulant():
     sched = build_schedule(MeshGrid2DGraph(6))
+    x = jnp.zeros((4,))
     with pytest.raises(ValueError, match="circulant"):
-        pallas_gossip.neighbor_allreduce_pallas(
-            jnp.zeros((4,)), sched, "bf", interpret=True
-        )
+        pallas_gossip.deliver_pallas(
+            x, jnp.zeros((sched.num_slots, 4)), sched, "bf",
+            accumulate=False, interpret=True)
 
 
 def test_circulant_shift_extraction():
@@ -176,29 +125,18 @@ def test_circulant_shift_extraction():
 
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
                          ids=["f32_wire", "bf16_wire"])
-def test_tiled_reduction_covers_full_tiles_and_remainder(dtype, monkeypatch):
-    """Payloads beyond one row tile reduce in a loop over full tiles plus a
-    static remainder: rank-distinct values across full tiles + a ragged
-    tail must still equal W @ x (gossip) and land whole (deliver).  The
-    tile is shrunk because the emulation stalls on payloads past ~32 KiB:
-    f32 pads to 40 rows (2 tiles of 16 + 8), bf16 to 48 (1 tile of 32 + 16)."""
+def test_tiled_store_covers_full_tiles_and_remainder(dtype, monkeypatch):
+    """Payloads beyond one row tile are stored in a loop over full tiles plus
+    a static remainder: rank-distinct values across full tiles + a ragged
+    tail must land whole.  The tile is shrunk because the emulation stalls
+    on payloads past ~32 KiB: f32 pads to 40 rows (2 tiles of 16 + 8), bf16
+    to 48 (1 tile of 32 + 16)."""
     monkeypatch.setattr(pallas_gossip, "_TILE_ROWS",
                         16 if dtype == jnp.float32 else 32)
-    topo = ExponentialTwoGraph(N)
-    sched = build_schedule(topo)
+    sched = build_schedule(ExponentialTwoGraph(N))
     elems = 40 * 128 - 100
     x = (jnp.arange(N, dtype=jnp.float32)[:, None]
          + jnp.linspace(0.0, 1.0, elems)[None, :]).astype(dtype)
-
-    def gossip(xs):
-        return pallas_gossip.neighbor_allreduce_pallas(
-            xs[0], sched, "bf", interpret=True)[None]
-
-    out = _run(gossip, x)
-    ref = topo.weights @ np.asarray(x, np.float64)
-    tol = 1e-6 if dtype == jnp.float32 else 2e-2
-    np.testing.assert_allclose(np.asarray(out, np.float64), ref,
-                               rtol=tol, atol=tol)
 
     def deliver(xs):
         bufs = jnp.zeros((sched.num_slots,) + xs[0].shape, xs.dtype)

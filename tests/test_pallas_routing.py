@@ -1,12 +1,12 @@
-"""Routing policy for the Pallas RDMA gossip transport.
+"""Routing policy for the Pallas RDMA window transport.
 
-`backend='auto'` must provably choose per the stated conditions
-(pallas_gossip.auto_gossip_backend): real TPU + multi-device + circulant +
-a payload one kernel carries (the whole gossip tree; each leaf of a window
-payload) -> pallas; anything else -> XLA.  The policy is pure
-and cheap, so every branch is asserted directly; integration (the XLA side
-of auto on the CPU mesh + interpret-mode kernel parity) is covered by
-test_collectives.py / test_pallas_gossip.py.
+`backend='auto'` of ``win_put`` / ``win_accumulate`` must provably choose
+per the stated conditions (pallas_gossip.auto_window_backend): real TPU +
+multi-device + circulant + every leaf of the payload one kernel carries ->
+pallas; anything else -> XLA.  The policy is pure and cheap, so every
+branch is asserted directly; interpret-mode kernel parity is covered by
+test_pallas_gossip.py / test_pallas_op_layer.py.  Gossip has no routing:
+it runs on collective-permutes alone (test_collectives.py).
 """
 
 import jax
@@ -30,15 +30,15 @@ def on_tpu(monkeypatch):
 def test_auto_is_xla_on_cpu():
     sched = build_schedule(RingGraph(8))
     assert jax.default_backend() == "cpu"
-    assert pg.auto_gossip_backend(sched, SMALL) == "xla"
+    assert pg.auto_window_backend(sched, SMALL) == "xla"
 
 
 def test_auto_picks_pallas_on_tpu_small_circulant(on_tpu):
     for topo in (RingGraph(8), ExponentialTwoGraph(8)):
-        assert pg.auto_gossip_backend(build_schedule(topo), SMALL) == "pallas"
+        assert pg.auto_window_backend(build_schedule(topo), SMALL) == "pallas"
     # pytrees: every leaf within the cutoff
     tree = {"a": SMALL, "b": jnp.zeros((16, 16), jnp.bfloat16)}
-    assert pg.auto_gossip_backend(build_schedule(RingGraph(8)), tree) == "pallas"
+    assert pg.auto_window_backend(build_schedule(RingGraph(8)), tree) == "pallas"
 
 
 CAP = pg.DEFAULT_AUTO_MAX_BYTES
@@ -48,59 +48,20 @@ def _f32(nbytes):
     return jax.ShapeDtypeStruct((nbytes // 4,), jnp.float32)
 
 
-@pytest.mark.parametrize("tree, want", [
-    (_f32(CAP), "pallas"),                            # one kernel's payload
-    (_f32(CAP + 4), "xla"),                           # one element beyond it
-    ({"a": _f32(CAP // 2), "b": _f32(CAP // 2)}, "pallas"),
-    # every leaf under the cap, the tree over it: the whole payload decides
-    ({"a": _f32(CAP // 2), "b": _f32(CAP // 2 + 4)}, "xla"),
-    # bf16 travels as bf16: twice the elements ride one kernel
-    (jax.ShapeDtypeStruct((CAP // 2,), jnp.bfloat16), "pallas"),
-    (jax.ShapeDtypeStruct((CAP // 2 + 1,), jnp.bfloat16), "xla"),
-    (BIG, "xla"),
-    ({"a": SMALL, "b": BIG}, "xla"),
-], ids=["at_cap", "over_cap", "tree_at_cap", "tree_over_cap", "bf16_at_cap",
-        "bf16_over_cap", "big_leaf", "small_and_big"])
-def test_auto_gossip_takes_the_async_path_beyond_one_kernels_payload(
-        on_tpu, tree, want):
-    """A Pallas gossip kernel occupies the TensorCore while its RDMAs fly
-    (168 us a 4 MiB kernel on a v5e, 180 of them a GPT-2-small step, none
-    hidden: PERF.md, PR 31), XLA's collective-permute-start/-done do not.
-    ``auto`` keeps the kernels for a payload one invocation carries and
-    gives everything larger to XLA, deciding from the tree's on-wire bytes
-    alone."""
-    sched = build_schedule(RingGraph(8))
-    assert pg.auto_gossip_backend(sched, tree) == want
-
-
-def test_any_optimizer_tree_is_on_the_async_side(on_tpu):
-    """What ``decentralized_optimizer`` hands the exchange — a few fused
-    buffers of about 8 MiB and the large leaves — is far beyond one
-    kernel's payload at either shape the rule was set on (PERF.md, PR 31):
-    GPT-2 small's 27 large leaves and 13 buffers, ResNet-50's 3 and 9."""
-    sched = build_schedule(ExponentialTwoGraph(4))
-    gpt2 = {"big": [_f32(154_533_888)] * 2 + [_f32(25_165_824)]
-            + [_f32(9_437_184)] * 24, "fused": [_f32(9_470_000)] * 12}
-    resnet = {"big": [_f32(9_437_184)] * 3, "fused": [_f32(8_500_000)] * 9}
-    assert pg.auto_gossip_backend(sched, gpt2) == "xla"
-    assert pg.auto_gossip_backend(sched, resnet) == "xla"
-
-
 def test_the_cutoff_follows_the_cap_override(on_tpu, monkeypatch):
-    """One number, no new knob: ``BLUEFOG_TPU_PALLAS_MAX_BYTES`` is the
-    chunk cap of a forced kernel path and the cutoff of ``auto``."""
+    """``BLUEFOG_TPU_PALLAS_MAX_BYTES`` is the cutoff of ``auto``."""
     sched = build_schedule(RingGraph(8))
-    assert pg.auto_gossip_backend(sched, BIG) == "xla"
+    assert pg.auto_window_backend(sched, BIG) == "xla"
     monkeypatch.setenv("BLUEFOG_TPU_PALLAS_MAX_BYTES", str(8 << 20))
-    assert pg.auto_gossip_backend(sched, BIG) == "pallas"
+    assert pg.auto_window_backend(sched, BIG) == "pallas"
     monkeypatch.setenv("BLUEFOG_TPU_PALLAS_MAX_BYTES", "1024")
-    assert pg.auto_gossip_backend(sched, SMALL) == "xla"
+    assert pg.auto_window_backend(sched, SMALL) == "xla"
 
 
 def test_a_forced_backend_is_not_routed(on_tpu):
     """``resolve_backend`` asks the rule only for ``auto``: a forced
-    ``'pallas'`` stays the kernels at any size (the op layer chunks), a
-    forced ``'xla'`` stays XLA for a payload the kernels would take."""
+    ``'pallas'`` stays the kernel at any size, a forced ``'xla'`` stays
+    XLA for a payload the kernel would take."""
     sched = build_schedule(RingGraph(8))
     assert pg.resolve_backend("pallas", sched, BIG) == "pallas"
     assert pg.resolve_backend("xla", sched, SMALL) == "xla"
@@ -111,66 +72,46 @@ def test_a_forced_backend_is_not_routed(on_tpu):
 
 
 def test_window_deliver_keeps_size_cutoff(on_tpu):
-    """The window transport cannot chunk (persistent landing buffers), so
-    for it the cap stays a routing cutoff."""
+    """The window transport cannot split a leaf (persistent landing
+    buffers), so the cap is a routing cutoff, leaf by leaf: the largest
+    leaf decides, not the tree."""
     sched = build_schedule(RingGraph(8))
-    assert pg.auto_gossip_backend(sched, BIG, chunkable=False) == "xla"
-    assert pg.auto_gossip_backend(
-        sched, {"a": SMALL, "b": BIG}, chunkable=False) == "xla"
-    assert pg.auto_gossip_backend(sched, SMALL, chunkable=False) == "pallas"
-    # and the cutoff is tunable
-    import os
-    os.environ["BLUEFOG_TPU_PALLAS_MAX_BYTES"] = str(1 << 30)
-    try:
-        assert pg.auto_gossip_backend(sched, BIG, chunkable=False) == "pallas"
-    finally:
-        del os.environ["BLUEFOG_TPU_PALLAS_MAX_BYTES"]
+    assert pg.auto_window_backend(sched, BIG) == "xla"
+    assert pg.auto_window_backend(sched, {"a": SMALL, "b": BIG}) == "xla"
+    assert pg.auto_window_backend(sched, SMALL) == "pallas"
+    assert pg.auto_window_backend(sched, _f32(CAP)) == "pallas"
+    assert pg.auto_window_backend(sched, _f32(CAP + 4)) == "xla"
+    # every leaf at the cap, the tree far over it: still the kernel
+    assert pg.auto_window_backend(
+        sched, {"a": _f32(CAP), "b": [_f32(CAP)] * 3}) == "pallas"
 
 
 def test_nonpositive_cap_disables_kernels(on_tpu, monkeypatch):
-    """MAX_BYTES=0 was the de facto 'always XLA' setting before chunking;
-    it must keep meaning that under auto — and raise loudly (not
-    ZeroDivisionError) if pallas is forced anyway."""
+    """MAX_BYTES=0 is the 'always XLA' setting under auto."""
     monkeypatch.setenv("BLUEFOG_TPU_PALLAS_MAX_BYTES", "0")
     sched = build_schedule(RingGraph(8))
-    assert pg.auto_gossip_backend(sched, SMALL) == "xla"
-    assert pg.auto_gossip_backend(sched, SMALL, chunkable=False) == "xla"
-    with pytest.raises(ValueError, match="must be positive"):
-        pg.leaf_chunk_count(SMALL)
+    assert pg.auto_window_backend(sched, SMALL) == "xla"
+    assert pg.auto_window_backend(
+        sched, jnp.zeros((0,), jnp.float32)) == "xla"
 
 
-def test_leaf_chunk_plan():
-    # 8 MiB f32 leaf at the default 4 MiB cap -> 2 chunks; bf16 ships at
-    # half the bytes -> 1 chunk at 4 MiB
-    assert pg.leaf_wire_bytes(BIG) == 8 << 20
-    assert pg.leaf_chunk_count(BIG) == 2
-    assert pg.leaf_chunk_count(BIG.astype(jnp.bfloat16)) == 1
-    assert pg.leaf_chunk_count(SMALL) == 1
-    # a ResNet-50-sized fused f32 buffer (~25.5M params, ~102 MiB wire)
-    fused = jax.ShapeDtypeStruct((25_500_000,), jnp.float32)
-    assert pg.leaf_chunk_count(fused) == 25
-    assert pg.leaf_chunk_count(fused, limit=1 << 30) == 1
-
-
-@pytest.mark.parametrize("deliver", [False, True], ids=["gossip", "deliver"])
 @pytest.mark.parametrize("itemsize", [4, 2], ids=["f32_wire", "bf16_wire"])
 @pytest.mark.parametrize("num_slots", [1, 2, 3])
-def test_vmem_plan_stays_under_the_limit(num_slots, itemsize, deliver):
-    """Arithmetic, no device: at the largest payload the planner can emit
-    (the per-invocation cap) the kernel's VMEM plan — every whole-payload
+def test_vmem_plan_stays_under_the_limit(num_slots, itemsize):
+    """Arithmetic, no device: at the largest payload auto can route to the
+    kernel (the per-invocation cap) its VMEM plan — every whole-payload
     buffer plus headroom — fits the budget, and the limit the call states
-    covers the plan.  The reduction is tiled, so the plan does not depend
-    on the wire dtype."""
+    covers the plan.  The stores are tiled, so the plan does not depend on
+    the wire dtype."""
     cap = pg.DEFAULT_AUTO_MAX_BYTES
-    copies = 2 * num_slots + 1 if deliver else num_slots + 2
-    plan = pg.vmem_plan_bytes(cap, num_slots, deliver=deliver)
-    assert plan == copies * cap + pg._VMEM_HEADROOM
+    plan = pg.vmem_plan_bytes(cap, num_slots)
+    assert plan == (2 * num_slots + 1) * cap + pg._VMEM_HEADROOM
     assert plan <= pg._VMEM_BUDGET
     dtype = jnp.float32 if itemsize == 4 else jnp.bfloat16
     block = jax.ShapeDtypeStruct((cap // itemsize // 128, 128), dtype)
-    stated = pg._vmem_limit(block, num_slots, deliver=deliver)
+    stated = pg._vmem_limit(block, num_slots)
     assert plan <= stated <= pg._VMEM_BUDGET
-    # one row tile of f32 temporaries per operand fits the headroom
+    # one row tile of temporaries per operand fits the headroom
     assert (num_slots + 2) * pg._TILE_ROWS * 128 * 4 <= pg._VMEM_HEADROOM
 
 
@@ -187,8 +128,9 @@ def test_dense_schedule_leaves_the_vmem_budget(on_tpu):
 
     dense = build_schedule(FullyConnectedGraph(16))  # 15 slots
     assert pg.circulant_shifts(dense) is not None
-    assert pg.auto_gossip_backend(dense, BIG) == "xla"
-    assert pg.auto_gossip_backend(dense, SMALL) == "pallas"  # small fits
+    assert pg.auto_window_backend(dense, BIG) == "xla"
+    assert pg.auto_window_backend(dense, _f32(CAP)) == "xla"  # under the cap
+    assert pg.auto_window_backend(dense, SMALL) == "pallas"  # small fits
     block = jax.ShapeDtypeStruct(
         (pg.DEFAULT_AUTO_MAX_BYTES // 4 // 128, 128), jnp.float32)
     with pytest.raises(ValueError, match="budget"):
@@ -198,11 +140,11 @@ def test_dense_schedule_leaves_the_vmem_budget(on_tpu):
 def test_auto_rejects_non_circulant_and_single_device(on_tpu):
     star = build_schedule(StarGraph(8))
     assert pg.circulant_shifts(star) is None
-    assert pg.auto_gossip_backend(star, SMALL) == "xla"
+    assert pg.auto_window_backend(star, SMALL) == "xla"
 
     from bluefog_tpu.topology.graphs import Topology
     solo = build_schedule(Topology(weights=np.ones((1, 1)), name="solo"))
-    assert pg.auto_gossip_backend(solo, SMALL) == "xla"
+    assert pg.auto_window_backend(solo, SMALL) == "xla"
 
 
 def test_auto_rejects_zero_slot_schedules(on_tpu):
@@ -213,7 +155,7 @@ def test_auto_rejects_zero_slot_schedules(on_tpu):
 
     ident = build_schedule(Topology(weights=np.eye(8), name="identity8"))
     assert ident.num_slots == 0 and ident.is_circulant
-    assert pg.auto_gossip_backend(ident, SMALL) == "xla"
+    assert pg.auto_window_backend(ident, SMALL) == "xla"
 
 
 def test_deliver_pallas_zero_slot_returns_bufs_unchanged():
@@ -237,24 +179,6 @@ def test_deliver_pallas_zero_slot_returns_bufs_unchanged():
     assert out.shape == (8, 0, 4)
 
 
-def test_pallas_zero_slot_degenerates_to_self_term():
-    """Forced backend='pallas' on a 0-slot schedule returns sw*x instead of
-    crashing in kernel lowering (interpret-free: no kernel is built)."""
-    from jax.sharding import Mesh, PartitionSpec as P
-
-    from bluefog_tpu.parallel.api import shard_map
-    from bluefog_tpu.topology.graphs import Topology
-
-    sched = build_schedule(Topology(weights=np.eye(8), name="identity8"))
-    mesh = Mesh(np.array(jax.devices()[:8]), ("bf",))
-    xs = jnp.arange(8 * 4, dtype=jnp.float32).reshape(8, 4)
-    out = jax.jit(shard_map(
-        lambda v: pg.neighbor_allreduce_pallas(v[0], sched, "bf")[None],
-        mesh=mesh, in_specs=(P("bf"),), out_specs=P("bf"),
-        check_vma=False))(xs)
-    np.testing.assert_allclose(np.asarray(out), np.asarray(xs), rtol=1e-6)
-
-
 def test_gate_predicates_agree(on_tpu):
     """is_pallas_supported and 'auto' routing share ONE platform predicate
     (on_tpu_platform) — they can never disagree about the same schedule."""
@@ -265,14 +189,14 @@ def test_gate_predicates_agree(on_tpu):
                  Topology(weights=np.eye(8), name="identity8")):
         sched = build_schedule(topo)
         assert pg.is_pallas_supported(sched) == \
-            (pg.auto_gossip_backend(sched, SMALL) == "pallas"), topo.name
+            (pg.auto_window_backend(sched, SMALL) == "pallas"), topo.name
 
 
 def test_gate_predicates_agree_on_cpu():
     sched = build_schedule(RingGraph(8))
     assert not pg.on_tpu_platform()
     assert not pg.is_pallas_supported(sched)
-    assert pg.auto_gossip_backend(sched, SMALL) == "xla"
+    assert pg.auto_window_backend(sched, SMALL) == "xla"
 
 
 def test_window_base_collision_raises(monkeypatch):
@@ -325,52 +249,24 @@ def test_window_base_released_on_free(monkeypatch):
 def test_kill_switch(on_tpu, monkeypatch):
     sched = build_schedule(RingGraph(8))
     monkeypatch.setenv("BLUEFOG_TPU_PALLAS_GOSSIP", "0")
-    assert pg.auto_gossip_backend(sched, SMALL) == "xla"
-
-
-def test_neighbor_allreduce_consults_policy(monkeypatch):
-    """backend='auto' actually dispatches on the policy's answer."""
-    from bluefog_tpu.ops import collectives as C
-
-    calls = {}
-
-    def fake_policy(sched, x, **kw):
-        calls["hit"] = True
-        return "xla"
-
-    monkeypatch.setattr(pg, "auto_gossip_backend", fake_policy)
-    from jax.sharding import Mesh, PartitionSpec as P
-
-    from bluefog_tpu.parallel.api import shard_map
-
-    sched = build_schedule(RingGraph(8))
-    mesh = Mesh(np.array(jax.devices()[:8]), ("bf",))
-    fn = jax.jit(shard_map(
-        lambda v: C.neighbor_allreduce(v, sched, "bf", backend="auto"),
-        mesh=mesh, in_specs=(P("bf"),), out_specs=P("bf"), check_vma=False))
-    out = fn(jnp.ones((8, 4), jnp.float32))
-    jax.block_until_ready(out)
-    assert calls.get("hit"), "auto did not consult auto_gossip_backend"
+    assert pg.auto_window_backend(sched, SMALL) == "xla"
 
 
 def test_win_put_consults_policy(monkeypatch):
-    """The window transport's backend='auto' routes through the same
-    policy as gossip (deliver = the RDMA kernels in put/acc mode)."""
+    """The window transport's backend='auto' routes through the policy."""
     import bluefog_tpu as bf
 
     calls = {}
-    real = pg.auto_gossip_backend
+    real = pg.auto_window_backend
 
-    def fake_policy(sched, x, **kw):
+    def fake_policy(sched, x):
         calls["hit"] = True
-        # the window transport must declare itself non-chunkable
-        assert kw.get("chunkable") is False
-        return real(sched, x, **kw)
+        return real(sched, x)
 
-    monkeypatch.setattr(pg, "auto_gossip_backend", fake_policy)
+    monkeypatch.setattr(pg, "auto_window_backend", fake_policy)
     bf.init(topology=RingGraph(8))
     x = jnp.ones((8, 4), jnp.float32)
     assert bf.win_create(x, "routing_probe")
     bf.win_put(x, "routing_probe")
-    assert calls.get("hit"), "window auto did not consult auto_gossip_backend"
+    assert calls.get("hit"), "window auto did not consult auto_window_backend"
     bf.win_free("routing_probe")

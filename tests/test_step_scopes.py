@@ -2,9 +2,8 @@
 
 The benchmark reads device time by phase through the HLO's ``op_name``
 (``chipbench/reducers/scope_ms.py``), so the scopes are part of what it
-measures with: each must reach the compiled text, no op may sit under two of
-them (the phases would not add up), and none may be open above a Pallas
-gossip kernel, which would rename the kernel in the device trace.
+measures with: each must reach the compiled text, and no op may sit under
+two of them (the phases would not add up).
 """
 
 import functools
@@ -22,6 +21,7 @@ from bluefog_tpu.optim import CommunicationType, decentralized_optimizer
 from bluefog_tpu.parallel.api import shard_map
 from bluefog_tpu.topology import ExponentialTwoGraph
 from bluefog_tpu.topology.schedule import build_schedule
+from tests._util import walk_jaxpr
 
 N = 4
 OPTIM = {"bf.optim.base_update", "bf.optim.apply", "bf.optim.as_updates"}
@@ -38,11 +38,11 @@ def lowered_fuse_threshold(monkeypatch):
         C.fuse_apply, threshold_bytes=FUSE_THRESHOLD))
 
 
-def mlp_step(comm, atc, backend="xla"):
+def mlp_step(comm, atc):
     """A train step of a tiny MLP over four ranks, and its stacked input."""
     opt = decentralized_optimizer(
         optax.sgd(0.1, momentum=0.9), build_schedule(ExponentialTwoGraph(N)),
-        "bf", communication_type=comm, atc=atc, backend=backend)
+        "bf", communication_type=comm, atc=atc)
 
     def loss(p, x):
         return jnp.mean((jnp.tanh(x @ p["w1"] + p["b1"]) @ p["w2"]
@@ -67,7 +67,7 @@ def mlp_step(comm, atc, backend="xla"):
     (CommunicationType.neighbor_allreduce, OPTIM | FUSE | EXCHANGE),
     (CommunicationType.allreduce, OPTIM | FUSE),
     (CommunicationType.empty, OPTIM),
-], ids=["neighbor_xla", "allreduce", "empty"])
+], ids=["neighbor", "allreduce", "empty"])
 def test_scopes_reach_the_compiled_step_and_never_nest(comm, expected, atc):
     fn, args = mlp_step(comm, atc)
     text = jax.jit(fn).lower(*args).compile().as_text()
@@ -81,61 +81,37 @@ def test_scopes_reach_the_compiled_step_and_never_nest(comm, expected, atc):
     assert found == expected
 
 
-def walk(jaxpr, above=""):
-    """Every equation with the name stacks of the equations enclosing it."""
-    for eqn in jaxpr.eqns:
-        stack = f"{above}/{eqn.source_info.name_stack}"
-        yield eqn, stack
-        for value in eqn.params.values():
-            for sub in value if isinstance(value, (list, tuple)) else [value]:
-                sub = getattr(sub, "jaxpr", sub)
-                if hasattr(sub, "eqns"):
-                    yield from walk(sub, stack)
+@pytest.mark.parametrize("op", ["win_put", "win_accumulate"])
+def test_pack_and_unpack_scopes_on_the_pallas_path(monkeypatch, op):
+    """The one site of ``bf.gossip.pack`` / ``.unpack`` from PR 47 on: the
+    window deliver kernel's padding to tiles and the slice back, a kernel a
+    leaf in between and under neither (``gossip_pack_ms_per_step`` reads the
+    two scopes; no cell runs a window op, so it reads 0.0)."""
+    from bluefog_tpu.ops import windows as W
 
-
-def pallas_step_equations(monkeypatch, atc, max_bytes):
     monkeypatch.setenv("BLUEFOG_TPU_PALLAS_INTERPRET", "1")
-    monkeypatch.setenv("BLUEFOG_TPU_PALLAS_MAX_BYTES", str(max_bytes))
-    fn, args = mlp_step(CommunicationType.neighbor_allreduce, atc,
-                        backend="pallas")
-    return list(walk(jax.make_jaxpr(fn)(*args).jaxpr))
+    sched = build_schedule(ExponentialTwoGraph(N))
+    tree = {"w": jnp.ones((N, 16, 64)), "b": jnp.ones((N, 64))}
 
+    def step(blk):
+        x = jax.tree_util.tree_map(lambda t: t[0], blk)
+        state = W.win_create(x, sched, "bf", name=f"scopes_{op}")
+        state = getattr(W, op)(state, x, "bf", backend="pallas")
+        return jax.tree_util.tree_map(lambda t: t[None],
+                                      W.win_update(state, "bf")[0])
 
-@pytest.mark.parametrize("atc", [False, True], ids=["awc", "atc"])
-def test_no_scope_and_no_name_above_a_gossip_kernel(monkeypatch, atc):
-    """A Pallas kernel takes its name in the device trace from the innermost
-    name-stack entry above its call (``shard_map.N`` today), or from its
-    ``name=``.  The benchmark's ``gossip_kernel_ms_per_step`` finds the
-    gossip kernels by ``^shard_map\\.\\d+$``: a scope opened at any depth
-    above the call, or a name on it, silently zeroes that metric."""
-    kernels = [(eqn, stack) for eqn, stack in pallas_step_equations(
-        monkeypatch, atc, max_bytes=1024)
-        if eqn.primitive.name == "pallas_call"]
-    assert len(kernels) >= 3
-    for eqn, stack in kernels:
-        assert "bf." not in stack, stack
-        assert eqn.params["name"] is None
-
-
-@pytest.mark.parametrize("max_bytes,kernels", [(1 << 20, 2), (1024, 7)],
-                         ids=["unchunked", "chunked"])
-def test_pack_and_unpack_scopes_on_the_pallas_path(monkeypatch, max_bytes,
-                                                   kernels):
-    """w1 (4096 bytes) and the fused buffer of the rest (2336 bytes): one
-    kernel each under a cap that holds them, 4 + 3 chunks under 1 KiB."""
-    equations = pallas_step_equations(monkeypatch, False, max_bytes)
+    mesh = Mesh(np.array(jax.devices()[:N]), ("bf",))
+    fn = shard_map(step, mesh=mesh, in_specs=(P("bf"),), out_specs=P("bf"),
+                   check_vma=False)
+    equations = list(walk_jaxpr(jax.make_jaxpr(fn)(tree).jaxpr))
     under = {scope: {eqn.primitive.name for eqn, stack in equations
-                     if scope in stack}
-             for scope in OPTIM | FUSE | PACK}
-    assert all(under.values()), under
-    assert not any("bf.gossip.exchange" in stack for _, stack in equations)
+                     if scope in stack} for scope in PACK}
     assert "pad" in under["bf.gossip.pack"]
     assert "slice" in under["bf.gossip.unpack"]
-    # the chunking itself (array_split / concatenate) is pack / unpack too
-    assert ("split" in under["bf.gossip.pack"]) == (kernels > 2)
-    assert ("concatenate" in under["bf.gossip.unpack"]) == (kernels > 2)
+    assert not any("pallas_call" in names for names in under.values())
+    assert not any("bf.gossip.exchange" in stack for _, stack in equations)
     assert sum(eqn.primitive.name == "pallas_call"
-               for eqn, _ in equations) == kernels
+               for eqn, _ in equations) == len(tree)
 
 
 MOE_SCOPE = re.compile(r"bf\.moe\.\w+")
