@@ -1,6 +1,6 @@
 """How an expert cell's routed load moves while it trains (``gqa_moe``'s,
-and from PR 41 ``linear_latent_moe``'s with ``--workload``; the leading
-dense blocks have no load to read): the share of
+and with ``--workload`` any family's that sows ``moe_metrics``; blocks
+without an expert layer have no load to read): the share of
 each layer's assignments that falls on the held experts, the passes of the
 row buffer, the fullest and emptiest held expert, and the step's time, every
 ``--every`` steps from the cell's own initial state.
@@ -66,8 +66,6 @@ def main(argv=None):
     cell = cells.build_cell(cells.Manifest.load(args.manifest),
                             args.workload, args.seed)
     model = cell.family.model
-    blocks = [f"block_{i}" for i in range(model.cfg.experts.first_dense,
-                                          model.cfg.num_layers)]
 
     @jax.jit
     def record(params, model_state, batch):
@@ -75,6 +73,9 @@ def main(argv=None):
             lambda t: t[0], (params, model_state))      # rank 0's
         _, sown = model.apply({"params": params, **model_state},
                               batch[0][:, :-1], mutable=["moe_metrics"])
+        # the blocks that hold an expert layer, in order (a model of
+        # single-sub-layer blocks has them among mixer blocks)
+        blocks = sorted(sown["moe_metrics"], key=lambda b: int(b[6:]))
         return [{k: sown["moe_metrics"][b]["moe"][k][0]
                  for k in ("held_share", "row_passes", "rows_per_expert")}
                 for b in blocks]

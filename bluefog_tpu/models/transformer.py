@@ -55,8 +55,17 @@ GPT-2 decoder this file always built, parameter for parameter:
                  them, or alone, ``short_conv`` (:class:`ShortConv`, a
                  gated causal depthwise convolution of ``short_conv.taps``
                  taps: LFM2's operator, neither an attention nor a
-                 recurrence; sizes in :class:`ShortConvSizes`); any ``ffn``
-                 and ``norm`` go with them.  Or a linear-attention / latent-attention hybrid
+                 recurrence; sizes in :class:`ShortConvSizes`) and
+                 ``mamba2`` (:class:`Mamba2Mixer`, Mamba-2's state-space
+                 layer with a scalar decay a head; sizes in
+                 :class:`Mamba2Sizes`); any ``ffn`` and ``norm`` go with
+                 them.  Also among them, ``feed_forward``: **a block that
+                 is the feed-forward alone**, ``x + FFN(norm(x))`` with no
+                 mixer.  A model that names one is a model of
+                 single-sub-layer blocks: its other blocks are a mixer
+                 alone, ``x + mixer(norm(x))``, and carry no feed-forward
+                 (Nemotron-H's ``M``, ``*`` and ``E`` blocks).
+                 Or a linear-attention / latent-attention hybrid
                  (Kimi Linear, arXiv:2510.26692; sizes in :class:`KdaSizes`
                  and :class:`LatentSizes`): ``kda`` (:class:`KdaMixer`, the
                  delta rule with a per-channel decay) and
@@ -103,7 +112,11 @@ grouped-query and ``short_conv`` layers hold every head and every channel
 (``heads_held`` is refused there), so a model of those layers with routed
 experts is shared by its experts and its vocabulary alone: every chip
 computes the mixers alike, and the expert shares add up to the uncut
-layer's (``tests/test_conv_gqa_moe.py``).
+layer's (``tests/test_conv_gqa_moe.py``).  The same holds with ``mamba2``
+layers among them: every Mamba-2 head and group and every attention head is
+held, the shared expert is every chip's alike, and sixteen chips' shares of
+an ungated expert block add up to the uncut block's
+(``tests/test_mamba2_gqa_moe.py``).
 """
 
 from __future__ import annotations
@@ -126,6 +139,7 @@ from bluefog_tpu.ops.ring_attention import local_attention
 from bluefog_tpu.ops.row_sums import take_rows
 from bluefog_tpu.ops.selective_scan import selective_scan
 from bluefog_tpu.ops.short_conv import gated_short_conv
+from bluefog_tpu.ops.ssd import ssd
 
 AttnFn = Callable[..., jnp.ndarray]  # (q, k, v) -> (B, T, H, D)
 
@@ -194,6 +208,23 @@ class ShortConvSizes:
     taps: int = 3
 
 
+@dataclasses.dataclass(frozen=True)
+class Mamba2Sizes:
+    """A ``mamba2`` layer (:class:`Mamba2Mixer`; arXiv:2405.21060):
+    ``heads`` of ``head_dim`` channels (the inner width is their product,
+    whatever the model's width), a state of ``state`` a channel, ``groups``
+    of heads sharing one ``B`` and ``C`` (head ``h`` reads group ``h //
+    (heads / groups)``; the gated norm runs over a group's channels) and
+    ``conv`` taps of the causal depthwise convolution.  The chunk of the
+    scan is :data:`bluefog_tpu.ops.ssd.CHUNK`: no knob."""
+
+    heads: int = 64
+    head_dim: int = 64
+    state: int = 128
+    groups: int = 8
+    conv: int = 4
+
+
 ROUTERS = ("sigmoid_noaux_tc", "softmax_topk")
 ROUTER_INPUTS = ("ffn", "block")
 
@@ -212,9 +243,14 @@ class ExpertSizes:
     group-limited: the experts in ``n_group`` equal groups, a group's score
     the sum of its two best, the ``topk_group`` best groups kept and the
     ``top_k`` taken among theirs) or ``softmax_topk`` (a softmax over the
-    chosen logits; no bias, no buffer, no ``scale``, one group).  ``activation`` of an
-    expert's gate: ``silu`` or ``relu`` (ReGLU).  ``num_shared`` experts
-    every token takes, as one gated MLP of that many widths; 0 builds none.
+    chosen logits; no bias, no buffer, no ``scale``, one group).  **The
+    expert's form**: ``gated`` (the default) is ``W_down (activation(W_gate
+    x) * W_up x)``, three leaves; ``gated=False`` is ``W_down
+    activation(W_up x)``, two leaves (no ``w_gate`` is built), the routed
+    and the shared experts alike.  ``activation``: ``silu``, ``relu``
+    (ReGLU) or ``relu2`` (``relu(x) ** 2``).  ``num_shared`` experts
+    every token takes, as one MLP of that many widths, or ``shared_width``
+    wide where that is stated; 0 builds none.
     ``first_dense`` leading blocks keep the dense ``swiglu``; 0 for none.
     ``router_input``: ``ffn``, the feed-forward's own normed input, or
     ``block``, the block's normed input that the attention reads too (the
@@ -244,6 +280,8 @@ class ExpertSizes:
     n_group: int = 1               # sigmoid router: groups of experts,
     topk_group: int = 1            # and how many of them a token keeps
     weight_eps: float = 0.0        # sigmoid router: added to the chosen sum
+    gated: bool = True             # False: W_down act(W_up x), two leaves
+    shared_width: Optional[int] = None   # None: num_shared * width
 
 
 MIXERS = ("mamba", "diff_attention", "diff_attention_window", "gmu",
@@ -251,6 +289,8 @@ MIXERS = ("mamba", "diff_attention", "diff_attention_window", "gmu",
 ATTENTION_LAYERS = ("full_attention", "window_rotary_attention",
                     "full_rotary_attention")
 CONV_LAYERS = ("short_conv",)      # built beside the attention layers
+SSD_LAYERS = ("mamba2",)           # likewise
+FEED_FORWARD = "feed_forward"      # a block of the feed-forward alone
 LINEAR_LAYERS = ("kda", "latent_attention")
 ROUTED = "routed+shared"
 
@@ -302,6 +342,13 @@ class GPTConfig:
     kda: Optional[KdaSizes] = None
     heads_held: Optional[Tuple[int, int]] = None    # (first, count)
     short_conv: Optional[ShortConvSizes] = None
+    mamba2: Optional[Mamba2Sizes] = None
+
+    @property
+    def single_sublayer(self) -> bool:
+        """Whether a block is a mixer or a feed-forward alone: a model that
+        names a ``feed_forward`` block (module docstring)."""
+        return FEED_FORWARD in (self.layer_types or ())
 
     def __post_init__(self):
         for field, kinds in (("attention", ("fused_qkv", "latent",
@@ -320,12 +367,13 @@ class GPTConfig:
         linear = [kind in LINEAR_LAYERS for kind in types]
         for kind in types:
             if kind not in (MIXERS + ATTENTION_LAYERS + CONV_LAYERS
-                            + LINEAR_LAYERS):
+                            + SSD_LAYERS + (FEED_FORWARD,) + LINEAR_LAYERS):
                 raise ValueError(
                     f"unknown layer type {kind!r} in layer_types; expected "
                     f"mixers {MIXERS}, attention layers {ATTENTION_LAYERS} "
-                    f"with {CONV_LAYERS} among them, or linear/latent "
-                    f"layers {LINEAR_LAYERS}")
+                    f"with {CONV_LAYERS + SSD_LAYERS} and {FEED_FORWARD!r} "
+                    f"blocks among them, or linear/latent layers "
+                    f"{LINEAR_LAYERS}")
         for family in (mixers, linear):
             if any(family) and not all(family):
                 raise ValueError(
@@ -342,6 +390,12 @@ class GPTConfig:
         if ("short_conv" in types) != (self.short_conv is not None):
             raise ValueError("the `short_conv` sizes and the 'short_conv' "
                              "layers of `layer_types` come together")
+        if ("mamba2" in types) != (self.mamba2 is not None):
+            raise ValueError("the `mamba2` sizes and the 'mamba2' layers of "
+                             "`layer_types` come together")
+        if self.mamba2 and self.mamba2.heads % self.mamba2.groups:
+            raise ValueError(f"mamba2: {self.mamba2.heads} heads do not "
+                             f"divide over {self.mamba2.groups} groups")
         if any(linear) and (self.attention, self.position) != (
                 "latent", "rotary"):
             raise ValueError(
@@ -424,6 +478,18 @@ class GPTConfig:
         if ex.num_shared < 0 or ex.first_dense < 0:
             raise ValueError("experts.num_shared and experts.first_dense "
                              "count experts and blocks: 0 or more")
+        if ex.shared_width is not None and (
+                ex.shared_width < 1 or not ex.num_shared):
+            raise ValueError(
+                f"experts.shared_width {ex.shared_width}: the width of the "
+                "shared expert, where num_shared says there is one")
+        if self.single_sublayer and (
+                ex.first_dense or ex.router_input != "ffn"):
+            raise ValueError(
+                "a model of 'feed_forward' blocks has no leading dense "
+                "blocks to count (experts.first_dense would point at a "
+                "mixer) and no block input but the feed-forward's own "
+                "(experts.router_input='ffn')")
         if not (1 <= ex.topk_group <= ex.n_group) or (
                 ex.num_experts % ex.n_group) or (
                     ex.n_group > 1 and ex.router != "sigmoid_noaux_tc"):
@@ -615,6 +681,31 @@ def _head_gate(a, gate):
         gate.astype(jnp.float32))[..., None]).astype(gate.dtype)
 
 
+def _step_bias_init(floor=None):
+    """Initialiser of a recurrence's step bias (Mamba's, Kimi Linear's):
+    the inverse softplus of a step log-uniform in [1e-3, 1e-1], no less than
+    ``floor`` where one is given."""
+    def init(key, shape, dtype):
+        dt = jnp.exp(jax.random.uniform(key, shape, dtype)
+                     * (math.log(0.1) - math.log(1e-3)) + math.log(1e-3))
+        if floor is not None:
+            dt = jnp.maximum(dt, floor)
+        return dt + jnp.log(-jnp.expm1(-dt))      # softplus's inverse
+    return init
+
+
+def _a_log_init(key, shape, dtype):
+    """``A_log = log U(1, 16)``, a head."""
+    return jnp.log(jax.random.uniform(key, shape, dtype, 1.0, 16.0))
+
+
+def _uniform_within(bound):
+    """Initialiser uniform in ``[-bound, bound]`` (a depthwise ``Conv1d``'s
+    default at ``bound = taps ** -0.5``)."""
+    return lambda key, shape, dtype=jnp.float32: jax.random.uniform(
+        key, shape, dtype, -bound, bound)
+
+
 class LatentAttention(nn.Module):
     """Multi-head latent attention, as trained (keys and values are
     materialised; no matrix absorption): ``(B, T, D) -> (B, T, D)``.
@@ -710,17 +801,7 @@ class KdaMixer(nn.Module):
         dense = functools.partial(nn.Dense, use_bias=False, dtype=cfg.dtype)
         lead = y.shape[:-1]
 
-        def dt_bias(key, shape, dtype):
-            dt = jnp.exp(jax.random.uniform(key, shape, dtype)
-                         * (math.log(0.1) - math.log(1e-3)) + math.log(1e-3))
-            return dt + jnp.log(-jnp.expm1(-dt))      # softplus's inverse
-
-        def taps(key, shape, dtype=jnp.float32):
-            bound = sizes.conv ** -0.5
-            return jax.random.uniform(key, shape, dtype, -bound, bound)
-
-        def a_log(key, shape, dtype):
-            return jnp.log(jax.random.uniform(key, shape, dtype, 1.0, 16.0))
+        taps = _uniform_within(sizes.conv ** -0.5)
 
         with jax.named_scope("bf.kda.project"):
             projected = [dense(h * d, name=name)(y) for name in "qkv"]
@@ -738,8 +819,10 @@ class KdaMixer(nn.Module):
                  * d ** -0.5).astype(cfg.dtype)
             k = (k * jax.lax.rsqrt(jnp.sum(k * k, -1, keepdims=True) + 1e-6)
                  ).astype(cfg.dtype)
-            rate = jnp.exp(self.param("A_log", a_log, (h,), jnp.float32))
-            bias = self.param("dt_bias", dt_bias, (h * d,), jnp.float32)
+            rate = jnp.exp(self.param("A_log", _a_log_init, (h,),
+                                      jnp.float32))
+            bias = self.param("dt_bias", _step_bias_init(), (h * d,),
+                              jnp.float32)
             g = sizes.lower_bound * jax.nn.sigmoid(
                 rate[:, None] * (decay.astype(jnp.float32) + bias).reshape(
                     lead + (h, d)))
@@ -850,14 +933,10 @@ class ShortConv(nn.Module):
         cfg, taps = self.cfg, self.cfg.short_conv.taps
         dense = functools.partial(nn.Dense, use_bias=False, dtype=cfg.dtype)
 
-        def within(key, shape, dtype=jnp.float32):
-            bound = taps ** -0.5
-            return jax.random.uniform(key, shape, dtype, -bound, bound)
-
         with jax.named_scope("bf.sconv.project"):
             bcz = dense(3 * cfg.hidden_size, name="in_proj")(y)
         with jax.named_scope("bf.sconv.gate_conv"):
-            kernel = self.param("conv_kernel", within,
+            kernel = self.param("conv_kernel", _uniform_within(taps ** -0.5),
                                 (taps, cfg.hidden_size), jnp.float32)
             gated = gated_short_conv(bcz, kernel)
         with jax.named_scope("bf.sconv.project"):
@@ -887,14 +966,7 @@ class MambaMixer(nn.Module):
         inner, n, rank = hy.d_inner, hy.d_state, hy.dt_rank
         dense = functools.partial(nn.Dense, use_bias=False, dtype=cfg.dtype)
 
-        def dt_bias(key, shape, dtype):
-            dt = jnp.exp(jax.random.uniform(key, shape, dtype)
-                         * (math.log(0.1) - math.log(1e-3)) + math.log(1e-3))
-            return dt + jnp.log(-jnp.expm1(-dt))      # softplus's inverse
-
-        def within(bound):
-            return lambda key, shape, dtype=jnp.float32: jax.random.uniform(
-                key, shape, dtype, -bound, bound)
+        within = _uniform_within
 
         with jax.named_scope("bf.ssm.project"):
             xi, z = jnp.split(dense(2 * inner, name="in_proj")(y), 2, axis=-1)
@@ -909,7 +981,8 @@ class MambaMixer(nn.Module):
             dbc = dense(rank + 2 * n, name="x_proj")(x)
             delta = nn.softplus(nn.Dense(
                 inner, dtype=jnp.float32, name="dt_proj",
-                kernel_init=within(rank ** -0.5), bias_init=dt_bias)(
+                kernel_init=within(rank ** -0.5),
+                bias_init=_step_bias_init())(
                     dbc[..., :rank]))
         a_log = self.param(
             "A_log", lambda key, shape, dtype: jnp.broadcast_to(
@@ -922,6 +995,75 @@ class MambaMixer(nn.Module):
                                skip)
         with jax.named_scope("bf.ssm.project"):
             return dense(cfg.hidden_size, name="out_proj")(m * nn.silu(z)), m
+
+
+class Mamba2Mixer(nn.Module):
+    """Mamba-2's mixer (arXiv:2405.21060; the layer of the published
+    ``nemotron_h`` modelling code): ``(B, T, D) -> (B, T, D)``.  With ``H``
+    heads of ``P`` channels, inner width ``I = H P``, state ``N`` and ``G``
+    groups (:class:`Mamba2Sizes`):
+
+    ``[z (I); xBC (I + 2 G N); dt (H)] = W_in y``, in that order;
+    ``xBC = silu(conv(xBC) + b_c)`` (causal depthwise, ``conv`` taps, f32);
+    ``[x (H, P); B (G, N); C (G, N)] = split(xBC)``; ``delta = softplus(dt +
+    dt_bias)`` a head; ``o = ssd(x, delta, -exp(A_log), B, C, D)``
+    (:func:`bluefog_tpu.ops.ssd.ssd`: the state decays by one scalar a head
+    and token, head ``h`` reads group ``h // (H / G)``); ``o = g *
+    GroupRMS(o * silu(z))``, the gate first and then the mean of squares
+    over each group's ``I / G`` channels; output ``W_out o``.  No bias but
+    the convolution's and ``dt_bias``.  ``delta``, ``A``, the recurrence's
+    decay and state, the gate and the norm are f32.  Initialisers: Mamba-2's
+    for the recurrence, ``A_log = log U(1, 16)`` a head, ``D = 1``,
+    ``dt_bias`` the inverse softplus of a step log-uniform in [1e-3, 1e-1]
+    floored at 1e-4; the taps and their bias uniform within ``conv **
+    -0.5``; flax's elsewhere."""
+
+    cfg: GPTConfig
+
+    @nn.compact
+    def __call__(self, y):
+        cfg, sizes = self.cfg, self.cfg.mamba2
+        h, p, n, g = sizes.heads, sizes.head_dim, sizes.state, sizes.groups
+        inner = h * p
+        dense = functools.partial(nn.Dense, use_bias=False, dtype=cfg.dtype)
+        lead = y.shape[:-1]
+
+        within = _uniform_within(sizes.conv ** -0.5)
+
+        with jax.named_scope("bf.ssd.project"):
+            zxbcdt = dense(2 * inner + 2 * g * n + h, name="in_proj")(y)
+            z = zxbcdt[..., :inner]
+            delta = nn.softplus(
+                zxbcdt[..., -h:].astype(jnp.float32)
+                + self.param("dt_bias", _step_bias_init(floor=1e-4), (h,),
+                             jnp.float32))
+            a = -jnp.exp(self.param("A_log", _a_log_init, (h,), jnp.float32))
+            skip = self.param("D", nn.initializers.ones, (h,), jnp.float32)
+        with jax.named_scope("bf.ssd.conv"):
+            taps = self.param("conv_kernel", within,
+                              (sizes.conv, inner + 2 * g * n), jnp.float32)
+            bias = self.param("conv_bias", within, (inner + 2 * g * n,),
+                              jnp.float32)
+            xbc = nn.silu(causal_depthwise_conv(
+                zxbcdt[..., inner:-h].astype(jnp.float32), taps,
+                bias)).astype(cfg.dtype)
+        with jax.named_scope("bf.ssd.scan"):
+            o = ssd(xbc[..., :inner].reshape(lead + (h, p)), delta, a,
+                    xbc[..., inner:inner + g * n].reshape(lead + (g, n)),
+                    xbc[..., inner + g * n:].reshape(lead + (g, n)), skip)
+        with jax.named_scope("bf.ssd.norm_gate"):
+            gated = (o.reshape(lead + (inner,)).astype(jnp.float32)
+                     * nn.silu(z.astype(jnp.float32)))
+            scale = self.param("norm_scale", nn.initializers.ones, (inner,),
+                               jnp.float32)
+            grouped = gated.reshape(lead + (g, inner // g))
+            grouped = grouped * jax.lax.rsqrt(
+                jnp.mean(grouped * grouped, axis=-1, keepdims=True)
+                + cfg.norm_eps)
+            o = (grouped.reshape(lead + (inner,)) * scale).astype(cfg.dtype)
+        with jax.named_scope("bf.ssd.project"):
+            out = dense(cfg.hidden_size, name="out_proj")(o)
+        return metrics_comm.count(out, [("bf_ssd_calls_total", 1.0)])
 
 
 class GatedMemoryUnit(nn.Module):
@@ -1027,6 +1169,21 @@ class GatedMLP(nn.Module):
             gated * dense(self.width, name="up")(y))
 
 
+class UngatedMLP(nn.Module):
+    """``down(activation(up(y)))``, no bias: the shared expert of a layer
+    whose experts are ungated (``experts.gated=False``)."""
+
+    width: int
+    dtype: jnp.dtype
+    activation: str
+
+    @nn.compact
+    def __call__(self, y):
+        dense = functools.partial(nn.Dense, use_bias=False, dtype=self.dtype)
+        hidden = ACTIVATIONS[self.activation](dense(self.width, name="up")(y))
+        return dense(y.shape[-1], name="down")(hidden)
+
+
 def _route(ex: ExpertSizes, flat, router, bias):
     """``(idx, weights)`` of ``cfg.experts``' router over ``flat (T, D)``.
     A plain function, as :func:`_mix`."""
@@ -1047,7 +1204,9 @@ class RoutedFFN(nn.Module):
     (:func:`bluefog_tpu.ops.moe.routed_experts`: dropless, grouped matmuls
     over the held experts) plus, where ``experts.num_shared`` is not 0, a
     shared expert every token takes.  The parameters hold the held experts
-    only; the router scores all of them.  The sigmoid router's selection
+    only (``w_gate``, ``w_up``, ``w_down``; ungated experts have no
+    ``w_gate``, and their shared expert is an :class:`UngatedMLP`); the
+    router scores all of them.  The sigmoid router's selection
     bias is a buffer (collection ``buffers``, no gradient); the softmax
     router has none, and a layer without a shared expert no ``shared``
     module.  The routing record is sown into the collection ``moe_metrics``.
@@ -1070,13 +1229,18 @@ class RoutedFFN(nn.Module):
             "buffers", "selection_bias", jnp.zeros, (ex.num_experts,),
             jnp.float32) if ex.router == "sigmoid_noaux_tc" else None
         self.w_gate = self.param("w_gate", per_expert, (count, d, ex.width),
-                                 jnp.float32)
+                                 jnp.float32) if ex.gated else None
         self.w_up = self.param("w_up", per_expert, (count, d, ex.width),
                                jnp.float32)
         self.w_down = self.param("w_down", per_expert, (count, ex.width, d),
                                  jnp.float32)
-        self.shared = GatedMLP(ex.num_shared * ex.width,
-                               cfg.dtype) if ex.num_shared else None
+        shared_width = ex.shared_width or ex.num_shared * ex.width
+        if not ex.num_shared:
+            self.shared = None
+        elif ex.gated:
+            self.shared = GatedMLP(shared_width, cfg.dtype)
+        else:
+            self.shared = UngatedMLP(shared_width, cfg.dtype, ex.activation)
 
     def route(self, y):
         """``(idx, weights)`` over ``y (B, T, D)``'s tokens, flattened."""
@@ -1169,7 +1333,12 @@ class Block(nn.Module):
     ``mixer`` is the block's entry of ``cfg.layer_types``.  One of
     ``ATTENTION_LAYERS`` says what the grouped-query attention attends over
     and whether it turns its keys; ``short_conv`` puts :class:`ShortConv`
-    (parameters ``conv``) in the attention's place.  One of ``MIXERS`` replaces the attention
+    (parameters ``conv``) and ``mamba2`` :class:`Mamba2Mixer` (parameters
+    ``mixer``) in the attention's place.  **In a model of single-sub-layer
+    blocks** (``cfg.single_sublayer``: ``layer_types`` names a
+    ``feed_forward`` block) a block builds one norm and one sub-layer: a
+    ``feed_forward`` block is ``x + FFN(ln2(x))`` and every other block
+    ``x + mixer(ln1(x))``; still this one class.  One of ``MIXERS`` replaces the attention
     with that token mixer; such a block takes and returns ``carried =
     (memory, keys, values)`` beside ``x``: what the last Mamba block and the
     last full
@@ -1189,15 +1358,20 @@ class Block(nn.Module):
     @nn.compact
     def __call__(self, x, attn_fn: AttnFn, positions=None, carried=None):
         cfg = self.cfg
+        if self.mixer == FEED_FORWARD:
+            return _feed_forward(self, x, *_early_routing(self, None))
         y = _norm(cfg, "ln1", x, cfg.dtype)
         if self.mixer in MIXERS:
             a, carried = _mix(self, y, attn_fn, carried)
             return _feed_forward(self, x + a), carried
-        moe, routing = _early_routing(self, y)
+        moe, routing = (None, None) if cfg.single_sublayer else (
+            _early_routing(self, y))
         if self.mixer == "kda":
             a = KdaMixer(cfg, name="attn")(y)
         elif self.mixer == "short_conv":
             a = ShortConv(cfg, name="conv")(y)
+        elif self.mixer == "mamba2":
+            a = Mamba2Mixer(cfg, name="mixer")(y)
         elif cfg.attention == "latent":
             a = LatentAttention(cfg, name="attn")(y, attn_fn, positions)
         elif cfg.attention == "grouped_query":
@@ -1212,6 +1386,8 @@ class Block(nn.Module):
             with jax.named_scope("bf.attn.project"):
                 a = HeadDense(cfg.hidden_size, heads, inward=True,
                               dtype=cfg.dtype, name="proj")(a)
+        if cfg.single_sublayer:
+            return x + a
         return _feed_forward(self, x + a, moe, routing)
 
 

@@ -573,7 +573,15 @@ def _gmm_tiling(m: int, k: int, n: int) -> Tuple[int, int, int]:
     wide (one layer's nine products, forward and backward, 4,096 held rows
     of 65,536): 6.35 ms, against 8.45 ms at (512, 256, 256) and 6.87 ms at
     512 rows by the same widths; and the time grows half as fast with the
-    rows the router sends (PERF.md section 6, PR 28)."""
+    rows the router sends (PERF.md section 6, PR 28).
+
+    **A width that is not whole lanes gets one tile of 128 columns a step**
+    (nothing larger divides it) and the kernels take the ragged last one
+    (``megablox`` masks the contraction's and drops the output's): right for
+    a test's 32-wide model, slow at a published width.  An expert's width
+    therefore never comes here ragged: :func:`_cast_experts` hands the
+    products the experts' leaves padded with zero columns to whole lanes
+    (1,856 -> 1,920 = 3 x 640: 3.4 % more products, every tile whole)."""
     tm = 256
     while m % tm:
         tm //= 2
@@ -625,11 +633,19 @@ def _grouped_products(sizes, backend):
     return product, transposes
 
 
-ACTIVATIONS = {"silu": jax.nn.silu, "relu": jax.nn.relu}
+ACTIVATIONS = {"silu": jax.nn.silu, "relu": jax.nn.relu,
+               "relu2": lambda x: jnp.square(jax.nn.relu(x))}
 
 
-def _gate(activation, gate, up):
-    return ACTIVATIONS[activation](gate) * up
+def _hidden(activation, *products):
+    """What ``W_down`` reads, from the rows' products with the leaves before
+    it: ``activation(gate) * up`` of a gated expert's two, ``activation(up)``
+    of an ungated expert's one."""
+    if len(products) == 2:
+        gate, up = products
+        return ACTIVATIONS[activation](gate) * up
+    up, = products
+    return ACTIVATIONS[activation](up)
 
 
 def _window(j, weights, order, ends, k, c):
@@ -663,21 +679,36 @@ _add_rows_by_token = functools.partial(row_sums.add_rows_at,
                                        name="bf_moe_add_rows_by_token")
 
 
-def _cast_experts(x, *ws):
+def _cast_experts(x, ws, backend):
+    """The experts' leaves (``W_down`` last) in ``x``'s dtype and, **for the
+    grouped-matmul kernels, their width in whole lanes**: a width ``F`` that
+    is no multiple of 128 is padded with zero columns of the leaves before
+    ``W_down`` and zero rows of ``W_down`` (an activation of 0 is 0, gated
+    or not, and a zero row adds nothing), so that every tile of the
+    products is whole (:func:`_gmm_tiling`).  The pad rides on the cast's
+    own pass; ``lax.ragged_dot`` (the portable backend) takes any width as
+    it is."""
+    pad = 0 if backend == "ragged" else -ws[-1].shape[1] % _LANES
     with jax.named_scope("bf.moe.experts"):
-        return tuple(w.astype(x.dtype) for w in ws)
+        ws = tuple(w.astype(x.dtype) for w in ws)
+        if pad:
+            ws = (*(jnp.pad(w, ((0, 0), (0, 0), (0, pad))) for w in ws[:-1]),
+                  jnp.pad(ws[-1], ((0, 0), (0, pad), (0, 0))))
+        return ws
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(7, 8, 9, 10))
-def _held_experts(x, weights, order, ends, w_gate, w_up, w_down, k, c,
-                  backend, activation):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7, 8))
+def _held_experts(x, weights, order, ends, experts, k, c, backend,
+                  activation):
     """``y (T, D)``: every held assignment's ``weight * E(x[token])``,
     summed by token in f32, in passes of ``c`` rows over the held head of
     ``order`` (``ends``: the held groups' cumulative sizes); the sums by
     :func:`_add_rows_by_token` or by scatter-add (:func:`_sums_in_vmem`).
+    ``experts``: the leaves ``(w_gate, w_up, w_down)`` of gated experts or
+    ``(w_up, w_down)`` of ungated ones (:func:`_hidden`).
     One differentiation rule of its own: a loop with a traced trip count has
     no reverse mode, and the rule keeps nothing of a pass but the inputs."""
-    wg, wu, wd = _cast_experts(x, w_gate, w_up, w_down)
+    *w_in, wd = _cast_experts(x, experts, backend)
     in_vmem = _sums_in_vmem(*x.shape, backend)
 
     def one_pass(j, y):
@@ -687,8 +718,8 @@ def _held_experts(x, weights, order, ends, w_gate, w_up, w_down, k, c,
             rows = x[tokens]
         with jax.named_scope("bf.moe.experts"):
             product, _ = _grouped_products(sizes, backend)
-            out = product(_gate(activation, product(rows, wg),
-                                product(rows, wu)), wd)
+            out = product(_hidden(activation,
+                                  *(product(rows, wi) for wi in w_in)), wd)
         with jax.named_scope("bf.moe.combine"):
             if in_vmem:
                 return _add_rows_by_token(
@@ -702,19 +733,19 @@ def _held_experts(x, weights, order, ends, w_gate, w_up, w_down, k, c,
     return y.astype(x.dtype)
 
 
-def _held_experts_fwd(x, weights, order, ends, w_gate, w_up, w_down, k, c,
-                      backend, activation):
-    return (_held_experts(x, weights, order, ends, w_gate, w_up, w_down, k,
-                          c, backend, activation),
-            (x, weights, order, ends, w_gate, w_up, w_down))
+def _held_experts_fwd(x, weights, order, ends, experts, k, c, backend,
+                      activation):
+    return (_held_experts(x, weights, order, ends, experts, k, c, backend,
+                          activation),
+            (x, weights, order, ends, experts))
 
 
 def _held_experts_bwd(k, c, backend, activation, res, g):
     """The same passes: a pass's rows and products again, then their
     transposes; ``d_x``, ``d_weights`` and the experts' gradients add up
     over the passes in f32, ``d_x`` by token in the forward's form."""
-    x, weights, order, ends, *experts = res
-    wg, wu, wd = _cast_experts(x, *experts)
+    x, weights, order, ends, experts = res
+    *w_in, wd = cast = _cast_experts(x, experts, backend)
     in_vmem = _sums_in_vmem(*x.shape, backend)
 
     def one_pass(j, carry):
@@ -725,9 +756,9 @@ def _held_experts_bwd(k, c, backend, activation, res, g):
             rows = x[tokens]
         with jax.named_scope("bf.moe.experts"):
             product, transposes = _grouped_products(sizes, backend)
-            hidden, gate_transpose = jax.vjp(
-                functools.partial(_gate, activation), product(rows, wg),
-                product(rows, wu))
+            hidden, hidden_transpose = jax.vjp(
+                functools.partial(_hidden, activation),
+                *(product(rows, wi) for wi in w_in))
             out = product(hidden, wd)
         with jax.named_scope("bf.moe.combine"):
             g_rows = g[tokens].astype(jnp.float32)
@@ -736,30 +767,34 @@ def _held_experts_bwd(k, c, backend, activation, res, g):
             d_out = (g_rows * w[:, None]).astype(x.dtype)
         with jax.named_scope("bf.moe.experts"):
             d_hidden, d_wd = transposes(hidden, wd, d_out)
-            d_gate, d_up = gate_transpose(d_hidden)
-            d_rows_gate, d_wg = transposes(rows, wg, d_gate)
-            d_rows_up, d_wu = transposes(rows, wu, d_up)
+            d_rows, d_w_in = zip(*(
+                transposes(rows, wi, d)
+                for wi, d in zip(w_in, hidden_transpose(d_hidden))))
             d_ws = tuple(acc + d.astype(jnp.float32)
-                         for acc, d in zip(d_ws, (d_wg, d_wu, d_wd)))
+                         for acc, d in zip(d_ws, (*d_w_in, d_wd)))
         with jax.named_scope("bf.moe.dispatch"):
             if in_vmem:
                 d_x = _add_rows_by_token(
-                    d_x, tokens, sizes.sum(), (d_rows_gate, d_rows_up), None,
+                    d_x, tokens, sizes.sum(), d_rows, None,
                     interpret=backend == "gmm_interpret")
             else:
                 d_x = d_x.at[tokens].add(jnp.where(
-                    live[:, None], d_rows_gate.astype(jnp.float32)
-                    + d_rows_up.astype(jnp.float32), 0.0))
+                    live[:, None], functools.reduce(
+                        jnp.add, (d.astype(jnp.float32) for d in d_rows)),
+                    0.0))
         return d_x, d_weights, d_ws
 
     d_x, d_weights, d_ws = lax.fori_loop(
         0, _ceil_div(ends[-1], c), one_pass,
         (jnp.zeros(x.shape, jnp.float32),
          jnp.zeros(weights.size, jnp.float32),
-         tuple(jnp.zeros(w.shape, jnp.float32) for w in experts)))
+         tuple(jnp.zeros(w.shape, jnp.float32) for w in cast)))
+    # a width padded to whole lanes: the leaves' own columns and rows
+    d_ws = tuple(d if d.shape == w.shape else d[:, :w.shape[1], :w.shape[2]]
+                 for d, w in zip(d_ws, experts))
     return (d_x.astype(x.dtype),
             d_weights.reshape(weights.shape).astype(weights.dtype), None,
-            None, *(d.astype(w.dtype) for d, w in zip(d_ws, experts)))
+            None, tuple(d.astype(w.dtype) for d, w in zip(d_ws, experts)))
 
 
 _held_experts.defvjp(_held_experts_fwd, _held_experts_bwd)
@@ -772,10 +807,15 @@ def routed_experts(x, idx, weights, w_gate, w_up, w_down, *,
 
     ``x (T, D)`` tokens; ``idx``/``weights (T, k)`` from the router, over
     all ``num_experts``; ``w_gate``/``w_up (count, D, F)`` and
-    ``w_down (count, F, D)`` the gated experts ``E(x) = W_down
-    (activation(W_gate x) * W_up x)`` (``activation``: ``'silu'`` or
-    ``'relu'``) that ``held = (first, count)`` names: global experts
-    ``first .. first + count - 1``.  Returns
+    ``w_down (count, F, D)`` the experts that ``held = (first, count)``
+    names: global experts ``first .. first + count - 1``.  **An expert
+    takes one of two forms**, by its leaves: *gated*, ``E(x) = W_down
+    (activation(W_gate x) * W_up x)``, three leaves, three grouped products
+    forward and six back; or, with ``w_gate=None``, *ungated*, ``E(x) =
+    W_down activation(W_up x)``, two leaves, two products forward and four
+    back: no gate is built and nothing stands in for one.  ``activation``:
+    ``'silu'``, ``'relu'`` or ``'relu2'`` (``relu(x) ** 2``).  ``F`` need
+    not be whole lanes (:func:`_cast_experts` pads it for the kernels).  Returns
     ``(y, record)``: ``y[t] = sum over the chosen i that are held of
     weights[t, i] * E_i(x[t])`` in ``x.dtype`` — what the absent experts
     would add is left out, for the caller's exchange (or nothing, on one
@@ -819,9 +859,9 @@ def routed_experts(x, idx, weights, w_gate, w_up, w_down, *,
     if not (0 <= first and first + count <= num_experts and count >= 1):
         raise ValueError(f"held={held} is not a range of the "
                          f"{num_experts} experts")
-    if w_gate.shape[0] != count:
+    if w_down.shape[0] != count:
         raise ValueError(f"held {count} experts but the weights bring "
-                         f"{w_gate.shape[0]}")
+                         f"{w_down.shape[0]}")
     if backend == "auto":
         backend = "gmm" if jax.default_backend() == "tpu" else "ragged"
     if backend not in ("gmm", "gmm_interpret", "ragged"):
@@ -842,8 +882,9 @@ def routed_experts(x, idx, weights, w_gate, w_up, w_down, *,
                         (0, -n_rows % c))
         ends = (group[None, :] <= jnp.arange(count)[:, None]).sum(
             axis=1, dtype=jnp.int32)                 # held groups, cumulative
-    y = _held_experts(x, weights, order, ends, w_gate, w_up, w_down, k, c,
-                      backend, activation)
+    experts = (w_up, w_down) if w_gate is None else (w_gate, w_up, w_down)
+    y = _held_experts(x, weights, order, ends, experts, k, c, backend,
+                      activation)
     row_passes = _ceil_div(ends[-1], c)
     vmem_passes = (row_passes if _sums_in_vmem(*x.shape, backend)
                    else jnp.zeros_like(row_passes))

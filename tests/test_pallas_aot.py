@@ -701,6 +701,7 @@ _ROUTERS = {
     "joyai": (8192, 2048, 256, 8, 1, 1, "sigmoid"),
     "lfm2moe": (32768, 2048, 32, 4, 1, 1, "sigmoid"),
     "smallthinker": (16384, 2560, 64, 6, 1, 1, "softmax"),
+    "nemotron3nano": (16384, 2688, 128, 6, 1, 1, "sigmoid"),
 }
 
 
@@ -763,3 +764,99 @@ def test_the_routers_selection_is_one_kernel_and_no_larger_on_v5e(
     assert size(kernel) <= 1.5 * size(sorted_form)
     assert (kernel.memory_analysis().temp_size_in_bytes
             <= sorted_form.memory_analysis().temp_size_in_bytes)
+
+
+def test_ssd_kernels_compile_for_v5e(tpu_aot_topology):
+    """The state-space kernels at the published Mamba-2 layer: 2 x 8,192
+    tokens, 64 heads of 64 in slabs of two, state 128, 8 groups, bf16
+    ``x``, ``B`` and ``C`` beside f32 steps; value and all six gradients.
+    Mosaic takes the group chunk's ``jax.vjp`` as the backward kernel's
+    body, reads the operands as ``(128, 512)`` and ``(128, 128)`` blocks of
+    ``(B, T, H * P)`` and ``(B, T, G * N)`` with no relayout before them,
+    and the names are the ones the trace shows."""
+    from bluefog_tpu.ops.ssd import ssd
+
+    one = _one_chip(tpu_aot_topology)
+
+    def shape(dims, kind=jnp.float32):
+        return jax.ShapeDtypeStruct(dims, kind, sharding=one)
+
+    args = (shape((2, 8192, 64, 64), jnp.bfloat16), shape((2, 8192, 64)),
+            shape((64,)), shape((2, 8192, 8, 128), jnp.bfloat16),
+            shape((2, 8192, 8, 128), jnp.bfloat16), shape((64,)))
+
+    def grads(*operands):
+        return jax.grad(lambda *a: ssd(
+            *a, backend="pallas").astype(jnp.float32).sum(),
+            argnums=tuple(range(6)))(*operands)
+
+    txt = jax.jit(grads).lower(*args).compile().as_text()
+    assert txt.count("tpu_custom_call") == 2
+    assert "bf_ssd_fwd" in txt and "bf_ssd_bwd" in txt
+    copies = [line for line in txt.splitlines()
+              if _re.search(r" (copy|transpose)\(", line)
+              and "8192,64,64" in line.replace(" ", "")]
+    assert not copies, copies
+
+
+def test_full_attention_at_sixteen_queries_a_key_head_compiles_for_v5e(
+        tpu_aot_topology):
+    """The splash kernels at Nemotron-H's grouped heads: 32 query heads over
+    2 key/value heads of 128 (sixteen a group; the other cells run 1, 4 and
+    7), two sequences of 8,192, full causal, forward and fused backward,
+    keys and values repeated from 2 heads."""
+    from bluefog_tpu.ops.ring_attention import _repeat_heads, _splash_attention
+
+    one = _one_chip(tpu_aot_topology)
+    q = jax.ShapeDtypeStruct((2, 8192, 32, 128), jnp.bfloat16, sharding=one)
+    kv = jax.ShapeDtypeStruct((2, 8192, 2, 128), jnp.bfloat16, sharding=one)
+
+    def grads(q, k, v):
+        return jax.grad(lambda q, k, v: _splash_attention(
+            q, _repeat_heads(k, 32), _repeat_heads(v, 32), causal=True,
+            scale=128 ** -0.5).astype(jnp.float32).sum(),
+            argnums=(0, 1, 2))(q, k, v)
+
+    txt = jax.jit(grads).lower(q, kv, kv).compile().as_text()
+    assert txt.count("tpu_custom_call") >= 2
+    assert "flash_attention_splash_mha_fwd" in txt
+    assert "flash_mha_bwd_splash_mha_dkv" in txt
+
+
+def test_ungated_grouped_matmuls_1856_wide_compile_for_v5e(tpu_aot_topology):
+    """``routed_experts`` on the Pallas grouped matmul at Nemotron-H's
+    experts: 16,384 tokens of 2,688 choosing 6 of 128, 8 of them held,
+    **ungated** and 1,856 wide (14.5 lanes), so two leaves, a row buffer of
+    12,288 of the 98,304 sorted rows and the products at 1,920 columns
+    (zero-padded inside the op: every tile whole); value and gradient."""
+    from bluefog_tpu.ops.moe import _row_buffer, routed_experts
+
+    one = _one_chip(tpu_aot_topology)
+    assert _row_buffer(16384 * 6, 8, 128) == 12288
+
+    def shape(dims, dtype):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=one)
+
+    def value_and_grads(x, idx, weights, wu, wd):
+        def total(x, weights, wu, wd):
+            return (routed_experts(
+                x, idx, weights, None, wu, wd, num_experts=128, held=(0, 8),
+                backend="gmm", activation="relu2")[0].astype(
+                    jnp.float32) ** 2).sum()
+        return jax.value_and_grad(total, argnums=(0, 1, 2, 3))(
+            x, weights, wu, wd)
+
+    compiled = jax.jit(value_and_grads).lower(
+        shape((16384, 2688), jnp.bfloat16), shape((16384, 6), jnp.int32),
+        shape((16384, 6), jnp.float32), shape((8, 2688, 1856), jnp.float32),
+        shape((8, 1856, 2688), jnp.float32)).compile()
+    txt = compiled.as_text()
+    # two products in the forward's loop; in the backward's, the two once
+    # more, their two row transposes and two weight transposes
+    assert len(_re.findall(r"%gmm(\.\d+)? = ", txt)) == 2 + 4
+    assert len(_re.findall(r"%tgmm(\.\d+)? = ", txt)) == 2
+    assert "bf16[12288,1920]" in txt and "bf16[12288,1856]" not in txt
+    # the gradients come back at the leaves' own shapes
+    shapes = [g.shape for g in jax.tree_util.tree_leaves(
+        compiled.out_info[1])]
+    assert shapes[2:] == [(8, 2688, 1856), (8, 1856, 2688)]
