@@ -138,7 +138,7 @@ from bluefog_tpu.ops.moe import (
 from bluefog_tpu.ops.ring_attention import local_attention
 from bluefog_tpu.ops.row_sums import take_rows
 from bluefog_tpu.ops.selective_scan import selective_scan
-from bluefog_tpu.ops.short_conv import gated_short_conv
+from bluefog_tpu.ops.short_conv import gated_short_conv, silu_short_conv
 from bluefog_tpu.ops.ssd import ssd
 
 AttnFn = Callable[..., jnp.ndarray]  # (q, k, v) -> (B, T, H, D)
@@ -997,6 +997,24 @@ class MambaMixer(nn.Module):
             return dense(cfg.hidden_size, name="out_proj")(m * nn.silu(z)), m
 
 
+@jax.custom_vjp
+def _sum_cotangents_once(x):
+    """``x``, with a fence on its cotangent: where several readers each hand
+    back a piece (a Mamba-2 mixer's ``dz``, the convolution's three and ``d
+    dt``), the pieces are padded and added in one pass of their own and
+    every matmul of the backward pass reads the sum.  Without it XLA fuses
+    the padding and adding into each matmul that reads it, operand tile by
+    operand tile (PERF.md section 6, PR 49: 17 ms a step in
+    ``nemotron3nano.t8192.solo`` against 4 for the pass).  Forward it is
+    ``x`` itself and compiles to nothing."""
+    return x
+
+
+_sum_cotangents_once.defvjp(
+    lambda x: (x, None),
+    lambda _, g: (jax.lax.optimization_barrier(g),))
+
+
 class Mamba2Mixer(nn.Module):
     """Mamba-2's mixer (arXiv:2405.21060; the layer of the published
     ``nemotron_h`` modelling code): ``(B, T, D) -> (B, T, D)``.  With ``H``
@@ -1004,7 +1022,12 @@ class Mamba2Mixer(nn.Module):
     groups (:class:`Mamba2Sizes`):
 
     ``[z (I); xBC (I + 2 G N); dt (H)] = W_in y``, in that order;
-    ``xBC = silu(conv(xBC) + b_c)`` (causal depthwise, ``conv`` taps, f32);
+    ``xBC = silu(conv(xBC) + b_c)`` (causal depthwise, ``conv`` taps, f32
+    between the projection's ``dtype`` in and out:
+    :func:`bluefog_tpu.ops.short_conv.silu_short_conv`, on a TPU one kernel
+    a direction and piece of the split, reading the projection's output
+    where it lies, the pieces of its cotangent summed in one pass behind
+    :func:`_sum_cotangents_once`; elsewhere ``jax.numpy``);
     ``[x (H, P); B (G, N); C (G, N)] = split(xBC)``; ``delta = softplus(dt +
     dt_bias)`` a head; ``o = ssd(x, delta, -exp(A_log), B, C, D)``
     (:func:`bluefog_tpu.ops.ssd.ssd`: the state decays by one scalar a head
@@ -1031,7 +1054,8 @@ class Mamba2Mixer(nn.Module):
         within = _uniform_within(sizes.conv ** -0.5)
 
         with jax.named_scope("bf.ssd.project"):
-            zxbcdt = dense(2 * inner + 2 * g * n + h, name="in_proj")(y)
+            zxbcdt = _sum_cotangents_once(
+                dense(2 * inner + 2 * g * n + h, name="in_proj")(y))
             z = zxbcdt[..., :inner]
             delta = nn.softplus(
                 zxbcdt[..., -h:].astype(jnp.float32)
@@ -1044,13 +1068,11 @@ class Mamba2Mixer(nn.Module):
                               (sizes.conv, inner + 2 * g * n), jnp.float32)
             bias = self.param("conv_bias", within, (inner + 2 * g * n,),
                               jnp.float32)
-            xbc = nn.silu(causal_depthwise_conv(
-                zxbcdt[..., inner:-h].astype(jnp.float32), taps,
-                bias)).astype(cfg.dtype)
+            x, b, c = silu_short_conv(zxbcdt, taps, bias, offset=inner,
+                                      pieces=(inner, g * n, g * n))
         with jax.named_scope("bf.ssd.scan"):
-            o = ssd(xbc[..., :inner].reshape(lead + (h, p)), delta, a,
-                    xbc[..., inner:inner + g * n].reshape(lead + (g, n)),
-                    xbc[..., inner + g * n:].reshape(lead + (g, n)), skip)
+            o = ssd(x.reshape(lead + (h, p)), delta, a,
+                    b.reshape(lead + (g, n)), c.reshape(lead + (g, n)), skip)
         with jax.named_scope("bf.ssd.norm_gate"):
             gated = (o.reshape(lead + (inner,)).astype(jnp.float32)
                      * nn.silu(z.astype(jnp.float32)))
@@ -1063,7 +1085,8 @@ class Mamba2Mixer(nn.Module):
             o = (grouped.reshape(lead + (inner,)) * scale).astype(cfg.dtype)
         with jax.named_scope("bf.ssd.project"):
             out = dense(cfg.hidden_size, name="out_proj")(o)
-        return metrics_comm.count(out, [("bf_ssd_calls_total", 1.0)])
+        return metrics_comm.count(out, [("bf_ssd_calls_total", 1.0),
+                                        ("bf_cconv_calls_total", 1.0)])
 
 
 class GatedMemoryUnit(nn.Module):

@@ -1,14 +1,24 @@
-"""The gate, causal depthwise convolution and gate between the two
-projections of a gated short convolution (LFM2's operator), with its backward
-pass:
+"""Two causal depthwise convolutions a few taps long with what stands around
+them between two projections, each with its backward pass.  No matrix unit
+is involved in either: the work is one read of the operands and one write,
+and what it costs is how often those tensors cross HBM.
+
+:func:`gated_short_conv`, the gate, convolution and gate of LFM2's operator:
 
     s = b * z;   conv_t = sum_j k_j * s_{t - (K - 1) + j};   out = c * conv
 
 ``bcz (B, T, 3 D)`` holds ``[b; c; z]`` as the in projection leaves them,
 ``kernel (K, D)`` the taps (f32), zeros stand before the sequence, and the
 result ``(B, T, D)`` comes back in ``bcz``'s dtype.  Everything between is
-f32.  No matrix unit is involved: the work is one read of three tensors and
-one write, and what it costs is how often those tensors cross HBM.
+f32.
+
+:func:`silu_short_conv`, the convolution, bias and SiLU of Mamba-2's mixer:
+
+    p_t = sum_j k_j * x_{t - (K - 1) + j} + bias;   out = p * sigmoid(p)
+
+over the channels ``offset : offset + C`` of ``x (B, T, W)`` (the in
+projection's output, handed over whole), ``kernel (K, C)`` and ``bias (C,)``
+f32, the result ``(B, T, C)`` in ``x``'s dtype, everything between f32.
 
 Backends (``backend=``):
 
@@ -32,49 +42,69 @@ Backends (``backend=``):
   (computed on the first of the three, kept in VMEM for the other two:
   an input block whose index stays is not read again); the taps' gradient
   adds up in a VMEM block a channel block over the whole grid.
+  :func:`silu_short_conv` runs as ``bf_cconv_fwd`` / ``bf_cconv_bwd`` on
+  the same plan: a tile of ``x`` with the ``K - 1`` rows before it in, the
+  taps and the bias a channel block in VMEM, one write of the result.  The
+  backward kernel computes ``p`` again for the tile and for the first rows
+  of the tile after it (whose ``p`` reaches back into this tile's last
+  rows, which are at hand), ``d_p = g * sigmoid(p) (1 + p (1 -
+  sigmoid(p)))``, runs the taps the other way over ``d_p`` for ``dx`` and
+  adds the taps' and the bias's gradients up in one VMEM block a channel
+  block.  Residuals: ``x``, the taps and the bias; no f32 tensor is saved or
+  written between the two (as XLA compiles the ``jax.numpy`` form the
+  convolution goes f32 in and f32 out through HBM in each of its passes:
+  PERF.md section 6, PR 49).  ``dx`` comes back padded with zeros to ``x``'s
+  width, one of the pieces XLA adds up to the in projection's cotangent
+  (``Mamba2Mixer`` fences that sum into one pass).  The two ``pallas_call``s
+  sit in jitted functions, so a model's layers and passes trace and lower
+  each of them once.
 - ``'pallas_interpret'``: the same kernels in the Pallas interpreter (CPU
   tests).
 - ``'auto'``: the kernels on a TPU when the shapes tile (whole 16-token
-  tiles, channels a multiple of 128), else ``'xla'``.
+  tiles, channels and ``offset`` a multiple of 128, in every piece), else
+  ``'xla'``.
 """
 
 from __future__ import annotations
 
 import functools
+import itertools
 
 import jax
 import jax.numpy as jnp
 from jax import lax
 
-__all__ = ["gated_short_conv"]
+__all__ = ["gated_short_conv", "silu_short_conv"]
 
 BACKENDS = ("auto", "xla", "pallas", "pallas_interpret")
 _EDGE = 16      # rows of a neighbouring tile a step reads: a bf16 tile's
 
 
-def _tiles(t: int, d: int):
+def _tiles(t: int, d: int, offset: int = 0):
     """``(tokens, channels)`` of a grid step, or ``None`` where the shapes
     do not tile: up to 256 tokens in whole 16-row tiles by up to 1,024
-    channels in whole lanes."""
-    if t % _EDGE or d % 128:
+    channels in whole lanes, ``offset`` (the first channel, where the
+    operand is wider than the convolution) a whole number of blocks."""
+    if t % _EDGE or d % 128 or offset % 128:
         return None
     tt = next(x for x in (256, 128, 64, 32, 16) if t % x == 0)
-    dc = next(x for x in (1024, 512, 256, 128) if d % x == 0)
+    dc = next(x for x in (1024, 512, 256, 128)
+              if d % x == 0 and offset % x == 0)
     return tt, dc
 
 
-def _resolve(backend: str, t: int, d: int, taps: int) -> str:
+def _resolve(backend: str, t: int, d: int, taps: int, offset: int = 0) -> str:
     if backend not in BACKENDS:
         raise ValueError(f"unknown backend {backend!r}; expected one of "
                          f"{BACKENDS}")
-    fits = _tiles(t, d) is not None and taps <= _EDGE
+    fits = _tiles(t, d, offset) is not None and taps <= _EDGE
     if backend == "auto":
         return "pallas" if fits and jax.default_backend() == "tpu" else "xla"
     if backend != "xla" and not fits:
         raise ValueError(
             f"the kernels tile whole {_EDGE}-token rows and 128-channel "
             f"lanes and reach at most {_EDGE} taps back; got {t} tokens, "
-            f"{d} channels, {taps} taps")
+            f"{d} channels from channel {offset}, {taps} taps")
     return backend
 
 
@@ -98,6 +128,45 @@ def _plain(bcz, kernel):
     padded = jnp.pad(b * z, ((0, 0), (taps - 1, 0), (0, 0)))
     conv = sum(kernel[j] * padded[:, j:j + t] for j in range(taps))
     return (c * conv).astype(bcz.dtype)
+
+
+def silu_short_conv(x, kernel, bias, *, offset: int = 0, pieces=None,
+                    backend: str = "auto"):
+    """``silu(conv(x[..., offset:offset + C]) + bias)`` under the taps
+    ``kernel (K, C)``: ``(B, T, C)`` in ``x.dtype`` (module docstring).
+    ``x (B, T, W)`` is handed over whole so that the kernels read their
+    channels where they lie and no slice of it is copied.  With ``pieces``
+    (channel counts that add up to ``C``) the result comes back cut into that
+    many arrays: from the kernels a call a piece, so that a reader that wants
+    them apart copies no slice of the result either."""
+    c = kernel.shape[1]
+    widths = (c,) if pieces is None else tuple(pieces)
+    if (x.ndim != 3 or kernel.ndim != 2 or bias.shape != (c,)
+            or sum(widths) != c or not 0 <= offset <= x.shape[-1] - c):
+        raise ValueError(f"x {x.shape} is not (B, T, W) with channels "
+                         f"{offset}:{offset + c} in pieces of {widths} for "
+                         f"taps {kernel.shape} = (K, C) and bias {bias.shape}")
+    edges = list(itertools.accumulate(widths, initial=0))
+    backends = {_resolve(backend, x.shape[1], hi - lo, kernel.shape[0],
+                         offset + lo) for lo, hi in zip(edges, edges[1:])}
+    if "xla" in backends:
+        out = _plain_silu(x, kernel, bias, offset)
+        if pieces is None:
+            return out
+        return tuple(out[..., lo:hi] for lo, hi in zip(edges, edges[1:]))
+    taps_bias = jnp.concatenate([kernel, bias[None]]).astype(jnp.float32)
+    outs = tuple(_silu_kernels(x, taps_bias[:, lo:hi], offset + lo,
+                               "pallas_interpret" in backends)
+                 for lo, hi in zip(edges, edges[1:]))
+    return outs[0] if pieces is None else outs
+
+
+def _plain_silu(x, kernel, bias, offset):
+    taps, t = kernel.shape[0], x.shape[1]
+    live = x[..., offset:offset + kernel.shape[1]].astype(jnp.float32)
+    padded = jnp.pad(live, ((0, 0), (taps - 1, 0), (0, 0)))
+    conv = sum(kernel[j] * padded[:, j:j + t] for j in range(taps)) + bias
+    return jax.nn.silu(conv).astype(x.dtype)
 
 
 # ---- the kernels -------------------------------------------------------------
@@ -186,30 +255,36 @@ def _bwd_kernel(k_ref, b_ref, c_ref, z_ref, b_before, z_before, g_ref,
     d_ref[0] = thirds[third]
 
 
-def _specs(t, d, order):
+def _specs(t, d, order, offset=0):
     """Block specs over ``bcz (B, T, 3 D)`` and ``g (B, T, D)`` for a grid
     whose indices ``order`` maps to ``(batch, tile, channel block)``: a tile
-    of a third, the ``_EDGE`` rows before or after it, the taps' block."""
+    of a third, the ``_EDGE`` rows before or after it, the taps' block.
+    With ``offset`` the tiles are those of an operand whose channel
+    ``offset`` is the convolution's first, and ``whole=True`` asks for the
+    spec over that operand."""
     from jax.experimental import pallas as pl
 
-    tt, dc = _tiles(t, d)
+    tt, dc = _tiles(t, d, offset)
     blocks, edges = d // dc, tt // _EDGE
 
     def spec(shape, index):
         return pl.BlockSpec(shape, lambda *grid: index(*order(*grid)))
 
-    def tile_of(third):
+    def first(third, whole):
+        return (offset // dc if whole else 0) + third * blocks
+
+    def tile_of(third, whole=False):
         return spec((1, tt, dc), lambda bi, ti, ci: (
-            bi, ti, third * blocks + ci))
+            bi, ti, first(third, whole) + ci))
 
-    def before(third):
+    def before(third, whole=False):
         return spec((1, _EDGE, dc), lambda bi, ti, ci: (
-            bi, jnp.maximum(ti * edges - 1, 0), third * blocks + ci))
+            bi, jnp.maximum(ti * edges - 1, 0), first(third, whole) + ci))
 
-    def after(third):
+    def after(third, whole=False):
         return spec((1, _EDGE, dc), lambda bi, ti, ci: (
             bi, jnp.minimum((ti + 1) * edges, t // _EDGE - 1),
-            third * blocks + ci))
+            first(third, whole) + ci))
 
     def taps_of(taps):
         return spec((taps, dc), lambda bi, ti, ci: (0, ci))
@@ -275,3 +350,124 @@ def _kernels_bwd(interpret, residuals, g):
 
 
 _kernels.defvjp(_kernels_fwd, _kernels_bwd)
+
+
+# ---- convolution, bias and SiLU ---------------------------------------------
+
+def _preactivation(kb_ref, x, before, taps):
+    """``p = conv(x) + bias`` of a block ``x`` (f32) whose ``_EDGE`` earlier
+    rows are ``before``, under ``kb_ref (taps + 1, dc)`` (the taps, then the
+    bias); and ``x`` shifted by each tap, for the taps' gradient."""
+    shifted = [x] + [_earlier(x, before, n) for n in range(1, taps)]
+    p = kb_ref[taps:taps + 1, :] + kb_ref[taps - 1:taps, :] * x
+    for n in range(1, taps):
+        p = p + kb_ref[taps - 1 - n:taps - n, :] * shifted[n]
+    return p, shifted
+
+
+def _d_silu(p):
+    sig = jax.nn.sigmoid(p)
+    return sig * (1.0 + p * (1.0 - sig))
+
+
+def _rows(ref, live=None):
+    """A block in f32; zero unless ``live`` where it may stand for rows
+    outside the sequence."""
+    x = ref[0].astype(jnp.float32)
+    return x if live is None else jnp.where(live, x, 0.0)
+
+
+def _silu_fwd_kernel(kb_ref, x_ref, x_before, o_ref, *, taps):
+    from jax.experimental import pallas as pl
+
+    p, _ = _preactivation(kb_ref, _rows(x_ref),
+                          _rows(x_before, pl.program_id(1) > 0), taps)
+    o_ref[0] = (p * jax.nn.sigmoid(p)).astype(o_ref.dtype)
+
+
+def _silu_bwd_kernel(kb_ref, x_ref, x_before, x_after, g_ref, g_after,
+                     dx_ref, dkb_ref, *, taps, tiles):
+    from jax.experimental import pallas as pl
+
+    batch, tile = pl.program_id(1), pl.program_id(2)
+
+    @pl.when((batch == 0) & (tile == 0))
+    def _():
+        dkb_ref[...] = jnp.zeros_like(dkb_ref)
+
+    x = _rows(x_ref)
+    p, shifted = _preactivation(kb_ref, x, _rows(x_before, tile > 0), taps)
+    d_p = _rows(g_ref) * _d_silu(p)
+    # the tile after this one: its first rows' p reaches into this tile
+    p_after, _ = _preactivation(kb_ref, _rows(x_after), x[-_EDGE:], taps)
+    d_after = jnp.where(tile < tiles - 1, _rows(g_after) * _d_silu(p_after),
+                        0.0)
+    d_x = kb_ref[taps - 1:taps, :] * d_p
+    for n in range(1, taps):
+        d_x = d_x + kb_ref[taps - 1 - n:taps - n, :] * _later(d_p, d_after, n)
+    dx_ref[0] = d_x.astype(dx_ref.dtype)
+    for n in range(taps):
+        dkb_ref[taps - 1 - n:taps - n, :] += jnp.sum(
+            d_p * shifted[n], axis=0, keepdims=True)
+    dkb_ref[taps:taps + 1, :] += jnp.sum(d_p, axis=0, keepdims=True)
+
+
+@functools.partial(jax.jit, static_argnums=(2, 3))
+def _silu_forward(x, taps_bias, offset, interpret):
+    from jax.experimental import pallas as pl
+
+    batch, t, c = x.shape[0], x.shape[1], taps_bias.shape[1]
+    taps = taps_bias.shape[0] - 1
+    tile_of, before, _, taps_of, (tt, _, blocks) = _specs(
+        t, c, lambda bi, ti, ci: (bi, ti, ci), offset)
+    return pl.pallas_call(
+        functools.partial(_silu_fwd_kernel, taps=taps),
+        grid=(batch, t // tt, blocks),
+        in_specs=[taps_of(taps + 1), tile_of(0, True), before(0, True)],
+        out_specs=tile_of(0),
+        out_shape=jax.ShapeDtypeStruct((batch, t, c), x.dtype),
+        interpret=interpret, name="bf_cconv_fwd",
+    )(taps_bias, x, x)
+
+
+@functools.partial(jax.jit, static_argnums=(3, 4))
+def _silu_backward(x, taps_bias, g, offset, interpret):
+    from jax.experimental import pallas as pl
+
+    batch, t, c = x.shape[0], x.shape[1], taps_bias.shape[1]
+    taps = taps_bias.shape[0] - 1
+    # channel blocks outermost: a block's taps' and bias's gradients stay in
+    # VMEM over its whole run
+    tile_of, before, after, taps_of, (tt, _, blocks) = _specs(
+        t, c, lambda ci, bi, ti: (bi, ti, ci), offset)
+    d_x, d_taps_bias = pl.pallas_call(
+        functools.partial(_silu_bwd_kernel, taps=taps, tiles=t // tt),
+        grid=(blocks, batch, t // tt),
+        in_specs=[taps_of(taps + 1), tile_of(0, True), before(0, True),
+                  after(0, True), tile_of(0), after(0)],
+        out_specs=[tile_of(0), taps_of(taps + 1)],
+        out_shape=[jax.ShapeDtypeStruct((batch, t, c), x.dtype),
+                   jax.ShapeDtypeStruct(taps_bias.shape, jnp.float32)],
+        interpret=interpret, name="bf_cconv_bwd",
+    )(taps_bias, x, x, x, g, g)
+    # the cotangent of x whole: zeros beside the channels the taps read
+    beside = (offset, x.shape[-1] - offset - c, 0)
+    return lax.pad(d_x, jnp.zeros((), d_x.dtype),
+                   ((0, 0, 0), (0, 0, 0), beside)), d_taps_bias
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(2, 3))
+def _silu_kernels(x, taps_bias, offset, interpret):
+    return _silu_forward(x, taps_bias, offset, interpret)
+
+
+def _silu_kernels_fwd(x, taps_bias, offset, interpret):
+    return _silu_forward(x, taps_bias, offset, interpret), (x, taps_bias)
+
+
+def _silu_kernels_bwd(offset, interpret, residuals, g):
+    x, taps_bias = residuals
+    return _silu_backward(x, taps_bias, g.astype(x.dtype), offset, interpret)
+
+
+_silu_kernels.defvjp(_silu_kernels_fwd, _silu_kernels_bwd)

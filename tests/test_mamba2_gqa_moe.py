@@ -12,6 +12,7 @@ import os
 import re
 import sys
 
+import flax.linen
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -154,6 +155,136 @@ def test_the_mixer_is_causal(t):
     out = module.apply({"params": p}, u.at[:, t].add(1.0))
     np.testing.assert_array_equal(out[:, :t], base[:, :t])
     assert float(jnp.max(jnp.abs(out[:, t:] - base[:, t:]))) > 1e-3
+
+
+# ---- the convolution's kernels, and the form before them ------------------------
+
+def test_the_mixer_on_the_convolution_s_kernels_equals_the_plain_form(
+        monkeypatch):
+    """Widths that tile (128 inner channels, one group of 128 states): the
+    convolution, bias and SiLU in ``bf_cconv_fwd`` / ``bf_cconv_bwd``
+    (interpreted), a call a piece of the scan's operands, against the
+    ``jax.numpy`` form: output, input gradient and every leaf's."""
+    from bluefog_tpu.models import transformer
+    from bluefog_tpu.ops import short_conv
+
+    module = Mamba2Mixer(config(mamba2=Mamba2Sizes(
+        heads=4, head_dim=32, state=128, groups=1, conv=4)))
+    u = rand((2, 48, 64), 3)
+    p = shaken(module.init(jax.random.PRNGKey(0), u)["params"], scale=0.2)
+    assert p["conv_kernel"].shape == (4, 384)
+    probe = rand((2, 48, 64), 4)
+
+    def value():
+        return jax.jit(jax.value_and_grad(lambda p, u: jnp.sum(
+            probe * module.apply({"params": p}, u)), argnums=(0, 1)))(p, u)
+
+    want, want_grads = value()
+    calls = []
+
+    def interpreted(x, *args, **kwargs):
+        calls.append((x.shape, kwargs))
+        return short_conv.silu_short_conv(x, *args, **kwargs,
+                                          backend="pallas_interpret")
+
+    monkeypatch.setattr(transformer, "silu_short_conv", interpreted)
+    got, got_grads = value()
+    assert calls == [((2, 48, 2 * 128 + 2 * 128 + 4),
+                      {"offset": 128, "pieces": (128, 128, 128)})]
+    np.testing.assert_allclose(got, want, rtol=1e-5)
+    assert_trees_close(got_grads, want_grads, tol=1e-5)
+
+
+def test_the_fence_is_on_the_cotangent_alone():
+    """Forward the in projection's output itself (no instruction); backward
+    one barrier on the sum of the pieces its readers hand back."""
+    from bluefog_tpu.models.transformer import _sum_cotangents_once
+
+    x = rand((3, 8), 1)
+
+    def readers(fence):
+        def total(x):
+            y = fence(x)
+            return jnp.sum(y[:, :2] ** 2) + jnp.sum(jnp.sin(y[:, 2:]))
+        return total
+
+    fenced, plain = readers(_sum_cotangents_once), readers(lambda x: x)
+    assert "optimization_barrier" not in str(jax.make_jaxpr(fenced)(x))
+    assert str(jax.make_jaxpr(jax.grad(fenced))(x)).count(
+        "optimization_barrier") == 1
+    np.testing.assert_array_equal(fenced(x), plain(x))
+    np.testing.assert_array_equal(jax.grad(fenced)(x), jax.grad(plain)(x))
+
+
+class MixerBeforeTheKernels(Mamba2Mixer):
+    """``Mamba2Mixer`` as PR 48 wrote it: the convolution over all of
+    ``xBC`` in ``jax.numpy`` on an f32 copy of the projection's slice, the
+    scan's operands sliced from its result."""
+
+    @flax.linen.compact
+    def __call__(self, y):
+        from bluefog_tpu.models import transformer as tr
+
+        cfg, sizes = self.cfg, self.cfg.mamba2
+        h, p, n, g = sizes.heads, sizes.head_dim, sizes.state, sizes.groups
+        inner, lead = h * p, y.shape[:-1]
+        nn, f32 = flax.linen, jnp.float32
+        dense = lambda width, name: nn.Dense(
+            width, use_bias=False, dtype=cfg.dtype, name=name)
+        within = tr._uniform_within(sizes.conv ** -0.5)
+        zxbcdt = dense(2 * inner + 2 * g * n + h, "in_proj")(y)
+        z = zxbcdt[..., :inner]
+        delta = nn.softplus(zxbcdt[..., -h:].astype(f32) + self.param(
+            "dt_bias", tr._step_bias_init(floor=1e-4), (h,), f32))
+        a = -jnp.exp(self.param("A_log", tr._a_log_init, (h,), f32))
+        skip = self.param("D", nn.initializers.ones, (h,), f32)
+        taps = self.param("conv_kernel", within,
+                          (sizes.conv, inner + 2 * g * n), f32)
+        bias = self.param("conv_bias", within, (inner + 2 * g * n,), f32)
+        xbc = nn.silu(tr.causal_depthwise_conv(
+            zxbcdt[..., inner:-h].astype(f32), taps, bias)).astype(cfg.dtype)
+        o = tr.ssd(xbc[..., :inner].reshape(lead + (h, p)), delta, a,
+                   xbc[..., inner:inner + g * n].reshape(lead + (g, n)),
+                   xbc[..., inner + g * n:].reshape(lead + (g, n)), skip)
+        gated = o.reshape(lead + (inner,)).astype(f32) * nn.silu(
+            z.astype(f32))
+        scale = self.param("norm_scale", nn.initializers.ones, (inner,), f32)
+        grouped = gated.reshape(lead + (g, inner // g))
+        grouped = grouped * jax.lax.rsqrt(
+            jnp.mean(grouped * grouped, axis=-1, keepdims=True)
+            + cfg.norm_eps)
+        o = (grouped.reshape(lead + (inner,)) * scale).astype(cfg.dtype)
+        return dense(cfg.hidden_size, "out_proj")(o)
+
+
+@pytest.mark.parametrize("remat", [False, True], ids=["stored", "remat"])
+def test_on_a_cpu_the_model_computes_what_it_did_bit_for_bit(
+        remat, tokens, monkeypatch):
+    """``'auto'`` off a TPU is the ``jax.numpy`` form over all of ``xBC`` at
+    once: the loss and every leaf's gradient of a model of two Mamba-2
+    blocks equal those of the mixer written as it was before
+    ``silu_short_conv``, to the bit."""
+    from bluefog_tpu.models import transformer
+
+    cfg = config(num_layers=2, layer_types=(M, M), ffn="gelu", experts=None,
+                 remat=remat)
+    params = shaken(jax.jit(TransformerLM(cfg).init)(
+        jax.random.PRNGKey(0), tokens[:, :-1])["params"])
+
+    def loss_and_grads():
+        model = TransformerLM(cfg)
+        return jax.jit(jax.value_and_grad(lambda p: next_token_loss(
+            model, p, {}, tokens)))(params)
+
+    got, got_grads = loss_and_grads()
+    monkeypatch.setattr(transformer, "Mamba2Mixer", MixerBeforeTheKernels)
+    want, want_grads = loss_and_grads()
+    assert float(got) == float(want)
+    for (path, a), b in zip(jax.tree_util.tree_leaves_with_path(got_grads),
+                            jax.tree_util.tree_leaves(want_grads)):
+        np.testing.assert_array_equal(a, b, err_msg=jax.tree_util.keystr(path))
+    assert float(jnp.max(jnp.abs(
+        got_grads["block_0"]["mixer"]["conv_bias"]))) > 0.0
 
 
 # ---- the ungated expert layer ---------------------------------------------------
@@ -408,6 +539,7 @@ def test_counters_of_the_new_layers(variables, tokens):
         jax.effects_barrier()
         snap = reg.snapshot()
         assert snap["bf_ssd_calls_total"] == LETTERS.count("M")
+        assert snap["bf_cconv_calls_total"] == LETTERS.count("M")
         assert snap["bf_attn_full_calls_total"] == LETTERS.count("*")
         assigned = LETTERS.count("E") * 2 * 20 * K
         assert snap["bf_moe_assignments_total"] == assigned

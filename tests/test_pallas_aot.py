@@ -648,6 +648,75 @@ def test_short_convolution_kernels_compile_for_v5e(tpu_aot_topology):
     assert not copies, copies
 
 
+def test_convolution_and_silu_kernels_compile_for_v5e(tpu_aot_topology):
+    """``bf_cconv_fwd`` / ``bf_cconv_bwd`` at ``nemotron3nano.t8192.solo``'s
+    shapes, within the default scoped VMEM (the op asks for no other): they
+    read the in projection's ``(2, 8192, 10304)`` output as it lies, channels
+    4,096 : 10,240 under 4 taps, and write the scan's three operands as three
+    arrays, so no slice of the operand or of the result is copied."""
+    from bluefog_tpu.ops.short_conv import silu_short_conv
+
+    one = _one_chip(tpu_aot_topology)
+
+    def value_and_grads(y, w_in, kernel, bias):
+        def total(y, w_in, kernel, bias):
+            # 10,304 channels are no whole number of lanes: as in the
+            # model, a matmul writes them in the layout the kernels read
+            return sum(
+                (out.astype(jnp.float32) ** 2).sum()
+                for out in silu_short_conv(
+                    y @ w_in, kernel, bias, offset=4096,
+                    pieces=(4096, 1024, 1024), backend="pallas"))
+        return jax.value_and_grad(total, argnums=(0, 1, 2, 3))(
+            y, w_in, kernel, bias)
+
+    txt = jax.jit(value_and_grads).lower(
+        _lfm2_shape(one, (2, 8192, 256)), _lfm2_shape(one, (256, 10304)),
+        _lfm2_shape(one, (4, 6144), jnp.float32),
+        _lfm2_shape(one, (6144,), jnp.float32)).compile().as_text()
+    assert txt.count("tpu_custom_call") == 6
+    assert len(_re.findall(r"%\S*bf_cconv_fwd\S* = ", txt)) == 3
+    assert len(_re.findall(r"%\S*bf_cconv_bwd\S* = ", txt)) == 3
+    assert "vmem_limit_bytes" not in txt
+    copies = [line for line in txt.splitlines()
+              if _re.search(r" (copy|transpose|slice)\(", line)
+              and "2,8192," in line.replace(" ", "")]
+    assert not copies, copies
+
+
+def test_the_nemotron_step_holds_no_f32_convolution_on_v5e(monkeypatch):
+    """``nemotron3nano.t8192.solo``'s step, optimizer included: a kernel call
+    a piece, layer and pass (3 x 4 layers, the forward twice under remat),
+    no f32 tensor of the convolution's ``(2, 8192, 6144)`` under
+    ``bf.ssd.conv`` (as XLA compiled the ``jax.numpy`` form it held 76:
+    the f32 copy of the slice, its padded form and the pre-activation, in
+    every pass), and the step's temporaries under what they were with them
+    (3,529,905,152 bytes at PR 48)."""
+    compiled = _compile_cell_step("nemotron3nano.t8192.solo", monkeypatch)
+    txt = compiled.as_text()
+    assert len(_re.findall(r"%bf_cconv_fwd(\.\d+)? = ", txt)) == 3 * 4 * 2
+    assert len(_re.findall(r"%bf_cconv_bwd(\.\d+)? = ", txt)) == 3 * 4
+    wide = [line.strip()[:200] for line in txt.splitlines()
+            if "bf.ssd.conv" in line and _re.search(       # what is written
+                r" = \(?[^=]*f32\[2,819\d,\d+\][^=]* (fusion|custom-call|"
+                r"copy)\(", line)]
+    assert not wide, wide[:3]
+    assert compiled.memory_analysis().temp_size_in_bytes < 3.0 * 2 ** 30
+    # the fence: a layer's three dx (with dz and d dt) meet in ONE fusion
+    # that writes the in projection's cotangent, not in both of its matmuls
+    kernels = set(_re.findall(r"%(bf_cconv_bwd[.\d]*) = ", txt))
+    pieces = {name for name, source in _re.findall(
+        r"%(\S+) = \S+ get-tuple-element\(%(\S+)\), index=0", txt)
+        if source in kernels}
+    readers = [line for line in txt.splitlines()
+               if _re.search(r" = .* (fusion|custom-call)\(", line)
+               and pieces & set(_re.findall(r"%([\w.\-]+)", line.split(
+                   " = ", 1)[1]))]
+    assert len(pieces) == 12 and len(readers) == 4, readers
+    assert all(_re.search(r" = bf16\[2,8192,10304\]\S* fusion\(", line)
+               for line in readers), readers
+
+
 def test_gpt2_attention_sublayer_keeps_its_layout_copies_few_on_v5e(
         tpu_aot_topology):
     """One GPT-2 block of ``gpt2s.t2048.solo`` (batch 8, T=2048, 768 wide,
