@@ -19,9 +19,16 @@ gradients: the gradients alone need no forward kernel, which would drop out).
   holds the pass that pads the three ``dx`` to the projection's width and
   adds them (in the cell's step that pass takes ``dz`` and ``d dt`` in as
   well, behind ``Mamba2Mixer``'s fence), and the operand's copy below.
+- ``--shape ling3flash``: one KDA layer's three chains as
+  ``ling3flash.t8192.solo``'s mixer calls them: the q, k and v projections'
+  ``(1, 8192, 2048)`` outputs, 4 taps and no bias, q and k normalised over
+  each head's 128 channels (q times ``128 ** -0.5``) inside the same two
+  kernels, bf16.  The ``jax.numpy`` form is ``KdaMixer``'s before them: f32
+  from the convolution through the norm.  The bounds a tensor: 0.067 GB
+  forward and 0.10 GB backward.
 
-  chiprun -- python3 benchmarks/short_conv_bench.py --shape cell,nemotron --out chiprun_out/short_conv_v5e.json
-  JAX_PLATFORMS=cpu python3 benchmarks/short_conv_bench.py --shape tiny,nemotron_tiny
+  chiprun -- python3 benchmarks/short_conv_bench.py --shape cell,nemotron,ling3flash --out chiprun_out/short_conv_v5e.json
+  JAX_PLATFORMS=cpu python3 benchmarks/short_conv_bench.py --shape tiny,nemotron_tiny,ling3flash_tiny
 
 ``benchmarks/short_conv_v5e.json`` is a chip run's.
 """
@@ -46,17 +53,22 @@ from chipbench.peaks import peaks_for
 from moe_combine_bench import device_times
 
 # gated: batch, tokens, channels, dtype; convolution and SiLU: batch, tokens,
-# the operand's width, dtype, the pieces' first channels and the last's end
+# the operand's width, dtype, the pieces' first channels and the last's end;
+# a KDA layer's q, k and v: batch, tokens, channels, dtype, a head's channels
 SHAPES = {
     "cell": (4, 8192, 2048, jnp.bfloat16),
     "tiny": (2, 64, 128, jnp.float32),
     "nemotron": (2, 8192, 10304, jnp.bfloat16, (4096, 8192, 9216, 10240)),
-    "nemotron_tiny": (2, 64, 832, jnp.float32, (256, 512, 640, 768))}
+    "nemotron_tiny": (2, 64, 832, jnp.float32, (256, 512, 640, 768)),
+    "ling3flash": (1, 8192, 2048, jnp.bfloat16, 128),
+    "ling3flash_tiny": (2, 64, 256, jnp.float32, 128)}
 SILU_TAPS = 4
 
 
-def _is_silu(shape):
-    return len(shape) == 5
+def _builder(shape):
+    if len(shape) == 4:
+        return _gated
+    return _silu if isinstance(shape[4], tuple) else _kda
 
 
 def _gated(shape, backend):
@@ -100,9 +112,32 @@ def _silu(shape, backend):
         x, kernel, bias)
 
 
+def _kda(shape, backend):
+    batch, t, channels, dtype, head = shape
+    keys = jax.random.split(jax.random.PRNGKey(0), 9)
+    xs = [jax.random.normal(key, (batch, t, channels)).astype(dtype)
+          for key in keys[:3]]
+    kernels = [jax.random.uniform(key, (SILU_TAPS, channels), minval=-0.5,
+                                  maxval=0.5) for key in keys[3:6]]
+    probes = [jax.random.normal(key, (batch, t, channels)).astype(dtype)
+              for key in keys[6:]]
+    norms = ((head, 1e-6, head ** -0.5), (head, 1e-6, 1.0), None)
+    no_bias = jnp.zeros((channels,), jnp.float32)
+
+    def forward(xs, kernels):           # as KdaMixer calls it
+        return [short_conv.silu_short_conv(x, kernel, no_bias, l2norm=norm,
+                                           backend=backend)
+                for x, kernel, norm in zip(xs, kernels, norms)]
+
+    def total(xs, kernels):
+        return sum(jnp.sum((probe * out).astype(jnp.float32)) for probe, out
+                   in zip(probes, forward(xs, kernels)))
+
+    return forward, jax.value_and_grad(total, argnums=(0, 1)), (xs, kernels)
+
+
 def measure(backend, shape, iters, tiles=None):
-    forward, both, operands = (_silu if _is_silu(shape) else _gated)(
-        shape, backend)
+    forward, both, operands = _builder(shape)(shape, backend)
     real = short_conv._tiles
     if tiles is not None:
         short_conv._tiles = lambda *shape: tiles
@@ -139,9 +174,10 @@ def measure(backend, shape, iters, tiles=None):
 def bounds_ms(shape, hbm_bytes_per_s):
     """The least a forward call, and a forward and a backward call, could
     take: the bytes no kernel avoids over the chip's HBM rate."""
-    if _is_silu(shape):
-        batch, t, _, dtype, edges = shape
-        elements = batch * t * (edges[-1] - edges[0])
+    if len(shape) == 5:
+        batch, t, channels, dtype, edges = shape
+        elements = batch * t * (edges[-1] - edges[0] if isinstance(
+            edges, tuple) else 3 * channels)
         forward = 2 * elements * jnp.dtype(dtype).itemsize
         both = 5 * elements * jnp.dtype(dtype).itemsize
     else:

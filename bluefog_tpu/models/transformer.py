@@ -780,8 +780,15 @@ class KdaMixer(nn.Module):
     ``d = kda.head_dim``:
 
     ``q~, k~, v = silu(conv(W_q y)), silu(conv(W_k y)), silu(conv(W_v y))``
-    (causal depthwise convolutions of ``kda.conv`` taps, no bias, f32);
-    ``q = l2norm(q~) d^-1/2``, ``k = l2norm(k~)``; ``beta = sigmoid(W_b y)``
+    (causal depthwise convolutions of ``kda.conv`` taps, no bias);
+    ``q = l2norm(q~) d^-1/2``, ``k = l2norm(k~)`` (f32 from the convolution
+    through the norm between the projection's ``dtype`` in and out:
+    :func:`bluefog_tpu.ops.short_conv.silu_short_conv`, on a TPU one kernel
+    a direction and tensor where a head is whole lanes; elsewhere
+    ``jax.numpy``; the six projections' pieces of ``y``'s cotangent are
+    summed in one pass behind :func:`_sum_cotangents_once`, which also
+    keeps a plain reference step's matmuls tiled as this step's: PERF.md
+    section 6, PR 50); ``beta = sigmoid(W_b y)``
     a head; the log-decay a channel ``g = lower_bound sigmoid(exp(A_log_h)
     (W_f y + dt_bias))`` in f32; ``o = kda(q, k, v, g, beta)``
     (:func:`bluefog_tpu.ops.kda.kda`); output ``W_o [RMSNorm_head(o)
@@ -804,21 +811,20 @@ class KdaMixer(nn.Module):
         taps = _uniform_within(sizes.conv ** -0.5)
 
         with jax.named_scope("bf.kda.project"):
+            y = _sum_cotangents_once(y)
             projected = [dense(h * d, name=name)(y) for name in "qkv"]
             decay = dense(h * d, name="f")(y)
             beta = jax.nn.sigmoid(dense(h, name="b")(y).astype(jnp.float32))
             gate = dense(h, name="head_gate")(y)
         with jax.named_scope("bf.kda.conv"):
-            q, k, v = (nn.silu(causal_depthwise_conv(
-                x.astype(jnp.float32),
-                self.param(f"{name}_conv", taps, (sizes.conv, h * d),
-                           jnp.float32), 0.0)).reshape(lead + (h, d))
-                       for name, x in zip("qkv", projected))
+            no_bias = jnp.zeros((h * d,), jnp.float32)
+            norms = ((d, 1e-6, d ** -0.5), (d, 1e-6, 1.0), None)
+            q, k, v = (silu_short_conv(
+                x, self.param(f"{name}_conv", taps, (sizes.conv, h * d),
+                              jnp.float32), no_bias,
+                l2norm=l2norm).reshape(lead + (h, d))
+                       for name, x, l2norm in zip("qkv", projected, norms))
         with jax.named_scope("bf.kda.project"):
-            q = (q * jax.lax.rsqrt(jnp.sum(q * q, -1, keepdims=True) + 1e-6)
-                 * d ** -0.5).astype(cfg.dtype)
-            k = (k * jax.lax.rsqrt(jnp.sum(k * k, -1, keepdims=True) + 1e-6)
-                 ).astype(cfg.dtype)
             rate = jnp.exp(self.param("A_log", _a_log_init, (h,),
                                       jnp.float32))
             bias = self.param("dt_bias", _step_bias_init(), (h * d,),
@@ -827,14 +833,14 @@ class KdaMixer(nn.Module):
                 rate[:, None] * (decay.astype(jnp.float32) + bias).reshape(
                     lead + (h, d)))
         with jax.named_scope("bf.kda.scan"):
-            o = kda(q, k, v.astype(cfg.dtype), g, beta)
+            o = kda(q, k, v, g, beta)
         with jax.named_scope("bf.kda.norm_gate"):
             o = nn.RMSNorm(epsilon=cfg.norm_eps, dtype=jnp.float32,
                            name="o_norm")(o)
             o = _head_gate(o, gate)
         with jax.named_scope("bf.kda.project"):
-            return dense(cfg.hidden_size, name="o")(
-                o.reshape(lead + (h * d,)))
+            out = dense(cfg.hidden_size, name="o")(o.reshape(lead + (h * d,)))
+        return metrics_comm.count(out, [("bf_cconv_calls_total", 1.0)])
 
 
 class GroupedQueryAttention(nn.Module):
@@ -1001,7 +1007,8 @@ class MambaMixer(nn.Module):
 def _sum_cotangents_once(x):
     """``x``, with a fence on its cotangent: where several readers each hand
     back a piece (a Mamba-2 mixer's ``dz``, the convolution's three and ``d
-    dt``), the pieces are padded and added in one pass of their own and
+    dt``; a KDA mixer's six projections of its input), the pieces are
+    padded and added in one pass of their own and
     every matmul of the backward pass reads the sum.  Without it XLA fuses
     the padding and adding into each matmul that reads it, operand tile by
     operand tile (PERF.md section 6, PR 49: 17 ms a step in

@@ -19,6 +19,13 @@ f32.
 over the channels ``offset : offset + C`` of ``x (B, T, W)`` (the in
 projection's output, handed over whole), ``kernel (K, C)`` and ``bias (C,)``
 f32, the result ``(B, T, C)`` in ``x``'s dtype, everything between f32.
+Its second caller is Kimi Delta Attention's mixer (``KdaMixer``: q, k and v,
+a projection's output each, no bias), whose q and k are l2-normalised a
+head before they are rounded; with ``l2norm=(width, eps, scale)``
+
+    s = p * sigmoid(p);   out = scale * s / sqrt(sum_group s ** 2 + eps)
+
+over each ``width`` consecutive channels, still f32 up to the one cast.
 
 Backends (``backend=``):
 
@@ -55,13 +62,22 @@ Backends (``backend=``):
   convolution goes f32 in and f32 out through HBM in each of its passes:
   PERF.md section 6, PR 49).  ``dx`` comes back padded with zeros to ``x``'s
   width, one of the pieces XLA adds up to the in projection's cotangent
-  (``Mamba2Mixer`` fences that sum into one pass).  The two ``pallas_call``s
-  sit in jitted functions, so a model's layers and passes trace and lower
-  each of them once.
+  (``Mamba2Mixer`` fences that sum into one pass).  The normalised form is
+  the same two kernels with an epilogue: a group is whole lanes, so the sum
+  of squares is a lane reduction a row of a 128-lane column slice of the
+  block, and the tile's edges need nothing new (the norm is a row's own).
+  Backward, ``s`` and ``r = rsqrt(sum s ** 2 + eps)`` are computed again
+  with ``p``, for the tile and for the first rows of the tile after it, and
+  ``d_s = scale r (g - s r ** 2 sum_group g s)`` stands where ``g`` stood.
+  ``scale`` is an operand (one f32 in SMEM), so q (``head_dim ** -0.5``)
+  and k (1) share a body.  The two ``pallas_call``s sit in jitted
+  functions, so a model's layers and passes trace and lower each of them
+  once a form: at most two forward and two backward bodies a step.
 - ``'pallas_interpret'``: the same kernels in the Pallas interpreter (CPU
   tests).
 - ``'auto'``: the kernels on a TPU when the shapes tile (whole 16-token
-  tiles, channels and ``offset`` a multiple of 128, in every piece), else
+  tiles, channels and ``offset`` a multiple of 128, in every piece; a
+  normalisation's groups whole lanes that divide a block of channels), else
   ``'xla'``.
 """
 
@@ -80,31 +96,36 @@ BACKENDS = ("auto", "xla", "pallas", "pallas_interpret")
 _EDGE = 16      # rows of a neighbouring tile a step reads: a bf16 tile's
 
 
-def _tiles(t: int, d: int, offset: int = 0):
+def _tiles(t: int, d: int, offset: int = 0, group: int = 128):
     """``(tokens, channels)`` of a grid step, or ``None`` where the shapes
     do not tile: up to 256 tokens in whole 16-row tiles by up to 1,024
     channels in whole lanes, ``offset`` (the first channel, where the
-    operand is wider than the convolution) a whole number of blocks."""
-    if t % _EDGE or d % 128 or offset % 128:
+    operand is wider than the convolution) a whole number of blocks and a
+    block a whole number of ``group``s (the channels a normalisation sums
+    over, whole lanes themselves)."""
+    if t % _EDGE or d % 128 or offset % 128 or group % 128:
         return None
     tt = next(x for x in (256, 128, 64, 32, 16) if t % x == 0)
-    dc = next(x for x in (1024, 512, 256, 128)
-              if d % x == 0 and offset % x == 0)
-    return tt, dc
+    dc = next((x for x in (1024, 512, 256, 128)
+               if d % x == 0 and offset % x == 0 and x % group == 0), None)
+    return None if dc is None else (tt, dc)
 
 
-def _resolve(backend: str, t: int, d: int, taps: int, offset: int = 0) -> str:
+def _resolve(backend: str, t: int, d: int, taps: int, offset: int = 0,
+             group: int = 128) -> str:
     if backend not in BACKENDS:
         raise ValueError(f"unknown backend {backend!r}; expected one of "
                          f"{BACKENDS}")
-    fits = _tiles(t, d, offset) is not None and taps <= _EDGE
+    fits = _tiles(t, d, offset, group) is not None and taps <= _EDGE
     if backend == "auto":
         return "pallas" if fits and jax.default_backend() == "tpu" else "xla"
     if backend != "xla" and not fits:
         raise ValueError(
             f"the kernels tile whole {_EDGE}-token rows and 128-channel "
-            f"lanes and reach at most {_EDGE} taps back; got {t} tokens, "
-            f"{d} channels from channel {offset}, {taps} taps")
+            f"lanes, normalise over whole lanes that divide a block of "
+            f"channels and reach at most {_EDGE} taps back; got {t} tokens, "
+            f"{d} channels from channel {offset} in groups of {group}, "
+            f"{taps} taps")
     return backend
 
 
@@ -131,42 +152,62 @@ def _plain(bcz, kernel):
 
 
 def silu_short_conv(x, kernel, bias, *, offset: int = 0, pieces=None,
-                    backend: str = "auto"):
+                    l2norm=None, backend: str = "auto"):
     """``silu(conv(x[..., offset:offset + C]) + bias)`` under the taps
     ``kernel (K, C)``: ``(B, T, C)`` in ``x.dtype`` (module docstring).
     ``x (B, T, W)`` is handed over whole so that the kernels read their
     channels where they lie and no slice of it is copied.  With ``pieces``
     (channel counts that add up to ``C``) the result comes back cut into that
     many arrays: from the kernels a call a piece, so that a reader that wants
-    them apart copies no slice of the result either."""
+    them apart copies no slice of the result either.  With ``l2norm =
+    (width, eps, scale)`` every ``width`` consecutive channels of the SiLU's
+    result (a head's) are divided by ``sqrt(their sum of squares + eps)``
+    and multiplied by ``scale``, in f32, before the one cast."""
     c = kernel.shape[1]
     widths = (c,) if pieces is None else tuple(pieces)
+    whole_groups = l2norm is None or (
+        l2norm[0] > 0 and not any(width % l2norm[0] for width in widths))
     if (x.ndim != 3 or kernel.ndim != 2 or bias.shape != (c,)
-            or sum(widths) != c or not 0 <= offset <= x.shape[-1] - c):
+            or sum(widths) != c or not 0 <= offset <= x.shape[-1] - c
+            or not whole_groups):
         raise ValueError(f"x {x.shape} is not (B, T, W) with channels "
                          f"{offset}:{offset + c} in pieces of {widths} for "
-                         f"taps {kernel.shape} = (K, C) and bias {bias.shape}")
+                         f"taps {kernel.shape} = (K, C) and bias {bias.shape}"
+                         + ("" if l2norm is None else
+                            f", normalised in whole groups of {l2norm[0]}"))
     edges = list(itertools.accumulate(widths, initial=0))
     backends = {_resolve(backend, x.shape[1], hi - lo, kernel.shape[0],
-                         offset + lo) for lo, hi in zip(edges, edges[1:])}
+                         offset + lo, _group(l2norm))
+                for lo, hi in zip(edges, edges[1:])}
     if "xla" in backends:
-        out = _plain_silu(x, kernel, bias, offset)
+        out = _plain_silu(x, kernel, bias, offset, l2norm)
         if pieces is None:
             return out
         return tuple(out[..., lo:hi] for lo, hi in zip(edges, edges[1:]))
     taps_bias = jnp.concatenate([kernel, bias[None]]).astype(jnp.float32)
-    outs = tuple(_silu_kernels(x, taps_bias[:, lo:hi], offset + lo,
-                               "pallas_interpret" in backends)
+    # the scale is an operand, not part of the kernels: one body normalises
+    # whatever the constant (a query's head_dim ** -0.5, a key's 1)
+    norm, scale = (None, None) if l2norm is None else (
+        l2norm[:2], jnp.full((1,), l2norm[2], jnp.float32))
+    outs = tuple(_silu_kernels(x, taps_bias[:, lo:hi], scale, offset + lo,
+                               "pallas_interpret" in backends, norm)
                  for lo, hi in zip(edges, edges[1:]))
     return outs[0] if pieces is None else outs
 
 
-def _plain_silu(x, kernel, bias, offset):
+def _plain_silu(x, kernel, bias, offset, l2norm):
     taps, t = kernel.shape[0], x.shape[1]
     live = x[..., offset:offset + kernel.shape[1]].astype(jnp.float32)
     padded = jnp.pad(live, ((0, 0), (taps - 1, 0), (0, 0)))
     conv = sum(kernel[j] * padded[:, j:j + t] for j in range(taps)) + bias
-    return jax.nn.silu(conv).astype(x.dtype)
+    out = jax.nn.silu(conv)
+    if l2norm is not None:
+        width, eps, scale = l2norm
+        heads = out.reshape(out.shape[:-1] + (-1, width))
+        heads = heads * lax.rsqrt(
+            jnp.sum(heads * heads, -1, keepdims=True) + eps)
+        out = (heads if scale == 1.0 else heads * scale).reshape(out.shape)
+    return out.astype(x.dtype)
 
 
 # ---- the kernels -------------------------------------------------------------
@@ -255,7 +296,7 @@ def _bwd_kernel(k_ref, b_ref, c_ref, z_ref, b_before, z_before, g_ref,
     d_ref[0] = thirds[third]
 
 
-def _specs(t, d, order, offset=0):
+def _specs(t, d, order, offset=0, group=128):
     """Block specs over ``bcz (B, T, 3 D)`` and ``g (B, T, D)`` for a grid
     whose indices ``order`` maps to ``(batch, tile, channel block)``: a tile
     of a third, the ``_EDGE`` rows before or after it, the taps' block.
@@ -264,7 +305,7 @@ def _specs(t, d, order, offset=0):
     spec over that operand."""
     from jax.experimental import pallas as pl
 
-    tt, dc = _tiles(t, d, offset)
+    tt, dc = _tiles(t, d, offset, group)
     blocks, edges = d // dc, tt // _EDGE
 
     def spec(shape, index):
@@ -377,19 +418,61 @@ def _rows(ref, live=None):
     return x if live is None else jnp.where(live, x, 0.0)
 
 
-def _silu_fwd_kernel(kb_ref, x_ref, x_before, o_ref, *, taps):
+def _group(norm):
+    """The channels a normalisation sums over; a lane tile without one."""
+    return 128 if norm is None else norm[0]
+
+
+def _groups(norm, *blocks):
+    """The blocks' columns a group of ``norm``'s width at a time."""
+    for lo in range(0, blocks[0].shape[1], norm[0]):
+        yield tuple(x[:, lo:lo + norm[0]] for x in blocks)
+
+
+def _normalised(s, norm, scale):
+    """``scale * s / sqrt(sum of s ** 2 + eps)`` a row and group of ``norm =
+    (width, eps)`` consecutive channels of the block ``s`` (f32): a group is
+    whole lanes, so a lane reduction a row of a column slice."""
+    return jnp.concatenate([
+        group * (scale * lax.rsqrt(
+            jnp.sum(group * group, axis=-1, keepdims=True) + norm[1]))
+        for group, in _groups(norm, s)], axis=-1)
+
+
+def _d_preactivation(p, g, norm, scale):
+    """The cotangent of ``p`` where ``g`` is that of ``silu(p)``, or of its
+    normalised form ``c r s`` (``s = silu(p)``, ``r = rsqrt(sum of s ** 2 +
+    eps)`` a row and group): ``d_s = c r (g - s r ** 2 sum of g s)``."""
+    if norm is None:
+        return g * _d_silu(p)
+    sig = jax.nn.sigmoid(p)
+    s = p * sig
+    d_s = []
+    for s_h, g_h in _groups(norm, s, g):
+        r = lax.rsqrt(jnp.sum(s_h * s_h, axis=-1, keepdims=True) + norm[1])
+        along = jnp.sum(g_h * s_h, axis=-1, keepdims=True)
+        d_s.append((scale * r) * (g_h - s_h * (r * r * along)))
+    return jnp.concatenate(d_s, axis=-1) * (sig * (1.0 + p * (1.0 - sig)))
+
+
+def _silu_fwd_kernel(scale_ref, kb_ref, x_ref, x_before, o_ref, *, taps,
+                     norm):
     from jax.experimental import pallas as pl
 
     p, _ = _preactivation(kb_ref, _rows(x_ref),
                           _rows(x_before, pl.program_id(1) > 0), taps)
-    o_ref[0] = (p * jax.nn.sigmoid(p)).astype(o_ref.dtype)
+    s = p * jax.nn.sigmoid(p)
+    if norm is not None:
+        s = _normalised(s, norm, scale_ref[0])
+    o_ref[0] = s.astype(o_ref.dtype)
 
 
-def _silu_bwd_kernel(kb_ref, x_ref, x_before, x_after, g_ref, g_after,
-                     dx_ref, dkb_ref, *, taps, tiles):
+def _silu_bwd_kernel(scale_ref, kb_ref, x_ref, x_before, x_after, g_ref,
+                     g_after, dx_ref, dkb_ref, *, taps, tiles, norm):
     from jax.experimental import pallas as pl
 
     batch, tile = pl.program_id(1), pl.program_id(2)
+    scale = None if norm is None else scale_ref[0]
 
     @pl.when((batch == 0) & (tile == 0))
     def _():
@@ -397,11 +480,12 @@ def _silu_bwd_kernel(kb_ref, x_ref, x_before, x_after, g_ref, g_after,
 
     x = _rows(x_ref)
     p, shifted = _preactivation(kb_ref, x, _rows(x_before, tile > 0), taps)
-    d_p = _rows(g_ref) * _d_silu(p)
+    d_p = _d_preactivation(p, _rows(g_ref), norm, scale)
     # the tile after this one: its first rows' p reaches into this tile
     p_after, _ = _preactivation(kb_ref, _rows(x_after), x[-_EDGE:], taps)
-    d_after = jnp.where(tile < tiles - 1, _rows(g_after) * _d_silu(p_after),
-                        0.0)
+    d_after = jnp.where(
+        tile < tiles - 1,
+        _d_preactivation(p_after, _rows(g_after), norm, scale), 0.0)
     d_x = kb_ref[taps - 1:taps, :] * d_p
     for n in range(1, taps):
         d_x = d_x + kb_ref[taps - 1 - n:taps - n, :] * _later(d_p, d_after, n)
@@ -412,26 +496,42 @@ def _silu_bwd_kernel(kb_ref, x_ref, x_before, x_after, g_ref, g_after,
     dkb_ref[taps:taps + 1, :] += jnp.sum(d_p, axis=0, keepdims=True)
 
 
-@functools.partial(jax.jit, static_argnums=(2, 3))
-def _silu_forward(x, taps_bias, offset, interpret):
+def _with_scale(kernel, scale, specs, operands):
+    """The kernel, its specs and its operands behind the normalisation's
+    scale (one f32 in SMEM), where there is one."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    if scale is None:
+        return functools.partial(kernel, None), specs, operands
+    return (kernel, [pl.BlockSpec(memory_space=pltpu.SMEM)] + specs,
+            (scale,) + operands)
+
+
+@functools.partial(jax.jit, static_argnums=(3, 4, 5))
+def _silu_forward(x, taps_bias, scale, offset, interpret, norm):
     from jax.experimental import pallas as pl
 
     batch, t, c = x.shape[0], x.shape[1], taps_bias.shape[1]
     taps = taps_bias.shape[0] - 1
     tile_of, before, _, taps_of, (tt, _, blocks) = _specs(
-        t, c, lambda bi, ti, ci: (bi, ti, ci), offset)
+        t, c, lambda bi, ti, ci: (bi, ti, ci), offset, _group(norm))
+    kernel, in_specs, operands = _with_scale(
+        functools.partial(_silu_fwd_kernel, taps=taps, norm=norm), scale,
+        [taps_of(taps + 1), tile_of(0, True), before(0, True)],
+        (taps_bias, x, x))
     return pl.pallas_call(
-        functools.partial(_silu_fwd_kernel, taps=taps),
+        kernel,
         grid=(batch, t // tt, blocks),
-        in_specs=[taps_of(taps + 1), tile_of(0, True), before(0, True)],
+        in_specs=in_specs,
         out_specs=tile_of(0),
         out_shape=jax.ShapeDtypeStruct((batch, t, c), x.dtype),
         interpret=interpret, name="bf_cconv_fwd",
-    )(taps_bias, x, x)
+    )(*operands)
 
 
-@functools.partial(jax.jit, static_argnums=(3, 4))
-def _silu_backward(x, taps_bias, g, offset, interpret):
+@functools.partial(jax.jit, static_argnums=(4, 5, 6))
+def _silu_backward(x, taps_bias, scale, g, offset, interpret, norm):
     from jax.experimental import pallas as pl
 
     batch, t, c = x.shape[0], x.shape[1], taps_bias.shape[1]
@@ -439,35 +539,43 @@ def _silu_backward(x, taps_bias, g, offset, interpret):
     # channel blocks outermost: a block's taps' and bias's gradients stay in
     # VMEM over its whole run
     tile_of, before, after, taps_of, (tt, _, blocks) = _specs(
-        t, c, lambda ci, bi, ti: (bi, ti, ci), offset)
+        t, c, lambda ci, bi, ti: (bi, ti, ci), offset, _group(norm))
+    kernel, in_specs, operands = _with_scale(
+        functools.partial(_silu_bwd_kernel, taps=taps, tiles=t // tt,
+                          norm=norm), scale,
+        [taps_of(taps + 1), tile_of(0, True), before(0, True),
+         after(0, True), tile_of(0), after(0)],
+        (taps_bias, x, x, x, g, g))
     d_x, d_taps_bias = pl.pallas_call(
-        functools.partial(_silu_bwd_kernel, taps=taps, tiles=t // tt),
+        kernel,
         grid=(blocks, batch, t // tt),
-        in_specs=[taps_of(taps + 1), tile_of(0, True), before(0, True),
-                  after(0, True), tile_of(0), after(0)],
+        in_specs=in_specs,
         out_specs=[tile_of(0), taps_of(taps + 1)],
         out_shape=[jax.ShapeDtypeStruct((batch, t, c), x.dtype),
                    jax.ShapeDtypeStruct(taps_bias.shape, jnp.float32)],
         interpret=interpret, name="bf_cconv_bwd",
-    )(taps_bias, x, x, x, g, g)
+    )(*operands)
     # the cotangent of x whole: zeros beside the channels the taps read
     beside = (offset, x.shape[-1] - offset - c, 0)
     return lax.pad(d_x, jnp.zeros((), d_x.dtype),
                    ((0, 0, 0), (0, 0, 0), beside)), d_taps_bias
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(2, 3))
-def _silu_kernels(x, taps_bias, offset, interpret):
-    return _silu_forward(x, taps_bias, offset, interpret)
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5))
+def _silu_kernels(x, taps_bias, scale, offset, interpret, norm):
+    return _silu_forward(x, taps_bias, scale, offset, interpret, norm)
 
 
-def _silu_kernels_fwd(x, taps_bias, offset, interpret):
-    return _silu_forward(x, taps_bias, offset, interpret), (x, taps_bias)
+def _silu_kernels_fwd(x, taps_bias, scale, offset, interpret, norm):
+    return (_silu_forward(x, taps_bias, scale, offset, interpret, norm),
+            (x, taps_bias, scale))
 
 
-def _silu_kernels_bwd(offset, interpret, residuals, g):
-    x, taps_bias = residuals
-    return _silu_backward(x, taps_bias, g.astype(x.dtype), offset, interpret)
+def _silu_kernels_bwd(offset, interpret, norm, residuals, g):
+    x, taps_bias, scale = residuals
+    return _silu_backward(x, taps_bias, scale, g.astype(x.dtype), offset,
+                          interpret, norm) + (
+        jax.tree_util.tree_map(jnp.zeros_like, scale),)
 
 
 _silu_kernels.defvjp(_silu_kernels_fwd, _silu_kernels_bwd)

@@ -12,6 +12,7 @@ import json
 import os
 import sys
 
+import flax.linen as nn
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -414,10 +415,19 @@ def test_the_reference_tells_each_wrong_model_apart(control, tokens,
     assert abs(wrong - got) > 1e-4 * got, (control, wrong, got)
 
 
+@pytest.mark.duration_budget(40)    # a model initialised and lowered anew
+@pytest.mark.parametrize("heads", ["narrow", "a_lane_tile"])
 def test_scopes_and_kernel_names_the_benchmark_reads_reach_the_step(
-        tokens, variables):
-    params, state = variables
-    model = TransformerLM(config(heads_held=(0, 2), remat=True))
+        heads, tokens, variables, monkeypatch):
+    """With heads a lane tile wide and whole tiles of tokens the
+    convolutions run in their kernels (interpreted here), by the names a
+    trace shows and under the convolution's scope."""
+    model, (params, state) = (
+        TransformerLM(config(heads_held=(0, 2), remat=True)), variables)
+    if heads == "a_lane_tile":
+        run_the_convolution_s_kernels(monkeypatch)
+        model, (params, state) = wide_heads(remat=True)
+        tokens = tokens[:, :49]
     text = jax.jit(jax.grad(lambda p: next_token_loss(
         model, p, state, tokens))).lower(params).as_text(debug_info=True)
     for scope in ("bf.kda.project", "bf.kda.conv", "bf.kda.scan",
@@ -425,6 +435,118 @@ def test_scopes_and_kernel_names_the_benchmark_reads_reach_the_step(
         assert scope in text, scope
     for outer in ("bf.kda.project", "bf.kda.conv", "bf.kda.norm_gate"):
         assert f"{outer}/bf." not in text            # leaf-level, unnested
+    for kernel, call in (("bf_cconv_fwd", "jit(_silu_forward)"),
+                         ("bf_cconv_bwd", "jit(_silu_backward)")):
+        sites = [line for line in text.splitlines() if call in line]
+        assert (kernel in text and bool(sites)) == (heads == "a_lane_tile")
+        assert all("/bf.kda.conv/" + call in line for line in sites), sites
+
+
+# ---- the convolutions' kernels, and the mixer before them ---------------------
+
+class MixerBeforeTheKernels(KdaMixer):
+    """``KdaMixer`` as PR 41 wrote it: each projection's output cast to f32,
+    convolved and put through SiLU in ``jax.numpy``, q and k normalised a
+    head under the projections' scope, one cast on the way into the scan."""
+
+    @nn.compact
+    def __call__(self, y):
+        from bluefog_tpu.models import transformer as tr
+
+        cfg, sizes, h = self.cfg, self.cfg.kda, tr._heads(self.cfg)
+        d, lead, f32 = sizes.head_dim, y.shape[:-1], jnp.float32
+        dense = functools.partial(nn.Dense, use_bias=False, dtype=cfg.dtype)
+        taps = tr._uniform_within(sizes.conv ** -0.5)
+        projected = [dense(h * d, name=name)(y) for name in "qkv"]
+        decay = dense(h * d, name="f")(y)
+        beta = jax.nn.sigmoid(dense(h, name="b")(y).astype(f32))
+        gate = dense(h, name="head_gate")(y)
+        q, k, v = (nn.silu(tr.causal_depthwise_conv(
+            x.astype(f32), self.param(f"{name}_conv", taps,
+                                      (sizes.conv, h * d), f32),
+            0.0)).reshape(lead + (h, d)) for name, x in zip("qkv", projected))
+        q = (q * lax.rsqrt(jnp.sum(q * q, -1, keepdims=True) + 1e-6)
+             * d ** -0.5).astype(cfg.dtype)
+        k = (k * lax.rsqrt(jnp.sum(k * k, -1, keepdims=True) + 1e-6)
+             ).astype(cfg.dtype)
+        rate = jnp.exp(self.param("A_log", tr._a_log_init, (h,), f32))
+        bias = self.param("dt_bias", tr._step_bias_init(), (h * d,), f32)
+        g = sizes.lower_bound * jax.nn.sigmoid(
+            rate[:, None] * (decay.astype(f32) + bias).reshape(
+                lead + (h, d)))
+        o = tr.kda(q, k, v.astype(cfg.dtype), g, beta)
+        o = nn.RMSNorm(epsilon=cfg.norm_eps, dtype=f32, name="o_norm")(o)
+        o = tr._head_gate(o, gate)
+        return dense(cfg.hidden_size, name="o")(o.reshape(lead + (h * d,)))
+
+
+def wide_heads(**over):
+    """The model with KDA heads a lane tile wide (two held: 256 channels a
+    projection), which the convolutions' kernels tile, and its state."""
+    model = TransformerLM(config(kda=KdaSizes(head_dim=128),
+                                 heads_held=(0, 2), **over))
+    made = jax.jit(model.init)(jax.random.PRNGKey(0),
+                               jnp.zeros((2, 16), jnp.int32))
+    return model, (shaken(made["params"]), {"buffers": made["buffers"]})
+
+
+def run_the_convolution_s_kernels(monkeypatch, calls=None):
+    from bluefog_tpu.models import transformer
+    from bluefog_tpu.ops import short_conv
+
+    def interpreted(x, *args, **kwargs):
+        if calls is not None:
+            calls.append((x.shape, kwargs))
+        return short_conv.silu_short_conv(x, *args, **kwargs,
+                                          backend="pallas_interpret")
+
+    monkeypatch.setattr(transformer, "silu_short_conv", interpreted)
+
+
+@pytest.mark.duration_budget(90)    # the model's gradient compiled three times
+@pytest.mark.parametrize("remat", [False, True], ids=["stored", "remat"])
+def test_the_model_on_the_convolution_s_kernels_equals_the_mixer_before_them(
+        remat, tokens, monkeypatch):
+    """Three tiles of 16 tokens, 256 channels a projection in heads of 128:
+    q and k through ``bf_cconv_fwd`` / ``bf_cconv_bwd`` with the norm inside
+    (interpreted), v through the plain pair, against the mixer as it stood
+    before ``silu_short_conv``: the loss and every leaf's gradient.  Off a
+    TPU ``'auto'`` is that mixer's own arithmetic, to the bit; the parameter
+    tree is the one it built."""
+    from bluefog_tpu.models import transformer
+
+    model, (params, state) = wide_heads(remat=remat)
+    assert params["block_0"]["attn"]["q_conv"].shape == (4, 256)
+
+    def loss_and_grads():
+        return jax.jit(jax.value_and_grad(lambda p: next_token_loss(
+            model, p, state, tokens[:, :49])))(params)
+
+    plain, plain_grads = loss_and_grads()
+    calls = []
+    with monkeypatch.context() as patched:
+        run_the_convolution_s_kernels(patched, calls)
+        got, got_grads = loss_and_grads()
+    norms = [call[1]["l2norm"] for call in calls[:3]]
+    assert norms == [(128, 1e-6, 128 ** -0.5), (128, 1e-6, 1.0), None]
+    assert all(shape == (2, 48, 256) for shape, _ in calls) and calls
+    monkeypatch.setattr(transformer, "KdaMixer", MixerBeforeTheKernels)
+    want, want_grads = loss_and_grads()
+    before = jax.eval_shape(model.init, jax.random.PRNGKey(0),
+                            jnp.zeros((2, 16), jnp.int32))["params"]
+    assert jax.tree_util.tree_structure(before) == (
+        jax.tree_util.tree_structure(params))
+    assert jax.tree_util.tree_map(jnp.shape, before) == (
+        jax.tree_util.tree_map(jnp.shape, params))
+    assert float(plain) == float(want)
+    for (path, a), b in zip(jax.tree_util.tree_leaves_with_path(plain_grads),
+                            jax.tree_util.tree_leaves(want_grads)):
+        np.testing.assert_array_equal(a, b, err_msg=jax.tree_util.keystr(path))
+    np.testing.assert_allclose(got, want, rtol=1e-6)
+    assert_trees_close(got_grads, want_grads)
+    for name in ("q_conv", "k_conv", "v_conv"):
+        assert float(jnp.max(jnp.abs(
+            got_grads["block_0"]["attn"][name]))) > 0.0
 
 
 def test_counters_of_the_new_layers(tokens, variables):
@@ -442,6 +564,8 @@ def test_counters_of_the_new_layers(tokens, variables):
         snap = reg.snapshot()
         # three KDA layers, two sequences, two heads held, 70 tokens: 2 chunks
         assert snap["bf_kda_chunks_total"] == 3 * 2 * 2 * 2
+        # a layer's three convolutions, SiLUs and two norms: one a pass
+        assert snap["bf_cconv_calls_total"] == 3
         # three expert layers, 140 tokens, two groups kept a token
         assert snap["bf_moe_groups_kept_total"] == 3 * 140 * 2
         assert snap["bf_moe_assignments_total"] == 3 * 140 * 4

@@ -717,6 +717,71 @@ def test_the_nemotron_step_holds_no_f32_convolution_on_v5e(monkeypatch):
                for line in readers), readers
 
 
+def test_normalised_convolution_kernels_compile_for_v5e(tpu_aot_topology):
+    """``bf_cconv_fwd`` / ``bf_cconv_bwd`` as ``ling3flash.t8192.solo``'s KDA
+    layers call them: three projections' ``(1, 8192, 2048)`` bf16 outputs
+    under 4 taps, q and k normalised over each head's 128 channels inside the
+    kernels (the scale an operand: one body for both), v plain; within the
+    default scoped VMEM, two bodies a direction, and no f32 tensor of that
+    shape between the matmuls and the kernels."""
+    from bluefog_tpu.ops.short_conv import silu_short_conv
+
+    one = _one_chip(tpu_aot_topology)
+    norms = ((128, 1e-6, 128 ** -0.5), (128, 1e-6, 1.0), None)
+
+    def value_and_grads(y, w, kernels):
+        def total(y, w, kernels):
+            return sum(silu_short_conv(
+                y @ w[i], kernels[i], jnp.zeros((2048,)), l2norm=norm,
+                backend="pallas").astype(jnp.float32)[..., ::128].sum()
+                for i, norm in enumerate(norms))
+        return jax.value_and_grad(total, argnums=(0, 1, 2))(y, w, kernels)
+
+    txt = jax.jit(value_and_grads).lower(
+        _lfm2_shape(one, (1, 8192, 256)), _lfm2_shape(one, (3, 256, 2048)),
+        _lfm2_shape(one, (3, 4, 2048), jnp.float32)).compile().as_text()
+    for kernel in ("bf_cconv_fwd", "bf_cconv_bwd"):
+        calls = [line for line in txt.splitlines()
+                 if _re.search(rf"%\S*{kernel}\S* = ", line)]
+        assert len(calls) == 3, calls
+        assert len({_re.search(r'"body":"([^"]*)"', line).group(1)
+                    for line in calls}) == 2
+    assert txt.count("tpu_custom_call") == 6
+    assert "vmem_limit_bytes" not in txt
+    wide = [line.strip()[:200] for line in txt.splitlines()
+            if _re.search(r" = \(?[^=]*f32\[1,8192,2048\][^=]* (fusion|"
+                          r"custom-call|copy|convolution)\(", line)]
+    assert not wide, wide[:3]
+
+
+def test_the_ling_step_holds_no_f32_convolution_on_v5e(monkeypatch):
+    """``ling3flash.t8192.solo``'s step, optimizer included: a kernel call a
+    tensor, layer and pass (3 x 6 layers, the forward twice under remat) in
+    two bodies a direction (normalised and plain), no f32 tensor of a
+    projection's ``(1, 8192, 2048)`` written under ``bf.kda.conv`` (as XLA
+    compiled the ``jax.numpy`` form 198 fusions held such ops and the
+    projections wrote f32), and the step's temporaries under what they were
+    with them (2,546,220,544 bytes at PR 49)."""
+    compiled = _compile_cell_step("ling3flash.t8192.solo", monkeypatch)
+    txt = compiled.as_text()
+    bodies = set()
+    for kernel, count in (("bf_cconv_fwd", 3 * 6 * 2), ("bf_cconv_bwd",
+                                                         3 * 6)):
+        calls = [line for line in txt.splitlines()
+                 if _re.search(rf"%{kernel}(\.\d+)? = ", line)]
+        assert len(calls) == count, (kernel, len(calls))
+        assert all("bf.kda.conv" in line for line in calls)
+        bodies |= {(kernel, _re.search(r'"body":"([^"]*)"', line).group(1))
+                   for line in calls}
+    assert len(bodies) <= 4
+    wide = [line.strip()[:200] for line in txt.splitlines()
+            if "bf.kda.conv" in line and _re.search(
+                r" = \(?[^=]*f32\[1,819\d,2048\][^=]* (fusion|custom-call|"
+                r"copy|convolution)\(", line)]
+    assert not wide, wide[:3]
+    assert compiled.memory_analysis().temp_size_in_bytes < 2_546_220_544
+
+
 def test_gpt2_attention_sublayer_keeps_its_layout_copies_few_on_v5e(
         tpu_aot_topology):
     """One GPT-2 block of ``gpt2s.t2048.solo`` (batch 8, T=2048, 768 wide,
