@@ -36,6 +36,7 @@ from bluefog_tpu.metrics import registry as _reg
 __all__ = [
     "record_collective",
     "count",
+    "gauge",
     "inc",
     "observe",
     "set",
@@ -103,21 +104,41 @@ def count(x, counters: Sequence[Tuple[str, object]],
     reg = _reg.current()
     if reg is None or _suppressed() or not counters:
         return x
+    return _stamp_values(x, [reg.counter(name) for name, _ in counters],
+                         "inc", [a for _, a in counters], labels)
 
+
+def gauge(x, gauges: Sequence[Tuple[str, object]],
+          labels: Optional[Dict[str, object]] = None):
+    """Set ``gauges`` (``(name, value)`` pairs; values may be traced
+    scalars) at every execution of the program position where this is
+    traced, returning ``x`` unchanged: :func:`count`'s twin for a quantity
+    whose last value is what a reader wants (a looped model's exit mass),
+    trace-time gated alike."""
+    reg = _reg.current()
+    if reg is None or _suppressed() or not gauges:
+        return x
+    return _stamp_values(x, [reg.gauge(name) for name, _ in gauges], "set",
+                         [v for _, v in gauges], labels)
+
+
+def _stamp_values(x, objs, method, values, labels):
+    """``getattr(obj, method)(value, **labels)`` for each pair, from an
+    unordered callback folded into ``x``."""
     import jax.numpy as jnp
     import numpy as np
 
     from bluefog_tpu.utils.stamping import stamp
 
     lbls = {str(k): str(v) for k, v in (labels or {}).items()}
-    # materialize the counter objects at trace time: name/kind conflicts
-    # surface here (at the call site), not inside a device callback
-    objs = [reg.counter(name) for name, _ in counters]
-    amounts = [jnp.asarray(a, jnp.float32) for _, a in counters]
+    # the metric objects were materialized at trace time: name/kind
+    # conflicts surface there (at the call site), not inside a device
+    # callback
+    amounts = [jnp.asarray(a, jnp.float32) for a in values]
 
     def cb(_token, *vals):
         for obj, v in zip(objs, vals):
-            obj.inc(float(v), **lbls)
+            getattr(obj, method)(float(v), **lbls)
         return np.float32(0.0)
 
     # fire-after-data, order-by-dataflow, custom_jvp differentiability:

@@ -89,6 +89,27 @@ GPT-2 decoder this file always built, parameter for parameter:
 ``tie_head``     the head is the embedding's transpose: one leaf, whose
                  gradient is the sum of both uses
 ``heads_held``   ``None``, or this chip's share of the heads (below)
+``rounds``       1, or **how many times the stack of blocks runs over the
+                 same leaves** (a looped / universal transformer; Ouro,
+                 arXiv:2510.25741): ``x_r = ln_f(blocks(x_{r-1}))``, the
+                 final norm once a round, its output the next round's
+                 input.  The ``num_layers`` blocks, ``ln_f``, the embedding
+                 and the head are one leaf each whatever ``rounds`` is, and a
+                 leaf's gradient is the sum over its uses.  What a round
+                 does not carry is refused: the SambaY mixers' ``carried``
+                 tensors, an MTP module, the sigmoid router's
+                 selection-bias buffer.  The rounds are one ``lax.scan``
+                 (:func:`_run_rounds`): a round's passes are compiled once
+``sandwich_norm``  a norm **after** each sub-layer as well as before:
+                 ``x + ln1_post(mixer(ln1(x)))`` and ``x +
+                 ln2_post(FFN(ln2(x)))`` (two more scales a block)
+``exit_gate``    with ``rounds > 1``: one ``Linear(hidden_size, 1)`` with a
+                 bias, shared by the rounds, reads each ``x_r`` in f32;
+                 the model hands back every round's output and the gate's
+                 logits, and :func:`next_token_loss` is the expected loss
+                 over the exits (there).  A looped model has one (a loop
+                 whose last round alone is read is refused: no
+                 configuration runs it)
 ===============  ===========================================================
 
 **A chip's share of a layer** (the one place that states it).  A layer
@@ -343,6 +364,9 @@ class GPTConfig:
     heads_held: Optional[Tuple[int, int]] = None    # (first, count)
     short_conv: Optional[ShortConvSizes] = None
     mamba2: Optional[Mamba2Sizes] = None
+    rounds: int = 1                  # passes of the stack over the same leaves
+    sandwich_norm: bool = False      # a norm after each sub-layer too
+    exit_gate: bool = False          # rounds > 1: a gate on every round's exit
 
     @property
     def single_sublayer(self) -> bool:
@@ -409,6 +433,7 @@ class GPTConfig:
                              "type says whether it turns its keys); fused_qkv "
                              "and latent heads need positions")
         self._check_heads(any(linear))
+        self._check_rounds(any(mixers))
         if any(mixers):
             self._check_mixers()
             return
@@ -443,6 +468,27 @@ class GPTConfig:
                              "the attention layers; `layer_types` has none")
         if self.experts is not None:
             self._check_experts()
+
+    def _check_rounds(self, mixers: bool):
+        if self.rounds < 1 or self.exit_gate != (self.rounds > 1):
+            raise ValueError(
+                f"rounds {self.rounds}, exit_gate {self.exit_gate}: one "
+                "pass of the stack or more, and a gate where, and only "
+                "where, there are several exits to weigh (rounds > 1: no "
+                "configuration runs a loop whose last round alone is read)")
+        if self.rounds == 1:
+            return
+        sigmoid_router = (self.experts is not None
+                          and self.experts.router == "sigmoid_noaux_tc")
+        if mixers or self.mtp_depth or sigmoid_router:
+            raise ValueError(
+                f"rounds {self.rounds}: a round hands the next one the "
+                "normed residual stream and nothing else, so a looped "
+                "model takes no SambaY mixers (their `carried` memory, "
+                "keys and values), no MTP module (mtp_depth) and no "
+                "sigmoid router (its selection-bias buffer is one a "
+                "layer, not one a round); nothing published says what "
+                "those would be across rounds")
 
     def _check_heads(self, linear: bool):
         held, kda_sizes = self.heads_held, self.kda
@@ -553,10 +599,24 @@ def _norm(cfg: GPTConfig, name: str, x, dtype=jnp.float32):
     returned in ``dtype``, under the layer scope ``bf.block.norm`` (the
     cast with it: where XLA makes it a fusion's root, the fusion is the
     norm's)."""
-    norm = nn.RMSNorm if cfg.norm == "rmsnorm" else nn.LayerNorm
     with jax.named_scope("bf.block.norm"):
-        return norm(epsilon=cfg.norm_eps, dtype=jnp.float32, name=name)(
-            x).astype(dtype)
+        return _norm_module(cfg, name)(x).astype(dtype)
+
+
+def _norm_module(cfg: GPTConfig, name: str, remat: bool = False, **parent):
+    """The module of :func:`_norm`, for a caller that builds it under a
+    ``parent`` of its choice and may ask for it rematerialised (a looped
+    model's ``ln_f``, inside the scan over the rounds)."""
+    norm = nn.RMSNorm if cfg.norm == "rmsnorm" else nn.LayerNorm
+    if remat:
+        norm = nn.remat(norm)
+    return norm(epsilon=cfg.norm_eps, dtype=jnp.float32, name=name, **parent)
+
+
+def _after(cfg: GPTConfig, name: str, y):
+    """A sub-layer's output ``y`` on its way into the residual sum: through
+    the norm ``name`` where the blocks are sandwiched, else as it is."""
+    return _norm(cfg, name, y, cfg.dtype) if cfg.sandwich_norm else y
 
 
 _LANES = 128     # of a TPU tile: a head this wide fills a matmul's output
@@ -1336,9 +1396,9 @@ def _feed_forward(block, x, moe=None, routing=None):
     y = _norm(cfg, "ln2", x, cfg.dtype)
     ffn = block.ffn or cfg.ffn
     if block.mlp is not None:
-        return x + block.mlp()(y)
+        return x + _after(cfg, "ln2_post", block.mlp()(y))
     if ffn == ROUTED:
-        return x + moe(y, routing)
+        return x + _after(cfg, "ln2_post", moe(y, routing))
     width = cfg.ffn_width or cfg.mlp_ratio * cfg.hidden_size
     with jax.named_scope("bf.mlp.dense"):
         if ffn == "swiglu":
@@ -1347,12 +1407,15 @@ def _feed_forward(block, x, moe=None, routing=None):
             y = nn.Dense(width, dtype=cfg.dtype, name="up")(y)
             y = nn.gelu(y)
             y = nn.Dense(cfg.hidden_size, dtype=cfg.dtype, name="down")(y)
-    return x + y
+    return x + _after(cfg, "ln2_post", y)
 
 
 class Block(nn.Module):
     """Pre-norm attention + feed-forward residual block, assembled from the
-    configuration's kinds (module docstring).
+    configuration's kinds (module docstring); with ``cfg.sandwich_norm`` each
+    sub-layer's output goes through a norm of its own (``ln1_post``,
+    ``ln2_post``) before the residual sum.  A looped model
+    (``cfg.rounds > 1``) calls one block once a round, on the same leaves.
 
     ``mlp`` is a pluggable sublayer factory ``() -> nn.Module`` that replaces
     the configured feed-forward kind: ``mlp()`` maps ``(B, T, D) -> (B, T,
@@ -1393,7 +1456,7 @@ class Block(nn.Module):
         y = _norm(cfg, "ln1", x, cfg.dtype)
         if self.mixer in MIXERS:
             a, carried = _mix(self, y, attn_fn, carried)
-            return _feed_forward(self, x + a), carried
+            return _feed_forward(self, x + _after(cfg, "ln1_post", a)), carried
         moe, routing = (None, None) if cfg.single_sublayer else (
             _early_routing(self, y))
         if self.mixer == "kda":
@@ -1416,9 +1479,55 @@ class Block(nn.Module):
             with jax.named_scope("bf.attn.project"):
                 a = HeadDense(cfg.hidden_size, heads, inward=True,
                               dtype=cfg.dtype, name="proj")(a)
+        a = _after(cfg, "ln1_post", a)
         if cfg.single_sublayer:
             return x + a
         return _feed_forward(self, x + a, moe, routing)
+
+
+def _run_rounds(model, x, make_blocks, attn_fn, positions, logits_of):
+    """``cfg.rounds`` passes of the blocks over ``x``, the final norm once a
+    round: ``(every round's logits, the gate's logits (rounds, B, T))``.
+    ``make_blocks(parent=m)`` builds the stack under the module ``m``.
+
+    The rounds are a ``lax.scan`` with the leaves broadcast (``nn.scan`` over
+    a function of ``model``, so the blocks and ``ln_f`` keep their places in
+    the parameter tree): XLA compiles one round's passes and runs them
+    ``rounds`` times, where the unrolled loop compiles every pass of every
+    round: four times the code, which no compile cache held (PERF.md
+    section 6, PR 51).  A trace shows the loop's body ``rounds`` times a
+    pass under ``bf.loop.round``; ``exit_<r>`` tells the exits apart."""
+    cfg = model.cfg
+
+    def one_round(mdl, x, _):
+        # no callback inside the loop: flax's scan partial-evaluates its
+        # body, which a rematerialised block's effects do not survive, so
+        # the blocks' own counters are silent here and the loop's is
+        # stamped once, after it
+        with metrics_comm.suppress_comm_metrics(), jax.named_scope(
+                "bf.loop.round"):
+            for block in make_blocks(parent=mdl):
+                x = block(x, attn_fn, positions)
+            with jax.named_scope("bf.block.norm"):
+                # f32: the exit's hidden state, and the next round's input;
+                # under remat its f32 input is recomputed, not saved a round
+                h = _norm_module(cfg, "ln_f", cfg.remat, parent=mdl)(x)
+        return h.astype(cfg.dtype), h
+
+    metrics_comm.set("bf_loop_rounds", cfg.rounds)
+    _, hidden = nn.scan(one_round, variable_broadcast="params",
+                        split_rngs={"params": False}, length=cfg.rounds)(
+                            model, x, None)
+    hidden = metrics_comm.count(hidden, [(
+        "bf_loop_block_calls_total", float(cfg.rounds * cfg.num_layers))])
+    with jax.named_scope("bf.loop.exit"):
+        gates = nn.Dense(1, dtype=jnp.float32, precision="highest",
+                         name="exit_gate")(hidden)[..., 0]
+    exits = []
+    for r in range(cfg.rounds):
+        with jax.named_scope(f"exit_{r + 1}"):
+            exits.append(logits_of(hidden[r]))
+    return tuple(exits), gates
 
 
 class TransformerLM(nn.Module):
@@ -1438,7 +1547,13 @@ class TransformerLM(nn.Module):
     embedding and head (one leaf each, used twice); ``mtp_logits`` predicts
     ``t_{i+2}``.  ``head=False`` is :func:`next_token_loss`'s: the normed
     hidden states the head would read, in place of each logits (an ``init``
-    runs with the head, which makes an untied head's leaf)."""
+    runs with the head, which makes an untied head's leaf).
+
+    With ``cfg.rounds > 1`` the blocks run that many times over the same
+    leaves, ``x_r = ln_f(blocks(x_{r-1}))`` (:func:`_run_rounds`), and the
+    result is ``(logits of every round, gate logits (rounds, B, T))``: the gate
+    ``w_g . x_r + b_g`` in f32, whose sigmoids :func:`exit_distribution`
+    turns into the probability of leaving after each round."""
 
     cfg: GPTConfig
     mlp: Optional[Callable[[], nn.Module]] = None
@@ -1468,18 +1583,21 @@ class TransformerLM(nn.Module):
         block_cls = nn.remat(Block, static_argnums=(2,)) if cfg.remat else Block
         dense_blocks = cfg.experts.first_dense if cfg.experts else 0
         carried = (None, None, None)     # memory, keys, values
-        for i in range(cfg.num_layers):
-            if cfg.hybrid is not None:
+        if cfg.hybrid is not None:
+            for i in range(cfg.num_layers):
                 x, carried = block_cls(
                     cfg, mixer=cfg.layer_types[i],
                     layer=cfg.hybrid.first_layer + i, name=f"block_{i}")(
                         x, attn_fn, positions, carried)
-                continue
-            x = block_cls(cfg, mlp=self.mlp,
-                          ffn="swiglu" if i < dense_blocks else None,
-                          mixer=cfg.layer_types[i] if cfg.layer_types
-                          else None,
-                          name=f"block_{i}")(x, attn_fn, positions)
+
+        def make_blocks(**parent):
+            # a looped model's rounds build them under the scan's module:
+            # the same names, so the same leaves every round
+            return [block_cls(cfg, mlp=self.mlp, **parent,
+                              ffn="swiglu" if i < dense_blocks else None,
+                              mixer=cfg.layer_types[i] if cfg.layer_types
+                              else None, name=f"block_{i}")
+                    for i in range(cfg.num_layers)]
         if cfg.tie_head:
             def project(h):    # f32 logits from the f32 leaf, as lm_head's
                 return jnp.einsum("...d,vd->...v", h, embed.embedding)
@@ -1493,6 +1611,12 @@ class TransformerLM(nn.Module):
             with jax.named_scope("bf.head.logits"):
                 return project(h)
 
+        if cfg.rounds > 1:
+            return _run_rounds(self, x, make_blocks, attn_fn, positions,
+                               logits_of)
+        if cfg.hybrid is None:
+            for block in make_blocks():
+                x = block(x, attn_fn, positions)
         logits = logits_of(_norm(cfg, "ln_f", x))
         if next_tokens is None:
             return logits
@@ -1510,20 +1634,41 @@ class TransformerLM(nn.Module):
         return logits, logits_of(_norm(cfg, "mtp_norm", z))
 
 
+def exit_distribution(gate_logits):
+    """``log p (R, ...)`` from the exit gate's logits ``(R, ...)``: with
+    ``g_r = sigmoid(l_r)``, ``p_r = g_r prod_{j<r} (1 - g_j)`` for ``r < R``
+    and ``p_R = prod_{j<R} (1 - g_j)`` (the last round takes what is left;
+    its own gate is not read), a distribution over the exits a token.  In
+    logs, so that a gate far from 0 gives a small ``p`` and no ``nan``."""
+    stay = jnp.cumsum(jax.nn.log_sigmoid(-gate_logits[:-1]), axis=0)
+    return jnp.concatenate([
+        jax.nn.log_sigmoid(gate_logits[:1]),
+        jax.nn.log_sigmoid(gate_logits[1:-1]) + stay[:-1], stay[-1:]])
+
+
 def next_token_loss(model: TransformerLM, params, model_state, tokens, *,
-                    mtp_weight: float = 0.0, attn_fn: Optional[AttnFn] = None):
+                    mtp_weight: float = 0.0, exit_entropy_weight: float = 0.0,
+                    attn_fn: Optional[AttnFn] = None):
     """The training loss of ``tokens (B, T + 1 + mtp_depth)``: the mean over
     the ``B * T`` positions of the cross entropy of the main head against
     ``t_{i+1}`` plus, with a multi-token-prediction module, ``mtp_weight``
     times that of the module's head against ``t_{i+2}``; logits in f32.
     ``model_state`` holds the non-parameter collections (``buffers``).
 
+    With an exit gate (``cfg.exit_gate``; Ouro, arXiv:2510.25741 section 3)
+    it is the expected loss over the exits less the entropy of where a token
+    leaves: ``mean_i [sum_r p_r(i) CE_r(i) - exit_entropy_weight H(p(i))]``,
+    ``p`` :func:`exit_distribution` of the gate's logits, ``CE_r`` the cross
+    entropy of round ``r``'s logits.  ``p`` is a function of the parameters:
+    each exit's head call takes ``p_r / (B T)`` as its rows' weights and
+    hands the gate ``CE_r`` as their gradient.
+
     No whole ``(B, T, V)`` logits and no gradient of them are made: the
     model hands back its normed hidden states and ``ops/head_loss.py`` takes
     the head's leaf (``lm_head/kernel``, or the tied ``tok/embedding``)
     through the matmul, the cross entropy and both of the head's gradients
-    a chunk of token rows at a time.  The MTP pair shares the leaf: two
-    calls, whose gradients of it add."""
+    a chunk of token rows at a time.  The MTP pair shares the leaf, as a
+    looped model's exits do: a call each, whose gradients of it add."""
     cfg = model.cfg
     depth = cfg.mtp_depth
     t = tokens.shape[1] - 1 - depth
@@ -1531,9 +1676,32 @@ def next_token_loss(model: TransformerLM, params, model_state, tokens, *,
     leaf = (params["tok"]["embedding"] if cfg.tie_head
             else params["lm_head"]["kernel"])
 
-    def cross_entropy(h, targets, site):
-        return head_loss(h, leaf, targets, tied=cfg.tie_head, site=site)
+    def cross_entropy(h, targets, site, weights=None):
+        return head_loss(h, leaf, targets, tied=cfg.tie_head, site=site,
+                         weights=weights)
 
+    if cfg.exit_gate:
+        exits, gate_logits = model.apply(variables, tokens[:, :t],
+                                         attn_fn=attn_fn, head=False)
+        with jax.named_scope("bf.loop.exit"):
+            log_p = exit_distribution(gate_logits)
+            p = jnp.exp(log_p)
+            entropy = -jnp.sum(p * log_p, axis=0).mean()
+            # read by the gauges alone: outside the differentiation, whose
+            # tracers a callback's operands may not be
+            mass = jax.lax.stop_gradient(p).mean(
+                axis=tuple(range(1, p.ndim)))
+            weights = p / p[0].size          # the mean over the positions
+            loss = -exit_entropy_weight * entropy
+        for r, h in enumerate(exits, 1):
+            with jax.named_scope(f"exit_{r}"):
+                loss = loss + cross_entropy(h, tokens[:, 1:], f"exit_{r}",
+                                            weights[r - 1])
+            loss = metrics_comm.gauge(loss, [("bf_loop_exit_mass",
+                                              mass[r - 1])], {"round": r})
+        return metrics_comm.gauge(loss, [(
+            "bf_loop_expected_rounds",
+            jnp.sum(mass * jnp.arange(1, len(exits) + 1)))])
     if not depth:
         h = model.apply(variables, tokens[:, :t], attn_fn=attn_fn, head=False)
         return cross_entropy(h, tokens[:, 1:], "main")

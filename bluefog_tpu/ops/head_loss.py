@@ -8,7 +8,11 @@ same mean cross entropy with no array of more than a chunk's rows by ``V``,
 forward or backward, and the three matmuls the whole form runs (logits,
 ``d h``, ``d w``), each once: the loss is a scalar mean, so ``d logits =
 (softmax - onehot) / rows`` is known as soon as a chunk's logits are, and the
-backward pass is left two products with the scalar cotangent.  One caller,
+backward pass is left two products with the scalar cotangent.  With
+``weights`` a row (a looped model's exits, each weighted by the probability
+the gate gives it) the same pass makes ``sum_i w_i CE_i``: ``d logits_i = w_i
+(softmax_i - onehot_i)`` is as soon known, and the cross entropy a row goes
+out beside the loss, since it is the loss's gradient in ``w_i``.  One caller,
 ``models/transformer.py::next_token_loss``.
 """
 
@@ -47,10 +51,13 @@ def chunk_rows(rows: int, vocab: int) -> int:
     return min(rows, c)
 
 
-def head_loss(h, w, targets, *, tied: bool = False, site: str = "main"):
+def head_loss(h, w, targets, *, tied: bool = False, site: str = "main",
+              weights=None):
     """The mean over all rows of the cross entropy of ``h @ w`` against
     ``targets``: ``h (..., D)`` hidden states, ``targets (...)`` ids, ``w``
     the head's leaf, ``(D, V)`` or, ``tied``, the token table ``(V, D)``.
+    With ``weights (...)``, f32, a weight a row, it is the weighted **sum**
+    ``sum_i weights_i CE_i`` (a mean is the caller's ``weights / rows``).
     Logits in f32 from the f32 operands at the default matmul precision,
     as ``nn.Dense(dtype=float32)`` makes them; the value and both gradients
     are those of ``optax.softmax_cross_entropy_with_integer_labels`` on
@@ -61,7 +68,8 @@ def head_loss(h, w, targets, *, tied: bool = False, site: str = "main"):
     loss, :func:`chunk_rows` rows at a time (one ``lax.scan`` body; a short
     last chunk, and an untied head's last chunk, runs after the loop), and
     keeps them, the size of ``h`` and of ``w``, for the backward pass to
-    scale.
+    scale.  And in ``weights``, where given: the rule keeps the rows' cross
+    entropies too (``d loss / d weights_i = CE_i``, f32, one a row).
 
     With metrics on, the gauges ``bf_head_loss_chunks`` and
     ``bf_head_loss_chunk_rows`` hold, by ``site``, what the call traced last.
@@ -70,21 +78,28 @@ def head_loss(h, w, targets, *, tied: bool = False, site: str = "main"):
     c = chunk_rows(rows, w.shape[0 if tied else 1])
     metrics_comm.set("bf_head_loss_chunks", -(-rows // c), site=site)
     metrics_comm.set("bf_head_loss_chunk_rows", c, site=site)
-    return _head_loss(h, w, targets, tied, c)
+    if weights is None:
+        return _head_loss(h, w, targets, tied, c)
+    return _weighted_head_loss(h, w, weights.astype(jnp.float32), targets,
+                               tied, c)
 
 
-def _chunked(h, w, targets, tied, c, with_grads):
-    """``(loss, d h, d w)`` of the mean cross entropy, ``c`` rows at a time;
-    the gradients ``None`` unless asked for."""
+def _chunked(h, w, targets, tied, c, with_grads, weights=None):
+    """``(loss, d h, d w, ce)`` of the mean cross entropy, ``c`` rows at a
+    time, or with ``weights`` of the weighted sum; the gradients ``None``
+    unless asked for, ``ce`` (the cross entropy a row, in ``weights``'
+    shape) ``None`` without ``weights``."""
     rows, d = math.prod(h.shape[:-1]), h.shape[-1]
     h2, t2 = h.reshape(rows, d), targets.reshape(rows)
+    weighted = weights is not None
+    w2 = weights.reshape(rows) if weighted else None
     wf = w.astype(jnp.float32)      # once, not once a chunk
     v_axis = 0 if tied else 1
 
     def contract(a, a_axis, b, b_axis):
         return lax.dot_general(a, b, (((a_axis,), (b_axis,)), ((), ())))
 
-    def chunk(dw, hc, tc):
+    def chunk(dw, hc, tc, wc=None):
         hc = hc.astype(jnp.float32)
         with jax.named_scope("bf.head.logits"):
             logits = contract(hc, 1, wf, 1 - v_axis)
@@ -93,16 +108,19 @@ def _chunked(h, w, targets, tied, c, with_grads):
             e = jnp.exp(logits - top)
             z = e.sum(axis=-1, keepdims=True)
             mine = jnp.take_along_axis(logits, tc[:, None], axis=-1)
-            loss = (jnp.log(z) + top - mine).sum()
+            ce = jnp.log(z) + top - mine
+            loss = (wc[:, None] * ce).sum() if weighted else ce.sum()
+            ce = ce[:, 0] if weighted else None
             if not with_grads:
-                return dw, loss, None
+                return dw, loss, None, ce
             hit = tc[:, None] == jnp.arange(logits.shape[-1])[None, :]
-            dlogits = (e / z - hit.astype(jnp.float32)) / rows
+            dlogits = e / z - hit.astype(jnp.float32)
+            dlogits = dlogits * wc[:, None] if weighted else dlogits / rows
         with jax.named_scope("bf.head.logits"):
             dh = contract(dlogits, 1, wf, v_axis).astype(h.dtype)
             dw = dw + (contract(dlogits, 0, hc, 0) if tied
                        else contract(hc, 0, dlogits, 0))
-        return dw, loss, dh
+        return dw, loss, dh, ce
 
     # An untied head's last chunk runs after the loop (as any short last
     # chunk does), so that the sum of ``d w`` ends in a matmul of the step's
@@ -120,29 +138,40 @@ def _chunked(h, w, targets, tied, c, with_grads):
 
     def body(carry, xs):
         dw, dh = carry
-        i, hc, tc = xs
-        dw, loss, dh_c = chunk(dw, hc, tc)
+        i, hc, tc, *wc = xs
+        dw, loss, dh_c, ce = chunk(dw, hc, tc, *wc)
         if with_grads:      # in place: the chunks' d h are never put together
             with jax.named_scope("bf.head.logits"):
                 dh = lax.dynamic_update_slice(dh, dh_c, (i * c, 0))
-        return (dw, dh), loss
+        return (dw, dh), (loss, ce)
 
-    loss = 0.0
+    loss, ces = 0.0, []     # ``ces``: the rows' cross entropies, by piece
     if n:
-        (dw, dh), losses = lax.scan(
-            body, (dw, dh),
-            (jnp.arange(n), h2[:n * c].reshape(n, c, d),
-             t2[:n * c].reshape(n, c)))
+        xs = (jnp.arange(n), h2[:n * c].reshape(n, c, d),
+              t2[:n * c].reshape(n, c))
+        if weighted:
+            xs += (w2[:n * c].reshape(n, c),)
+        (dw, dh), (losses, ce) = lax.scan(body, (dw, dh), xs)
         loss = losses.sum()
+        if weighted:
+            ces.append(ce.reshape(n * c))
     if n < chunks:
-        dw, last, dh_c = chunk(dw, h2[n * c:], t2[n * c:])
+        dw, last, dh_c, ce = chunk(dw, h2[n * c:], t2[n * c:],
+                                   *((w2[n * c:],) if weighted else ()))
         loss = loss + last
+        if weighted:
+            ces.append(ce)
         if with_grads:
             with jax.named_scope("bf.head.logits"):
                 dh = dh.at[n * c:].set(dh_c)
+    if weighted:
+        with jax.named_scope("bf.head.loss"):
+            ce = jnp.concatenate(ces).reshape(weights.shape)
+    else:
+        loss, ce = loss / rows, None
     if not with_grads:
-        return loss / rows, None, None
-    return loss / rows, dh.reshape(h.shape), dw.astype(w.dtype)
+        return loss, None, None, ce
+    return loss, dh.reshape(h.shape), dw.astype(w.dtype), ce
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
@@ -151,7 +180,7 @@ def _head_loss(h, w, targets, tied, c):
 
 
 def _forward(h, w, targets, tied, c):
-    loss, dh, dw = _chunked(h, w, targets, tied, c, True)
+    loss, dh, dw, _ = _chunked(h, w, targets, tied, c, True)
     return loss, (dh, dw)
 
 
@@ -162,3 +191,24 @@ def _backward(tied, c, gradients, g):
 
 
 _head_loss.defvjp(_forward, _backward)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5))
+def _weighted_head_loss(h, w, weights, targets, tied, c):
+    return _chunked(h, w, targets, tied, c, False, weights)[0]
+
+
+def _weighted_forward(h, w, weights, targets, tied, c):
+    loss, *gradients = _chunked(h, w, targets, tied, c, True, weights)
+    return loss, tuple(gradients)
+
+
+def _weighted_backward(tied, c, gradients, g):
+    dh, dw, ce = gradients
+    with jax.named_scope("bf.head.logits"):
+        dh, dw = (dh * g).astype(dh.dtype), (dw * g).astype(dw.dtype)
+    with jax.named_scope("bf.head.loss"):
+        return dh, dw, ce * g, None
+
+
+_weighted_head_loss.defvjp(_weighted_forward, _weighted_backward)

@@ -240,3 +240,118 @@ def test_the_gauges_say_how_each_site_was_chunked(small_chunks):
     for site in ("main", "mtp"):
         assert snap[f'bf_head_loss_chunks{{site="{site}"}}'] == 7   # 210 rows
         assert snap[f'bf_head_loss_chunk_rows{{site="{site}"}}'] == 32
+
+
+# ---- weights a row: the expected loss over a looped model's exits -----------
+
+def weighted_operands(lead, tied):
+    weights = jax.random.uniform(jax.random.PRNGKey(7), lead) / math.prod(lead)
+    return (*operands(lead, tied), weights)
+
+
+def whole_weighted(h, w, targets, weights, tied):
+    h = h.astype(jnp.float32)
+    logits = jnp.einsum("...d,vd->...v", h, w) if tied else h @ w
+    ce = -jnp.take_along_axis(jax.nn.log_softmax(logits), targets[..., None],
+                              axis=-1)[..., 0]
+    return jnp.sum(weights * ce)
+
+
+@pytest.mark.parametrize("tied", [False, True], ids=["untied", "tied"])
+@pytest.mark.parametrize("lead,chunks", [
+    ((2, 16), 1), ((4, 64), 4), ((5, 67), 6), ((1, 7), 1)],
+    ids=["one", "many", "ragged", "short"])
+def test_weighted_value_and_three_gradients_are_whole_logits_arithmetic(
+        lead, chunks, tied, small_chunks):
+    """``sum_i w_i CE_i`` with ``d h``, ``d w`` and ``d weights = CE``."""
+    h, w, targets, weights = weighted_operands(lead, tied)
+    assert -(-math.prod(lead) // hl.chunk_rows(math.prod(lead), V)) == chunks
+    got = jax.jit(jax.value_and_grad(
+        lambda h, w, x: hl.head_loss(h, w, targets, tied=tied, weights=x),
+        argnums=(0, 1, 2)))(h, w, weights)
+    want = jax.jit(jax.value_and_grad(
+        lambda h, w, x: whole_weighted(h, w, targets, x, tied),
+        argnums=(0, 1, 2)))(h, w, weights)
+    np.testing.assert_allclose(got[0], want[0], rtol=1e-6)
+    for g, w_ in zip(got[1], want[1]):
+        assert g.shape == w_.shape and g.dtype == w_.dtype
+        np.testing.assert_allclose(g, w_, rtol=1e-5, atol=1e-7)
+    alone = jax.jit(lambda h, w: hl.head_loss(
+        h, w, targets, tied=tied, weights=weights))(h, w)
+    np.testing.assert_allclose(alone, want[0], rtol=1e-6)
+
+
+@pytest.mark.parametrize("tied", [False, True], ids=["untied", "tied"])
+def test_weights_of_one_over_the_rows_give_the_mean(tied, small_chunks):
+    h, w, targets = operands((4, 64), tied)
+    rows = jnp.full((4, 64), 1.0 / 256)
+    got = jax.jit(jax.value_and_grad(lambda h, w: hl.head_loss(
+        h, w, targets, tied=tied, weights=rows), argnums=(0, 1)))(h, w)
+    want = jax.jit(jax.value_and_grad(lambda h, w: hl.head_loss(
+        h, w, targets, tied=tied), argnums=(0, 1)))(h, w)
+    np.testing.assert_allclose(got[0], want[0], rtol=1e-6)
+    for g, w_ in zip(got[1], want[1]):
+        np.testing.assert_allclose(g, w_, rtol=1e-5, atol=1e-8)
+
+
+@pytest.mark.parametrize("tied", [False, True], ids=["untied", "tied"])
+def test_the_cotangent_scales_the_weights_gradient_too(tied, small_chunks):
+    h, w, targets, weights = weighted_operands((2, 96), tied)
+
+    def grads(f):
+        return jax.grad(lambda h, w, x: 0.3 * f(h, w, x) ** 2,
+                        argnums=(0, 1, 2))(h, w, weights)
+
+    got = grads(lambda h, w, x: hl.head_loss(h, w, targets, tied=tied,
+                                             weights=x))
+    want = grads(lambda h, w, x: whole_weighted(h, w, targets, x, tied))
+    for g, w_ in zip(got, want):
+        np.testing.assert_allclose(g, w_, rtol=1e-5, atol=1e-7)
+
+
+@pytest.mark.parametrize("tied", [False, True], ids=["untied", "tied"])
+def test_the_weighted_gradient_holds_no_array_above_a_chunk_by_v(tied):
+    """At 4,096 rows of a 40,000-row vocabulary (four chunks of 1,024): no
+    array of ``rows x V`` elements anywhere, and the head's three
+    contractions once a chunk, as without weights."""
+    vocab, rows = 40_000, 4096
+    assert hl.chunk_rows(rows, vocab) == 1024
+    h = jnp.zeros((2, 2048, 16))
+    w = jnp.zeros((vocab, 16) if tied else (16, vocab))
+    targets = jnp.zeros((2, 2048), jnp.int32)
+    weights = jnp.full((2, 2048), 1.0 / rows)
+
+    def f(weights=None):
+        return jax.make_jaxpr(jax.grad(
+            lambda h, w: hl.head_loss(h, w, targets, tied=tied,
+                                      weights=weights),
+            argnums=(0, 1)))(h, w).jaxpr
+
+    largest = max(math.prod(v.aval.shape) for e in equations(f(weights))
+                  for v in e.outvars if hasattr(v.aval, "shape"))
+    assert 1024 * vocab <= largest < rows * vocab // 2
+
+    def dots(jaxpr):
+        return sum(e.primitive.name == "dot_general" for e in equations(jaxpr))
+
+    assert dots(f(weights)) == dots(f()) == (3 if tied else 6)
+
+
+@pytest.mark.parametrize("tied", [False, True], ids=["untied", "tied"])
+def test_without_weights_the_program_is_the_one_it_was(tied):
+    """The unweighted call's jaxpr names no weight: the same equations as
+    a call that never heard of them (``_chunked`` with ``weights=None``
+    traces what it traced)."""
+    h, w, targets, weights = weighted_operands((4, 64), tied)
+
+    def text(**kw):
+        return str(jax.make_jaxpr(jax.value_and_grad(
+            lambda h, w: hl.head_loss(h, w, targets, tied=tied, **kw),
+            argnums=(0, 1)))(h, w))
+
+    plain = text()
+    assert plain == text(weights=None)
+    assert plain != text(weights=weights)
+    # a division by the row count, no multiplication by a weight's column
+    assert "div" in plain and plain.count("mul") < text(
+        weights=weights).count("mul")

@@ -994,3 +994,34 @@ def test_ungated_grouped_matmuls_1856_wide_compile_for_v5e(tpu_aot_topology):
     shapes = [g.shape for g in jax.tree_util.tree_leaves(
         compiled.out_info[1])]
     assert shapes[2:] == [(8, 2688, 1856), (8, 1856, 2688)]
+
+
+def test_the_looped_step_is_one_round_s_code_and_fits_a_chip(monkeypatch):
+    """``ouro.t4096.solo``'s step compiled for a v5e: every leaf once in
+    the arguments whatever the rounds (6.84 GiB of state); the rounds a
+    loop, so one round's block passes are compiled (the attention kernel's
+    forward call 8 + 8 times in the text, its fused backward 8) and the
+    code stays near the size the chip machine's compile cache has kept for
+    ``joyai`` (242 MiB) where the unrolled rounds were 863 MiB and never
+    cached; four exits' heads; the temporaries under what leaves the
+    agreement check its room beside 6.84 + 2.28 GiB (PERF.md section 6,
+    PR 51: the runtime reserves less than ``temp_size_in_bytes`` says)."""
+    compiled = _compile_cell_step("ouro.t4096.solo", monkeypatch)
+    txt = compiled.as_text()
+    memory = compiled.memory_analysis()
+    assert 6.8 * 2 ** 30 < memory.argument_size_in_bytes < 6.9 * 2 ** 30
+    assert memory.temp_size_in_bytes < 7.8 * 2 ** 30, (
+        memory.temp_size_in_bytes)
+    assert memory.generated_code_size_in_bytes < 250 * 2 ** 20, (
+        memory.generated_code_size_in_bytes)
+    forward = len(_re.findall(r"%(?:flash_attention|splash_mha_fwd)\S* = ",
+                              txt))
+    backward = len(_re.findall(r"%(?:flash_mha_bwd|splash_mha_dkv)\S* = ",
+                               txt))
+    assert (forward, backward) == (16, 8), (forward, backward)
+    assert "bf.loop.round/" in txt
+    for r in range(1, 5):
+        assert _re.search(rf"exit_{r}\)?/bf\.head\.logits", txt)
+    whole = [line.strip()[:200] for line in txt.splitlines()
+             if _re.search(r"f32\[(1,)?4096,49152\]", line)]
+    assert not whole, whole[:3]
