@@ -34,7 +34,19 @@ bluefog/run/ (bfrun launcher)                   bluefog_tpu.runtime.launch
 ==============================================  =================================
 """
 
+import sys as _sys
+import time as _time
+
+_import_t0, _import_modules = _time.time(), len(_sys.modules)
+
+# the record of this process's start: it times this file's imports a
+# subpackage (bf.setup.import.<name>), in the order they stand here
+from bluefog_tpu.tracing import startup as _startup
+
+_import = _startup.ImportSpan(_import_t0, _import_modules)
+_import.lap("tracing")
 from bluefog_tpu import topology
+_import.lap("topology")
 from bluefog_tpu.parallel.context import (
     init,
     shutdown,
@@ -85,6 +97,7 @@ from bluefog_tpu.parallel.api import (
     synchronize,
     wait_all_host_ops,
 )
+_import.lap("parallel")
 from bluefog_tpu.utils import (
     timeline_start,
     timeline_stop,
@@ -94,8 +107,14 @@ from bluefog_tpu.utils import (
 )
 from bluefog_tpu.utils.checkpoint import CheckpointManager, run_with_restart
 from bluefog_tpu.utils.compile_cache import configure_compile_cache
+_import.lap("utils")
 from bluefog_tpu import metrics
 from bluefog_tpu.metrics import metrics_active, metrics_start, metrics_stop
+_import.lap("metrics")
 from bluefog_tpu import blackbox
+_import.lap("blackbox")
 
 __version__ = "0.1.0"
+
+_import.close()
+del _import, _import_t0, _import_modules

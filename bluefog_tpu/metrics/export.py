@@ -207,7 +207,11 @@ def _summarize(writer: MetricsWriter) -> None:
     if reg is None:
         return
     _drain_effects()
-    snap = reg.snapshot()
+    from bluefog_tpu.tracing import startup
+
+    # the start's counters (bf_setup_*) ride the summary line: they are kept
+    # from the import on, before any registry could be switched on
+    snap = {**reg.snapshot(), **startup.RECORD.counter_series()}
     writer.write({"summary": True, "time": time.time(), "metrics": snap})
     from bluefog_tpu.utils import log
 

@@ -15,6 +15,8 @@ from typing import Any, Callable, Sequence, Tuple
 import flax.linen as nn
 import jax.numpy as jnp
 
+from bluefog_tpu.tracing import startup
+
 ModuleDef = Any
 
 
@@ -102,6 +104,7 @@ class BottleneckBlock(nn.Module):
     strides: Tuple[int, int] = (1, 1)
 
     @nn.compact
+    @startup.spanned("bf.setup.trace.block", "bottleneck")
     def __call__(self, x):
         residual = x
         y = self.conv(self.filters, (1, 1))(x)

@@ -161,6 +161,7 @@ from bluefog_tpu.ops.row_sums import take_rows
 from bluefog_tpu.ops.selective_scan import selective_scan
 from bluefog_tpu.ops.short_conv import gated_short_conv, silu_short_conv
 from bluefog_tpu.ops.ssd import ssd
+from bluefog_tpu.tracing import startup
 
 AttnFn = Callable[..., jnp.ndarray]  # (q, k, v) -> (B, T, H, D)
 
@@ -1449,6 +1450,8 @@ class Block(nn.Module):
     layer: int = 0                   # published index (differential lambda)
 
     @nn.compact
+    @startup.spanned("bf.setup.trace.block",
+                     lambda block: block.mixer or block.cfg.attention)
     def __call__(self, x, attn_fn: AttnFn, positions=None, carried=None):
         cfg = self.cfg
         if self.mixer == FEED_FORWARD:

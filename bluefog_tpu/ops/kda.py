@@ -80,6 +80,7 @@ import jax.numpy as jnp
 from jax import lax
 
 from bluefog_tpu.metrics import comm as metrics_comm
+from bluefog_tpu.tracing import startup
 
 __all__ = ["kda", "LOWER"]
 
@@ -407,6 +408,7 @@ def _pallas_fwd(q, k, kb, v, decay, heads, interpret):
     d_k, d_v, chunks = q.shape[2] // heads, v.shape[2] // heads, t // CHUNK
     block = _heads_a_step(heads)
     keyed, valued, inverse, states = _specs(block, d_k, d_v, lambda j: j)
+    startup.kernel_traced("bf_kda_fwd")
     return pl.pallas_call(
         _fwd_kernel, grid=(b, heads // block, chunks),
         in_specs=[keyed, keyed, keyed, valued, keyed],
@@ -431,6 +433,7 @@ def _pallas_bwd(q, k, kb, v, decay, starts, inverses, do, heads, interpret):
     block = _heads_a_step(heads)
     keyed, valued, inverse, states = _specs(block, d_k, d_v,
                                             lambda j: chunks - 1 - j)
+    startup.kernel_traced("bf_kda_bwd_chunks")
     return tuple(pl.pallas_call(
         _bwd_kernel, grid=(b, heads // block, chunks),
         in_specs=[keyed, keyed, keyed, valued, keyed, valued, states,

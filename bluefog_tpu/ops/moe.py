@@ -49,6 +49,7 @@ from jax import lax
 
 from bluefog_tpu.metrics import comm as metrics_comm
 from bluefog_tpu.ops import row_sums
+from bluefog_tpu.tracing import startup
 
 __all__ = [
     "RouterOutput",
@@ -383,6 +384,7 @@ def _select_in_vmem(scores, values, k, n_group, topk_group, interpret):
         lax.fori_loop(0, tt // _LANES, one_slab, jnp.int32(0))
 
     block = pl.BlockSpec((e, tt), lambda i: (0, i))
+    startup.kernel_traced("bf_moe_select")
     idx, chosen = pl.pallas_call(
         kernel, grid=(_ceil_div(t, tt),),
         in_specs=[block] * (1 if own else 2),

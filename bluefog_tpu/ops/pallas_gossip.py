@@ -47,6 +47,7 @@ import numpy as np
 from jax import lax
 
 from bluefog_tpu.topology.schedule import GossipSchedule
+from bluefog_tpu.tracing import startup
 
 __all__ = [
     "is_pallas_supported",
@@ -414,6 +415,7 @@ def deliver_pallas(
         mask = jnp.asarray(sched.recv_src >= 0, jnp.int32)[i].reshape(1, -1)
 
     kernel = _make_exchange_kernel(shifts, n, axis_name, accumulate)
+    startup.kernel_traced("window_deliver")
     # no name=: in a device trace the kernel is ``shard_map.N``, the pattern
     # the benchmark's gossip_kernel_ms_per_step matches
     out_bufs = pl.pallas_call(

@@ -20,6 +20,7 @@ import jax.numpy as jnp
 from jax import lax
 
 from bluefog_tpu.metrics import comm as metrics_comm
+from bluefog_tpu.tracing import startup
 
 __all__ = ["sums_tile", "add_rows_at", "take_rows"]
 
@@ -179,6 +180,7 @@ def add_rows_at(acc, index, live, rows, w, *, name: str,
             pl.when(tile < tiles - 1)(lambda: sum_tile(ts))
             pl.when(tile == tiles - 1)(lambda: sum_tile(tail))
 
+    startup.kernel_traced(name)
     return pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(

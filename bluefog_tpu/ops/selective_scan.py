@@ -48,6 +48,7 @@ import jax.numpy as jnp
 from jax import lax
 
 from bluefog_tpu.metrics import comm as metrics_comm
+from bluefog_tpu.tracing import startup
 
 __all__ = ["selective_scan"]
 
@@ -359,6 +360,7 @@ def _pallas_fwd(x, delta, a, b, c, d, chunk, interpret):
                            memory_space=pltpu.SMEM)
     tokens = pl.BlockSpec((None, chunk, sub, _LANES),
                           lambda i, j, k: (i, j, k, 0))
+    startup.kernel_traced("bf_selective_scan_fwd")
     m, starts = pl.pallas_call(
         functools.partial(_fwd_kernel, chunk=chunk, states=states),
         grid=(batch, chunks, tiles // sub),
@@ -403,6 +405,7 @@ def _pallas_bwd(x, delta, a, b, c, d, starts, dm, chunk, interpret):
     partials = pl.BlockSpec((None, chunk, states, _LANES),
                             lambda i, j, k: (i, back(j), 0, 0))
     state_block = (tiles // sub, states, sub, _LANES)
+    startup.kernel_traced("bf_selective_scan_bwd")
     dx, ddt, dbp, dcp, da, dd = pl.pallas_call(
         functools.partial(_bwd_kernel, chunk=chunk, states=states, sub=sub),
         grid=(batch, chunks, tiles // sub),

@@ -90,6 +90,8 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
+from bluefog_tpu.tracing import startup
+
 __all__ = ["gated_short_conv", "silu_short_conv"]
 
 BACKENDS = ("auto", "xla", "pallas", "pallas_interpret")
@@ -340,6 +342,7 @@ def _forward(bcz, kernel, interpret):
     taps = kernel.shape[0]
     tile_of, before, _, taps_of, (tt, dc, blocks) = _specs(
         t, d, lambda bi, ti, ci: (bi, ti, ci))
+    startup.kernel_traced("bf_sconv_fwd")
     return pl.pallas_call(
         functools.partial(_fwd_kernel, taps=taps),
         grid=(batch, t // tt, blocks),
@@ -363,6 +366,7 @@ def _backward(bcz, kernel, g, interpret):
         t, d, lambda ci, bi, ti, third: (bi, ti, ci))
     d_bcz = pl.BlockSpec((1, tt, dc), lambda ci, bi, ti, third: (
         bi, ti, third * blocks + ci))
+    startup.kernel_traced("bf_sconv_bwd")
     return pl.pallas_call(
         functools.partial(_bwd_kernel, taps=taps, tiles=t // tt),
         grid=(blocks, batch, t // tt, 3),
@@ -520,6 +524,7 @@ def _silu_forward(x, taps_bias, scale, offset, interpret, norm):
         functools.partial(_silu_fwd_kernel, taps=taps, norm=norm), scale,
         [taps_of(taps + 1), tile_of(0, True), before(0, True)],
         (taps_bias, x, x))
+    startup.kernel_traced("bf_cconv_fwd")
     return pl.pallas_call(
         kernel,
         grid=(batch, t // tt, blocks),
@@ -546,6 +551,7 @@ def _silu_backward(x, taps_bias, scale, g, offset, interpret, norm):
         [taps_of(taps + 1), tile_of(0, True), before(0, True),
          after(0, True), tile_of(0), after(0)],
         (taps_bias, x, x, x, g, g))
+    startup.kernel_traced("bf_cconv_bwd")
     d_x, d_taps_bias = pl.pallas_call(
         kernel,
         grid=(blocks, batch, t // tt),

@@ -69,6 +69,8 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
+from bluefog_tpu.tracing import startup
+
 __all__ = ["ssd", "CHUNK"]
 
 BACKENDS = ("auto", "chunked", "pallas", "pallas_interpret")
@@ -407,6 +409,7 @@ def _pallas_fwd(x, dt, cum, b, c, skip, groups, p, interpret):
 
     bsz, chunks, hg, n, slabs, w = _sizes(x, dt, b, groups, p)
     wide, narrow, heads, skips, states = _specs(hg, p, n, slabs, lambda j: j)
+    startup.kernel_traced("bf_ssd_fwd")
     return pl.pallas_call(
         functools.partial(_fwd_kernel, p), grid=(bsz, groups, chunks),
         in_specs=[wide, narrow, narrow, heads, heads, skips],
@@ -431,6 +434,7 @@ def _pallas_bwd(x, dt, cum, b, c, skip, starts, dy, groups, p, interpret):
     # a group's skip gradient, summed over its chunks in the resident block
     d_skips = pl.BlockSpec((None, None, 1, hg * p),
                            lambda i, g, j: (i, g, 0, 0))
+    startup.kernel_traced("bf_ssd_bwd")
     d_x, d_b, d_c, d_dt, d_cum, d_skip = pl.pallas_call(
         functools.partial(_bwd_kernel, p), grid=(bsz, groups, chunks),
         in_specs=[wide, narrow, narrow, heads, heads, skips, wide, states],

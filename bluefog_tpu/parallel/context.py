@@ -27,6 +27,7 @@ import numpy as np
 from bluefog_tpu.topology.graphs import ExponentialTwoGraph, Topology
 from bluefog_tpu.topology.mapping import ici_ring_order
 from bluefog_tpu.topology.schedule import GossipSchedule, build_schedule
+from bluefog_tpu.tracing import startup
 from bluefog_tpu.utils import log
 
 __all__ = [
@@ -107,6 +108,7 @@ class BluefogContext:
 _CTX: Optional[BluefogContext] = None
 
 
+@startup.spanned("bf.setup.init", "init")
 def init(
     *,
     topology: Optional[Topology] = None,
@@ -134,8 +136,10 @@ def init(
     import jax
     from jax.sharding import Mesh
 
+    startup.RECORD.look_at_backend()
     if devices is None:
         devices = jax.devices()
+        startup.RECORD.look_at_backend()
     devices = list(devices)
     if use_ici_order:
         devices = ici_ring_order(devices)

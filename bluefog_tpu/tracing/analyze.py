@@ -37,12 +37,14 @@ import argparse
 import glob
 import json
 import os
+import re
+import sys
 from collections import defaultdict
 from typing import Dict, List, Optional, Tuple
 
 __all__ = ["load_traces", "build_graph", "edge_report", "critical_path",
            "overlap_report", "round_report", "analyze", "chrome_trace",
-           "main"]
+           "startup_report", "format_startup", "main"]
 
 #: client-side phases of one deposit batch, in pipeline order
 CLIENT_PHASES = ("snapshot", "enqueue", "coalesce", "wire", "ack_wait")
@@ -404,6 +406,242 @@ def analyze(directory: str, *, spans: Optional[List[dict]] = None
 
 
 # ---------------------------------------------------------------------------
+# A process's start (``bftrace-tpu startup``)
+# ---------------------------------------------------------------------------
+
+_SETUP = "bf.setup."
+_STAGES = (_SETUP + "trace", _SETUP + "lower", _SETUP + "compile")
+_SERIES = re.compile(r'^(\w+)(?:\{\w+="(.*)"\})?$')
+
+
+def _union_s(intervals) -> float:
+    """Seconds covered by any of the ``(start, end)`` pairs."""
+    total, covered_to = 0.0, float("-inf")
+    for start, end in sorted(intervals):
+        if end > covered_to:
+            total += end - max(start, covered_to)
+            covered_to = end
+    return total
+
+
+def startup_report(spans: List[dict], until: Optional[str] = None
+                   ) -> List[dict]:
+    """The start of each process whose record
+    (:mod:`bluefog_tpu.tracing.startup`) is among ``spans``, from its
+    ``bf.setup.import`` span's start to the end of its last span — or, with
+    ``until``, to **the cut**: the end of the first compile of that program
+    (``jit(f)``); ``until`` reads ``None`` in the report of a process that
+    never compiled it.  Every span is clipped to the interval.  This is
+    the one place the start's arithmetic is done: the benchmark's reducer
+    (``chipbench/reducers/startup_spans.py``) reads its seven values here.
+
+    - ``covered_s``: seconds covered by the spans of each name (their
+      union: an inner jitted function's trace lies inside the outer one and
+      is not counted twice), and ``unspanned_s``: the interval less the
+      union of every span;
+    - ``by_stage``: **self time** by span name (a span's duration less
+      what its children cover);
+    - ``programs``: seconds of trace, lowering and compile by program,
+      with the cache's verdict on the compile and its read seconds;
+    - ``blocks``: the model's blocks by kind — calls, seconds, self time;
+    - ``counters``: the ``bf_setup_*`` counters as they stood at the cut
+      (the ``counters`` the last compile span in the interval carries), and
+      from them ``cache`` (hits, misses, read seconds), ``programs_counted``
+      by stage and ``kernels``: Pallas kernels by name with the times
+      Python traced them — and the seconds of those traces
+      (``pl.pallas_call`` traces a kernel's body in a jitted function of
+      its own, ``wrapped``: the span of JAX's that follows the kernel's
+      instant under the same parent);
+    - ``imports``: the import's children in order — seconds and
+      ``modules_loaded``; ``import_at_age_s``: how old the process was when
+      the import began (``process_t0`` is on the spans' clock), so the
+      interval can be laid on the axis ``setup_s`` is measured on;
+      ``dropped``: spans the bounded record did not keep."""
+    processes: Dict[Tuple, List[dict]] = defaultdict(list)
+    for sp in spans:
+        if sp.get("name", "").startswith(_SETUP) and not sp.get("open"):
+            processes[(sp.get("rank"), sp.get("pid"))].append(sp)
+    reports = []
+    for (rank, pid), own in sorted(processes.items(), key=str):
+        notes = [s for s in own if s["name"] == _SETUP + "record"]
+        timed = [s for s in own if s["name"] != _SETUP + "record"]
+        if not timed:
+            continue
+        imports = [s for s in timed if s["name"] == _SETUP + "import"]
+        start = min(float(s["t0"]) for s in imports or timed)
+        cuts = [_end(s) for s in timed if until is not None
+                and s["name"] == _SETUP + "compile" and s["cat"] == until]
+        cut = min(cuts) if cuts else max(_end(s) for s in timed)
+        inside = sorted((s for s in timed if start <= float(s["t0"]) <= cut),
+                        key=lambda s: (float(s["t0"]), -float(s["dur"])))
+
+        def clipped(sp):
+            return float(sp["t0"]), min(_end(sp), cut)
+
+        children: Dict[int, list] = defaultdict(list)
+        for sp in inside:
+            if sp.get("par"):
+                children[sp["par"]].append(clipped(sp))
+        by_stage: Dict[str, float] = defaultdict(float)
+        covered: Dict[str, list] = defaultdict(list)
+        programs: Dict[str, dict] = defaultdict(dict)
+        blocks: Dict[str, dict] = {}
+        kernel_s: Dict[str, float] = defaultdict(float)
+        traced_at: Dict[Tuple, str] = {}    # (thread, parent) -> kernel
+        counters: Dict[str, float] = {}
+        by_sid = {s["sid"]: s for s in inside}
+
+        def inside_another(sp):
+            """Whether a trace or a lowering lies inside another span of
+            JAX's (an inner jitted function's): not a program of its own."""
+            par = by_sid.get(sp.get("par"))
+            while par is not None and par["name"] not in _STAGES:
+                par = by_sid.get(par.get("par"))
+            return par is not None
+
+        for sp in inside:
+            t0, t1 = clipped(sp)
+            self_s = t1 - t0 - _union_s(children.get(sp["sid"], []))
+            name = sp["name"][len(_SETUP):]
+            by_stage[name] += self_s
+            covered[name].append((t0, t1))
+            if name == "trace.block":
+                b = blocks.setdefault(sp["cat"], {"calls": 0, "seconds": 0.0,
+                                                  "self_s": 0.0})
+                b["calls"] += 1
+                b["seconds"] += t1 - t0
+                b["self_s"] += self_s
+            elif name == "trace.kernel":
+                traced_at[sp.get("thread"), sp.get("par")] = sp["cat"]
+            elif name == "trace" and sp["cat"] == "wrapped" and (
+                    sp.get("thread"), sp.get("par")) in traced_at:
+                kernel_s[traced_at.pop((sp.get("thread"), sp.get("par")))
+                         ] += t1 - t0
+            elif sp["name"] in _STAGES and (name == "compile"
+                                            or not inside_another(sp)):
+                program = sp["cat"] if sp["cat"].startswith(
+                    ("jit(", "pmap(")) else f"jit({sp['cat']})"
+                entry = programs[program]
+                entry[name] = entry.get(name, 0.0) + t1 - t0
+                if name == "compile" and _end(sp) <= cut:
+                    entry["cache"] = sp.get("cache")
+                    entry["cache_read_s"] = float(
+                        sp.get("cache_read_s") or 0.0)
+                    counters = sp.get("counters") or counters
+        counted: Dict[str, Dict[str, float]] = defaultdict(dict)
+        for series, value in counters.items():
+            name, label = _SERIES.match(series).groups()
+            counted[name][label] = value
+        total = "bf_setup_cache_{}_total".format
+        process_t0 = next((n["process_t0"] for n in notes
+                           if n.get("process_t0") is not None), None)
+        reports.append({
+            "rank": rank, "pid": pid, "until": until if cuts else None,
+            "interval_s": cut - start, "spans": len(inside),
+            "dropped": max((n.get("dropped", 0) for n in notes), default=0),
+            "import_at_age_s": None if process_t0 is None
+            else start - process_t0,
+            "imports": [{"name": s["name"][len(_SETUP + "import."):],
+                         "seconds": float(s["dur"]),
+                         "modules_loaded": s.get("modules_loaded")}
+                        for s in inside
+                        if s["name"].startswith(_SETUP + "import.")],
+            "covered_s": {name: _union_s(pairs)
+                          for name, pairs in covered.items()},
+            "unspanned_s": cut - start - _union_s(map(clipped, inside)),
+            "by_stage": dict(by_stage), "programs": dict(programs),
+            "blocks": blocks, "counters": counters,
+            "programs_counted": counted["bf_setup_programs_total"],
+            "kernels": {kernel: {"traces": traces,
+                                 "seconds": kernel_s.get(kernel, 0.0)}
+                        for kernel, traces in counted[
+                            "bf_setup_kernel_traces_total"].items()},
+            "cache": {"hits": counted[total("hits")].get(None, 0.0),
+                      "misses": counted[total("misses")].get(None, 0.0),
+                      "read_s": counted[total("read_seconds")].get(
+                          None, 0.0)},
+        })
+    return reports
+
+
+def format_startup(rep: dict) -> str:
+    """One process's report of :func:`startup_report`, as text."""
+    def secs(items, n=8):
+        top = sorted(items, key=lambda kv: -kv[1])[:n]
+        return ", ".join(f"{k} {v:.2f}s" for k, v in top)
+
+    who = f"rank {rep['rank']}" if rep["rank"] is not None else "no rank"
+    lines = [
+        f"bftrace startup: {who} pid {rep['pid']}: {rep['interval_s']:.2f}s "
+        f"from import to " + (f"the first compile of {rep['until']}"
+                              if rep["until"] else "the last span")
+        + f" ({rep['spans']} spans, {rep['dropped']} dropped)"
+        + (f"; the import began at process age "
+           f"{rep['import_at_age_s']:.2f}s"
+           if rep["import_at_age_s"] is not None else ""),
+        "  covered by stage: " + secs(
+            (kv for kv in rep["covered_s"].items() if "." not in kv[0]), 16),
+        "  self time by stage: " + secs(rep["by_stage"].items(), 16)]
+    if rep["imports"]:
+        lines.append("  imports: " + ", ".join(
+            f"{i['name']} {i['seconds']:.2f}s (+{i['modules_loaded']} "
+            "modules)" for i in rep["imports"]))
+    lines.append("  programs counted: " + ", ".join(
+        f"{stage} {n:g}" for stage, n in sorted(
+            rep["programs_counted"].items())))
+    totals = {p: sum(e.get(st, 0.0) for st in ("trace", "lower", "compile"))
+              for p, e in rep["programs"].items()}
+    for program, _ in sorted(totals.items(), key=lambda kv: -kv[1])[:8]:
+        entry = rep["programs"][program]
+        lines.append(f"  program {program}: " + ", ".join(
+            f"{st} {entry[st]:.2f}s" for st in ("trace", "lower", "compile")
+            if st in entry) + (
+                f" (cache {entry['cache']}, {entry['cache_read_s']:.2f}s "
+                "reading)" if entry.get("cache") else ""))
+    if rep["blocks"]:
+        lines.append("  blocks: " + ", ".join(
+            f"{kind} x{b['calls']} {b['seconds']:.2f}s (self "
+            f"{b['self_s']:.2f}s)" for kind, b in sorted(
+                rep["blocks"].items(), key=lambda kv: -kv[1]["seconds"])))
+    if rep["kernels"]:
+        lines.append("  kernels traced: " + ", ".join(
+            f"{name} x{k['traces']:g} {k['seconds']:.2f}s" for name, k in
+            sorted(rep["kernels"].items(),
+                   key=lambda kv: -kv[1]["seconds"])))
+    c = rep["cache"]
+    lines.append(f"  cache: {c['hits']:g} hit(s), {c['misses']:g} miss(es), "
+                 f"{c['read_s']:.2f}s reading")
+    lines.append(f"  no span owns: {rep['unspanned_s']:.2f}s ("
+                 + _pct(rep["unspanned_s"] / rep["interval_s"]
+                        if rep["interval_s"] else 0.0) + ")")
+    return "\n".join(lines)
+
+
+def _startup_main(argv: List[str]) -> int:
+    ap = argparse.ArgumentParser(
+        prog="bftrace-tpu startup",
+        description="Where a process's start went: the spans and counters "
+        "bluefog_tpu keeps from `import bluefog_tpu` on, written beside the "
+        "job's rounds when BLUEFOG_TPU_TRACE is set")
+    ap.add_argument("trace_dir")
+    ap.add_argument("--until", default=None, metavar="PROGRAM",
+                    help="end the start at the first compile of this "
+                    "program, e.g. 'jit(train_step)' (default: the last "
+                    "span of the record)")
+    ap.add_argument("--json", action="store_true")
+    args = ap.parse_args(argv)
+    reports = startup_report(load_traces(args.trace_dir), args.until)
+    if not reports:
+        print(f"bftrace: no bf.setup.* spans under {args.trace_dir}")
+        return 1
+    if args.json:
+        print(json.dumps(reports, indent=2, default=str))
+    else:
+        print("\n".join(format_startup(rep) for rep in reports))
+    return 0
+
+
+# ---------------------------------------------------------------------------
 # Chrome trace export
 # ---------------------------------------------------------------------------
 
@@ -507,6 +745,9 @@ def _format_report(rep: dict, directory: str) -> str:
 
 
 def main(argv: Optional[List[str]] = None) -> int:
+    argv = sys.argv[1:] if argv is None else list(argv)
+    if argv[:1] == ["startup"]:
+        return _startup_main(argv[1:])
     ap = argparse.ArgumentParser(
         prog="bftrace-tpu",
         description="Merge per-rank trace JSONL, reconstruct the "

@@ -25,6 +25,11 @@ _CHECKOUT = os.path.dirname(os.path.dirname(os.path.dirname(
 
 def configure_compile_cache() -> str:
     """Point JAX at the persistent compile cache; returns the directory."""
+    from bluefog_tpu.tracing import startup
+
+    # every entry point calls this just before its first device query: the
+    # start's record brackets the runtime's start-up from here
+    startup.RECORD.look_at_backend()
     placed = os.environ.get("JAX_COMPILATION_CACHE_DIR")
     if placed:
         return placed

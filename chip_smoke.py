@@ -66,27 +66,20 @@ def require_tpu(devices) -> None:
             "the chip: python chip_smoke.py")
 
 
-class CompileClock:
-    """Sums JAX's own backend-compile durations (cache lookups included), so
-    each phase can report its compile seconds apart from its run time."""
+def compile_seconds():
+    """JAX's own backend-compile durations so far (cache lookups included),
+    from the record the program keeps of its start."""
+    from bluefog_tpu.tracing import startup
 
-    def __init__(self):
-        import jax
-
-        self.total = 0.0
-        jax.monitoring.register_event_duration_secs_listener(self._on_event)
-
-    def _on_event(self, event, duration, **_):
-        if event == "/jax/core/compile/backend_compile_duration":
-            self.total += duration
+    return startup.RECORD.stage_seconds["compile"]
 
 
-def phase(name, clock, fn):
+def phase(name, fn):
     print(f"chip_smoke: [{name}] start", flush=True)
-    c0, t0 = clock.total, time.perf_counter()
+    c0, t0 = compile_seconds(), time.perf_counter()
     fn()
     print(f"chip_smoke: [{name}] ok  wall_s={time.perf_counter() - t0:.1f} "
-          f"compile_s={clock.total - c0:.1f}", flush=True)
+          f"compile_s={compile_seconds() - c0:.1f}", flush=True)
 
 
 def run_trainer():
@@ -272,16 +265,15 @@ def main():
           f"{native.load() is not None} compile_cache={cache_dir}",
           flush=True)
 
-    clock = CompileClock()
-    t0 = time.perf_counter()
-    phase("trainer", clock, run_trainer)
+    c0, t0 = compile_seconds(), time.perf_counter()
+    phase("trainer", run_trainer)
     if device["count"] > 1:
-        phase("gossip", clock, run_gossip)
-    phase("gpt", clock, run_gpt)
-    phase("flash", clock, run_flash)
+        phase("gossip", run_gossip)
+    phase("gpt", run_gpt)
+    phase("flash", run_flash)
     print(f"chip_smoke: all phases ok  wall_s="
-          f"{time.perf_counter() - t0:.1f} compile_s={clock.total:.1f}",
-          flush=True)
+          f"{time.perf_counter() - t0:.1f} "
+          f"compile_s={compile_seconds() - c0:.1f}", flush=True)
     faulthandler.cancel_dump_traceback_later()
     print(json.dumps({"ok": True, "device": device}), flush=True)
 
